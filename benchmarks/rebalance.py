@@ -55,7 +55,7 @@ def _spawn(port: int, http_port: int, cfgpath: str, self_id: str,
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO] + env.get("PYTHONPATH", "").split(os.pathsep))
     env["JAX_PLATFORMS"] = "cpu"
-    env["RATELIMITER_TPU_COMPILE_CACHE"] = ""
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     # limit 100 / window 600: the admission oracle needs counters that
     # outlive the whole EWMA-settle + apply + verify sequence.
     argv = [sys.executable, "-m", "ratelimiter_tpu.serving",

@@ -54,7 +54,7 @@ def _spawn(port: int, cfgpath: str, self_id: str, snap: str,
     env["JAX_PLATFORMS"] = "cpu"
     # Private jit compiles: shared persistent-cache reads can abort
     # XLA-CPU when the handoff compiles new shapes mid-serving.
-    env["RATELIMITER_TPU_COMPILE_CACHE"] = ""
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     argv = [sys.executable, "-m", "ratelimiter_tpu.serving",
             "--backend", "sketch", "--limit", "1000000",
             "--window", "60", "--sketch-width", "16384",

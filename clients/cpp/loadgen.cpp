@@ -80,6 +80,11 @@ inline uint64_t splitmix64(uint64_t x) {
 struct Shared {
   std::atomic<uint64_t> completed{0};
   std::atomic<uint64_t> allowed{0};
+  // Replies that are not decisions: decisions answered by the server's
+  // fail-open policy (flag bit 1), and typed error frames. A run in
+  // which either is nonzero was not (only) decided by the limiter.
+  std::atomic<uint64_t> fail_open{0};
+  std::atomic<uint64_t> error_frames{0};
   std::atomic<uint64_t> ser_ns{0};       // serialize phase, timed window
   std::atomic<uint64_t> wire_ns{0};      // wire-write phase, timed window
   std::atomic<uint64_t> timed_frames{0};
@@ -433,6 +438,7 @@ void worker(const char* host, int port, int inflight, int frame_keys,
   char tmp[65536];
   std::vector<double> local_lat;
   uint64_t local_completed = 0, local_allowed = 0;
+  uint64_t local_fail_open = 0, local_errors = 0;
   while (now_s() < sh->t_stop) {
     if (use_shm) {
       if (!shm_recv(&shm, &rbuf, sh->t_stop)) break;
@@ -449,7 +455,10 @@ void worker(const char* host, int port, int inflight, int frame_keys,
       uint8_t type = (uint8_t)rbuf[off + 4];
       uint64_t rid;
       memcpy(&rid, rbuf.data() + off + 5, 8);
-      if (type == rltpu::T_RESULT_BATCH || type == rltpu::T_RESULT_HASHED) {
+      bool is_result =
+          type == rltpu::T_RESULT_BATCH || type == rltpu::T_RESULT_HASHED;
+      if (type == rltpu::T_ERROR) ++local_errors;
+      if (is_result) {
         const char* body = rbuf.data() + off + 13;
         uint32_t count;
         // RESULT_BATCH: i64 limit | u32 count | 25B items.
@@ -457,6 +466,13 @@ void worker(const char* host, int port, int inflight, int frame_keys,
         // columnar i64/f64/f64.
         bool h = type == rltpu::T_RESULT_HASHED;
         memcpy(&count, body + (h ? 9 : 8), 4);
+        // Policy answers count over the whole run, warmup included.
+        if (h) {
+          if ((uint8_t)body[0] & 2) local_fail_open += count;
+        } else {
+          for (uint32_t i = 0; i < count; ++i)
+            local_fail_open += ((uint8_t)body[12 + i * 25] >> 1) & 1;
+        }
         double t1 = now_s();
         bool timed = t1 >= sh->t_measure;
         if (timed) {
@@ -473,6 +489,10 @@ void worker(const char* host, int port, int inflight, int frame_keys,
           double t0 = sent_at[rid % sent_at.size()];
           if (t0 > 0) local_lat.push_back(t1 - t0);
         }
+      }
+      // An error frame answers a request too: keep the window full.
+      if (is_result || type == rltpu::T_ERROR) {
+        bool timed = now_s() >= sh->t_measure;
         if (now_s() < sh->t_stop) {
           double ts0 = now_s();
           double t;
@@ -497,6 +517,8 @@ void worker(const char* host, int port, int inflight, int frame_keys,
   close(fd);
   sh->completed.fetch_add(local_completed);
   sh->allowed.fetch_add(local_allowed);
+  sh->fail_open.fetch_add(local_fail_open);
+  sh->error_frames.fetch_add(local_errors);
   sh->ser_ns.fetch_add(local_ser_ns);
   sh->wire_ns.fetch_add(local_wire_ns);
   sh->timed_frames.fetch_add(local_timed);
@@ -571,14 +593,18 @@ int main(int argc, char** argv) {
   const char* trs = tr == TR_SHM ? "shm" : (tr == TR_UDS ? "uds" : "tcp");
   std::printf(
       "{\"decisions_per_sec\": %.1f, \"completed\": %llu, "
-      "\"allowed\": %llu, \"frame_p50_ms\": %.2f, \"frame_p99_ms\": %.2f, "
+      "\"allowed\": %llu, \"fail_open\": %llu, \"error_frames\": %llu, "
+      "\"frame_p50_ms\": %.2f, \"frame_p99_ms\": %.2f, "
       "\"threads\": %d, \"inflight_frames\": %d, \"keys_per_frame\": %d, "
       "\"mode\": \"%s\", \"affine_shards\": %d, \"spread\": %d, "
       "\"transport\": \"%s\", \"serialize_us_per_frame\": %.3f, "
       "\"wire_write_us_per_frame\": %.3f}\n",
       (double)sh.completed.load() / span,
       (unsigned long long)sh.completed.load(),
-      (unsigned long long)sh.allowed.load(), pct(0.50), pct(0.99), threads,
+      (unsigned long long)sh.allowed.load(),
+      (unsigned long long)sh.fail_open.load(),
+      (unsigned long long)sh.error_frames.load(), pct(0.50), pct(0.99),
+      threads,
       inflight, frame_keys, hashed ? "hashed" : "batch", affine, spread, trs,
       ser_us, wire_us);
   return 0;

@@ -99,7 +99,7 @@ def spawn(port, cfgpath, self_id, snap):
     # Private jit compiles: the shared persistent cache can hold torn
     # entries (kill -9 tests) and aborts XLA-CPU when the handoff
     # compiles new shapes mid-serving.
-    env["RATELIMITER_TPU_COMPILE_CACHE"] = ""
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO] + env.get("PYTHONPATH", "").split(os.pathsep))
     return subprocess.Popen(

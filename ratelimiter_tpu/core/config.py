@@ -68,14 +68,14 @@ class SketchParams:
     #: exported via /metrics and healthz (docs/OPERATIONS.md §3).
     overload_policy: str = "warn"
     #: Hot-loop kernel implementation (ADR-011):
-    #:   "auto"   (default) fused Pallas kernels on TPU backends (when the
-    #:            geometry fits the VMEM budget and no heavy-hitter side
-    #:            table is configured), the jnp/XLA reference path
-    #:            everywhere else;
-    #:   "pallas" force the fused kernels — on non-TPU backends they run
-    #:            in Pallas interpret mode (the CI parity lane), which is
+    #:   "auto"   (default) the jnp/XLA path on every platform: no fused
+    #:            kernel is selected until one has been compiled by
+    #:            Mosaic and raced on a chip (ADR-011 addendum);
+    #:   "pallas" force the fused kernels — off-TPU they run in Pallas
+    #:            interpret mode (the CI parity lane), which is
     #:            bit-identical but slow: a correctness tool, not a
-    #:            serving configuration;
+    #:            serving configuration; on a TPU it is rejected when
+    #:            the limiter is built (Mosaic refuses the kernels);
     #:   "jnp"    force the XLA reference path (the pre-ADR-011 kernels,
     #:            kept as the parity oracle).
     #: Decisions are bit-identical across the three (tier-1 enforced by

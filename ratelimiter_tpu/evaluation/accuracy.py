@@ -10,9 +10,9 @@ semantics is impossible in that configuration. With
 ``conservative_update=True`` (the flagship bench config) the guarantee is
 weaker: CU writes raise a cell only to the largest single-key target, so a
 cell can undercount colliding traffic once boundary slabs holding part of a
-CU write expire — a small, *measured* false-allow risk (BENCH_r02:
-``false_allow_rate_vs_oracle ~= 2e-8``), traded for a large false-deny
-reduction. Allow-where-oracle-denied events therefore combine that CU
+CU write expire — a small false-allow risk (its rate: not measured on
+this round's code; ``false_allow_rate_vs_oracle`` reports it per run),
+traded for a large false-deny reduction. Allow-where-oracle-denied events therefore combine that CU
 effect with the *semantic* difference between sub-window-ring sliding and
 the reference's two-window weighting; the three-way comparison separates
 the CMS-error component from the semantic component.

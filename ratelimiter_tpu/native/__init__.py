@@ -5,10 +5,10 @@ string keys into u64 hashes at ingest (SURVEY.md §7.4 hard part #4). The
 reference pays a Redis round-trip per key so its host cost never shows; at
 10M+ decisions/s ours does, so hashing is native:
 
-* ``hasher.cpp``   — the C++ kernel, built into ``_hasher.so`` by make
-                     (or automatically, once, on first import when a
-                     compiler is present — exactly the role a prebuilt
-                     wheel would play);
+* ``hasher.cpp``   — the C++ kernel, built into ``_hasher.so`` on first
+                     use and again whenever its bytes change
+                     (``build.py``: the binary carries a hash of its
+                     source);
 * ``fallback.py``  — bit-identical vectorized NumPy twin for hosts with no
                      compiler;
 * this module      — packing (Python strings -> one contiguous byte buffer
@@ -21,14 +21,14 @@ call through ctypes — zero copies beyond the unavoidable UTF-8 encode.
 from __future__ import annotations
 
 import ctypes
+import functools
 import os
-import subprocess
-import sysconfig
 import threading
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from ratelimiter_tpu.native.build import load_extension
 from ratelimiter_tpu.native.fallback import hash_packed_numpy
 
 DEFAULT_SEED = 0x52_4C_54_50_55_31  # "RLTPU1"
@@ -38,83 +38,33 @@ _SO = os.path.join(_DIR, "_hasher.so")
 _SRC = os.path.join(_DIR, "hasher.cpp")
 _ABI = 2
 
-_lock = threading.Lock()
-_lib: Optional[ctypes.CDLL] = None
-_mod = None  # the CPython extension module (hash_keylist lives here)
-_tried = False
+
+_load_lock = threading.Lock()
 
 
-def _try_build() -> bool:
-    """One-shot best-effort build of the extension (g++ in the image)."""
-    try:
-        inc = sysconfig.get_paths()["include"]
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", f"-I{inc}",
-             "-o", _SO, _SRC],
-            check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+def _load() -> Optional[tuple]:
+    """(ctypes handle, CPython extension module) of a ``_hasher.so``
+    built from this checkout's hasher.cpp (native/build.py), or None on a
+    host that cannot build it — the NumPy twin serves there. Compiler and
+    loader errors propagate. Serialized: the native door's dispatcher
+    threads reach their first hash together."""
+    with _load_lock:
+        return _load_once()
 
 
-def _check_abi(lib: ctypes.CDLL) -> bool:
-    lib.rl_hasher_abi_version.restype = ctypes.c_int64
-    return lib.rl_hasher_abi_version() == _ABI
-
-
-def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _mod, _tried
-    if _lib is not None or _tried:
-        return _lib
-    with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        try:
-            if not os.path.exists(_SO) and os.environ.get(
-                    "RATELIMITER_TPU_NO_BUILD") != "1":
-                _try_build()
-            if not os.path.exists(_SO):
-                return None
-            lib = ctypes.CDLL(_SO)
-            mod_path = _SO
-            if not _check_abi(lib):
-                # Stale binary from an older algorithm; rebuild once. dlopen
-                # caches by pathname — asking for _SO again would hand back
-                # the still-mapped stale object — so the fresh build is
-                # copied to and loaded from a distinct per-process name.
-                os.remove(_SO)
-                if not _try_build():
-                    return None
-                import shutil
-
-                mod_path = os.path.join(_DIR, f"_hasher_r{os.getpid()}.so")
-                shutil.copy2(_SO, mod_path)
-                lib = ctypes.CDLL(mod_path)
-                if not _check_abi(lib):
-                    return None
-            lib.rl_bulk_hash_u64.restype = None
-            lib.rl_bulk_hash_u64.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_uint64, ctypes.c_void_p, ctypes.c_int64,
-            ]
-            # The same .so is also a CPython extension module exposing the
-            # list fast path; load it from the SAME file the ctypes handle
-            # came from (spec_from_file_location derives PyInit__hasher
-            # from the final name component, so the temp name is fine).
-            import importlib.util
-
-            spec = importlib.util.spec_from_file_location(
-                "ratelimiter_tpu.native._hasher", mod_path)
-            _hasher = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(_hasher)
-
-            _mod = _hasher
-            _lib = lib
-        except Exception:
-            _lib = None
-            _mod = None
-        return _lib
+@functools.lru_cache(maxsize=None)
+def _load_once() -> Optional[tuple]:
+    loaded = load_extension(
+        _SO, [_SRC], module="ratelimiter_tpu.native._hasher",
+        abi_symbol="rl_hasher_abi_version", abi=_ABI, opt="-O3")
+    if loaded is not None:
+        # The ctypes face; the module face carries hash_keylist.
+        loaded[0].rl_bulk_hash_u64.restype = None
+        loaded[0].rl_bulk_hash_u64.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_uint64, ctypes.c_void_p, ctypes.c_int64,
+        ]
+    return loaded
 
 
 def native_available() -> bool:
@@ -150,9 +100,10 @@ def pack_keys(keys: Sequence[str]) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
 def hash_packed(buf: np.ndarray, offsets: np.ndarray, lengths: np.ndarray,
                 seed: int = DEFAULT_SEED) -> np.ndarray:
     """Hash a packed batch; native kernel when available, NumPy twin else."""
-    lib = _load()
-    if lib is None:
+    loaded = _load()
+    if loaded is None:
         return hash_packed_numpy(buf, offsets, lengths, seed)
+    lib = loaded[0]
     n = offsets.shape[0]
     out = np.empty(n, dtype=np.uint64)
     if n:
@@ -172,11 +123,12 @@ def bulk_hash_u64(keys: Sequence[str], seed: int = DEFAULT_SEED) -> np.ndarray:
     Fast path: the CPython extension iterates the list directly (zero-copy
     UTF-8 views, no Python-level packing). Fallback: pack + NumPy twin.
     """
-    _load()
-    if _mod is not None:
+    loaded = _load()
+    if loaded is not None:
         if not isinstance(keys, list):
             keys = list(keys)
         out = np.empty(len(keys), dtype=np.uint64)
-        _mod.hash_keylist(keys, seed & 0xFFFFFFFFFFFFFFFF, out.ctypes.data)
+        loaded[1].hash_keylist(keys, seed & 0xFFFFFFFFFFFFFFFF,
+                               out.ctypes.data)
         return out
     return hash_packed(*pack_keys(keys), seed=seed)

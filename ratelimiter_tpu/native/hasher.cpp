@@ -86,6 +86,14 @@ void rl_bulk_hash_u64(const uint8_t* buf, const int64_t* offsets,
 // algorithm changes.
 int64_t rl_hasher_abi_version() { return 2; }
 
+// SHA-256 of the sources this object was built from (native/build.py
+// passes it in and reads the marker back out of the file's bytes to
+// decide whether the binary on disk belongs to this checkout).
+#ifndef RL_SRC_HASH
+#define RL_SRC_HASH "unhashed"
+#endif
+const char* rl_hasher_src_hash() { return "RL_SRC_HASH:" RL_SRC_HASH; }
+
 }  // extern "C"
 
 // ------------------------------------------------------------------ module

@@ -3512,6 +3512,12 @@ extern "C" {
 // C ABI probe so the loader can verify the build (native/__init__ pattern).
 int64_t rl_server_abi_version() { return 13; }
 
+// SHA-256 of server.cpp + shm_ring.h as built (see hasher.cpp).
+#ifndef RL_SRC_HASH
+#define RL_SRC_HASH "unhashed"
+#endif
+const char* rl_server_src_hash() { return "RL_SRC_HASH:" RL_SRC_HASH; }
+
 PyMODINIT_FUNC PyInit__server(void) {
   PyServerType.tp_name = "ratelimiter_tpu.native._server.Server";
   PyServerType.tp_basicsize = sizeof(PyServer);

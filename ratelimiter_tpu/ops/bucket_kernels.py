@@ -321,7 +321,7 @@ def build_steps(cfg: Config) -> Tuple[Callable, Callable]:
     ensure_x64()
     limit, num, den, d, w, iters = _params(cfg)
     tenants, wus = _hier_params(cfg)
-    use_pallas = _resolve_pallas(cfg, bucket=True)
+    use_pallas = _resolve_pallas(cfg)
     key = (limit, num, den, d, w, iters, tenants, wus, use_pallas)
     cached = _STEP_CACHE.get(key)
     if cached is not None:
@@ -361,7 +361,7 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     ensure_x64()
     limit, num, den, d, w, iters = _params(cfg)
     tenants, wus = _hier_params(cfg)
-    use_pallas = _resolve_pallas(cfg, bucket=True)
+    use_pallas = _resolve_pallas(cfg)
     seed = cfg.sketch.seed
     key = (limit, num, den, d, w, iters, tenants, wus, use_pallas, seed,
            premix)
@@ -384,7 +384,7 @@ def build_scan(cfg: Config) -> Callable:
 
     ensure_x64()
     limit, num, den, d, w, iters = _params(cfg)
-    use_pallas = _resolve_pallas(cfg, bucket=True)
+    use_pallas = _resolve_pallas(cfg)
     key = (limit, num, den, d, w, iters, use_pallas)
     cached = _SCAN_CACHE.get(key)
     if cached is not None:

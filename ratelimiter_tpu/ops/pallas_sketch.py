@@ -35,13 +35,12 @@ lowering), already TPU-shaped, and SHARED with the reference path — which
 is also how bit-identity of the decision logic is maintained. The fused
 kernels bracket it: fused read -> admit -> fused write.
 
-Backend handling: on non-TPU backends every kernel runs in Pallas
-interpret mode (bit-identical, slow — the CI parity lane and the
-``kernels="pallas"`` fallback everywhere). The bucket kernels operate on
-the int64 debt slab; Mosaic has no 64-bit vector path today, so the auto
-selector never picks them on real TPUs (ops resolve_kernels) — forcing
-``kernels="pallas"`` for a bucket limiter on a TPU is a parity tool, not
-a serving configuration.
+Backend handling: off-TPU every kernel runs in Pallas interpret mode
+(bit-identical, slow) — the CPU parity lane, which is all these kernels
+have ever run as. On a TPU nothing reaches them: Mosaic refuses all five
+(MOSAIC_REFUSAL), so ``auto`` resolves to the jnp path on every platform
+and ``kernels="pallas"`` is rejected at config resolution
+(resolve_kernels). Kernels Mosaic accepts are ROADMAP S3/D5.
 """
 
 from __future__ import annotations
@@ -52,18 +51,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on every backend; guard for exotic builds
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
 from ratelimiter_tpu.core.errors import InvalidConfigError
+from ratelimiter_tpu.core.jaxcfg import on_tpu
 
-#: Auto-selector VMEM budget for one (d, w) int32 slab: each fused kernel
-#: holds up to three row blocks plus batch vectors resident; geometries
-#: past this fall back to the jnp path rather than risk a VMEM OOM at
-#: compile time (docs/OPERATIONS.md, `kernels` row).
-AUTO_VMEM_SLAB_BYTES = 4 << 20
+#: Why no configuration reaches these kernels on a TPU: what the Pallas
+#: TPU lowering of jax 0.9.0 answers when they are lowered with
+#: interpret=False (tests/test_pallas_parity.py re-checks the first refusal).
+MOSAIC_REFUSAL = (
+    "the Pallas TPU lowering refuses the fused sketch kernels: their "
+    "(1, w) row blocks over a (d, w) table ('the last two dimensions of "
+    "your block shape [must be] divisible by 8 and 128'), the in-kernel "
+    "1-D gather ('Only 2D gather is supported'), scatter-max/scatter-add "
+    "('Unimplemented primitive in Pallas TPU lowering') and the bucket "
+    "kernels' int64 debt vectors")
 
 #: Debt-cell clamp, mirrored from ops/bucket_kernels._DEBT_CAP (importing
 #: it would be circular: bucket_kernels imports this module).
@@ -72,38 +72,33 @@ _DEBT_CAP = 1 << 61
 
 def _interpret() -> bool:
     """Interpret mode off-TPU: same numerics, no Mosaic requirement."""
-    return jax.default_backend() not in ("tpu",)
+    return not on_tpu()
 
 
-def resolve_kernels(cfg, *, bucket: bool = False) -> str:
+def resolve_kernels(cfg) -> str:
     """Resolve cfg.sketch.kernels to a concrete choice ("pallas"|"jnp").
 
-    auto: pallas on TPU backends when the geometry fits the VMEM budget,
-    no heavy-hitter side table is configured, and (for the windowed
-    sketch) the slabs are int32; the int64 debt slab keeps auto on jnp
-    for bucket limiters on real TPUs (no Mosaic 64-bit vector path).
-    Forcing "pallas" with hh_slots raises — the side table's private-cell
-    reads are not fused (ADR-011 §limits).
+    auto: the jnp path on every platform — no fused kernel has been
+    compiled by Mosaic, let alone raced on a chip, so none is selected
+    (a kernel that earns a regime gets it here, from what the code can
+    observe: platform, batch, width). "pallas" off-TPU is the interpret
+    parity lane; on a TPU it raises with the compiler's reason instead
+    of a lowering traceback at the first dispatch, and never runs
+    interpreted or gives way to the reference silently. Forcing "pallas"
+    with hh_slots raises — the side table's private-cell reads are not
+    fused (ADR-011 §limits).
     """
-    choice = cfg.sketch.kernels
-    if choice == "jnp":
+    if cfg.sketch.kernels != "pallas":
         return "jnp"
-    hh = cfg.sketch.hh_slots
-    if choice == "pallas":
-        if hh:
-            raise InvalidConfigError(
-                "kernels='pallas' does not support the heavy-hitter side "
-                "table (hh_slots > 0); use kernels='jnp' for hh configs")
-        return "pallas"
-    # auto
-    if hh:
-        return "jnp"
-    if jax.default_backend() != "tpu":
-        return "jnp"
-    if bucket:
-        return "jnp"  # int64 debt slab: no Mosaic 64-bit vector path
-    if cfg.sketch.depth * cfg.sketch.width * 4 > AUTO_VMEM_SLAB_BYTES:
-        return "jnp"
+    if cfg.sketch.hh_slots:
+        raise InvalidConfigError(
+            "kernels='pallas' does not support the heavy-hitter side "
+            "table (hh_slots > 0); use kernels='jnp' for hh configs")
+    if on_tpu():
+        raise InvalidConfigError(
+            f"kernels='pallas' cannot serve on a TPU: {MOSAIC_REFUSAL}. "
+            f"Use kernels='auto' or 'jnp'; off-TPU 'pallas' runs the "
+            f"interpret-mode parity lane")
     return "pallas"
 
 

@@ -229,7 +229,7 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
         tenants_, wus = bucket_kernels._hier_params(cfg)
         from ratelimiter_tpu.ops.sketch_kernels import _resolve_pallas
 
-        use_pallas = _resolve_pallas(cfg, bucket=True)
+        use_pallas = _resolve_pallas(cfg)
         statics = (limit, num, den, d, w, iters, tenants_, wus, use_pallas)
         step_kw = dict(limit=limit, rate_num=num, rate_den=den, d=d, w=w,
                        iters=iters, tenants=tenants_, window_us=wus,

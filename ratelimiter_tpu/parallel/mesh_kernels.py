@@ -26,25 +26,12 @@ from functools import partial
 from typing import Callable, Dict, Tuple
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.ops import sketch_kernels
 from ratelimiter_tpu.parallel.mesh import AXIS
-
-# jax >= 0.8 exposes top-level shard_map with the check_vma kwarg; older
-# releases ship it under jax.experimental with the same semantics behind a
-# check_rep kwarg. The thin adapter below maps one onto the other so the
-# mesh tier (and its CI runs) work on both.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma)
 
 MERGE_MODES = ("gather", "delta")
 

@@ -24,6 +24,7 @@ host encode/decode instead of the old launch→block→serialize lockstep.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Optional
@@ -43,61 +44,24 @@ from ratelimiter_tpu.serving import protocol as p
 _ABI = 13
 
 
+@functools.lru_cache(maxsize=None)
 def _load_extension():
-    """Build/load native/_server.so (same auto-build + stale-rebuild
-    pattern as the hasher; returns None when no compiler is available)."""
-    import ctypes
+    """The ``native/_server.so`` extension module, built from this
+    checkout's server.cpp + shm_ring.h (native/build.py decides
+    staleness by source hash), or None on a host that cannot build it.
+    Compiler and loader errors propagate."""
     import os
-    import subprocess
-    import sysconfig
 
-    d = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    so = os.path.join(d, "native", "_server.so")
-    src = os.path.join(d, "native", "server.cpp")
+    from ratelimiter_tpu.native.build import load_extension
 
-    def build() -> bool:
-        if os.environ.get("RATELIMITER_TPU_NO_BUILD") == "1":
-            return False
-        try:
-            inc = sysconfig.get_paths()["include"]
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", f"-I{inc}",
-                 "-o", so, src],
-                check=True, capture_output=True, timeout=180)
-            return True
-        except Exception:
-            return False
-
-    if not os.path.exists(so) and not build():
-        return None
-    if not os.path.exists(so):
-        return None
-    try:
-        lib = ctypes.CDLL(so)
-        lib.rl_server_abi_version.restype = ctypes.c_int64
-        mod_path = so
-        if lib.rl_server_abi_version() != _ABI:
-            # Stale build: rebuild and load under a per-process name
-            # (dlopen caches by pathname — see native/__init__.py).
-            os.remove(so)
-            if not build():
-                return None
-            import shutil
-
-            mod_path = os.path.join(d, "native", f"_server_r{os.getpid()}.so")
-            shutil.copy2(so, mod_path)
-            lib = ctypes.CDLL(mod_path)
-            if lib.rl_server_abi_version() != _ABI:
-                return None
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "ratelimiter_tpu.native._server", mod_path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-    except Exception:
-        return None
+    d = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
+    loaded = load_extension(
+        os.path.join(d, "_server.so"),
+        [os.path.join(d, "server.cpp"), os.path.join(d, "shm_ring.h")],
+        module="ratelimiter_tpu.native._server",
+        abi_symbol="rl_server_abi_version", abi=_ABI)
+    return loaded[1] if loaded is not None else None
 
 
 def native_server_available() -> bool:
@@ -159,8 +123,9 @@ class NativeRateLimitServer:
         ext = _load_extension()
         if ext is None:
             raise RuntimeError(
-                "native server extension unavailable (no g++?); use the "
-                "asyncio RateLimitServer")
+                "native server extension unavailable: no g++ here (or "
+                "RATELIMITER_TPU_NO_BUILD=1) and no _server.so built "
+                "from these sources; use the asyncio RateLimitServer")
         if inflight < 1:
             raise ValueError(f"inflight must be >= 1, got {inflight}")
         self.limiter = limiter

@@ -587,7 +587,7 @@ def _spawn_member(port, cfgpath, self_id, snap, extra=()):
     # mid-serving — concurrent/torn cache reads abort XLA-CPU
     # (observed SIGSEGV/SIGABRT ~10%). Fleet members here compile
     # privately instead.
-    env["RATELIMITER_TPU_COMPILE_CACHE"] = ""
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO] + env.get("PYTHONPATH", "").split(os.pathsep))
     argv = [sys.executable, "-m", "ratelimiter_tpu.serving",

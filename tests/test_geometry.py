@@ -47,9 +47,10 @@ class TestForLoad:
             assert p.mass_budget(100) >= mass
 
     def test_config3_literal_geometry_is_declared_undersized(self):
-        """The BASELINE config-3 literal geometry (d=4 w=65536) measured
-        46.6% false denies at saturation (RESULTS_r03). Its budget must
-        declare saturation mass (~100M admitted) far out of envelope."""
+        """The BASELINE config-3 literal geometry (d=4 w=65536) is far too
+        narrow for saturation traffic (its false-deny rate there: not
+        measured on this round's code). Its budget must declare
+        saturation mass (~100M admitted) far out of envelope."""
         literal = SketchParams(depth=4, width=65536)
         assert literal.mass_budget(100) < 100_000_000 / 5
 

@@ -333,7 +333,7 @@ class TestGrpcServer:
             assert not stub.DeleteTenant(
                 pb2.DeleteTenantRequest(name="gold")).deleted
             # Unknown tenant on assign -> INVALID_ARGUMENT (core error
-            # taxonomy, same as every other surface).
+            # classes, same as every other surface).
             with pytest.raises(grpc.RpcError) as ei:
                 stub.AssignTenant(pb2.AssignTenantRequest(
                     key="k", tenant="nope"))

@@ -201,3 +201,24 @@ def test_kernels_knob_excluded_from_fingerprint():
     assert (config_fingerprint(_cfg("pallas"))
             == config_fingerprint(_cfg("jnp"))
             == config_fingerprint(_cfg("auto")))
+
+
+def test_mosaic_still_refuses_the_fused_kernels(monkeypatch):
+    """Why resolve_kernels keeps every TPU off these kernels
+    (pallas_sketch.MOSAIC_REFUSAL): lowered for a TPU with interpret
+    mode off, the first of them is refused at trace time. When a JAX
+    upgrade or a kernel rewrite makes this pass, the selection rule is
+    due for review (ROADMAP S3/D5)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ratelimiter_tpu.ops import pallas_sketch
+
+    monkeypatch.setattr(pallas_sketch, "_interpret", lambda: False)
+    d, w, B = 4, 65536, 4096
+    table = jax.ShapeDtypeStruct((d, w), jnp.int32)
+    h = jax.ShapeDtypeStruct((B,), jnp.uint32)
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        jax.jit(pallas_sketch.window_estimate).trace(
+            table, table, jnp.float32(0.5), h, h).lower(
+                lowering_platforms=("tpu",))
