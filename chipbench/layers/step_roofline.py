@@ -1,0 +1,25 @@
+"""The device step's share of its roofline: the bytes one dispatch must
+move (chipbench/bytes.py) over the peak HBM rate (chipbench/peaks.json),
+divided by the device time one dispatch took. Bound by bytes."""
+
+from chipbench import bytes as need
+from chipbench.layers import closed_loop, device_us_per_dispatch
+from chipbench.layers import dispatch_batch_mean
+
+META = {"name": "step_roofline", "unit": "%", "better": "higher",
+        "layer": "device step", "moves": "decisions_per_s",
+        "source": "device_trace", "applies": closed_loop}
+
+
+def read(sources: dict):
+    step_us = device_us_per_dispatch.read(sources)
+    batch = dispatch_batch_mean.read(sources)
+    peaks, trace = sources.get("peaks"), sources.get("trace")
+    if not step_us or not batch or not peaks:
+        return None
+    # Executions are counted over every device plane; a slice rotates its
+    # own ring, so the rotations are shared by one device's dispatches.
+    per_s = (trace["step"]["executions"] / trace["n_devices"]
+             / trace["window_s"])
+    must = need.step_bytes(sources["cell"]["config"], batch, per_s)
+    return 100.0 * (must / peaks["hbm_bytes_per_s"]) / (step_us * 1e-6)

@@ -1,0 +1,582 @@
+"""One run of one cell: ``python -m chipbench --workload <name> --seed <n>
+--seconds <s> --trace <0|1>``.
+
+The runner never imports JAX. It builds the load generator once per
+checkout, starts ONE server child (chipbench/serve_child.py: the
+program's ``python -m ratelimiter_tpu.serving --port 0 <server_flags>``
+unchanged, with the chip as its device), reads the device from the server's banner and
+refuses anything but a TPU, probes correctness, warms up with the cell's
+own traffic, measures, checks again, stops the child and prints the
+result object as the last line of stdout.
+
+``JAX_PLATFORMS=cpu`` set by the caller makes the run a rehearsal: the
+configuration's and the mix's ``rehearsal`` overrides (a tiny geometry),
+counts and no rate, no result line, exit code 3.
+
+Exit codes: 0 a result line was printed; 1 the run broke; 2 no TPU (or
+fewer chips than the cell asks for), or not a checkout of the program;
+3 a rehearsal passed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import hashlib
+import json
+import math
+import os
+import re
+import select
+import shutil
+import signal
+import subprocess
+import sys
+import threading
+import time
+import urllib.request
+
+from chipbench import layers, probe, promtext
+from chipbench.layers import prewarm_s
+from chipbench.wire import Wire
+
+T_SPAWN = time.monotonic()
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+REHEARSAL = os.environ.get("JAX_PLATFORMS") == "cpu"
+PROFILE_S = 1.0 if REHEARSAL else 5.0   # the traced stretch of the window
+TRACED_MIN_S = 16.0        # a traced window holds profiler start-up + 5 s
+WARMUP_S = 3.0             # the cell's own traffic, counted as set-up
+DRAIN_S = 2.0              # open loop: a frame unanswered by then has failed
+
+#: Every field of a traffic file, with its default.
+TRAFFIC_DEFAULTS = {
+    "lane": "hashed",          # hashed (ALLOW_HASHED u64 ids) | string (ALLOW_BATCH "user:<id>")
+    "frame_keys": 4096,        # decisions per frame
+    "connections": 4,
+    "loop": "closed",          # closed | open
+    "inflight": 4,             # closed loop: frames in flight per connection
+    "rate": None,              # open loop: decisions per second, all connections
+    "arrival": "poisson",      # poisson | uniform
+    "zipf_s": 1.1,             # 0 = uniform
+    "cost_n": 1,               # the n of every decision (a cost MIX is not implemented)
+    "burst_on_s": 0.0,         # on/off bursts: not implemented, must stay 0
+    "burst_off_s": 0.0,
+    "affine_spread": 0,        # slice-affine connections: not implemented, must stay 0
+    "rehearsal": {},           # overrides under JAX_PLATFORMS=cpu
+}
+_NOT_IMPLEMENTED = ("burst_on_s", "burst_off_s", "affine_spread")
+
+_BANNER = re.compile(
+    r"device=(?P<platform>\w+)/(?P<kind>.+?) x(?P<count>\d+) "
+    r"kernels=(?P<kernels>\w+) slice_devices=(?P<slices>[\d,+]+)")
+_PORT = re.compile(r" on \S+:(\d+) ")
+_HTTP = re.compile(r" http:(\d+)")
+
+
+class RunFailure(Exception):
+    """The run cannot give a result."""
+
+
+class NoChip(RunFailure):
+    """The server's device is not what the cell asks for."""
+
+
+def say(kind: str, **fields) -> None:
+    """An earlier line of stdout: one JSON object, ``kind`` first."""
+    print(json.dumps({"line": kind, **fields}), flush=True)
+
+
+# ------------------------------------------------------------ the manifest
+
+def load_cell(workload: str, root: str = ROOT) -> dict:
+    """The cell as data: its entry of BENCHMARK.json, its configuration
+    file and its traffic file (defaults filled in, rehearsal overrides
+    applied under JAX_PLATFORMS=cpu)."""
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        manifest = json.load(fh)
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    if workload not in cells:
+        raise RunFailure(f"no workload {workload!r} in BENCHMARK.json "
+                         f"(have {sorted(cells)})")
+    entry = cells[workload]
+    cfg_file = {c["name"]: c["file"] for c in manifest["configs"]}[entry["config"]]
+    with open(os.path.join(root, cfg_file)) as fh:
+        config = json.load(fh)
+    with open(os.path.join(root, "chipbench", "traffic",
+                           entry["traffic"] + ".json")) as fh:
+        given = json.load(fh)
+    unknown = set(given) - set(TRAFFIC_DEFAULTS) - {"why"}
+    if unknown:
+        raise RunFailure(f"traffic file {entry['traffic']}: unknown fields "
+                         f"{sorted(unknown)}")
+    traffic = {**TRAFFIC_DEFAULTS, **given}
+    if REHEARSAL:
+        traffic.update(traffic["rehearsal"])
+        config = {**config, **config.get("rehearsal", {})}
+    for field in _NOT_IMPLEMENTED:
+        if traffic[field]:
+            raise RunFailure(f"traffic field {field!r} is not implemented by "
+                             f"chipbench/loadgen yet")
+    if traffic["loop"] == "open" and not traffic["rate"]:
+        raise RunFailure("an open-loop mix needs a rate")
+    return {"name": workload, "chips": entry["chips"], "config": config,
+            "traffic": traffic, "config_name": entry["config"],
+            "traffic_name": entry["traffic"], "manifest": manifest}
+
+
+def cell_metrics(cell: dict, group: str) -> list:
+    """The manifest's metrics of ``group`` that this cell reports."""
+    return [m for m in cell["manifest"][group]
+            if "workloads" not in m or cell["name"] in m["workloads"]]
+
+
+# ----------------------------------------------------------- the generator
+
+def build_loadgen(root: str = ROOT) -> tuple:
+    """(binary, seconds spent building): chipbench/.build/<sha256 of the
+    sources>/loadgen, compiled once per checkout."""
+    src_dir = os.path.join(root, "chipbench", "loadgen")
+    sources = sorted(f for f in os.listdir(src_dir)
+                     if f.endswith((".cpp", ".hpp")))
+    digest = hashlib.sha256()
+    for name in sources:
+        with open(os.path.join(src_dir, name), "rb") as fh:
+            digest.update(name.encode() + b"\0" + fh.read() + b"\0")
+    out_dir = os.path.join(root, "chipbench", ".build", digest.hexdigest())
+    binary = os.path.join(out_dir, "loadgen")
+    if os.path.exists(binary):
+        return binary, 0.0
+    if shutil.which("g++") is None:
+        raise RunFailure("no g++ on this host")
+    t0 = time.monotonic()
+    os.makedirs(out_dir, exist_ok=True)
+    tmp = f"{binary}.{os.getpid()}.tmp"
+    done = subprocess.run(
+        ["g++", "-O2", "-std=c++17", os.path.join(src_dir, "loadgen.cpp"),
+         "-o", tmp, "-pthread"], capture_output=True, text=True, timeout=300)
+    if done.returncode != 0:
+        raise RunFailure(f"loadgen.cpp did not build:\n{done.stderr}")
+    os.replace(tmp, binary)
+    return binary, time.monotonic() - t0
+
+
+def loadgen_args(cell: dict, port: int, seed: int, seconds: float,
+                 start_at: float) -> list:
+    t, cfg = cell["traffic"], cell["config"]
+    args = {
+        "port": port, "seed": seed, "lane": t["lane"],
+        "frame-keys": t["frame_keys"], "conns": t["connections"],
+        "loop": t["loop"], "inflight": t["inflight"],
+        "arrival": t["arrival"], "zipf-s": t["zipf_s"],
+        "keys": cfg["key_population"], "cost-n": t["cost_n"],
+        "slices": cell["chips"], "start-at": f"{start_at:.6f}",
+        "warmup": WARMUP_S, "seconds": seconds, "drain": DRAIN_S,
+    }
+    if t["loop"] == "open":
+        args["rate"] = t["rate"]
+    return [x for k, v in args.items() for x in (f"--{k}", str(v))]
+
+
+# -------------------------------------------------------------- the server
+
+class Server:
+    def __init__(self, proc, banner: str, log_path: str, report_path: str):
+        self.proc, self.banner = proc, banner
+        self.log_path, self.report_path = log_path, report_path
+        m = _BANNER.search(banner)
+        if m is None:
+            raise RunFailure(f"the banner names no device: {banner}")
+        self.device = {"platform": m["platform"], "kind": m["kind"],
+                       "count": int(m["count"])}
+        self.kernels, self.slices = m["kernels"], m["slices"].split(",")
+        self.port = int(_PORT.search(banner).group(1))
+        http = _HTTP.search(banner)
+        self.http_port = int(http.group(1)) if http else None
+
+    def log(self) -> str:
+        with open(self.log_path, errors="replace") as fh:
+            return fh.read()
+
+
+def _read_banner(proc) -> str:
+    """The stdout line starting with ``serving`` (printed after prewarm),
+    read without blocking past the child's death."""
+    fd, buf = proc.stdout.fileno(), b""
+    while True:
+        while b"\n" in buf:
+            line, buf = buf.split(b"\n", 1)
+            if line.startswith(b"serving"):
+                return line.decode()
+        ready, _, _ = select.select([fd], [], [], 1.0)
+        chunk = os.read(fd, 65536) if ready else None
+        if chunk == b"" or (chunk is None and proc.poll() is not None):
+            raise RunFailure(f"the server exited with code {proc.wait()} "
+                             f"before its banner")
+        buf += chunk or b""
+
+
+@contextlib.contextmanager
+def serving(cell: dict, out_dir: str, trace: bool):
+    """Start the one server child; yield it once its banner is out; on
+    the way out SIGTERM it and wait for its exit."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [ROOT] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p])
+    if REHEARSAL and cell["chips"] > 1:
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                            f"platform_device_count={cell['chips']}").strip()
+    tmp = os.path.join(out_dir, "tmp")
+    os.makedirs(tmp, exist_ok=True)
+    env["TMPDIR"] = tmp        # the profile lands here, inside the checkout
+    report = os.path.join(out_dir, "device.json")
+    flags = list(cell["config"]["server_flags"])
+    if trace:
+        flags += ["--flight-recorder", "--trace", "--http-port", "0",
+                  "--debug-trace"]
+    cmd = [sys.executable, "-m", "chipbench.serve_child", report,
+           "--port", "0"] + flags
+    log_path = os.path.join(out_dir, "server.stderr")
+    with open(log_path, "wb") as err:
+        proc = subprocess.Popen(cmd, cwd=ROOT, env=env,
+                                stdout=subprocess.PIPE, stderr=err)
+    clean = False
+    try:
+        yield Server(proc, _read_banner(proc), log_path, report)
+        if proc.poll() is None:
+            proc.send_signal(signal.SIGTERM)
+        if proc.wait(timeout=90) != 0:
+            raise RunFailure(f"the server exited with code "
+                             f"{proc.returncode} on SIGTERM")
+        clean = True
+    finally:
+        if proc.poll() is None:
+            proc.terminate()
+            try:
+                proc.wait(timeout=30)
+            except subprocess.TimeoutExpired:
+                proc.kill()
+                proc.wait()
+        proc.stdout.close()
+        if not clean:
+            with open(log_path, errors="replace") as fh:
+                sys.stderr.write(f"--- stderr of {' '.join(cmd)}\n"
+                                 f"{fh.read()[-8000:]}\n--- end stderr\n")
+
+
+# --------------------------------------------------------------- the trace
+
+def fetch_profile(http_port: int, box: dict) -> None:
+    try:
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{http_port}/debug/profile"
+                f"?seconds={PROFILE_S:g}", timeout=120) as resp:
+            box["profile"] = json.loads(resp.read())
+    except Exception as exc:  # noqa: BLE001 — reported by the caller
+        box["error"] = repr(exc)
+
+
+def reduce_trace(profile: dict, out_dir: str):
+    """Run chipbench/trace_reduce.py in a child pinned to the CPU, keep
+    the reduced JSON and delete the raw trace."""
+    pbs = [os.path.join(profile["dir"], f) for f in profile["files"]
+           if f.endswith(".xplane.pb")]
+    if not pbs:
+        return None
+    dst = os.path.join(out_dir, "trace_reduced.json")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    done = subprocess.run(
+        [sys.executable, "-m", "chipbench.trace_reduce", pbs[0], dst],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    size = os.path.getsize(pbs[0])
+    shutil.rmtree(profile["dir"], ignore_errors=True)
+    if done.returncode != 0:
+        raise RunFailure(f"trace_reduce failed:\n{done.stderr[-4000:]}")
+    with open(dst) as fh:
+        reduced = json.load(fh)
+    say("trace", xplane_bytes=size, window_s=reduced["window_s"],
+        step=reduced["step"], modules=reduced["modules"][:6],
+        per_chip=[{k: d[k] for k in ("plane", "busy_s", "idle_pct", "gaps",
+                                     "longest_gap_s")}
+                  for d in reduced["devices"]])
+    return reduced
+
+
+# ------------------------------------------------------------ the run
+
+def check(ok: bool, what: str, failures: list) -> None:
+    if not ok:
+        failures.append(what)
+
+
+def admitted_cap(cfg: dict, run_s: float) -> int:
+    """Most one key may be allowed in ``run_s`` seconds under the rule."""
+    limit, window = cfg["limit"], cfg["window_s"]
+    if cfg["algorithm"] == "token_bucket":
+        return limit + math.ceil(run_s * limit / window)
+    return limit * (int(run_s // window) + 1)
+
+
+def result_line(correct: bool, gen: dict, metrics: dict, device: dict,
+                breakdown=None) -> dict:
+    """The one object the driver reads."""
+    failed = (gen["policy"] + gen["error_decisions"]
+              + (gen["unanswered"] if gen["loop"] == "open" else 0))
+    line = {"correct": bool(correct), "attempted": gen["sent"],
+            "failed": failed,
+            "metrics": {name: {"value": value, "unit": unit}
+                        for name, (value, unit) in metrics.items()},
+            "device": device}
+    if breakdown is not None:
+        line["breakdown"] = breakdown
+    return line
+
+
+def end_to_end(cell: dict, gen: dict, setup_s: float) -> dict:
+    """name -> (value, unit) for the cell's end-to-end metrics."""
+    have = {"setup_s": setup_s,
+            "decisions_per_s": gen["completed"] / gen["window_s"],
+            "latency_p50_ms": gen["latency_ms"]["p50"],
+            "latency_p99_ms": gen["latency_ms"]["p99_median_of_seconds"]}
+    return {m["name"]: (have[m["name"]], m["unit"])
+            for m in cell_metrics(cell, "end_to_end")}
+
+
+def per_layer(cell: dict, sources: dict) -> dict:
+    """Run every reader that applies; name -> (value, unit) for those the
+    manifest lists for this cell. The rest go on an earlier line."""
+    listed = {m["name"] for m in cell_metrics(cell, "per_layer")}
+    facts = {k: cell[k] for k in ("name", "chips", "config", "traffic")}
+    out, extra = {}, {}
+    for mod in layers.load():
+        if not mod.META["applies"](facts):
+            continue
+        value = mod.read(sources)
+        if value is None:
+            continue
+        if mod.META["name"] in listed:
+            out[mod.META["name"]] = (value, mod.META["unit"])
+        else:
+            extra[mod.META["name"]] = value
+    if extra:
+        say("unlisted_layer_metrics", **extra)
+    return out
+
+
+def drive(cell: dict, srv: Server, binary: str, seed: int, seconds: float,
+          trace: bool) -> tuple:
+    """Warm-up and window: one generator process on a schedule both sides
+    know (CLOCK_MONOTONIC). Traced, the window also holds the /metrics
+    scrapes at its two ends and the profile. Returns the generator's
+    JSON, the scrapes and what /debug/profile answered."""
+    start_at = time.monotonic() + 0.3
+    t_win0 = start_at + WARMUP_S
+    t_win1 = t_win0 + seconds
+    gen_proc = subprocess.Popen(
+        [binary] + loadgen_args(cell, srv.port, seed, seconds, start_at),
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+    box: dict = {}
+    scrapes: dict = {}
+    prof_thread = None
+    try:
+        if trace:
+            time.sleep(max(0.0, t_win0 - time.monotonic()))
+            with Wire(srv.port) as wire:
+                scrapes["start"] = (time.monotonic(), wire.metrics())
+            if srv.http_port:
+                prof_thread = threading.Thread(
+                    target=fetch_profile, args=(srv.http_port, box))
+                time.sleep(max(0.0, t_win0 + 1.0 - time.monotonic()))
+                prof_thread.start()
+            time.sleep(max(0.0, t_win1 - 0.05 - time.monotonic()))
+            with Wire(srv.port) as wire:
+                scrapes["end"] = (time.monotonic(), wire.metrics())
+        gen_out, gen_err = gen_proc.communicate(
+            timeout=WARMUP_S + seconds + DRAIN_S + 60)
+    finally:
+        if gen_proc.poll() is None:
+            gen_proc.kill()
+            gen_proc.wait()
+        if prof_thread is not None:
+            prof_thread.join(timeout=150)
+    if gen_proc.returncode != 0:
+        raise RunFailure(f"the load generator exited "
+                         f"{gen_proc.returncode}: {gen_err[-2000:]}")
+    return json.loads(gen_out.strip().splitlines()[-1]), scrapes, box
+
+
+def run(args) -> int:
+    cell = load_cell(args.workload)
+    cfg, traffic = cell["config"], cell["traffic"]
+    trace = bool(args.trace)
+    out_dir = os.path.join(HERE, "out",
+                           f"{args.workload}-{args.seed}-{args.trace}")
+    shutil.rmtree(out_dir, ignore_errors=True)
+    os.makedirs(out_dir)
+    seconds = max(args.seconds, TRACED_MIN_S) if trace and not REHEARSAL \
+        else args.seconds
+    if not os.path.isdir(os.path.join(ROOT, "ratelimiter_tpu")):
+        raise NoChip("no ratelimiter_tpu/ beside chipbench/: not a checkout "
+                     "of the program")
+    binary, build_s = build_loadgen()
+    failures: list = []
+    t_server = time.monotonic()
+    with serving(cell, out_dir, trace) as srv:
+        start_s = time.monotonic() - t_server
+        dev = srv.device
+        say("server", banner=srv.banner, start_s=start_s,
+            host_cores=os.cpu_count())
+        if dev["platform"] != "tpu" and not (REHEARSAL
+                                             and dev["platform"] == "cpu"):
+            raise NoChip(f"the server's device is {dev['platform']}/"
+                         f"{dev['kind']}, not a TPU (set JAX_PLATFORMS=cpu "
+                         f"for a rehearsal)")
+        if dev["count"] < cell["chips"]:
+            raise NoChip(f"the cell asks for {cell['chips']} chips, the "
+                         f"server sees {dev['count']}")
+        if cell["chips"] > 1:
+            check(sorted(srv.slices) == sorted(str(i) for i in
+                                               range(dev["count"])),
+                  f"mesh over x{dev['count']} devices, slice state on "
+                  f"{srv.slices}", failures)
+        peaks = None
+        if not REHEARSAL:
+            with open(os.path.join(HERE, "peaks.json")) as fh:
+                table = json.load(fh)
+            if dev["kind"] not in table:
+                raise RunFailure(f"device kind {dev['kind']!r} is not in "
+                                 f"chipbench/peaks.json")
+            peaks = table[dev["kind"]]
+
+        # (4) the probe, on the fresh server, on the cell's own lane.
+        t_probe = time.monotonic()
+        with Wire(srv.port) as wire:
+            try:
+                probed = probe.probe(wire, cfg, traffic["lane"], args.seed)
+                say("probe", **probed)
+            except probe.CheckFailed as exc:
+                failures.append(str(exc))
+                probed = {"sent": 0}
+        probe_s = time.monotonic() - t_probe
+
+        # (5)+(6) warm-up and the window: one generator process.
+        gen, scrapes, box = drive(cell, srv, binary, args.seed, seconds,
+                                  trace)
+        with open(os.path.join(out_dir, "loadgen.json"), "w") as fh:
+            json.dump(gen, fh)
+        setup_s = gen["t_window_start"] - T_SPAWN
+
+        # (7) after the window.
+        with Wire(srv.port) as wire:
+            cold = probe.cold_keys(wire, traffic["lane"])
+            text = wire.metrics()
+            served = wire.decisions_total()
+        with open(os.path.join(out_dir, "metrics_end.txt"), "w") as fh:
+            fh.write(text)
+        samples = promtext.parse(text)
+        server_log = srv.log()
+    # The server has exited; its device report is written.
+    with open(srv.report_path) as fh:
+        report = json.load(fh)
+    peak = max((d["peak_bytes_in_use"] or 0 for d in report["devices"]),
+               default=0)
+
+    run_s = gen["run_s"] + DRAIN_S
+    cap = admitted_cap(cfg, run_s)
+    worst = max(gen["top_allowed"], default=0)
+    check(worst <= cap, f"a hot key was allowed {worst} times in "
+          f"{run_s:g} s; the rule admits at most {cap}", failures)
+    check(cold["cold_false_deny_pct"] <= 1.0 and cold["policy"] == 0,
+          f"{cold['denied']} of {cold['sent']} never-seen keys denied "
+          f"({cold['policy']} by policy)", failures)
+    policy, errors = promtext.policy_answered(samples), \
+        promtext.dispatch_errors(samples)
+    check(policy <= gen["all"]["policy"] and errors <= gen["all"]["error_frames"],
+          f"server metrics: {policy:g} decisions answered by policy, "
+          f"{errors:g} dispatch errors; the generator saw "
+          f"{gen['all']['policy']} and {gen['all']['error_frames']}", failures)
+    client_done = probed["sent"] + gen["all"]["completed"] + cold["sent"]
+    check(served >= client_done, f"the server counted {served} decisions, "
+          f"the client completed {client_done}", failures)
+    check(gen["completed"] > 0, "no decision completed in the window",
+          failures)
+    check(not gen["io_failed"], "a generator connection broke", failures)
+
+    say("loadgen", **{k: gen[k] for k in (
+        "loop", "lane", "threads", "conns", "inflight", "frame_keys", "rate",
+        "sent", "completed", "allowed", "policy", "error_frames",
+        "unanswered", "backlog_max_frames", "slice_sent")},
+        allowed_share=(gen["allowed"] / gen["completed"]
+                       if gen["completed"] else None),
+        top_allowed_max=worst, admitted_cap=cap,
+        **({} if REHEARSAL else {"latency_ms": gen["latency_ms"],
+                                 "gen_late_ms": gen["gen_late_ms"]}))
+    say("per_second", slices=[
+        {k: s[k] for k in (("completed", "frames", "pending_frames")
+                           if REHEARSAL else s)} for s in gen["per_second"]])
+    say("checks", cold=cold, server_decisions=served,
+        client_decisions=client_done, policy_answered=policy,
+        dispatch_errors=errors, failures=failures)
+    prewarm = prewarm_s.read({"server_log": server_log}) or 0.0
+    say("setup", setup_s=setup_s, build_s=build_s,
+        start_to_prewarm_s=start_s - prewarm, prewarm_s=prewarm,
+        probe_s=probe_s, warmup_s=WARMUP_S,
+        other_s=setup_s - build_s - start_s - probe_s - WARMUP_S)
+
+    device = {**dev, "memory_peak_bytes": peak}
+    correct = not failures
+    breakdown = None
+    if trace:
+        reduced = None
+        if "profile" in box:
+            reduced = reduce_trace(box["profile"], out_dir)
+        elif "error" in box:
+            raise RunFailure(f"/debug/profile failed: {box['error']}")
+        sources = {
+            "cell": cell, "loadgen": gen, "trace": reduced, "peaks": peaks,
+            "server_log": server_log,
+            "metrics_start": promtext.parse(scrapes["start"][1]),
+            "metrics_end": promtext.parse(scrapes["end"][1]),
+            "scrape_s": scrapes["end"][0] - scrapes["start"][0]}
+        metrics = per_layer(cell, sources)
+        if reduced:
+            device.update(busy_s=reduced["busy_s"],
+                          window_s=reduced["window_s"])
+            breakdown = {"device_ops": reduced["device_ops"],
+                         "idle_gaps": reduced["idle_gaps"]}
+    else:
+        metrics = end_to_end(cell, gen, setup_s)
+    shutil.rmtree(os.path.join(out_dir, "tmp"), ignore_errors=True)
+
+    if "jax" in sys.modules:
+        raise RunFailure("the runner imported jax: it would hold the chip")
+    if REHEARSAL:
+        say("rehearsal", workload=cell["name"], correct=correct,
+            attempted=gen["sent"], device=dev,
+            metric_names=sorted(metrics), failures=failures)
+        print(f"rehearsal on {dev['platform']}: "
+              f"{'passed' if correct else 'FAILED'}; no accelerator, so no "
+              f"result")
+        return 3 if correct else 1
+    print(json.dumps(result_line(correct, gen, metrics, device, breakdown)),
+          flush=True)
+    return 0
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(prog="python -m chipbench",
+                                 description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--seconds", type=float, default=10.0)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    try:
+        return run(args)
+    except NoChip as exc:
+        sys.stderr.write(f"chipbench: {exc}\n")
+        return 2
+    except (RunFailure, probe.CheckFailed, OSError,
+            subprocess.TimeoutExpired) as exc:
+        sys.stderr.write(f"chipbench: {type(exc).__name__}: {exc}\n")
+        return 1
