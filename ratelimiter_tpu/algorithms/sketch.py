@@ -36,6 +36,7 @@ from ratelimiter_tpu.core.types import (
     Result,
     batch_fail_open,
 )
+from ratelimiter_tpu.observability import tracing
 from ratelimiter_tpu.ops.hashing import split_hash
 
 _MIN_PAD = 8
@@ -326,79 +327,91 @@ class SketchLimiter(RateLimiter):
         import jax.numpy as jnp
 
         b = h64.shape[0]
-        padded = self._padded_size(b)
-        slot = self._acquire_staging(padded)
-        h64p, nsp = slot
-        h64p[:b] = h64
-        h64p[b:] = 0
-        nsp[:b] = ns
-        nsp[b:] = 0
-        launched = False
-        try:
-            with self._lock:
-                if self._injected_failure is not None:
-                    raise self._injected_failure
-                self._sync_period(now_us)
-                if self._strict and self._over_budget_locked(now_us):
-                    # Strict overload policy: REJECT new admissions (no
-                    # state write, no dispatch) while admitted in-window
-                    # mass exceeds the geometry's accuracy budget — loud
-                    # bounded denials instead of silent unbounded
-                    # misaccounting. Clears as history ages out of the
-                    # ring.
-                    return DispatchTicket(result=self._deny_all(b, now_us))
-                step = self._get_ids_step() if premix else self._step
-                args = (self._state, self._place(h64p), self._place(nsp),
-                        jnp.int64(now_us), self._policy_device())
-                if self._hier_table is not None:
-                    # Cascade tables ride as one extra replicated operand
-                    # — tenant ids derive on device, same dispatch.
-                    args = args + (self._hier_device(),)
-                self._state, outs = step(*args)
-                self._fence_dispatch(outs)
-                # Inside the lock: a concurrent set/delete_override
-                # rebuilds the table's sorted views, and a torn read
-                # would mis-index. Raw-id launches finalize host-side
-                # ONLY when overrides exist (the common empty-table case
-                # stays hash-free on the host).
-                if premix:
-                    from ratelimiter_tpu.ops.hashing import splitmix64
+        # The dispatch stage from inside (ADR-014 addendum): prep ->
+        # place -> step -> finish, back to back. Tracing off, sp is the
+        # shared no-op and next() does nothing.
+        with tracing.span("prep", batch=b) as sp:
+            padded = self._padded_size(b)
+            slot = self._acquire_staging(padded)
+            h64p, nsp = slot
+            h64p[:b] = h64
+            h64p[b:] = 0
+            nsp[:b] = ns
+            nsp[b:] = 0
+            launched = False
+            try:
+                with self._lock:
+                    if self._injected_failure is not None:
+                        raise self._injected_failure
+                    self._sync_period(now_us)
+                    if self._strict and self._over_budget_locked(now_us):
+                        # Strict overload policy: REJECT new admissions
+                        # (no state write, no dispatch) while admitted
+                        # in-window mass exceeds the geometry's accuracy
+                        # budget — loud bounded denials instead of silent
+                        # unbounded misaccounting. Clears as history ages
+                        # out of the ring.
+                        return DispatchTicket(
+                            result=self._deny_all(b, now_us))
+                    step = self._get_ids_step() if premix else self._step
+                    sp.next("place")
+                    args = (self._state, self._place(h64p),
+                            self._place(nsp), jnp.int64(now_us),
+                            self._policy_device())
+                    if self._hier_table is not None:
+                        # Cascade tables ride as one extra replicated
+                        # operand — tenant ids derive on device, same
+                        # dispatch.
+                        args = args + (self._hier_device(),)
+                    sp.next("step")
+                    self._state, outs = step(*args)
+                    self._fence_dispatch(outs)
+                    sp.next("finish")
+                    # Inside the lock: a concurrent set/delete_override
+                    # rebuilds the table's sorted views, and a torn read
+                    # would mis-index. Raw-id launches finalize host-side
+                    # ONLY when overrides exist (the common empty-table
+                    # case stays hash-free on the host).
+                    if premix:
+                        from ratelimiter_tpu.ops.hashing import splitmix64
 
-                    limits = (self._policy_limits(splitmix64(h64))
-                              if len(self._policy_table) else None)
-                else:
-                    limits = self._policy_limits(h64)
-                self._inflight_mass += int(ns.sum())
-            launched = True
-        finally:
-            # Any non-launch exit (injected failure, strict deny-all, a
-            # failing step/rollover) must return the slot to the pool —
-            # only a ticket-owned slot is recycled by _retire_ticket.
-            if not launched:
-                self._release_staging(padded, slot)
-        t = DispatchTicket()
-        # retry/reset float math runs ON DEVICE (finish kernels), queued
-        # behind the step — resolve does one bulk fetch, no NumPy per
-        # request (ISSUE-3 tentpole item 3).
-        t.outs = self._launch_finish(outs, now_us)
-        if wire:
-            # Wire-lane tickets additionally pack the response ON DEVICE
-            # (bit-packed allow mask + one int64 word array) so resolve
-            # fetches two compact buffers and the responder frames them
-            # with three slice memcpys (ADR-011).
-            from ratelimiter_tpu.ops import sketch_kernels
+                        limits = (self._policy_limits(splitmix64(h64))
+                                  if len(self._policy_table) else None)
+                    else:
+                        limits = self._policy_limits(h64)
+                    self._inflight_mass += int(ns.sum())
+                launched = True
+            finally:
+                # Any non-launch exit (injected failure, strict deny-all,
+                # a failing step/rollover) must return the slot to the
+                # pool — only a ticket-owned slot is recycled by
+                # _retire_ticket.
+                if not launched:
+                    self._release_staging(padded, slot)
+            t = DispatchTicket()
+            # retry/reset float math runs ON DEVICE (finish kernels),
+            # queued behind the step — resolve does one bulk fetch, no
+            # NumPy per request (ISSUE-3 tentpole item 3).
+            t.outs = self._launch_finish(outs, now_us)
+            if wire:
+                # Wire-lane tickets additionally pack the response ON
+                # DEVICE (bit-packed allow mask + one int64 word array)
+                # so resolve fetches two compact buffers and the
+                # responder frames them with three slice memcpys
+                # (ADR-011).
+                from ratelimiter_tpu.ops import sketch_kernels
 
-            t.outs = sketch_kernels.pack_wire(*t.outs)
-            t.wire = True
-        t.b = b
-        t.limit = self.config.limit
-        t.limits = limits
-        t.ns = np.asarray(ns)
-        t.now_us = now_us
-        t.t_sec = t_sec
-        t.slot = slot
-        t.padded = padded
-        return t
+                t.outs = sketch_kernels.pack_wire(*t.outs)
+                t.wire = True
+            t.b = b
+            t.limit = self.config.limit
+            t.limits = limits
+            t.ns = np.asarray(ns)
+            t.now_us = now_us
+            t.t_sec = t_sec
+            t.slot = slot
+            t.padded = padded
+            return t
 
     def _fence_dispatch(self, outs) -> None:
         """Complete a just-launched step before the dispatch lock drops.

@@ -185,7 +185,7 @@ class DispatchTicket:
 
     __slots__ = ("outs", "b", "limit", "limits", "ns", "now_us", "t_sec",
                  "slot", "padded", "result", "meta", "wire", "trace_id",
-                 "audit")
+                 "audit", "t_door")
 
     def __init__(self, result: "BatchResult | None" = None):
         self.outs = None        # device-side (allowed, remaining, retry, reset)
@@ -205,6 +205,11 @@ class DispatchTicket:
         #                         0 = unsampled. Set by the serving doors
         #                         at launch so resolve-side spans (incl.
         #                         mesh per-slice spans) link to the frame.
+        self.t_door = None      # recorder on: (enter, leave) monotonic ns
+        #                         of the native door's launch callback;
+        #                         the completer's spans callback records
+        #                         the "enter" and "leave" stages from
+        #                         them (ADR-014 addendum)
         self.audit = None       # (h64, ns) pinned by the native door's
         #                         launch callbacks ONLY while the live
         #                         auditor is on (ADR-016), so resolve can

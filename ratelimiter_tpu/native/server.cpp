@@ -1424,6 +1424,9 @@ void completer_main(Server* s, uint32_t shard) {
     Server* s;
     ~Depart() {
       s->live_completers.fetch_sub(1);
+      // Under the waiter's mutex: a responder between its predicate
+      // check and its block would miss this, its last, wakeup.
+      std::lock_guard<std::mutex> g(s->rmx);
       s->rcv.notify_all();  // responder re-checks its exit condition
     }
   } depart{s};
@@ -1889,8 +1892,15 @@ void dispatcher_main(Server* s, uint32_t shard) {
     Server* s;
     ~Depart() {
       s->live_dispatchers.fetch_sub(1);
-      s->rcv.notify_all();  // let the responder re-check its exit condition
-      for (auto& pq : s->pipeqs) pq->cv_items.notify_all();  // completers too
+      // Each notify under its waiter's mutex (see wake_all_waiters).
+      {
+        std::lock_guard<std::mutex> g(s->rmx);
+        s->rcv.notify_all();  // let the responder re-check its exit condition
+      }
+      for (auto& pq : s->pipeqs) {  // completers too
+        std::lock_guard<std::mutex> g(pq->mx);
+        pq->cv_items.notify_all();
+      }
     }
   } depart{s};
   while (true) {
@@ -3075,6 +3085,35 @@ PyObject* server_start(PyObject* self, PyObject* args) {
   return PyLong_FromLong(s->port);
 }
 
+// After `stop` is stored: wake every thread that sleeps on a predicate
+// reading it. Each notify is made UNDER the waiter's mutex: the atomics
+// the predicates read (`stop`, `live_dispatchers`, `live_completers`)
+// change outside those mutexes, so a bare notify can land between a
+// waiter's predicate check and its block, and that waiter then sleeps
+// for good with the joins below waiting on it (tests hung 4 runs in 80).
+// Holding the mutex, the waiter is either blocked (and wakes) or has yet
+// to check (and sees the store).
+void wake_all_waiters(Server* s) {
+  for (auto& q : s->shardqs) {
+    std::lock_guard<std::mutex> g(q->qmx);
+    q->qcv.notify_all();
+  }
+  for (auto& pq : s->pipeqs) {
+    std::lock_guard<std::mutex> g(pq->mx);
+    pq->cv_items.notify_all();
+    pq->cv_space.notify_all();
+  }
+  {
+    std::lock_guard<std::mutex> g(s->ifmx);
+    s->ifcv.notify_all();
+  }
+  {
+    std::lock_guard<std::mutex> g(s->rmx);
+    s->rcv.notify_all();
+  }
+  for (auto& ring : s->rings) ding_efd(ring->event_fd);
+}
+
 PyObject* server_shutdown(PyObject* self, PyObject* Py_UNUSED(ignored)) {
   PyServer* ps = (PyServer*)self;
   Server* s = ps->s;
@@ -3119,14 +3158,7 @@ PyObject* server_shutdown(PyObject* self, PyObject* Py_UNUSED(ignored)) {
     }
     usleep(20000);  // let final responses flush
     s->stop.store(true);
-    for (auto& q : s->shardqs) q->qcv.notify_all();
-    for (auto& pq : s->pipeqs) {
-      pq->cv_items.notify_all();
-      pq->cv_space.notify_all();
-    }
-    s->ifcv.notify_all();
-    s->rcv.notify_all();
-    for (auto& ring : s->rings) ding_efd(ring->event_fd);
+    wake_all_waiters(s);
     for (auto& ring : s->rings)
       if (ring->thread.joinable()) ring->thread.join();
     for (auto& t : s->dispatch_threads)
@@ -3327,14 +3359,7 @@ void server_dealloc(PyObject* self) {
   if (ps->s != nullptr) {
     if (ps->s->listen_fd >= 0) {
       ps->s->stop.store(true);
-      for (auto& q : ps->s->shardqs) q->qcv.notify_all();
-      for (auto& pq : ps->s->pipeqs) {
-        pq->cv_items.notify_all();
-        pq->cv_space.notify_all();
-      }
-      ps->s->ifcv.notify_all();
-      ps->s->rcv.notify_all();
-      for (auto& ring : ps->s->rings) ding_efd(ring->event_fd);
+      wake_all_waiters(ps->s);
       // The dispatcher may be blocked in PyGILState_Ensure for a decide;
       // joining while holding the GIL would deadlock.
       Py_BEGIN_ALLOW_THREADS;

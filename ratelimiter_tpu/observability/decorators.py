@@ -41,6 +41,7 @@ from ratelimiter_tpu.core.errors import (
 )
 from ratelimiter_tpu.core.types import BatchResult, Result
 from ratelimiter_tpu.observability import metrics as m
+from ratelimiter_tpu.observability import tracing
 
 
 class LimiterDecorator(RateLimiter):
@@ -492,21 +493,22 @@ class TracingDecorator(LimiterDecorator):
     ``TracingDecorator``, ``docs/ADR/003:115-124``, realized with the
     JAX profiler — the native tracing stack on TPU).
 
-    Every decorated call runs inside a named ``jax.profiler``
-    TraceAnnotation, so device dispatches show up attributed by
-    op/algorithm in xplane traces. ``capture(path)`` context-manages a
-    full profiler capture around a workload for offline analysis
-    (tensorboard / xprof)."""
+    Every decorated call runs inside a ``ratelimiter/<algo>/<op>``
+    TraceMe, so device dispatches show up attributed by op/algorithm in
+    xplane traces, and the stage spans below it (``tracing.span``: prep,
+    place, step, finish) nest in it. Having one in the stack IS
+    ``--trace``: construction turns the profiler sink of ``tracing.span``
+    on for the process. ``capture(path)`` context-manages a full profiler
+    capture around a workload for offline analysis (tensorboard /
+    xprof)."""
 
     def __init__(self, inner: RateLimiter):
         super().__init__(inner)
         self._algo = str(inner.config.algorithm)
+        tracing.annotate()
 
     def _annotation(self, op: str):
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(
-            f"ratelimiter/{self._algo}/{op}")
+        return tracing.annotation(f"{self._algo}/{op}")
 
     def allow_n(self, key: str, n: int, *, now: Optional[float] = None) -> Result:
         with self._annotation("allow_n"):
@@ -517,17 +519,38 @@ class TracingDecorator(LimiterDecorator):
         with self._annotation("allow_batch"):
             return self.inner.allow_batch(keys, ns, now=now)
 
+    def allow_hashed(self, h64, ns=None, *,
+                     now: Optional[float] = None) -> BatchResult:
+        with self._annotation("allow_hashed"):
+            return self.inner.allow_hashed(h64, ns, now=now)
+
+    def allow_ids(self, ids, ns=None, *,
+                  now: Optional[float] = None) -> BatchResult:
+        with self._annotation("allow_ids"):
+            return self.inner.allow_ids(ids, ns, now=now)
+
     def reset(self, key: str) -> None:
         with self._annotation("reset"):
             self.inner.reset(key)
 
+    # The pipelined hot path's two phases each get their own annotation
+    # — without these, the default serving path's device work would show
+    # up unattributed in xplane traces. The native door launches through
+    # the hashed lanes, not launch_batch.
+
     def launch_batch(self, keys: Sequence[str], ns=None, *,
                      now: Optional[float] = None):
-        # The pipelined hot path's two phases each get their own
-        # annotation — without these, the default serving path's device
-        # work would show up unattributed in xplane traces.
         with self._annotation("launch"):
             return self.inner.launch_batch(keys, ns, now=now)
+
+    def launch_hashed(self, h64, ns=None, *, now: Optional[float] = None):
+        with self._annotation("launch"):
+            return self.inner.launch_hashed(h64, ns, now=now)
+
+    def launch_ids(self, ids, ns=None, *, now: Optional[float] = None,
+                   wire: bool = False):
+        with self._annotation("launch"):
+            return self.inner.launch_ids(ids, ns, now=now, wire=wire)
 
     def resolve(self, ticket):
         with self._annotation("resolve"):
@@ -537,13 +560,8 @@ class TracingDecorator(LimiterDecorator):
     def capture(self, path: str):
         """Profile everything inside the with-block to ``path`` (xplane
         format; view with tensorboard's profile plugin)."""
-        import jax.profiler
-
-        jax.profiler.start_trace(path)
-        try:
+        with tracing.profile(path):
             yield self
-        finally:
-            jax.profiler.stop_trace()
 
 
 class CircuitBreakerDecorator(LimiterDecorator):

@@ -17,12 +17,21 @@ Design (ADR-014):
   (trace_id, stage, shard, batch, t_start/t_end monotonic ns, outcome)
   in a numpy structured array — one row assignment per span, never a
   lock, never an allocation, never I/O on the record path. Rings are
-  registered once per thread (the only locked operation) and drained
-  only at dump/scrape time.
+  registered once per OS thread (the only locked operation), keyed by
+  ``threading.get_ident()``, and drained only at dump/scrape time. Not
+  a ``threading.local``: the native door's C++ threads enter Python
+  through ``PyGILState_Ensure``/``Release`` pairs, each pair makes and
+  destroys a thread state, and thread-local values die with it — a
+  ring per dispatch, kept for good (ADR-014 addendum).
 * **Off by default, zero overhead when off**: hot paths read the module
   global ``RECORDER`` once and skip everything — no clock reads, no
   branches beyond the None check, byte-identical decisions either way
   (tests/test_tracing.py pins this).
+* **One span primitive, two sinks**: ``span(stage)`` writes a ring row
+  when the recorder is on and, when ``--trace`` is on (``annotate``),
+  holds a ``jax.profiler.TraceAnnotation("ratelimiter/<stage>")`` open
+  for the same interval — the span is then in the device trace, on the
+  profiler's clock. Both off: one shared no-op object.
 * **Trace context** is a caller-supplied u64 id (0 = unsampled). The
   binary protocol carries it as a flagged extension on any request frame
   (``protocol.with_trace``), HTTP carries W3C ``traceparent``, gRPC the
@@ -43,6 +52,7 @@ Design (ADR-014):
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import threading
 import time
@@ -66,7 +76,10 @@ STAGES = (
     "resolve",    # host bookkeeping after the device fetch
     "complete",   # native door: completer post-processing
     "encode",     # response framing
-    "respond",    # native door: responder encode+send (aggregate only)
+    None,         # code 12 was "respond": no door ever recorded it (the
+                  # responder's time is a sum on the door, stats()
+                  # ["stage_ns"]); the slot stays so later codes keep
+                  # their value
     "http",       # HTTP gateway decision (traceparent attribution)
     "grpc",       # gRPC decision (traceparent metadata attribution)
     "dcn",        # one DCN push round-trip to a peer
@@ -74,8 +87,19 @@ STAGES = (
     "forward",    # fleet forward lane: one coalesced wire window's
                   # round trip to a peer (send -> parsed reply), recorded
                   # under the WINDOW-level trace id (ADR-021)
+    # The native door's ``dispatch`` stage from inside, in order
+    # (sketch-family launch path; ADR-014 addendum):
+    "enter",      # C++ drain stamp -> first line of the launch callback
+                  # (column gather + the wait for the GIL); ring only
+    "hash",       # string lane: bulk-hash the drained keys
+    "prep",       # staging slot, pad copies, lock waits, rollover check
+    "place",      # host -> device placement of the step's operands
+    "step",       # the jitted step call returning (enqueue, not execution)
+    "finish",     # host limits, finish/pack programs enqueued, ticket filled
+    "leave",      # launch callback's last line -> C++ push stamp (GIL
+                  # release + the wait for an in-flight slot); ring only
 )
-_STAGE_CODE: Dict[str, int] = {s: i for i, s in enumerate(STAGES)}
+_STAGE_CODE: Dict[str, int] = {s: i for i, s in enumerate(STAGES) if s}
 
 #: Outcome codes.
 OK, ERROR, FAIL_OPEN = 0, 1, 2
@@ -139,7 +163,11 @@ class _Ring:
         self.buf = np.zeros(capacity, dtype=RECORD_DTYPE)
         self.idx = 0  # total records ever written (monotone)
         self.tid = threading.get_ident()
-        self.name = threading.current_thread().name
+        name = threading.current_thread().name
+        # A thread Python did not create (the native door's dispatcher
+        # and completer) only has the placeholder name threading invents.
+        self.name = (f"native-{self.tid}" if name.startswith("Dummy-")
+                     else name)
 
 
 class FlightRecorder:
@@ -154,8 +182,10 @@ class FlightRecorder:
             cap <<= 1
         self.capacity = cap
         self._mask = cap - 1
-        self._local = threading.local()
-        self._rings: List[_Ring] = []
+        #: OS thread id -> that thread's ring. Read without a lock on
+        #: the record path (a thread only ever looks up its own key),
+        #: written under ``_rings_lock``.
+        self._rings: Dict[int, _Ring] = {}
         self._rings_lock = threading.Lock()
         self._registries: list = []
         #: Parent-child trace-id links (ADR-021): a fleet forward lane
@@ -171,17 +201,16 @@ class FlightRecorder:
     # ------------------------------------------------------------ record
 
     def _ring(self) -> _Ring:
-        ring = getattr(self._local, "ring", None)
+        ring = self._rings.get(threading.get_ident())
         if ring is None:
             ring = _Ring(self.capacity)
-            self._local.ring = ring
             with self._rings_lock:
-                self._rings.append(ring)
+                self._rings[ring.tid] = ring
         return ring
 
     def record(self, stage, t_start: int, t_end: int, *, trace_id: int = 0,
                shard: int = -1, batch: int = 1, outcome: int = OK) -> None:
-        """Stamp one span. Hot-path cost: a thread-local lookup and one
+        """Stamp one span. Hot-path cost: a dict lookup and one
         structured-row assignment (no locks, no allocation)."""
         ring = self._ring()
         i = ring.idx & self._mask
@@ -215,7 +244,7 @@ class FlightRecorder:
         """[(ring, entries-copy oldest-first, first_seq)] without
         stopping writers (copies are taken per ring)."""
         with self._rings_lock:
-            rings = list(self._rings)
+            rings = list(self._rings.values())
         out = []
         for ring in rings:
             idx = ring.idx
@@ -280,8 +309,8 @@ class FlightRecorder:
             "traceEvents": events,
             "displayTimeUnit": "ms",
             "otherData": {"clock": "CLOCK_MONOTONIC",
-                          "threads": {str(r.tid): r.name
-                                      for r in list(self._rings)},
+                          "threads": {str(tid): r.name for tid, r
+                                      in list(self._rings.items())},
                           # Fragment -> wire-window linkage plus a
                           # (mono, wall) clock stamp, so an offline
                           # stitcher can join and align dumps pulled
@@ -389,6 +418,156 @@ def record(stage, t_start: int, t_end: int, **kw) -> None:
         rec.record(stage, t_start, t_end, **kw)
 
 
+# ------------------------------------------------------ span primitive
+
+#: ``--trace`` (``annotate``; a ``TracingDecorator`` in the stack turns
+#: it on): every ``span`` also writes a TraceMe into the profiler's
+#: timeline.
+ANNOTATE = False
+
+
+def annotate(on: bool = True) -> None:
+    """Turn the profiler sink of ``span`` on or off."""
+    global ANNOTATE
+    ANNOTATE = bool(on)
+
+
+class _NoSpan:
+    """What ``span`` returns when both sinks are off: one shared object,
+    no clock read, no allocation."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def next(self, stage: str) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+def _trace_me(name: str, **args):
+    """The one place a ``ratelimiter/*`` TraceMe is made."""
+    import jax.profiler
+
+    return jax.profiler.TraceAnnotation(f"ratelimiter/{name}", **args)
+
+
+def annotation(name: str):
+    """Profiler-only span ``ratelimiter/<name>``: ``TracingDecorator``'s
+    per-call annotations, whose names carry the algorithm and whose
+    intervals the doors already record as ring rows (``launch``,
+    ``dispatch``, ``resolve``)."""
+    return _trace_me(name) if ANNOTATE else NO_SPAN
+
+
+class _Span:
+    """One open stage with up to two sinks: a ring row at exit (recorder
+    on) and a TraceMe held open for the same interval (``--trace`` on)."""
+
+    __slots__ = ("_rec", "_annotate", "_ann", "_stage", "_t0", "_trace_id",
+                 "_shard", "_batch")
+
+    def __init__(self, rec, annotate_on, stage, trace_id, shard, batch):
+        self._rec = rec
+        self._annotate = annotate_on
+        self._ann = None
+        self._stage = stage
+        self._t0 = 0
+        self._trace_id = trace_id
+        self._shard = shard
+        self._batch = batch
+
+    def __enter__(self):
+        if self._annotate:
+            self._ann = _trace_me(self._stage)
+            self._ann.__enter__()
+        if self._rec is not None:
+            self._t0 = now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._rec is not None:
+            self._row(now(), ERROR if exc_type is not None else OK)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        return False
+
+    def next(self, stage: str) -> None:
+        """Close the open stage and open ``stage`` at the same instant
+        (one clock read): consecutive stages of one function that
+        straddle its ``with`` and ``try`` blocks — a lock taken in one
+        stage, dropped in a later one — take one ``with`` and a ``next``
+        per boundary."""
+        if self._rec is not None:
+            t = now()
+            self._row(t, OK)
+            self._t0 = t
+        self._stage = stage
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = _trace_me(stage)
+            self._ann.__enter__()
+
+    def _row(self, t_end: int, outcome: int) -> None:
+        self._rec.record(self._stage, self._t0, t_end,
+                         trace_id=self._trace_id, shard=self._shard,
+                         batch=self._batch, outcome=outcome)
+
+
+def span(stage: str, *, shard: int = -1, batch: int = 1, trace_id: int = 0):
+    """The one way the program opens a host span: a context manager for
+    ``stage`` on the calling thread. Without a ``trace_id`` / ``shard``
+    the row takes the thread's current ones (``set_current``): layers
+    below a door have no such parameters."""
+    rec = RECORDER
+    if rec is None:
+        if not ANNOTATE:
+            return NO_SPAN
+    else:
+        if not trace_id:
+            trace_id = current()
+        if shard < 0:
+            shard = getattr(_CTX, "shard", -1)
+    return _Span(rec, ANNOTATE, stage, trace_id, shard, batch)
+
+
+# ------------------------------------------------- profiler start/stop
+
+
+def clock_anchor() -> int:
+    """Write ``ratelimiter/clock_anchor`` into the running capture with
+    this module's clock as its ``mono_ns`` argument, and return that
+    reading: the TraceMe's start on the profiler's timeline IS that
+    CLOCK_MONOTONIC instant, so rows stamped outside Python (the native
+    door's ``io`` / ``dispatch`` / ``device`` / ``complete``) can be laid
+    on the profile by one offset."""
+    mono_ns = now()
+    with _trace_me("clock_anchor", mono_ns=mono_ns):
+        pass
+    return mono_ns
+
+
+@contextlib.contextmanager
+def profile(out_dir: str):
+    """One ``jax.profiler`` capture into ``out_dir`` (xplane format):
+    the start/stop behind ``TracingDecorator.capture`` and
+    ``/debug/profile``. Yields the capture's ``clock_anchor`` reading."""
+    import jax.profiler
+
+    jax.profiler.start_trace(out_dir)
+    try:
+        yield clock_anchor()
+    finally:
+        jax.profiler.stop_trace()
+
+
 # ----------------------------------------------- current-trace context
 #
 # A thread-local "trace id of the work currently being launched": the
@@ -402,8 +581,9 @@ def record(stage, t_start: int, t_end: int, **kw) -> None:
 _CTX = threading.local()
 
 
-def set_current(trace_id: int) -> None:
+def set_current(trace_id: int, shard: int = -1) -> None:
     _CTX.trace_id = trace_id
+    _CTX.shard = shard
 
 
 def current() -> int:

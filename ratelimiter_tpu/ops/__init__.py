@@ -10,6 +10,8 @@ behaves like the same requests serialized through Redis.
 
 from __future__ import annotations
 
+from functools import partial
+
 
 def ensure_x64() -> None:
     """The device kernels do exact integer state math in int64 microseconds
@@ -30,3 +32,13 @@ def ensure_x64() -> None:
             "JAX_ENABLE_X64=1 env var) before creating a dense/sketch "
             "limiter. The exact (host) backend works without it.")
 
+
+
+def named(name: str, fn, **static):
+    """``functools.partial(fn, **static)`` under a name. ``jax.jit``
+    names the compiled module after its function (``jit_<name>``) and a
+    bare partial has none, so every step was ``jit__unknown`` in a
+    profile. Metadata only: the traced program is the same."""
+    bound = partial(fn, **static)
+    bound.__name__ = name
+    return bound

@@ -43,7 +43,6 @@ State (see init_state):
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Callable, Dict, Tuple
 
 import jax
@@ -51,7 +50,7 @@ import jax.numpy as jnp
 
 from ratelimiter_tpu.core.clock import MICROS
 from ratelimiter_tpu.core.config import Config
-from ratelimiter_tpu.ops import ensure_x64, policy_kernels
+from ratelimiter_tpu.ops import ensure_x64, named, policy_kernels
 from ratelimiter_tpu.ops.dense_kernels import _check_gates
 from ratelimiter_tpu.ops.segment import admit
 from ratelimiter_tpu.ops.sketch_kernels import _columns, _pack_bits
@@ -130,36 +129,40 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
     token-form backends (whose overrides scale the refill rate too):
     overridden keys burst to their own limit immediately and refill at
     the default rate. Errors stay toward denying."""
-    decay, rem = _decay(state, now_us, rate_num=rate_num, rate_den=rate_den)
+    with jax.named_scope("decay"):
+        decay, rem = _decay(state, now_us, rate_num=rate_num,
+                            rate_den=rate_den)
     # Fused-kernel path (ADR-011): decay applies on the fly inside the
     # kernels (the decayed slab never materializes) and columns derive
     # in-kernel; collective merges stay on the reference path.
     use_pallas = use_pallas and axis_name is None
-    if use_pallas:
-        from ratelimiter_tpu.ops import pallas_sketch
+    with jax.named_scope("estimate"):
+        if use_pallas:
+            from ratelimiter_tpu.ops import pallas_sketch
 
-        debt = None
-        cols = None
-        est = pallas_sketch.bucket_estimate(state["debt"], decay, h1, h2)
-    else:
-        debt = jnp.maximum(jnp.int64(0), state["debt"] - decay)
-        cols = _columns(h1, h2, d, w)                   # (B, d)
-        est = None
-        for r in range(d):
-            (e_r,) = row_gather((debt[r],), cols[:, r])
-            est = e_r if est is None else jnp.minimum(est, e_r)
+            debt = None
+            cols = None
+            est = pallas_sketch.bucket_estimate(state["debt"], decay, h1, h2)
+        else:
+            debt = jnp.maximum(jnp.int64(0), state["debt"] - decay)
+            cols = _columns(h1, h2, d, w)                   # (B, d)
+            est = None
+            for r in range(d):
+                (e_r,) = row_gather((debt[r],), cols[:, r])
+                est = e_r if est is None else jnp.minimum(est, e_r)
 
-    if policy is not None:
-        q = policy_kernels.pack_halves(h1, h2)
-        pidx, pfound = policy_kernels.lookup_i64(policy["key"], q)
-        cap = jnp.where(pfound, policy["limit"][pidx],
-                        jnp.int64(limit)) * MICROS
-    else:
-        cap = limit * MICROS
-    avail = jnp.maximum(jnp.int64(0), cap - est)        # micro-tokens
-    n_units = n.astype(jnp.int64) * MICROS
-    sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
-    allowed, seen, consumed = admit(sid, n_units, avail, iters)
+    with jax.named_scope("admit"):
+        if policy is not None:
+            q = policy_kernels.pack_halves(h1, h2)
+            pidx, pfound = policy_kernels.lookup_i64(policy["key"], q)
+            cap = jnp.where(pfound, policy["limit"][pidx],
+                            jnp.int64(limit)) * MICROS
+        else:
+            cap = limit * MICROS
+        avail = jnp.maximum(jnp.int64(0), cap - est)        # micro-tokens
+        n_units = n.astype(jnp.int64) * MICROS
+        sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
+        allowed, seen, consumed = admit(sid, n_units, avail, iters)
 
     tn_hist = None
     if tenants and hier is not None:
@@ -204,22 +207,23 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
         tn_out = {}
         cascade_retry = None
 
-    if use_pallas:
-        from ratelimiter_tpu.ops import pallas_sketch
+    with jax.named_scope("write_back"):
+        if use_pallas:
+            from ratelimiter_tpu.ops import pallas_sketch
 
-        debt, acc = pallas_sketch.bucket_update(
-            state["debt"], state["acc"], decay, h1, h2, consumed)
-    else:
-        hists = jnp.stack([row_histogram(cols[:, r], consumed, w)
-                           for r in range(d)])
-        if axis_name is not None:
-            # Multi-chip delta merge: replicated debt, psum of increments
-            # over ICI (same invariant as sketch_kernels' delta mode). The
-            # psum'd histogram IS the pod's local traffic, so `acc` stays
-            # export-correct on meshes too.
-            hists = jax.lax.psum(hists, axis_name)
-        debt = jnp.minimum(debt + hists, _DEBT_CAP)
-        acc = jnp.minimum(state["acc"] + hists, _DEBT_CAP)
+            debt, acc = pallas_sketch.bucket_update(
+                state["debt"], state["acc"], decay, h1, h2, consumed)
+        else:
+            hists = jnp.stack([row_histogram(cols[:, r], consumed, w)
+                               for r in range(d)])
+            if axis_name is not None:
+                # Multi-chip delta merge: replicated debt, psum of increments
+                # over ICI (same invariant as sketch_kernels' delta mode). The
+                # psum'd histogram IS the pod's local traffic, so `acc` stays
+                # export-correct on meshes too.
+                hists = jax.lax.psum(hists, axis_name)
+            debt = jnp.minimum(debt + hists, _DEBT_CAP)
+            acc = jnp.minimum(state["acc"] + hists, _DEBT_CAP)
 
     new_state = {"debt": debt,
                  "acc": acc,
@@ -327,12 +331,13 @@ def build_steps(cfg: Config) -> Tuple[Callable, Callable]:
     if cached is not None:
         return cached
     step = jax.jit(
-        partial(_bucket_step, limit=limit, rate_num=num, rate_den=den,
-                d=d, w=w, iters=iters, tenants=tenants, window_us=wus,
-                use_pallas=use_pallas),
+        named("bucket_step_split", _bucket_step, limit=limit, rate_num=num,
+              rate_den=den, d=d, w=w, iters=iters, tenants=tenants,
+              window_us=wus, use_pallas=use_pallas),
         donate_argnums=(0,))
     reset = jax.jit(
-        partial(_bucket_reset, rate_num=num, rate_den=den, d=d, w=w),
+        named("bucket_reset", _bucket_reset, rate_num=num, rate_den=den,
+              d=d, w=w),
         donate_argnums=(0,))
     _STEP_CACHE[key] = (step, reset)
     return step, reset
@@ -345,10 +350,11 @@ def _bucket_step_h64(state: State, h64, n, now_us, policy=None, hier=None, *,
                      seed: int, premix: bool, **step_kw):
     from ratelimiter_tpu.ops.hashing import split_hash_dev, splitmix64_dev
 
-    h = h64
-    if premix:
-        h = splitmix64_dev(h)
-    h1, h2 = split_hash_dev(h, seed)
+    with jax.named_scope("hash_split"):
+        h = h64
+        if premix:
+            h = splitmix64_dev(h)
+        h1, h2 = split_hash_dev(h, seed)
     return _bucket_step(state, h1, h2, n, now_us, policy, hier, **step_kw)
 
 
@@ -369,10 +375,10 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     if cached is not None:
         return cached
     step = jax.jit(
-        partial(_bucket_step_h64, seed=seed, premix=premix,
-                limit=limit, rate_num=num, rate_den=den,
-                d=d, w=w, iters=iters, tenants=tenants, window_us=wus,
-                use_pallas=use_pallas),
+        named("bucket_step", _bucket_step_h64, seed=seed, premix=premix,
+              limit=limit, rate_num=num, rate_den=den,
+              d=d, w=w, iters=iters, tenants=tenants, window_us=wus,
+              use_pallas=use_pallas),
         donate_argnums=(0,))
     _HASHED_CACHE[key] = step
     return step
@@ -391,6 +397,7 @@ def build_scan(cfg: Config) -> Callable:
         return cached
     step_kw = dict(limit=limit, rate_num=num, rate_den=den, d=d, w=w,
                    iters=iters, use_pallas=use_pallas)
-    scan = jax.jit(partial(_bucket_scan, step_kw=step_kw), donate_argnums=(0,))
+    scan = jax.jit(named("bucket_scan", _bucket_scan, step_kw=step_kw),
+                   donate_argnums=(0,))
     _SCAN_CACHE[key] = scan
     return scan

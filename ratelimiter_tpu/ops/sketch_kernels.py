@@ -43,7 +43,6 @@ virtual-time tests are exact (SURVEY.md §4.3).
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Callable, Dict
 
 import jax
@@ -52,7 +51,7 @@ import jax.numpy as jnp
 from ratelimiter_tpu.core.clock import to_micros
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import InvalidConfigError
-from ratelimiter_tpu.ops import ensure_x64, policy_kernels
+from ratelimiter_tpu.ops import ensure_x64, named, policy_kernels
 from ratelimiter_tpu.ops.segment import admit
 from ratelimiter_tpu.ops.sortmerge import row_gather, row_histogram, row_histogram_max
 
@@ -137,6 +136,7 @@ def init_state(cfg: Config) -> State:
     return state
 
 
+@jax.named_scope("rotate")
 def _rollover(state: State, p, *, SW: int, S: int) -> State:
     """Advance state to period p (p > last_period). Flushes ``cur`` into the
     ring at slot ``last_period % S``, recomputes ``totals`` as the masked sum
@@ -312,23 +312,24 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
     # merges and the hh side table stay on the reference path (the psum'd
     # histogram and private-cell reads are not fused).
     use_pallas = use_pallas and axis_name is None and not hh
-    if use_pallas:
-        from ratelimiter_tpu.ops import pallas_sketch
+    with jax.named_scope("estimate"):
+        if use_pallas:
+            from ratelimiter_tpu.ops import pallas_sketch
 
-        cols = None
-        frac, boundary = _boundary_weight(state, p, now_us, sub_us=sub_us,
-                                          SW=SW, S=S, weighted=weighted,
-                                          pre=pre)
-        bop = (boundary if boundary is not None
-               else jnp.zeros_like(state["totals"]))
-        est = jnp.maximum(
-            pallas_sketch.window_estimate(state["totals"], bop, frac,
-                                          h1, h2), 0.0)
-    else:
-        cols = _columns(h1, h2, d, w)                        # (B, d)
-        est, frac, boundary = _estimate(state, cols, p, now_us,
-                                        sub_us=sub_us, SW=SW, S=S,
-                                        weighted=weighted, pre=pre)
+            cols = None
+            frac, boundary = _boundary_weight(state, p, now_us, sub_us=sub_us,
+                                              SW=SW, S=S, weighted=weighted,
+                                              pre=pre)
+            bop = (boundary if boundary is not None
+                   else jnp.zeros_like(state["totals"]))
+            est = jnp.maximum(
+                pallas_sketch.window_estimate(state["totals"], bop, frac,
+                                              h1, h2), 0.0)
+        else:
+            cols = _columns(h1, h2, d, w)                        # (B, d)
+            est, frac, boundary = _estimate(state, cols, p, now_us,
+                                            sub_us=sub_us, SW=SW, S=S,
+                                            weighted=weighted, pre=pre)
 
     if hh:
         # Heavy-hitter side table (ROADMAP v0.2): a promoted key's NEW
@@ -356,22 +357,23 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
     else:
         mine = None
 
-    if policy is not None:
-        # Per-key limit overrides (policy engine): the search key is the
-        # device-side packing of the (h1, h2) halves the columns already
-        # ride on, so the lookup costs log2(capacity) tiny gathers and no
-        # extra operand. Limits are validated < 2^24 at override-set time
-        # (the same f32-exactness gate as the base limit).
-        q = policy_kernels.pack_halves(h1, h2)
-        pidx, pfound = policy_kernels.lookup_i64(policy["key"], q)
-        lim_f = jnp.where(pfound, policy["limit"][pidx],
-                          jnp.int64(limit)).astype(jnp.float32)
-    else:
-        lim_f = jnp.float32(limit)
-    avail = jnp.maximum(lim_f - est, 0.0)
-    n_f = n.astype(jnp.float32)
-    sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
-    allowed, seen, _ = admit(sid, n_f, avail, iters)
+    with jax.named_scope("admit"):
+        if policy is not None:
+            # Per-key limit overrides (policy engine): the search key is the
+            # device-side packing of the (h1, h2) halves the columns already
+            # ride on, so the lookup costs log2(capacity) tiny gathers and no
+            # extra operand. Limits are validated < 2^24 at override-set time
+            # (the same f32-exactness gate as the base limit).
+            q = policy_kernels.pack_halves(h1, h2)
+            pidx, pfound = policy_kernels.lookup_i64(policy["key"], q)
+            lim_f = jnp.where(pfound, policy["limit"][pidx],
+                              jnp.int64(limit)).astype(jnp.float32)
+        else:
+            lim_f = jnp.float32(limit)
+        avail = jnp.maximum(lim_f - est, 0.0)
+        n_f = n.astype(jnp.float32)
+        sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
+        allowed, seen, _ = admit(sid, n_f, avail, iters)
 
     tn_hist = None
     if tenants and hier is not None:
@@ -414,56 +416,57 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
             tn_hist = jax.lax.psum(tn_hist, axis_name)
     not_mine = True if mine is None else ~mine
 
-    if conservative and axis_name is None:
-        # Conservative update (SURVEY.md hard part #3): raise each touched
-        # cell only as high as the largest single-key post-batch target that
-        # maps to it, never the sum of colliding keys. Target for a key's
-        # last allowed request is est + total in-batch consumption; the
-        # per-column segment-max picks exactly that. Denied requests write
-        # nothing (matching "denial consumes nothing").
-        #
-        # CU requires a globally-sequenced view of the batch, so it applies
-        # on single-chip and mesh-gather paths only. Under the delta merge
-        # (axis_name set) the else-branch's psum-of-increments runs instead:
-        # a pmax of per-chip CU targets would UNDERCOUNT cross-chip traffic
-        # (true counts add across chips) and a psum of per-chip CU deltas
-        # can undercount rows whose dense read exceeds the min-estimate —
-        # both break the never-over-admit direction. Vanilla sums never do.
-        target = jnp.where(allowed & not_mine, est + (avail - seen) + n_f, 0.0)
-        if use_pallas:
-            from ratelimiter_tpu.ops import pallas_sketch
+    with jax.named_scope("write_back"):
+        if conservative and axis_name is None:
+            # Conservative update (SURVEY.md hard part #3): raise each touched
+            # cell only as high as the largest single-key post-batch target that
+            # maps to it, never the sum of colliding keys. Target for a key's
+            # last allowed request is est + total in-batch consumption; the
+            # per-column segment-max picks exactly that. Denied requests write
+            # nothing (matching "denial consumes nothing").
+            #
+            # CU requires a globally-sequenced view of the batch, so it applies
+            # on single-chip and mesh-gather paths only. Under the delta merge
+            # (axis_name set) the else-branch's psum-of-increments runs instead:
+            # a pmax of per-chip CU targets would UNDERCOUNT cross-chip traffic
+            # (true counts add across chips) and a psum of per-chip CU deltas
+            # can undercount rows whose dense read exceeds the min-estimate —
+            # both break the never-over-admit direction. Vanilla sums never do.
+            target = jnp.where(allowed & not_mine, est + (avail - seen) + n_f, 0.0)
+            if use_pallas:
+                from ratelimiter_tpu.ops import pallas_sketch
 
-            totals, cur = pallas_sketch.cu_update(
-                state["totals"], state["cur"], bop, frac, h1, h2, target)
+                totals, cur = pallas_sketch.cu_update(
+                    state["totals"], state["cur"], bop, frac, h1, h2, target)
+            else:
+                deltas = []
+                for r in range(d):
+                    m_r = row_histogram_max(cols[:, r], target, w)
+                    read_r = state["totals"][r].astype(jnp.float32)
+                    if boundary is not None:
+                        read_r = read_r + frac * boundary[r].astype(jnp.float32)
+                    deltas.append(jnp.ceil(jnp.maximum(m_r - read_r, 0.0)))
+                hists = jnp.stack(deltas).astype(jnp.int32)
+                totals = state["totals"] + hists
+                cur = state["cur"] + hists
         else:
-            deltas = []
-            for r in range(d):
-                m_r = row_histogram_max(cols[:, r], target, w)
-                read_r = state["totals"][r].astype(jnp.float32)
-                if boundary is not None:
-                    read_r = read_r + frac * boundary[r].astype(jnp.float32)
-                deltas.append(jnp.ceil(jnp.maximum(m_r - read_r, 0.0)))
-            hists = jnp.stack(deltas).astype(jnp.int32)
-            totals = state["totals"] + hists
-            cur = state["cur"] + hists
-    else:
-        add = jnp.where(allowed & not_mine, n, 0).astype(jnp.int32)  # (B,)
-        if use_pallas:
-            from ratelimiter_tpu.ops import pallas_sketch
+            add = jnp.where(allowed & not_mine, n, 0).astype(jnp.int32)  # (B,)
+            if use_pallas:
+                from ratelimiter_tpu.ops import pallas_sketch
 
-            totals, cur = pallas_sketch.add_update(
-                state["totals"], state["cur"], h1, h2, add)
-        else:
-            hists = jnp.stack([row_histogram(cols[:, r], add, w)
-                               for r in range(d)])
-            if axis_name is not None:
-                # Multi-chip delta merge: every chip adds the summed
-                # histogram, keeping the replicated-state invariant (ICI
-                # psum — the analog of all app servers sharing one Redis,
-                # SURVEY.md §2.6).
-                hists = jax.lax.psum(hists, axis_name)
-            totals = state["totals"] + hists
-            cur = state["cur"] + hists
+                totals, cur = pallas_sketch.add_update(
+                    state["totals"], state["cur"], h1, h2, add)
+            else:
+                hists = jnp.stack([row_histogram(cols[:, r], add, w)
+                                   for r in range(d)])
+                if axis_name is not None:
+                    # Multi-chip delta merge: every chip adds the summed
+                    # histogram, keeping the replicated-state invariant (ICI
+                    # psum — the analog of all app servers sharing one Redis,
+                    # SURVEY.md §2.6).
+                    hists = jax.lax.psum(hists, axis_name)
+                totals = state["totals"] + hists
+                cur = state["cur"] + hists
     # cur and totals share the same histogram so the "current sub-window
     # also counts in totals" invariant holds by construction.
 
@@ -710,17 +713,18 @@ def build_steps(cfg: Config) -> tuple[Callable, Callable, Callable]:
     if cached is not None:
         return cached
     step = jax.jit(
-        partial(_sketch_step, limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-                iters=cfg.max_batch_admission_iters, weighted=weighted,
-                conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
-                use_pallas=use_pallas),
+        named("sketch_step_split", _sketch_step, limit=limit,
+              sub_us=sub_us, SW=SW, S=S, d=d, w=w,
+              iters=cfg.max_batch_admission_iters, weighted=weighted,
+              conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
+              use_pallas=use_pallas),
         donate_argnums=(0,))
     reset = jax.jit(
-        partial(_sketch_reset, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-                weighted=weighted, hh=hh),
+        named("sketch_reset", _sketch_reset, sub_us=sub_us, SW=SW, S=S,
+              d=d, w=w, weighted=weighted, hh=hh),
         donate_argnums=(0,))
     rollover = jax.jit(
-        partial(_rollover, SW=SW, S=S), donate_argnums=(0,))
+        named("sketch_rotate", _rollover, SW=SW, S=S), donate_argnums=(0,))
     _STEP_CACHE[key] = (step, reset, rollover)
     return step, reset, rollover
 
@@ -748,10 +752,11 @@ def _sketch_step_h64(state: State, h64, n, now_us, policy=None, hier=None, *,
                      seed: int, premix: bool, **step_kw):
     from ratelimiter_tpu.ops.hashing import split_hash_dev, splitmix64_dev
 
-    h = h64
-    if premix:
-        h = splitmix64_dev(h)
-    h1, h2 = split_hash_dev(h, seed)
+    with jax.named_scope("hash_split"):
+        h = h64
+        if premix:
+            h = splitmix64_dev(h)
+        h1, h2 = split_hash_dev(h, seed)
     return _sketch_step(state, h1, h2, n, now_us, policy, hier, **step_kw)
 
 
@@ -779,11 +784,11 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     if cached is not None:
         return cached
     step = jax.jit(
-        partial(_sketch_step_h64, seed=seed, premix=premix,
-                limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-                iters=cfg.max_batch_admission_iters, weighted=weighted,
-                conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
-                use_pallas=use_pallas),
+        named("sketch_step", _sketch_step_h64, seed=seed, premix=premix,
+              limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
+              iters=cfg.max_batch_admission_iters, weighted=weighted,
+              conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
+              use_pallas=use_pallas),
         donate_argnums=(0,))
     _HASHED_CACHE[key] = step
     return step
@@ -888,8 +893,8 @@ def build_migrate(old_cfg: Config, new_cfg: Config) -> Callable:
     # No donation: the ring shapes change (So != Sn in general), so the
     # old buffers cannot be reused anyway and donating only warns.
     return jax.jit(
-        partial(_migrate_window, sub_o=sub_o, SWo=SWo, So=So, sub_n=sub_n,
-                SWn=SWn, Sn=Sn, hh=hh))
+        named("sketch_migrate", _migrate_window, sub_o=sub_o, SWo=SWo,
+              So=So, sub_n=sub_n, SWn=SWn, Sn=Sn, hh=hh))
 
 
 _SCAN_CACHE: Dict[tuple, Callable] = {}
@@ -918,6 +923,7 @@ def build_scan(cfg: Config) -> Callable:
                    iters=cfg.max_batch_admission_iters, weighted=weighted,
                    conservative=cu, hh=hh, hh_thresh=hh_thresh,
                    use_pallas=use_pallas)
-    scan = jax.jit(partial(_sketch_scan, step_kw=step_kw), donate_argnums=(0,))
+    scan = jax.jit(named("sketch_scan", _sketch_scan, step_kw=step_kw),
+                   donate_argnums=(0,))
     _SCAN_CACHE[key] = scan
     return scan

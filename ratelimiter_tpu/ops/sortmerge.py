@@ -57,6 +57,7 @@ def _mix_keys(col: jnp.ndarray, w: int) -> jnp.ndarray:
     return jnp.concatenate([cells, batch])
 
 
+@jax.named_scope("sortmerge_gather")
 def row_gather(rows: Sequence[jnp.ndarray], col: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
     """Read ``row[col]`` for each row in ``rows`` at a common (B,) col vector.
 
@@ -84,6 +85,7 @@ def row_gather(rows: Sequence[jnp.ndarray], col: jnp.ndarray) -> Tuple[jnp.ndarr
     return tuple(u[:B] for u in unmixed[1:])
 
 
+@jax.named_scope("sortmerge_histogram")
 def row_histogram(col: jnp.ndarray, add: jnp.ndarray, w: int) -> jnp.ndarray:
     """Dense (w,) histogram H with ``H[c] = sum(add[col == c])``.
 
@@ -105,6 +107,7 @@ def row_histogram(col: jnp.ndarray, add: jnp.ndarray, w: int) -> jnp.ndarray:
     return jnp.diff(a_less, append=total[None])
 
 
+@jax.named_scope("sortmerge_histogram_max")
 def row_histogram_max(col: jnp.ndarray, val: jnp.ndarray, w: int) -> jnp.ndarray:
     """Dense (w,) per-column maxima: ``M[c] = max(val[col == c])``, 0 where
     a column has no entries. ``val`` must be non-negative f32.

@@ -520,17 +520,12 @@ class HttpGateway:
                     import tempfile
                     import time as _time
 
-                    import jax.profiler
-
                     out_dir = tempfile.mkdtemp(prefix="rl_profile_")
                     # NOTE: the first capture of a process pays several
                     # seconds of profiler-server init on top of N —
                     # budget the client timeout accordingly.
-                    jax.profiler.start_trace(out_dir)
-                    try:
+                    with tracing.profile(out_dir) as anchor_ns:
                         _time.sleep(seconds)
-                    finally:
-                        jax.profiler.stop_trace()
                     files = sorted(
                         os.path.relpath(os.path.join(root, f), out_dir)
                         for root, _, fs in os.walk(out_dir) for f in fs)
@@ -547,7 +542,11 @@ class HttpGateway:
                 # mid-capture must not be misreported as a profiler
                 # failure (the broken pipe surfaces in _handle's guard).
                 self._send(200, {"ok": True, "dir": out_dir,
-                                 "seconds": seconds, "files": files})
+                                 "seconds": seconds, "files": files,
+                                 # tracing.now() at the start of the
+                                 # trace's ratelimiter/clock_anchor
+                                 # TraceMe: the two clocks' offset.
+                                 "clock_anchor_mono_ns": anchor_ns})
 
             def _handle_debug_audit(self) -> None:
                 """Live accuracy observatory snapshot (ADR-016): the

@@ -215,6 +215,10 @@ class NativeRateLimitServer:
                 self._shard_limiters.append(
                     shard_decorate(clone, i) if shard_decorate else clone)
         self._locks = [threading.Lock() for _ in range(shards)]
+        #: Per shard: the launch callback's (enter, leave) stamps of the
+        #: ticket its completer resolved last (_resolve -> _spans, same
+        #: thread, back to back).
+        self._door_ns = [None] * shards
 
         # Fleet tier (ADR-017): the bridge partitions every decision
         # frame by keyspace owner BEFORE the shard limiter sees it —
@@ -325,7 +329,8 @@ class NativeRateLimitServer:
                t_d0: int, t_d1: int, t_v0: int, t_v1: int):
         """ABI 9 spans callback (ADR-014): per-ticket CLOCK_MONOTONIC
         stage stamps from the C++ completer — io (enqueue→drain),
-        dispatch (drain→launch returned), device (resolve blocking) and
+        dispatch (drain→ticket pushed), enter and leave (the stage's two
+        ends outside the launch callback), device (resolve blocking) and
         complete (resolve→now) — recorded into the flight recorder on
         the completer thread. Same clock domain as tracing.now()."""
         rec = tracing.RECORDER
@@ -336,6 +341,17 @@ class NativeRateLimitServer:
                        batch=count)
         rec.record("dispatch", t_d0, t_d1, trace_id=trace_id, shard=shard,
                    batch=count)
+        door = self._door_ns[shard]
+        if door is not None and t_d0 <= door[0] <= door[1] <= t_d1:
+            # What C++ holds of the stage, on either side of the launch
+            # callback (_resolve left the ticket's stamps here just
+            # before this call): the gather of the group's columns + the
+            # wait for the GIL, and the GIL's release + the wait for a
+            # slot of the in-flight window + the push.
+            rec.record("enter", t_d0, door[0], trace_id=trace_id,
+                       shard=shard, batch=count)
+            rec.record("leave", door[1], t_d1, trace_id=trace_id,
+                       shard=shard, batch=count)
         rec.record("device", t_v0, t_v1, trace_id=trace_id, shard=shard,
                    batch=count)
         rec.record("complete", t_v1, tracing.now(), trace_id=trace_id,
@@ -552,10 +568,31 @@ class NativeRateLimitServer:
         self._batch_hist.observe(float(b))
         return self._pack_result(out)
 
+    @staticmethod
+    def _trace_enter(shard: int, trace_id: int) -> int:
+        """First line of both launch callbacks. Recorder on: the entry
+        stamp and the thread's trace context, which the spans below the
+        door attribute themselves to. Nothing resets the context: the
+        C++ dispatcher thread runs only these callbacks, and each sets
+        it anew. Recorder off: one None check."""
+        if tracing.RECORDER is None:
+            return 0
+        tracing.set_current(trace_id, shard)
+        return tracing.now()
+
+    @staticmethod
+    def _trace_leave(ticket, t_enter: int):
+        """Last line of both launch callbacks: the ticket carries the
+        callback's two stamps to _spans."""
+        if t_enter:
+            ticket.t_door = (t_enter, tracing.now())
+        return ticket
+
     def _launch_hashed_cb(self, shard: int, ids_b: bytes, ns_b: bytes,
                           trace_id: int = 0):
         """Hashed-lane launch phase (pipelined): stage + enqueue without
         blocking; resolves through the same _resolve completer path."""
+        t_enter = self._trace_enter(shard, trace_id)
         t0 = time.perf_counter()
         lim = self._shard_limiters[shard]
         try:
@@ -571,7 +608,7 @@ class NativeRateLimitServer:
                         self._depth += 1
                         self._inflight_gauge.set(float(self._depth))
                     self._launch_hist.observe(time.perf_counter() - t0)
-                    return ticket
+                    return self._trace_leave(ticket, t_enter)
             with self._locks[shard]:
                 ticket = lim.launch_hashed(h64, ns)
         except Exception as exc:
@@ -585,7 +622,7 @@ class NativeRateLimitServer:
             self._depth += 1
             self._inflight_gauge.set(float(self._depth))
         self._launch_hist.observe(time.perf_counter() - t0)
-        return ticket
+        return self._trace_leave(ticket, t_enter)
 
     def _launch(self, shard: int, blob: bytes, offsets_b: bytes,
                 lengths_b: bytes, ns_b: bytes, trace_id: int = 0):
@@ -593,10 +630,13 @@ class NativeRateLimitServer:
         jitted step WITHOUT blocking on the device; the returned ticket
         is opaque to C++ and comes back through _resolve on the
         completer thread."""
+        t_enter = self._trace_enter(shard, trace_id)
         t0 = time.perf_counter()
         lim = self._shard_limiters[shard]
         try:
-            h64, ns = self._hash_buffers(blob, offsets_b, lengths_b, ns_b)
+            with tracing.span("hash", batch=len(offsets_b) // 8):
+                h64, ns = self._hash_buffers(blob, offsets_b, lengths_b,
+                                             ns_b)
             if self._fleet is not None:
                 ticket = self._fleet_launch(
                     shard, h64, ns, blob=blob,
@@ -610,7 +650,7 @@ class NativeRateLimitServer:
                         self._depth += 1
                         self._inflight_gauge.set(float(self._depth))
                     self._launch_hist.observe(time.perf_counter() - t0)
-                    return ticket
+                    return self._trace_leave(ticket, t_enter)
             with self._locks[shard]:
                 ticket = lim.launch_hashed(h64, ns)
         except Exception as exc:
@@ -622,7 +662,7 @@ class NativeRateLimitServer:
             self._depth += 1
             self._inflight_gauge.set(float(self._depth))
         self._launch_hist.observe(time.perf_counter() - t0)
-        return ticket
+        return self._trace_leave(ticket, t_enter)
 
     def _fleet_resolve(self, shard: int, ticket):
         """Resolve one ticket, merging fleet tickets (local sub-resolve
@@ -658,6 +698,7 @@ class NativeRateLimitServer:
         buffers back to the C++ responder."""
         t0 = time.perf_counter()
         lim = self._shard_limiters[shard]
+        self._door_ns[shard] = ticket.t_door
         try:
             out = self._fleet_resolve(shard, ticket)
         except Exception as exc:
@@ -1067,5 +1108,23 @@ class NativeRateLimitServer:
                 "Reply frames flushed through vectored writes — over "
                 "net_syscalls_total{kind=\"writev\"} this is the "
                 "reply batch factor").set(net.get("writev_frames", 0))
-
-
+        # The door's own stage sums (ABI 9): atomics the C++ completer
+        # adds the stamps of EVERY dispatch to (the ones _spans is
+        # handed), recorder on or off — what a stage costs in an
+        # untraced run, and the exact twin of the ring-derived
+        # rate_limiter_stage_seconds.
+        stage = self.stats()["stage_ns"]
+        tg = self.registry.gauge(
+            "rate_limiter_door_stage_seconds_total",
+            "Wall time the native door's pipeline stages have consumed "
+            "(cumulative, always on): io = enqueue to drain, dispatch = "
+            "drain to ticket pushed, device = blocked on the oldest "
+            "dispatch, complete = resolve returned to replies handed "
+            "over; over door_dispatches_total the exact mean per "
+            "dispatch")
+        for name in ("io", "dispatch", "device", "complete"):
+            tg.set(stage[name] / 1e9, stage=name)
+        self.registry.gauge(
+            "rate_limiter_door_dispatches_total",
+            "Batched dispatches the native door has completed "
+            "(cumulative)").set(stage["batches"])

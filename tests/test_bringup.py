@@ -137,6 +137,9 @@ def test_chip_smoke_reads_policy_answers_and_errors_off_the_metrics():
 _MAY_NAME_IT = {
     "ISSUE.md": "the driver's task statement for the PR; it quotes what "
                 "the PR removes",
+    "PERF_LEDGER.jsonl": "the driver's record of every PR, rewritten "
+                         "before each session; it quotes the title of "
+                         "the PR that removed it",
 }
 _SKIP_DIRS = {".git", "__pycache__", ".jax_cache", ".pytest_cache",
               ".hypothesis", "chiprun_out", ".chipcheck"}

@@ -14,13 +14,22 @@ Covers, per ISSUE 7:
   decisions are identical with the recorder on vs off;
 * metrics.py satellites: label-value escaping per the Prometheus spec,
   locked reads, the bisect bucket scan, OpenMetrics exemplars;
-* the /debug/trace and /debug/profile endpoints' trust boundary.
+* the /debug/trace and /debug/profile endpoints' trust boundary;
+* ISSUE 25, the native door's ``dispatch`` stage from inside: one ring
+  per OS thread whatever the dispatch count, the seven sub-stage spans
+  (enter / hash / prep / place / step / finish / leave) per dispatch, the span
+  primitive's two sinks and its shared no-op, the door's exact stage
+  counters on ``/metrics``.
 """
 
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import glob
 import json
+import os
+import statistics
 import threading
 import urllib.request
 
@@ -95,7 +104,13 @@ class TestRecorder:
         assert ids == list(range(41, cap + 41))
 
     def test_per_thread_rings_no_interleave_corruption(self, recorder):
+        # Rings are keyed by OS thread id: the three writers must be
+        # alive at once, or a later one can be handed an earlier one's id
+        # (and with it its ring, which then wraps).
+        alive = threading.Barrier(3)
+
         def worker(k):
+            alive.wait()
             for i in range(500):
                 t = tracing.now()
                 recorder.record("launch", t, t + 1, trace_id=k)
@@ -361,6 +376,257 @@ class TestNativeDoorSpanTree:
                                        "complete"))
 
 
+# ------------------------------ the dispatch stage from inside (ISSUE 25)
+
+_ALGOS = {"windowed": Algorithm.SLIDING_WINDOW,
+          "bucket": Algorithm.TOKEN_BUCKET}
+#: Both device programs x both lanes of the native door: the cases every
+#: test of this section runs.
+DOOR_CASES = [pytest.param(a, lane, id=f"{a}-{lane}")
+              for a in _ALGOS for lane in ("hashed", "string")]
+_SUB_STAGES = {"hashed": ("enter", "prep", "place", "step", "finish",
+                          "leave"),
+               "string": ("enter", "hash", "prep", "place", "step",
+                          "finish", "leave")}
+
+
+def _door_cfg(algo: str) -> Config:
+    return Config(algorithm=_ALGOS[algo], limit=100, window=60.0,
+                  sketch=SketchParams(depth=2, width=2048, sub_windows=8))
+
+
+@contextlib.contextmanager
+def _native_door(algo: str, registry=None):
+    """A one-shard native door on a frozen clock (so that two runs of the
+    same frames decide the same) with a connected client."""
+    lim = create_limiter(_door_cfg(algo), backend="sketch",
+                         clock=ManualClock(T0))
+    srv = NativeRateLimitServer(lim, "127.0.0.1", 0, max_batch=4096,
+                                max_delay=200e-6,
+                                registry=registry or m.Registry())
+    srv.start()
+    try:
+        with Client(port=srv.port) as c:
+            yield srv, c
+    finally:
+        srv.shutdown()
+        lim.close()
+
+
+def _frame(c, lane: str, i: int, trace_id: int = 0) -> np.ndarray:
+    """Frame i of the section's traffic -> its allow mask. Sixteen keys
+    a frame at cost 30, four key sets in turn: a key crosses its limit of
+    100 on its fourth frame."""
+    lo = 16 * (i % 4)
+    if lane == "hashed":
+        out = c.allow_hashed(np.arange(lo + 1, lo + 17, dtype=np.uint64),
+                             np.full(16, 30, dtype=np.int64),
+                             trace_id=trace_id)
+        return np.asarray(out.allowed)
+    res = c.allow_batch([f"u:{j}" for j in range(lo, lo + 16)], [30] * 16,
+                        trace_id=trace_id)
+    return np.array([r.allowed for r in res])
+
+
+@pytest.mark.skipif(not native_server_available(),
+                    reason="needs g++ for the native server")
+class TestDispatchStageFromInside:
+    @pytest.mark.parametrize("algo,lane", DOOR_CASES)
+    def test_one_ring_per_recording_thread(self, recorder, algo, lane):
+        """The C++ dispatcher and completer enter Python through
+        PyGILState pairs that make and destroy a thread state each: a
+        ring kept in a threading.local was a ring per dispatch. 1,000
+        dispatches = 1,000 _spans calls from the (foreign) completer."""
+        with _native_door(algo) as (srv, c):
+            for i in range(100):
+                _frame(c, lane, i)
+            after_100 = sorted(recorder._rings)
+            for i in range(100, 1000):
+                _frame(c, lane, i)
+            after_1000 = sorted(recorder._rings)
+            assert srv.stats()["stage_ns"]["batches"] == 1000
+        # One shard: its dispatcher and its completer record, nobody else.
+        assert 1 <= len(after_100) <= 2
+        assert after_1000 == after_100
+        assert all(r.name == f"native-{tid}"
+                   for tid, r in recorder._rings.items())
+        dispatches = [s for s in recorder.dump() if s["stage"] == "dispatch"]
+        assert len(dispatches) >= 170      # ring capacity 1024, 6 rows each
+
+    @pytest.mark.parametrize("algo,lane", DOOR_CASES)
+    def test_sub_stages_tile_the_dispatch_span(self, recorder, algo, lane):
+        n = 40
+        with _native_door(algo) as (_, c):
+            for i in range(n):
+                _frame(c, lane, i, trace_id=i + 1)
+        by_trace = {}
+        for s in recorder.dump():
+            by_trace.setdefault(s["trace_id"], {}).setdefault(
+                s["stage"], []).append(s)
+        covered = []
+        for tid in range(1, n + 1):
+            mine = by_trace[tid]
+            (whole,) = mine["dispatch"]
+            at = whole["t_start_ns"]
+            assert mine["enter"][0]["t_start_ns"] == at
+            for stage in _SUB_STAGES[lane]:
+                (sub,) = mine[stage]         # exactly one per dispatch
+                assert sub["shard"] == 0 and sub["batch"] == 16
+                assert at <= sub["t_start_ns"] <= sub["t_end_ns"], stage
+                at = sub["t_end_ns"]         # in order, no overlap
+            assert at == whole["t_end_ns"]    # leave ends where it does
+            if lane == "hashed":
+                assert "hash" not in mine
+            inside = sum(mine[st][0]["t_end_ns"] - mine[st][0]["t_start_ns"]
+                         for st in _SUB_STAGES[lane])
+            covered.append(inside / (whole["t_end_ns"] - whole["t_start_ns"]))
+        # What no sub-stage holds is Python of the callback outside the
+        # limiter (bookkeeping, delegation): a fixed ~0.1 ms, a few
+        # per cent of a dispatch on the chip (dispatch_covered_pct) and
+        # a tenth of the ~0.9 ms dispatches of this geometry on a CPU.
+        assert statistics.median(covered) >= 0.8, covered
+
+    @pytest.mark.parametrize("algo,lane", DOOR_CASES)
+    def test_decisions_identical_whatever_is_on(self, algo, lane):
+        def run(recorder_on: bool, annotate_on: bool) -> np.ndarray:
+            tracing.disable()
+            tracing.annotate(annotate_on)
+            if recorder_on:
+                tracing.enable(1024)
+            try:
+                with _native_door(algo) as (_, c):
+                    return np.concatenate(
+                        [_frame(c, lane, i, trace_id=i + 1)
+                         for i in range(16)])
+            finally:
+                tracing.disable()
+                tracing.annotate(False)
+
+        off = run(False, False)
+        assert off.any() and not off.all()   # the limit was crossed
+        np.testing.assert_array_equal(off, run(True, False))
+        np.testing.assert_array_equal(off, run(True, True))
+
+    @pytest.mark.parametrize("algo", list(_ALGOS))
+    def test_spans_reach_the_profiler_timeline(self, tmp_path, algo):
+        """--trace on (a TracingDecorator in the stack): the sub-stage
+        spans are TraceMes of the profiler's own timeline, nested in the
+        decorator's launch annotation, and the clock anchor is among
+        them with the recorder's clock as its argument."""
+        import jax.profiler
+
+        from ratelimiter_tpu.observability.decorators import TracingDecorator
+
+        tracing.annotate(False)
+        lim = TracingDecorator(create_limiter(
+            _door_cfg(algo), backend="sketch", clock=ManualClock(T0)))
+        assert tracing.ANNOTATE                   # the decorator IS --trace
+        ids = np.arange(1, 17, dtype=np.uint64)
+        lim.resolve(lim.launch_ids(ids))          # compile outside the trace
+        lim.resolve(lim.launch_hashed(ids))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0              # TraceMes only
+        try:
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                before = tracing.now()
+                anchor = tracing.clock_anchor()
+                after = tracing.now()
+                lim.resolve(lim.launch_hashed(ids))
+                lim.resolve(lim.launch_ids(ids, wire=True))
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            tracing.annotate(False)
+            lim.close()
+        assert before <= anchor <= after
+        (pb,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True)
+        found = [e for plane in jax.profiler.ProfileData.from_file(pb).planes
+                 for line in plane.lines for e in line.events
+                 if e.name.startswith("ratelimiter/")]
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for e in found]
+        (mark,) = [e for e in found if e.name == "ratelimiter/clock_anchor"]
+        assert dict(mark.stats)["mono_ns"] == anchor
+        launches = [e for e in events
+                    if e[0] == f"ratelimiter/{_ALGOS[algo].value}/launch"]
+        assert len(launches) == 2
+        for _, lo, hi in launches:
+            assert mark.start_ns <= lo            # the anchor came first
+            inside = [name for name, a, b in sorted(events, key=lambda e: e[1])
+                      if lo <= a and b <= hi and name.count("/") == 1]
+            assert inside == ["ratelimiter/prep", "ratelimiter/place",
+                              "ratelimiter/step", "ratelimiter/finish"]
+
+    @pytest.mark.parametrize("door", ["capture", "debug_profile"])
+    def test_one_profiler_start_stop(self, monkeypatch, tmp_path, door):
+        """TracingDecorator.capture and /debug/profile share
+        tracing.profile: one start, one stop, the anchor in between —
+        and the endpoint's reply names the anchor's reading."""
+        import jax.profiler
+
+        from ratelimiter_tpu.observability.decorators import TracingDecorator
+
+        calls = []
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda d, **kw: calls.append(("start", d)))
+        monkeypatch.setattr(jax.profiler, "stop_trace",
+                            lambda: calls.append(("stop", None)))
+        monkeypatch.setattr(
+            tracing, "clock_anchor",
+            lambda: calls.append(("anchor", None)) or 1234567)
+        lim = create_limiter(_sketch_cfg(), backend="sketch",
+                             clock=ManualClock(T0))
+        try:
+            if door == "capture":
+                with TracingDecorator(lim).capture(str(tmp_path)):
+                    calls.append(("body", None))
+                assert calls[0] == ("start", str(tmp_path))
+            else:
+                gw = HttpGateway(lambda key, n: lim.allow_n(key, n),
+                                 lim.reset, enable_debug=True)
+                gw.start()
+                try:
+                    code, body = TestDebugEndpoints()._get(
+                        gw.port, "/debug/profile?seconds=0.05")
+                finally:
+                    gw.shutdown()
+                assert code == 200, body
+                assert body["clock_anchor_mono_ns"] == 1234567
+                calls.insert(2, ("body", None))
+        finally:
+            tracing.annotate(False)
+            lim.close()
+        assert [c[0] for c in calls] == ["start", "anchor", "body", "stop"]
+
+    @pytest.mark.parametrize("algo,lane", DOOR_CASES)
+    def test_door_stage_counters_on_metrics(self, algo, lane):
+        """The door's always-on stage sums: exported at scrape time, with
+        the recorder off."""
+        tracing.disable()
+        reg = m.Registry()
+        with _native_door(algo, registry=reg) as (srv, c):
+            for i in range(10):
+                _frame(c, lane, i)
+            text = c.metrics()
+            door = srv.stats()["stage_ns"]
+        samples = {}
+        for line in text.splitlines():
+            if line.startswith("rate_limiter_door_"):
+                name, value = line.rsplit(" ", 1)
+                samples[name] = float(value)
+        assert samples["rate_limiter_door_dispatches_total"] \
+            == door["batches"] == 10
+        for stage in ("io", "dispatch", "device", "complete"):
+            got = samples[
+                f'rate_limiter_door_stage_seconds_total{{stage="{stage}"}}']
+            # The scrape came before stats(): sums only grow in between
+            # (the exposition keeps six significant digits).
+            assert 0 < got <= door[stage] / 1e9 * (1 + 1e-5), stage
+        assert "rate_limiter_stage_seconds" not in text   # recorder off
+
+
 # --------------------------------------------------- zero-overhead smoke
 
 
@@ -413,50 +679,58 @@ class TestZeroOverhead:
         decision, at clock-read cost — so the CI margin is loose (1.5x)
         to absorb shared-runner scheduler noise; the tight 3% A/B is a
         bench measurement (``bench.py`` with/without ``--trace``,
-        recorded in ADR-014)."""
+        recorded in ADR-014). Off and on rounds alternate on one warm
+        limiter and their MEDIANS are compared: a stall of the box under
+        six test workers lands in one round of either side, not in a
+        whole side as it did when off ran to its end before on began."""
         import time as _time
 
         from ratelimiter_tpu.serving.batcher import MicroBatcher
 
-        def run(enable: bool) -> float:
+        lim = create_limiter(_sketch_cfg(), backend="sketch")
+        ids = np.arange(1, 2049, dtype=np.uint64)
+        ns = np.ones(len(ids), dtype=np.int64)
+
+        def one_round(enable: bool) -> float:
             tracing.disable()
             if enable:
                 tracing.enable(4096)
+
+            async def drive() -> float:
+                b = MicroBatcher(lim, max_batch=4096, max_delay=50e-6,
+                                 registry=m.Registry())
+                await b.submit_hashed_nowait(ids, ns)   # warm/compile
+                t0 = _time.perf_counter()
+                for i in range(20):
+                    await b.submit_hashed_nowait(
+                        ids, ns, trace_id=(i + 1) if enable else 0)
+                dt = _time.perf_counter() - t0
+                await b.drain()
+                b.close()
+                return dt
+
             try:
-                lim = create_limiter(_sketch_cfg(), backend="sketch")
-                ids = np.arange(1, 2049, dtype=np.uint64)
-                ns = np.ones(len(ids), dtype=np.int64)
-
-                async def drive() -> float:
-                    b = MicroBatcher(lim, max_batch=4096,
-                                     max_delay=50e-6,
-                                     registry=m.Registry())
-                    await b.submit_hashed_nowait(ids, ns)  # warm/compile
-                    t0 = _time.perf_counter()
-                    for i in range(20):
-                        await b.submit_hashed_nowait(
-                            ids, ns, trace_id=(i + 1) if enable else 0)
-                    dt = _time.perf_counter() - t0
-                    await b.drain()
-                    b.close()
-                    return dt
-
-                # Best of 3 rounds: the per-round minimum is the
-                # noise-robust estimator for "cost of the code path".
-                best = min(asyncio.run(drive()) for _ in range(3))
-                lim.close()
-                return best
+                return asyncio.run(drive())
             finally:
                 tracing.disable()
 
-        off = run(False)
-        on = run(True)
+        try:
+            one_round(False)                            # compile, untimed
+            rounds = {False: [], True: []}
+            for i in range(14):
+                rounds[bool(i % 2)].append(one_round(bool(i % 2)))
+        finally:
+            lim.close()
+        off = statistics.median(rounds[False])
+        on = statistics.median(rounds[True])
         assert on <= off * 1.5, (
-            f"recorder-on hot path regressed: {on:.4f}s vs {off:.4f}s "
-            "for 20 traced 2048-id dispatches")
+            f"recorder-on hot path regressed: median {on:.4f}s vs "
+            f"{off:.4f}s for 20 traced 2048-id dispatches "
+            f"(on {rounds[True]}, off {rounds[False]})")
 
-    def test_hot_path_defaults_off(self):
+    def test_hot_path_defaults_off(self, monkeypatch):
         tracing.disable()
+        tracing.annotate(False)
         assert tracing.RECORDER is None
         from ratelimiter_tpu.serving.batcher import MicroBatcher
         lim = create_limiter(_sketch_cfg(), backend="sketch",
@@ -473,6 +747,14 @@ class TestZeroOverhead:
         res = asyncio.run(drive())
         assert res.allowed
         assert tracing.RECORDER is None
+        # Both sinks off: span() is one shared object, and the launch
+        # path under it reads no clock.
+        assert tracing.span("prep", batch=4) is tracing.NO_SPAN
+        monkeypatch.setattr(tracing, "now", lambda: pytest.fail(
+            "the launch path read the span clock with tracing off"))
+        out = lim.resolve(lim.launch_hashed(
+            np.arange(1, 5, dtype=np.uint64), now=T0))
+        assert out.allowed.all()
         lim.close()
 
 
