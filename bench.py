@@ -182,7 +182,7 @@ def measure_mesh_step_rate(n_devices: int, *, seconds: float = 2.0,
     """Aggregate per-device serving dispatch rate of the slice-parallel
     mesh backend (ADR-012): one thread per device slice drives its own
     pinned limiter through the REAL launch/resolve serving path
-    (staging pools, in-step hashing, device-side finish kernels) with a
+    (staging pools, in-step hashing, device-side finish arithmetic) with a
     ``window``-deep per-device in-flight chain. Decisions/s summed over
     devices. Importable — tests/test_mesh_serving.py runs it tiny as the
     CI scaling smoke."""

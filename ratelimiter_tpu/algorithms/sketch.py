@@ -220,12 +220,12 @@ class SketchLimiter(RateLimiter):
 
     def _sync_period(self, now_us: int) -> None:
         """Dispatch the rollover kernel if now_us entered a new sub-window.
-        Must be called with self._lock held."""
-        import jax.numpy as jnp
-
+        Must be called with self._lock held. The period rides as a NumPy
+        scalar — an operand of the one launch, where an eager
+        ``jnp.int64(p)`` is a transfer AND a program of its own."""
         p = now_us // self._sub_us
         if p > self._host_period:
-            self._state = self._rollover(self._state, jnp.int64(p))
+            self._state = self._rollover(self._state, np.int64(p))
             self._host_period = p
 
     # ------------------------------------------------------------- hashing
@@ -265,24 +265,21 @@ class SketchLimiter(RateLimiter):
 
         return {k: jax.device_put(v, self._device) for k, v in state.items()}
 
-    def _place(self, arr: np.ndarray):
-        """Host->device placement hook; mesh subclass shards over chips,
-        a pinned slice commits to its own device."""
-        import jax.numpy as jnp
-
-        if self._device is not None:
-            import jax
-
-            return jax.device_put(arr, self._device)
-        return jnp.asarray(arr)
+    def _stage_operands(self, buf: np.ndarray, padded: int) -> tuple:
+        """The step's batch operands from a filled staging buffer: ONE
+        explicit host->device transfer of the whole buffer (the step
+        slices it, sketch_kernels.unstage). The mesh placement overrides
+        this with its own — a buffer with a scalar tail cannot be
+        sharded by batch."""
+        return (self._place_replicated(buf),)
 
     def _init_staging(self) -> None:
-        # Reusable pinned staging buffers per padded-size bucket: a launch
-        # pops a free (h1p, h2p, nsp) triple (allocating only when every
-        # slot is in flight — bounded by the door's in-flight window) and
-        # resolve returns it AFTER the device has consumed the transfer.
-        # Eliminates the three per-dispatch np.zeros allocations the
-        # pre-pipeline hot path paid (ISSUE-3 tentpole item 2).
+        # Reusable staging buffers per padded-size bucket: a launch pops
+        # a free slot (allocating only when every slot is in flight —
+        # bounded by the door's in-flight window) and resolve returns it
+        # AFTER the device has consumed the transfer. Eliminates the
+        # per-dispatch np.zeros allocations the pre-pipeline hot path
+        # paid (ISSUE-3 tentpole item 2).
         self._staging: dict = {}
         self._staging_lock = threading.Lock()
         # Offered mass of launched-but-unresolved tickets: the strict
@@ -292,16 +289,16 @@ class SketchLimiter(RateLimiter):
         # pessimism errs toward denying, strict mode's direction.
         self._inflight_mass = 0
 
-    def _acquire_staging(self, padded: int):
+    def _acquire_staging(self, padded: int) -> np.ndarray:
         with self._staging_lock:
             free = self._staging.get(padded)
             if free:
                 return free.pop()
-        # One u64 hash buffer + one n buffer per slot: the (h1, h2) split
-        # moved inside the jitted step (ADR-011), halving the staged
-        # arrays and making the hashed wire lane a single memcpy.
-        return (np.empty(padded, dtype=np.uint64),
-                np.empty(padded, dtype=np.int32))
+        # A slot is ONE uint64 buffer [ids(P) | n(P) | now_us(1)]: one
+        # transfer carries every per-dispatch operand (a transfer's cost
+        # is per call, not per byte, at these sizes — PERF.md §6, PR 26),
+        # and the hashed wire lane stays a single memcpy into its head.
+        return np.empty(2 * padded + 1, dtype=np.uint64)
 
     def _release_staging(self, padded: int, slot) -> None:
         if slot is None:
@@ -324,20 +321,24 @@ class SketchLimiter(RateLimiter):
     def _launch_hashed(self, h64: np.ndarray, ns: np.ndarray,
                        now_us: int, t_sec: float, *, premix: bool = False,
                        wire: bool = False) -> DispatchTicket:
-        import jax.numpy as jnp
-
         b = h64.shape[0]
         # The dispatch stage from inside (ADR-014 addendum): prep ->
         # place -> step -> finish, back to back. Tracing off, sp is the
-        # shared no-op and next() does nothing.
+        # shared no-op and next() does nothing. A dispatch is ONE
+        # transfer (place) and ONE program launch (step): the step
+        # slices the slot and finishes its own results, so "finish" is
+        # host bookkeeping only (ADR-010 addendum).
         with tracing.span("prep", batch=b) as sp:
             padded = self._padded_size(b)
             slot = self._acquire_staging(padded)
-            h64p, nsp = slot
-            h64p[:b] = h64
-            h64p[b:] = 0
-            nsp[:b] = ns
-            nsp[b:] = 0
+            slot[:b] = h64
+            slot[b:padded] = 0
+            # n and now_us are signed: written through an int64 view of
+            # the same bytes, narrowed back on device.
+            tail = slot.view(np.int64)
+            tail[padded:padded + b] = ns
+            tail[padded + b:2 * padded] = 0
+            tail[2 * padded] = now_us
             launched = False
             try:
                 with self._lock:
@@ -355,8 +356,8 @@ class SketchLimiter(RateLimiter):
                             result=self._deny_all(b, now_us))
                     step = self._get_ids_step() if premix else self._step
                     sp.next("place")
-                    args = (self._state, self._place(h64p),
-                            self._place(nsp), jnp.int64(now_us),
+                    args = (self._state,
+                            *self._stage_operands(slot, padded),
                             self._policy_device())
                     if self._hier_table is not None:
                         # Cascade tables ride as one extra replicated
@@ -389,19 +390,20 @@ class SketchLimiter(RateLimiter):
                 if not launched:
                     self._release_staging(padded, slot)
             t = DispatchTicket()
-            # retry/reset float math runs ON DEVICE (finish kernels),
-            # queued behind the step — resolve does one bulk fetch, no
-            # NumPy per request (ISSUE-3 tentpole item 3).
-            t.outs = self._launch_finish(outs, now_us)
+            # The step's own outputs: retry/reset float math ran ON
+            # DEVICE at its end, so resolve does one bulk fetch, no
+            # NumPy per request and no second program.
+            t.outs = outs
             if wire:
                 # Wire-lane tickets additionally pack the response ON
                 # DEVICE (bit-packed allow mask + one int64 word array)
                 # so resolve fetches two compact buffers and the
                 # responder frames them with three slice memcpys
-                # (ADR-011).
+                # (ADR-011). A program of its own: folding it into the
+                # step would add a compile per pad shape to prewarm.
                 from ratelimiter_tpu.ops import sketch_kernels
 
-                t.outs = sketch_kernels.pack_wire(*t.outs)
+                t.outs = sketch_kernels.pack_wire(*outs)
                 t.wire = True
             t.b = b
             t.limit = self.config.limit
@@ -422,18 +424,6 @@ class SketchLimiter(RateLimiter):
         and on the CPU host platform concurrent in-flight rendezvous
         starve the shared device pool into a permanent deadlock (see
         _MeshPlacement._fence_dispatch)."""
-
-    def _launch_finish(self, outs, now_us: int):
-        """Queue the device-side result-assembly kernel behind the step
-        (windowed form; the token-bucket subclass overrides)."""
-        import jax.numpy as jnp
-
-        from ratelimiter_tpu.ops import sketch_kernels
-
-        allowed, remaining, _est = outs
-        return sketch_kernels.finish_window(
-            allowed, remaining, jnp.int64(now_us),
-            jnp.int64(self._window_us))
 
     def _retire_ticket(self, t: DispatchTicket, admitted: int) -> None:
         """Once per launched ticket (t.slot is the sentinel): recycle the
@@ -738,14 +728,11 @@ class SketchLimiter(RateLimiter):
     # --------------------------------------------------------------- reset
 
     def _place_replicated(self, arr: np.ndarray):
-        """Placement for inputs of replicated (non-sharded) computations."""
-        import jax.numpy as jnp
+        """Explicit placement for inputs of replicated (non-sharded)
+        computations: the pinned slice's own device, else the default."""
+        import jax
 
-        if self._device is not None:
-            import jax
-
-            return jax.device_put(arr, self._device)
-        return jnp.asarray(arr)
+        return jax.device_put(arr, self._device)
 
     def _reset(self, key: str) -> None:
         import jax.numpy as jnp
@@ -1143,20 +1130,6 @@ class SketchTokenBucketLimiter(SketchLimiter):
             self._state = dict(
                 self._state,
                 rem=self._place_replicated(np.asarray(0, np.int64)))
-
-    def _launch_finish(self, outs, now_us: int):
-        """Token-bucket result assembly, on device: retry-after = deficit /
-        refill rate computed exactly by the step (``tokenbucket.go:122-130``);
-        reset_at is the reference's approximation now + window (time to
-        refill the whole bucket from empty, ``tokenbucket.go:159-165``)."""
-        import jax.numpy as jnp
-
-        from ratelimiter_tpu.ops import bucket_kernels
-
-        allowed, remaining, retry_us = outs
-        return bucket_kernels.finish_bucket(
-            allowed, remaining, retry_us, jnp.int64(now_us),
-            jnp.int64(self._window_us))
 
     # _reset is inherited: the base implementation's _sync_period call is a
     # no-op here, and the reset-step dispatch shape is identical.

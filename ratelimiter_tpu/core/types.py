@@ -188,14 +188,17 @@ class DispatchTicket:
                  "audit", "t_door")
 
     def __init__(self, result: "BatchResult | None" = None):
-        self.outs = None        # device-side (allowed, remaining, retry, reset)
+        self.outs = None        # the step's own outputs, on device:
+        #                         (allowed, remaining, retry, reset) —
+        #                         no second program (ADR-010 addendum)
         self.b = len(result) if result is not None else 0
         self.limit = result.limit if result is not None else 0
         self.limits = None      # host per-request override limits (or None)
         self.ns = None          # host ns[:b] (admitted-mass accounting)
         self.now_us = 0
         self.t_sec = 0.0
-        self.slot = None        # staging buffers to recycle at resolve
+        self.slot = None        # staging buffer to recycle at resolve:
+        #                         one uint64 [ids(P) | n(P) | now_us(1)]
         self.padded = 0
         self.result = result    # set once resolved (or pre-resolved)
         self.meta = None        # decorator/door bookkeeping rides along

@@ -53,7 +53,12 @@ from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.ops import ensure_x64, named, policy_kernels
 from ratelimiter_tpu.ops.dense_kernels import _check_gates
 from ratelimiter_tpu.ops.segment import admit
-from ratelimiter_tpu.ops.sketch_kernels import _columns, _pack_bits
+from ratelimiter_tpu.ops.sketch_kernels import (
+    _columns,
+    _pack_bits,
+    split_staged,
+    unstage,
+)
 from ratelimiter_tpu.ops.sortmerge import row_gather, row_histogram
 
 State = Dict[str, jnp.ndarray]
@@ -287,13 +292,13 @@ def _bucket_scan(state: State, h1s, h2s, ns, now0_us, dt_us, *, step_kw):
     return state, packed, denies
 
 
-@jax.jit
-def finish_bucket(allowed, remaining, retry_us, now_us, window_us):
-    """Device-side result assembly for the debt sketch: retry-after =
-    deficit / refill rate already computed exactly on device by the step
+def finish_bucket(allowed, remaining, retry_us, now_us, window_us: int):
+    """Result assembly for the debt sketch: retry-after = deficit /
+    refill rate already computed exactly by the step
     (``tokenbucket.go:122-130``); reset_at is the reference's now + window
-    approximation (``tokenbucket.go:159-165``). Same one-bulk-fetch
-    contract as sketch_kernels.finish_window (ADR-010)."""
+    approximation (``tokenbucket.go:159-165``). A plain traced function
+    with the same contract as sketch_kernels.finish_window: the ONE
+    definition, shared by the serving step and ops/route_kernels.py."""
     reset = (now_us + window_us).astype(jnp.float64) / 1e6
     return (allowed, remaining.astype(jnp.int64),
             retry_us.astype(jnp.float64) / 1e6,
@@ -346,22 +351,23 @@ def build_steps(cfg: Config) -> Tuple[Callable, Callable]:
 _HASHED_CACHE: Dict[tuple, Callable] = {}
 
 
-def _bucket_step_h64(state: State, h64, n, now_us, policy=None, hier=None, *,
-                     seed: int, premix: bool, **step_kw):
-    from ratelimiter_tpu.ops.hashing import split_hash_dev, splitmix64_dev
-
-    with jax.named_scope("hash_split"):
-        h = h64
-        if premix:
-            h = splitmix64_dev(h)
-        h1, h2 = split_hash_dev(h, seed)
-    return _bucket_step(state, h1, h2, n, now_us, policy, hier, **step_kw)
+def _bucket_step_staged(state: State, staged, policy=None, hier=None, *,
+                        seed: int, premix: bool, window_us: int, **step_kw):
+    h64, n, now_us = unstage(staged)
+    h1, h2 = split_staged(h64, premix, seed)
+    state, (allowed, remaining, retry_us) = _bucket_step(
+        state, h1, h2, n, now_us, policy, hier, window_us=window_us,
+        **step_kw)
+    with jax.named_scope("finish"):
+        return state, finish_bucket(allowed, remaining, retry_us, now_us,
+                                    window_us)
 
 
 def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
-    """Jitted ``step(state, h64, n, now_us, policy)`` with the (h1, h2)
-    split (and, with premix, the splitmix64 finalizer) ON DEVICE — the
-    bucket twin of sketch_kernels.build_hashed_step (ADR-011)."""
+    """Jitted ``step(state, staged, policy[, hier])`` over one staging
+    buffer, returning ``(state, finish_bucket's four columns)`` — the
+    bucket twin of sketch_kernels.build_hashed_step (ADR-011, ADR-010
+    addendum)."""
     from ratelimiter_tpu.ops.sketch_kernels import _resolve_pallas
 
     ensure_x64()
@@ -375,7 +381,7 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     if cached is not None:
         return cached
     step = jax.jit(
-        named("bucket_step", _bucket_step_h64, seed=seed, premix=premix,
+        named("bucket_step", _bucket_step_staged, seed=seed, premix=premix,
               limit=limit, rate_num=num, rate_den=den,
               d=d, w=w, iters=iters, tenants=tenants, window_us=wus,
               use_pallas=use_pallas),

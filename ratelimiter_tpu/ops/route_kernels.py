@@ -298,13 +298,12 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
             from ratelimiter_tpu.ops import sketch_kernels
 
             fin = sketch_kernels.finish_window(
-                allowed_s, remaining_s, now_us, jnp.int64(W))
+                allowed_s, remaining_s, now_us, W)
         else:
             from ratelimiter_tpu.ops import bucket_kernels
 
             fin = bucket_kernels.finish_bucket(
-                allowed_s, remaining_s, rets[2], now_us,
-                jnp.int64(window_us))
+                allowed_s, remaining_s, rets[2], now_us, window_us)
         return (_rewrap_mut(new_state, mut, ovf), fin + (mass,),
                 ovf.astype(jnp.int32))
 

@@ -594,13 +594,15 @@ def _sketch_reset(state: State, h1, h2, now_us, *,
     return out
 
 
-@jax.jit
-def finish_window(allowed, remaining, now_us, window_us):
-    """Device-side result assembly for windowed sketches (sliding and
-    fixed): retry-after is time to window reset (``fixedwindow.go:107-112``)
-    computed ON DEVICE, so the pipelined serving path's resolve phase does
-    one bulk device→host fetch per batch instead of per-request NumPy
-    float math after the blocking readback (ADR-010). Returns
+def finish_window(allowed, remaining, now_us, window_us: int):
+    """Result assembly for windowed sketches (sliding and fixed):
+    retry-after is time to window reset (``fixedwindow.go:107-112``).
+    A plain traced function, the ONE definition of this arithmetic: the
+    serving step (build_hashed_step) and the collective router's step
+    (ops/route_kernels.py) both end with it, so resolve does one bulk
+    device→host fetch and no second program is launched (ADR-010
+    addendum). ``now_us`` is the dispatch's own timestamp operand, not
+    the step's skew-clamped copy; ``window_us`` is static. Returns
     ``(allowed bool[B], remaining int64[B], retry f64[B], reset f64[B])``."""
     cur_ws = (now_us // window_us) * window_us
     reset = (cur_ws + window_us).astype(jnp.float64) / 1e6
@@ -738,34 +740,54 @@ def _resolve_pallas(cfg: Config) -> bool:
 
 # ------------------------------------------------- hashed-operand steps
 #
-# The serving hot path stages ONE uint64 buffer per batch and the step
-# derives (h1, h2) ON DEVICE (ops/hashing.split_hash_dev) — the host
-# never runs per-key hash math after ingest (ADR-011). ``premix=True``
-# additionally applies the splitmix64 finalizer in-step: the raw-u64-id
-# wire lane (T_ALLOW_HASHED) ships tenant ids untouched and the device
-# does ALL the mixing.
+# The serving hot path stages ONE uint64 buffer per batch —
+# ``[ids(P) | n(P) | now_us(1)]``, one host→device transfer — and the
+# step does the rest ON DEVICE: it slices the buffer, narrows ``n``,
+# derives (h1, h2) (ops/hashing.split_hash_dev) and ends with the
+# retry/reset arithmetic, so a dispatch is one transfer and one program
+# launch and the host never runs per-key math after ingest (ADR-011,
+# ADR-010 addendum). ``premix=True`` additionally applies the splitmix64
+# finalizer in-step: the raw-u64-id wire lane (T_ALLOW_HASHED) ships
+# tenant ids untouched and the device does ALL the mixing.
 
 _HASHED_CACHE: Dict[tuple, Callable] = {}
 
 
-def _sketch_step_h64(state: State, h64, n, now_us, policy=None, hier=None, *,
-                     seed: int, premix: bool, **step_kw):
+def unstage(staged):
+    """``uint64[2P + 1]`` staging buffer -> ``(ids uint64[P], n
+    int32[P], now_us int64[])``. The layout is the host's
+    (SketchLimiter._acquire_staging); P is static under jit."""
+    P = (staged.shape[0] - 1) // 2
+    return (staged[:P], staged[P:2 * P].astype(jnp.int32),
+            staged[2 * P].astype(jnp.int64))
+
+
+def split_staged(h64, premix: bool, seed: int):
+    """(h1, h2) of staged keys: finalized hashes, or raw ids to premix."""
     from ratelimiter_tpu.ops.hashing import split_hash_dev, splitmix64_dev
 
     with jax.named_scope("hash_split"):
-        h = h64
-        if premix:
-            h = splitmix64_dev(h)
-        h1, h2 = split_hash_dev(h, seed)
-    return _sketch_step(state, h1, h2, n, now_us, policy, hier, **step_kw)
+        return split_hash_dev(splitmix64_dev(h64) if premix else h64, seed)
+
+
+def _sketch_step_staged(state: State, staged, policy=None, hier=None, *,
+                        seed: int, premix: bool, window_us: int, **step_kw):
+    h64, n, now_us = unstage(staged)
+    h1, h2 = split_staged(h64, premix, seed)
+    state, (allowed, remaining, _est) = _sketch_step(
+        state, h1, h2, n, now_us, policy, hier, **step_kw)
+    with jax.named_scope("finish"):
+        return state, finish_window(allowed, remaining, now_us, window_us)
 
 
 def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
-    """Jitted ``step(state, h64, n, now_us, policy)`` taking finalized
-    64-bit hashes (premix=False — string-key and pre-hashed traffic) or
-    raw u64 ids (premix=True — the hashed wire lane); memoized per static
-    config. Decision-identical to build_steps' (h1, h2) step by the
-    split_hash host/device bit-equality (tests/test_hashing_device.py)."""
+    """Jitted ``step(state, staged, policy[, hier])`` over one staging
+    buffer (see ``unstage``) of finalized 64-bit hashes (premix=False —
+    string-key and pre-hashed traffic) or raw u64 ids (premix=True — the
+    hashed wire lane); returns ``(state, finish_window's four columns)``.
+    Memoized per static config. Decision-identical to build_steps'
+    (h1, h2) step by the split_hash host/device bit-equality
+    (tests/test_hashing_device.py)."""
     ensure_x64()
 
     W, sub_us, SW, S, limit = sketch_geometry(cfg)
@@ -784,8 +806,8 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     if cached is not None:
         return cached
     step = jax.jit(
-        named("sketch_step", _sketch_step_h64, seed=seed, premix=premix,
-              limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
+        named("sketch_step", _sketch_step_staged, seed=seed, premix=premix,
+              window_us=W, limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
               iters=cfg.max_batch_admission_iters, weighted=weighted,
               conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
               use_pallas=use_pallas),

@@ -203,7 +203,6 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                        now: float, *, premix: bool,
                        wire: bool) -> CollectiveDispatchTicket:
         import jax
-        import jax.numpy as jnp
 
         from ratelimiter_tpu.ops import route_kernels
         from ratelimiter_tpu.parallel import mesh_kernels
@@ -235,7 +234,7 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                 args = (mut, ro,
                         mesh_kernels.shard_batch(h64p, self.mesh),
                         mesh_kernels.shard_batch(nsp, self.mesh),
-                        jnp.int64(b), jnp.int64(now_us),
+                        np.int64(b), np.int64(now_us),
                         self._policy_mesh())
                 hier = self._hier_mesh()
                 if hier is not None:

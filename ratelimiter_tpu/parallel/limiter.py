@@ -64,8 +64,17 @@ class _MeshPlacement:
         per_chip = _pad_size(max(1, -(-b // self.n_chips)))
         return per_chip * self.n_chips
 
-    def _place(self, arr: np.ndarray):
-        return mesh_kernels.shard_batch(arr, self.mesh)
+    def _stage_operands(self, buf: np.ndarray, padded: int) -> tuple:
+        # The slot's three views, placed explicitly in one call: ids and
+        # n sharded by batch, the timestamp replicated (the mesh steps
+        # narrow n and now_us on device, like the single-chip step).
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        batch = NamedSharding(self.mesh, P(mesh_kernels.AXIS))
+        return tuple(jax.device_put(
+            (buf[:padded], buf[padded:2 * padded], buf[2 * padded]),
+            (batch, batch, NamedSharding(self.mesh, P()))))
 
     def _place_replicated(self, arr: np.ndarray):
         import jax

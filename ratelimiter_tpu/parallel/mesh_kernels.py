@@ -26,6 +26,7 @@ from functools import partial
 from typing import Callable, Dict, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -142,36 +143,42 @@ def build_mesh_steps(cfg: Config, mesh: Mesh, merge: str = "gather",
 # ----------------------------------------------------- hashed-operand steps
 #
 # Mesh twins of sketch_kernels.build_hashed_step (ADR-011): the batch
-# shards carry ONE uint64 per key and the (h1, h2) split — plus, with
-# premix, the splitmix64 finalizer — runs inside the shard_map'd body
-# (elementwise, so sharding is preserved with no extra collective).
+# shards carry ONE uint64 per key and one per ``n``, the (h1, h2) split
+# — plus, with premix, the splitmix64 finalizer — runs inside the
+# shard_map'd body (elementwise, so sharding is preserved with no extra
+# collective), and the body ends with the single-chip steps' own finish
+# arithmetic on its shard. The operands stay three arrays: a buffer
+# with a scalar tail cannot be sharded by batch, so the mesh placement
+# stages the single-chip slot's three views itself
+# (_MeshPlacement._stage_operands).
 
 _MESH_HASHED_CACHE: Dict[tuple, Callable] = {}
 
 
-def _hashed_body(body, seed: int, premix: bool, step_kw,
+def _hashed_body(body, finish, seed: int, premix: bool, step_kw,
                  hier_arity: bool = False):
-    from ratelimiter_tpu.ops.hashing import split_hash_dev, splitmix64_dev
+    """Per-chip body over the staged views: ``n`` and ``now_us`` arrive
+    as uint64 (the slot's dtype) and narrow here; ``finish(allowed,
+    remaining, third, now_us)`` is the algorithm's finish arithmetic."""
+    def decide(state, h64, n, now_us, policy, hier):
+        h1, h2 = sketch_kernels.split_staged(h64, premix, seed)
+        now_us = now_us.astype(jnp.int64)
+        state, outs = body(state, h1, h2, n.astype(jnp.int32), now_us,
+                           policy, hier, step_kw=step_kw)
+        return state, finish(*outs, now_us)
 
     if hier_arity:
-        def f(state, h64, n, now_us, policy, hier):
-            h = splitmix64_dev(h64) if premix else h64
-            h1, h2 = split_hash_dev(h, seed)
-            return body(state, h1, h2, n, now_us, policy, hier,
-                        step_kw=step_kw)
-    else:
-        def f(state, h64, n, now_us, policy):
-            h = splitmix64_dev(h64) if premix else h64
-            h1, h2 = split_hash_dev(h, seed)
-            return body(state, h1, h2, n, now_us, policy, step_kw=step_kw)
-
-    return f
+        return decide
+    return lambda state, h64, n, now_us, policy: decide(
+        state, h64, n, now_us, policy, None)
 
 
 def build_mesh_hashed_step(cfg: Config, mesh: Mesh, merge: str = "gather",
                            *, premix: bool = False) -> Callable:
     """Jitted mesh ``step(state, h64, n, now_us, policy)`` — h64/n sharded
-    over AXIS, state and policy replicated (build_mesh_steps' contract)."""
+    over AXIS, state, now_us and policy replicated (build_mesh_steps'
+    contract) — returning ``(state, finish_window's four columns)``,
+    sharded like the batch."""
     if merge not in MERGE_MODES:
         raise ValueError(f"merge must be one of {MERGE_MODES}, got {merge!r}")
     W, sub_us, SW, S, limit = sketch_kernels.sketch_geometry(cfg)
@@ -209,10 +216,14 @@ def build_mesh_hashed_step(cfg: Config, mesh: Mesh, merge: str = "gather",
     if tenants:
         in_specs.append(_HIER_SPEC)
     mapped = shard_map(
-        _hashed_body(body, seed, premix, step_kw, hier_arity=bool(tenants)),
+        _hashed_body(
+            body,
+            lambda allowed, remaining, _est, now_us:
+            sketch_kernels.finish_window(allowed, remaining, now_us, W),
+            seed, premix, step_kw, hier_arity=bool(tenants)),
         mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(state_spec, (P(AXIS), P(AXIS), P(AXIS))),
+        out_specs=(state_spec, (P(AXIS),) * 4),
         check_vma=False,
     )
     step = jax.jit(mapped, donate_argnums=(0,))
@@ -250,10 +261,15 @@ def build_mesh_hashed_bucket_step(cfg: Config, mesh: Mesh,
     if tenants:
         in_specs.append(_HIER_SPEC)
     mapped = shard_map(
-        _hashed_body(body, seed, premix, step_kw, hier_arity=bool(tenants)),
+        _hashed_body(
+            body,
+            lambda allowed, remaining, retry_us, now_us:
+            bucket_kernels.finish_bucket(allowed, remaining, retry_us,
+                                         now_us, wus),
+            seed, premix, step_kw, hier_arity=bool(tenants)),
         mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(state_spec, (P(AXIS), P(AXIS), P(AXIS))),
+        out_specs=(state_spec, (P(AXIS),) * 4),
         check_vma=False,
     )
     step = jax.jit(mapped, donate_argnums=(0,))
