@@ -31,7 +31,7 @@ test-mesh:       ## mesh contract + multichip + slice-parallel serving tests
 	    tests/test_mesh_serving.py tests/test_scatter_gather.py -q
 
 test-collective: ## collective router parity + overflow fallback (ADR-024)
-	$(PY) -m pytest tests/test_collective_router.py -q
+	$(PY) -m pytest tests/test_collective_router.py tests/test_collective_deployment.py -q
 
 test-tracing:    ## flight-recorder span trees, both doors (ADR-014)
 	$(PY) -m pytest tests/test_tracing.py -q

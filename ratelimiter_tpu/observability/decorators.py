@@ -402,6 +402,33 @@ class MetricsDecorator(LimiterDecorator):
                 "occupied cell in every sketch row) — errors are toward "
                 "denying")
             reg.add_collect_hook(self._collect_debt_slab)
+        # Collective router (ADR-024): how many frames it launched as one
+        # mesh-wide program and how many it handed to the host router, by
+        # reason, from router_stats() — exported at scrape like the native
+        # door's stage sums, flight recorder on or off, so a run can tell
+        # the collective path from its escape hatch.
+        self._router = base if hasattr(base, "router_stats") else None
+        if self._router is not None:
+            self._coll_disp_g = reg.gauge(
+                "rate_limiter_collective_dispatches_total",
+                "Frames the collective mesh router launched as one "
+                "shard_map program (cumulative; a frame that then "
+                "overflowed a bin is counted here and under fallbacks)")
+            self._coll_fall_g = reg.gauge(
+                "rate_limiter_collective_fallbacks_total",
+                "Frames the collective mesh router handed to the host "
+                "router (cumulative): reason=overflow, a (source, "
+                "destination) bin was full and the step left state "
+                "untouched; reason=strict, the strict overload gate "
+                "decides per slice before dispatch")
+            reg.add_collect_hook(self._collect_router)
+
+    def _collect_router(self) -> None:
+        st = self._router.router_stats()
+        self._coll_disp_g.set(float(st["dispatches"]), shard=self._shard)
+        for reason, count in st["fallback_reasons"].items():
+            self._coll_fall_g.set(float(count), shard=self._shard,
+                                  reason=reason)
 
     def _collect_debt_slab(self) -> None:
         for i, sl in self._debt_slabs:
@@ -436,6 +463,8 @@ class MetricsDecorator(LimiterDecorator):
             self.registry.remove_collect_hook(self._collect_debt_slab)
         if self._hh_units:
             self.registry.remove_collect_hook(self._collect_consumers)
+        if self._router is not None:
+            self.registry.remove_collect_hook(self._collect_router)
         super().close()
 
     def _observe_envelope(self) -> None:

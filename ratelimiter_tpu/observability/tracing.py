@@ -98,6 +98,15 @@ STAGES = (
     "finish",     # host limits, finish/pack programs enqueued, ticket filled
     "leave",      # launch callback's last line -> C++ push stamp (GIL
                   # release + the wait for an in-flight slot); ring only
+    # The collective router's launch (parallel/collective.py) uses prep /
+    # place / step / finish for the work it shares with the launch above,
+    # route for the whole launch, barrier for its resolve, and two stages
+    # of its own:
+    "assemble",   # the mesh and slice locks taken, the rollover check,
+                  # per-slice state leaves gathered into global sharded
+                  # arrays (between place and step)
+    "writeback",  # each device's output shard installed as its slice's
+                  # state leaf (between step and finish)
 )
 _STAGE_CODE: Dict[str, int] = {s: i for i, s in enumerate(STAGES) if s}
 

@@ -94,39 +94,48 @@ def _route(h64, ns, b, n: int, L: int, C: int, premix: bool):
     frame order and padded with decision-inert (0, 0) rows."""
     from ratelimiter_tpu.ops.hashing import splitmix64_dev
 
-    me = jax.lax.axis_index(AXIS)
-    gidx = me.astype(jnp.int64) * L + jnp.arange(L, dtype=jnp.int64)
-    valid_src = gidx < b
-    hfin = splitmix64_dev(h64) if premix else h64
-    owner = (hfin % jnp.uint64(n)).astype(jnp.int32)
-    # Exclusive per-destination rank among this shard's valid rows: a
-    # one-hot cumsum (L x n) — no sort on the routing path.
-    oh = ((owner[:, None] == jnp.arange(n, dtype=jnp.int32)[None, :])
-          & valid_src[:, None]).astype(jnp.int32)
-    rank = jnp.take_along_axis(jnp.cumsum(oh, axis=0) - oh,
-                               owner[:, None].astype(jnp.int32),
-                               axis=1)[:, 0]
-    keep = valid_src & (rank < C)
-    ovf_local = jnp.any(valid_src & (rank >= C))
-    binpos = owner * C + rank
-    # Out-of-range scatter index drops the row (bin padding keeps the
-    # _EMPTY sentinel) — no host-side compaction, no dynamic shapes.
-    pos = jnp.where(keep, binpos, n * C)
-    send_h = jnp.zeros(n * C, jnp.uint64).at[pos].set(h64, mode="drop")
-    send_ns = jnp.full(n * C, _EMPTY, jnp.int32).at[pos].set(
-        ns, mode="drop")
-    recv_h = jax.lax.all_to_all(send_h, AXIS, 0, 0, tiled=True)
-    recv_ns = jax.lax.all_to_all(send_ns, AXIS, 0, 0, tiled=True)
-    valid_r = recv_ns != _EMPTY
-    # Compact owned rows to the front. Source shards are contiguous
-    # frame chunks and the tiled all_to_all concatenates source-major,
-    # so a STABLE sort on validity preserves global frame order — the
-    # order the host router's stable argsort would feed this slice
-    # (the bit-identity linchpin: in-batch same-key sequencing).
-    order = jnp.argsort(~valid_r, stable=True)
-    vr = valid_r[order]
-    h_own = jnp.where(vr, recv_h[order], jnp.uint64(0))
-    ns_own = jnp.where(vr, recv_ns[order], 0)
+    # The phases carry jax.named_scope's (owners, bin, exchange_out,
+    # unbin; the body adds decide, exchange_back) into each op's op_name,
+    # so a device trace can split the exchange from the decision kernel
+    # it wraps. "bin" is rows -> bin layout and "unbin" bin layout ->
+    # rows, on whichever side of an exchange they run.
+    with jax.named_scope("owners"):
+        me = jax.lax.axis_index(AXIS)
+        gidx = me.astype(jnp.int64) * L + jnp.arange(L, dtype=jnp.int64)
+        valid_src = gidx < b
+        hfin = splitmix64_dev(h64) if premix else h64
+        owner = (hfin % jnp.uint64(n)).astype(jnp.int32)
+    with jax.named_scope("bin"):
+        # Exclusive per-destination rank among this shard's valid rows:
+        # a one-hot cumsum (L x n) — no sort on the routing path.
+        oh = ((owner[:, None] == jnp.arange(n, dtype=jnp.int32)[None, :])
+              & valid_src[:, None]).astype(jnp.int32)
+        rank = jnp.take_along_axis(jnp.cumsum(oh, axis=0) - oh,
+                                   owner[:, None].astype(jnp.int32),
+                                   axis=1)[:, 0]
+        keep = valid_src & (rank < C)
+        ovf_local = jnp.any(valid_src & (rank >= C))
+        binpos = owner * C + rank
+        # Out-of-range scatter index drops the row (bin padding keeps the
+        # _EMPTY sentinel) — no host-side compaction, no dynamic shapes.
+        pos = jnp.where(keep, binpos, n * C)
+        send_h = jnp.zeros(n * C, jnp.uint64).at[pos].set(h64, mode="drop")
+        send_ns = jnp.full(n * C, _EMPTY, jnp.int32).at[pos].set(
+            ns, mode="drop")
+    with jax.named_scope("exchange_out"):
+        recv_h = jax.lax.all_to_all(send_h, AXIS, 0, 0, tiled=True)
+        recv_ns = jax.lax.all_to_all(send_ns, AXIS, 0, 0, tiled=True)
+    with jax.named_scope("unbin"):
+        valid_r = recv_ns != _EMPTY
+        # Compact owned rows to the front. Source shards are contiguous
+        # frame chunks and the tiled all_to_all concatenates source-major,
+        # so a STABLE sort on validity preserves global frame order — the
+        # order the host router's stable argsort would feed this slice
+        # (the bit-identity linchpin: in-batch same-key sequencing).
+        order = jnp.argsort(~valid_r, stable=True)
+        vr = valid_r[order]
+        h_own = jnp.where(vr, recv_h[order], jnp.uint64(0))
+        ns_own = jnp.where(vr, recv_ns[order], 0)
     return h_own, ns_own, order, binpos, keep, ovf_local
 
 
@@ -136,13 +145,14 @@ def _return_route(cols, order, binpos, keep):
     order. Rows the source never shipped (overflow) read slot 0 garbage
     — the frame is re-dispatched host-side in that case, so the values
     never reach a client."""
-    out = []
-    safe = jnp.where(keep, binpos, 0)
-    for c in cols:
-        back = jnp.zeros(c.shape, c.dtype).at[order].set(c)
-        ret = jax.lax.all_to_all(back, AXIS, 0, 0, tiled=True)
-        out.append(ret[safe])
-    return out
+    with jax.named_scope("bin"):
+        backs = [jnp.zeros(c.shape, c.dtype).at[order].set(c) for c in cols]
+    with jax.named_scope("exchange_back"):
+        rets = [jax.lax.all_to_all(back, AXIS, 0, 0, tiled=True)
+                for back in backs]
+    with jax.named_scope("unbin"):
+        safe = jnp.where(keep, binpos, 0)
+        return [ret[safe] for ret in rets]
 
 
 def state_layout(cfg: Config) -> Tuple[str, Tuple[str, ...],
@@ -270,22 +280,27 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
             h64, ns, b, n, L, C, premix)
         ovf = jax.lax.pmax(ovf_l.astype(jnp.int32), AXIS) > 0
         state = _unwrap(mut, ro)
-        h = splitmix64_dev(h_own) if premix else h_own
-        h1, h2 = split_hash_dev(h, seed)
-        if kind == "sketch":
-            from ratelimiter_tpu.ops import sketch_kernels
+        # The step's own scopes (hash_split, estimate, admit, ...) stay
+        # inside "decide".
+        with jax.named_scope("decide"):
+            h = splitmix64_dev(h_own) if premix else h_own
+            h1, h2 = split_hash_dev(h, seed)
+            if kind == "sketch":
+                from ratelimiter_tpu.ops import sketch_kernels
 
-            new_state, (allowed, remaining, _est) = \
-                sketch_kernels._sketch_step(
-                    state, h1, h2, ns_own, now_us, policy, hier, **step_kw)
-            retry_col = None
-        else:
-            from ratelimiter_tpu.ops import bucket_kernels
+                new_state, (allowed, remaining, _est) = \
+                    sketch_kernels._sketch_step(
+                        state, h1, h2, ns_own, now_us, policy, hier,
+                        **step_kw)
+                retry_col = None
+            else:
+                from ratelimiter_tpu.ops import bucket_kernels
 
-            new_state, (allowed, remaining, retry_us) = \
-                bucket_kernels._bucket_step(
-                    state, h1, h2, ns_own, now_us, policy, hier, **step_kw)
-            retry_col = retry_us
+                new_state, (allowed, remaining, retry_us) = \
+                    bucket_kernels._bucket_step(
+                        state, h1, h2, ns_own, now_us, policy, hier,
+                        **step_kw)
+                retry_col = retry_us
         mass = jnp.sum(jnp.where(allowed, ns_own, 0)
                        .astype(jnp.int64)).reshape(1)
         cols = [allowed.astype(jnp.uint8), remaining]
@@ -307,6 +322,9 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
         return (_rewrap_mut(new_state, mut, ovf), fin + (mass,),
                 ovf.astype(jnp.int32))
 
+    # jax.jit names the compiled module after its function: a profile
+    # shows jit_routed_sketch_step / jit_routed_bucket_step, not jit_body.
+    body.__name__ = f"routed_{kind}_step"
     mut_spec = {k: P(AXIS) for k in mut_keys}
     ro_spec = {k: P(AXIS) for k in ro_keys}
     policy_spec = {"key": P(), "limit": P()}

@@ -108,9 +108,14 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
         self._pol_ver = -1
         self._hier_dev_mesh = None
         self._hier_ver = -1
-        #: Host-router fallbacks taken (overflow or strict gate) —
-        #: surfaced in consumer stats for the bench's route-phase story.
-        self.fallbacks = 0
+        #: Frames launched as one mesh-wide program, and frames handed to
+        #: the host router instead, by reason: the one source router_stats()
+        #: and /metrics (MetricsDecorator's collect hook) both read. A
+        #: launch counts under _mesh_lock; an overflow is found on the
+        #: resolving thread, so the fallbacks have a lock of their own.
+        self.dispatches = 0
+        self._fallbacks = {"overflow": 0, "strict": 0}
+        self._stats_lock = threading.Lock()
         self._strict_gate = bool(getattr(self.slices[0], "_strict", False))
         self._cpu = self.mesh.devices.flat[0].platform == "cpu"
 
@@ -192,11 +197,22 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
 
     # --------------------------------------------------- routed dispatch
 
+    def _fell_back(self, reason: str) -> None:
+        with self._stats_lock:
+            self._fallbacks[reason] += 1
+
+    @property
+    def fallbacks(self) -> int:
+        """Frames the host router decided in this router's place."""
+        return sum(self._fallbacks.values())
+
     def _use_host_router(self, b: int) -> bool:
         # Strict overload gating is a host-side per-slice admission
         # decision made BEFORE dispatch against each slice's offered
         # mass — it cannot ride a whole-mesh step. Empty frames take
         # the host router's passthrough (nothing to route).
+        if b and self._strict_gate:
+            self._fell_back("strict")
         return b == 0 or self._strict_gate
 
     def _launch_routed(self, arrays: np.ndarray, ns: np.ndarray,
@@ -208,73 +224,91 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
         from ratelimiter_tpu.parallel import mesh_kernels
 
         b = int(arrays.shape[0])
-        n = self.n_slices
-        now_us = to_micros(now)
-        L = _pad_size(max(1, -(-b // n)))
-        C = route_kernels.bin_capacity(
-            L, n, self.config.mesh.bin_headroom)
-        step = route_kernels.build_routed_step(
-            self.config, self.mesh, premix=premix, L=L, capacity=C)
-        padded = L * n
-        h64p = np.zeros(padded, dtype=np.uint64)
-        h64p[:b] = arrays
-        nsp = np.zeros(padded, dtype=np.int32)
-        nsp[:b] = ns
-        rec = tracing.RECORDER
-        t_r0 = tracing.now() if rec is not None else 0
-        with self._mesh_lock:
-            for s in self.slices:
-                s._lock.acquire()
-            try:
+        # The launch from inside, on the stage names the single-chip
+        # launch uses where the work is the same (prep -> place -> step
+        # -> finish, algorithms/sketch.py) plus the two stages only this
+        # path has: assemble and writeback. "route" spans the whole
+        # launch. Tracing off, both are the shared no-op.
+        with tracing.span("route", batch=b), \
+                tracing.span("prep", batch=b) as sp:
+            n = self.n_slices
+            now_us = to_micros(now)
+            L = _pad_size(max(1, -(-b // n)))
+            C = route_kernels.bin_capacity(
+                L, n, self.config.mesh.bin_headroom)
+            step = route_kernels.build_routed_step(
+                self.config, self.mesh, premix=premix, L=L, capacity=C)
+            padded = L * n
+            h64p = np.zeros(padded, dtype=np.uint64)
+            h64p[:b] = arrays
+            nsp = np.zeros(padded, dtype=np.int32)
+            nsp[:b] = ns
+            # The frame's columns are placed BEFORE the locks: they read
+            # no limiter state, and they are the longest host stage of
+            # the launch (two placements sharded four ways). Held across
+            # them, the slice locks were taken for nearly the whole
+            # launch, and resolve — which needs each slice's lock for its
+            # mass bookkeeping — could finish only between two launches:
+            # on the chip the door then fell, within seconds and for
+            # good, into a state where dispatcher and completer take
+            # turns (PERF.md §6, PR 27).
+            sp.next("place")
+            frame = (mesh_kernels.shard_batch(h64p, self.mesh),
+                     mesh_kernels.shard_batch(nsp, self.mesh),
+                     np.int64(b), np.int64(now_us))
+            sp.next("assemble")
+            with self._mesh_lock:
                 for s in self.slices:
-                    if s._injected_failure is not None:
-                        raise s._injected_failure
-                    s._sync_period(now_us)
-                mut, ro = self._assemble_state()
-                args = (mut, ro,
-                        mesh_kernels.shard_batch(h64p, self.mesh),
-                        mesh_kernels.shard_batch(nsp, self.mesh),
-                        np.int64(b), np.int64(now_us),
-                        self._policy_mesh())
-                hier = self._hier_mesh()
-                if hier is not None:
-                    args = args + (hier,)
-                new_mut, fin, ovf = step(*args)
-                self._writeback(new_mut)
-                if self._cpu:
-                    # Same rationale as _MeshPlacement._fence_dispatch:
-                    # xla:cpu collective rendezvous starve the shared
-                    # device pool under concurrent executions — cap the
-                    # stream at one while the dispatch locks are held.
-                    jax.block_until_ready((fin, ovf))
-                if premix:
-                    from ratelimiter_tpu.ops.hashing import splitmix64
+                    s._lock.acquire()
+                try:
+                    for s in self.slices:
+                        if s._injected_failure is not None:
+                            raise s._injected_failure
+                        s._sync_period(now_us)
+                    mut, ro = self._assemble_state()
+                    # The tables are cached device copies, rebuilt under
+                    # the slice locks when an override or tenant changes.
+                    args = (mut, ro, *frame, self._policy_mesh())
+                    hier = self._hier_mesh()
+                    if hier is not None:
+                        args = args + (hier,)
+                    sp.next("step")
+                    new_mut, fin, ovf = step(*args)
+                    if self._cpu:
+                        # Same rationale as _MeshPlacement._fence_dispatch:
+                        # xla:cpu collective rendezvous starve the shared
+                        # device pool under concurrent executions — cap
+                        # the stream at one while the dispatch locks are
+                        # held.
+                        jax.block_until_ready((fin, ovf))
+                    sp.next("writeback")
+                    self._writeback(new_mut)
+                    self.dispatches += 1
+                    sp.next("finish")
+                    if premix:
+                        from ratelimiter_tpu.ops.hashing import splitmix64
 
-                    limits = (self.slices[0]._policy_limits(
-                        splitmix64(arrays))
-                        if len(self.slices[0]._policy_table) else None)
-                else:
-                    limits = self.slices[0]._policy_limits(arrays)
-            finally:
-                for s in reversed(self.slices):
-                    s._lock.release()
-        if rec is not None:
-            # The whole launch is one "route" span — the bench's
-            # host-phase story: no argsort, no index maps, no fan-out.
-            rec.record("route", t_r0, tracing.now(), batch=b)
-        t = CollectiveDispatchTicket()
-        t.outs = fin + (ovf,)
-        t.b = b
-        t.limit = self.config.limit
-        t.limits = limits
-        t.ns = np.asarray(ns)
-        t.now_us = now_us
-        t.t_sec = now
-        t.arrays = arrays
-        t.premix = premix
-        t.wire_lane = bool(wire and premix)
-        t.wire = t.wire_lane
-        return t
+                        limits = (self.slices[0]._policy_limits(
+                            splitmix64(arrays))
+                            if len(self.slices[0]._policy_table) else None)
+                    else:
+                        limits = self.slices[0]._policy_limits(arrays)
+                finally:
+                    for s in reversed(self.slices):
+                        s._lock.release()
+            t = CollectiveDispatchTicket()
+            t.outs = fin + (ovf,)
+            t.b = b
+            t.limit = self.config.limit
+            t.limits = limits
+            t.ns = np.asarray(ns)
+            t.now_us = now_us
+            t.t_sec = now
+            t.arrays = arrays
+            t.premix = premix
+            t.wire_lane = bool(wire and premix)
+            t.wire = t.wire_lane
+            return t
 
     def _launch_routed_guarded(self, arrays: np.ndarray, ns: np.ndarray,
                                now: float, *, premix: bool,
@@ -301,12 +335,12 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
             return ticket.result
         import jax
 
-        rec = tracing.RECORDER
-        t_b0 = tracing.now() if rec is not None else 0
         try:
-            jax.block_until_ready(ticket.outs)
-            allowed, remaining, retry, reset_at, mass, ovf = \
-                jax.device_get(ticket.outs)
+            with tracing.span("barrier", batch=ticket.b,
+                              trace_id=getattr(ticket, "trace_id", 0)):
+                jax.block_until_ready(ticket.outs)
+                allowed, remaining, retry, reset_at, mass, ovf = \
+                    jax.device_get(ticket.outs)
         except Exception as exc:
             ticket.outs = None
             if self.config.fail_open:
@@ -317,17 +351,13 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                 return res
             raise StorageUnavailableError(
                 f"collective resolve failed: {exc}") from exc
-        if rec is not None:
-            rec.record("barrier", t_b0, tracing.now(),
-                       trace_id=getattr(ticket, "trace_id", 0),
-                       batch=ticket.b)
         ticket.outs = None
         if int(ovf):
             # Bin overflow: the step left every state leaf untouched,
             # so re-dispatching the ORIGINAL frame (same rows, same
             # decision timestamp) through the host router admits each
             # row exactly once — no lost, no duplicated mass.
-            self.fallbacks += 1
+            self._fell_back("overflow")
             arrays = ticket.arrays
             owners = (self.owner_of_id(arrays) if ticket.premix
                       else self.owner_of_hash(arrays))
@@ -454,4 +484,6 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
 
     def router_stats(self) -> dict:
         """Collective-path bookkeeping for /v1/health and the bench."""
-        return {"mode": "collective", "fallbacks": self.fallbacks}
+        return {"mode": "collective", "dispatches": self.dispatches,
+                "fallbacks": self.fallbacks,
+                "fallback_reasons": dict(self._fallbacks)}

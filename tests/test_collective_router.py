@@ -102,8 +102,9 @@ class TestDecisionParity:
                 _assert_equal(host.allow_hashed(h, ns, now=now),
                               coll.allow_hashed(h, ns, now=now), i=i)
             assert coll.fallbacks == 0
-            assert coll.router_stats() == {"mode": "collective",
-                                           "fallbacks": 0}
+            assert coll.router_stats() == {
+                "mode": "collective", "dispatches": 12, "fallbacks": 0,
+                "fallback_reasons": {"overflow": 0, "strict": 0}}
         finally:
             host.close()
             coll.close()

@@ -2,7 +2,7 @@
 monitoring CONTRACT, so it must match what servers actually export —
 in BOTH directions.
 
-Three real server binaries (spawned concurrently) cover the
+Four real server binaries (spawned concurrently) cover the
 backend-conditional families:
 
 * a fully-featured windowed-sketch member (fleet + audit + hh +
@@ -13,7 +13,9 @@ backend-conditional families:
   families;
 * a token-bucket server behind the NATIVE door — the debt-slab
   families plus the multi-ring network-engine families (ADR-026:
-  engine info, syscall ledger, writev batch factor).
+  engine info, syscall ledger, writev batch factor);
+* a mesh member behind the collective router (ADR-024) — its dispatch
+  and fallback counters.
 
 Direction 1: every `rate_limiter_*` name written in OPERATIONS §3 must
 exist in the union scrape (a documented name may also be a PREFIX of a
@@ -74,8 +76,8 @@ def _families(text: str) -> set:
 class TestMetricNameDrift:
     def test_operations_section3_matches_scrape_both_directions(
             self, tmp_path):
-        ports = [free_port() for _ in range(3)]
-        https = [free_port() for _ in range(3)]
+        ports = [free_port() for _ in range(4)]
+        https = [free_port() for _ in range(4)]
         cfgpath = os.path.join(str(tmp_path), "fleet.json")
         with open(cfgpath, "w", encoding="utf-8") as f:
             json.dump({"buckets": 32, "epoch": 1, "hosts": [
@@ -108,6 +110,14 @@ class TestMetricNameDrift:
             _spawn(["--algorithm", "token_bucket", "--backend",
                     "sketch", "--native", "--port", str(ports[2]),
                     "--http-port", str(https[2])]),
+            # 4: mesh behind the collective router (its dispatch and
+            # fallback counters).
+            _spawn(["--backend", "mesh", "--mesh-devices", "2",
+                    "--router", "collective", "--sub-windows", "6",
+                    "--port", str(ports[3]),
+                    "--http-port", str(https[3])],
+                   {"XLA_FLAGS":
+                    "--xla_force_host_platform_device_count=2"}),
         ]
         try:
             for proc in procs:
