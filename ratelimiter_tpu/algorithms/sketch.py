@@ -35,6 +35,7 @@ from ratelimiter_tpu.core.types import (
     DispatchTicket,
     Result,
     batch_fail_open,
+    wire_pack,
 )
 from ratelimiter_tpu.observability import tracing
 from ratelimiter_tpu.ops.hashing import split_hash
@@ -49,6 +50,12 @@ def _pad_size(n: int) -> int:
     while size < n:
         size *= 2
     return size
+
+
+def fetch_count(buf) -> int:
+    """Device buffers a fetch of the array ``buf`` asks the device for:
+    one per addressable shard."""
+    return len(buf.sharding.addressable_devices)
 
 
 class SketchLimiter(RateLimiter):
@@ -288,6 +295,8 @@ class SketchLimiter(RateLimiter):
         # inflight*max_batch of admissions past the accuracy budget —
         # pessimism errs toward denying, strict mode's direction.
         self._inflight_mass = 0
+        # Device buffers resolve has fetched (result_fetches).
+        self._fetches = 0
 
     def _acquire_staging(self, padded: int) -> np.ndarray:
         with self._staging_lock:
@@ -326,8 +335,8 @@ class SketchLimiter(RateLimiter):
         # place -> step -> finish, back to back. Tracing off, sp is the
         # shared no-op and next() does nothing. A dispatch is ONE
         # transfer (place) and ONE program launch (step): the step
-        # slices the slot and finishes its own results, so "finish" is
-        # host bookkeeping only (ADR-010 addendum).
+        # slices the slot and packs its own verdicts, so "finish" is
+        # host bookkeeping only (ADR-010 addenda).
         with tracing.span("prep", batch=b) as sp:
             padded = self._padded_size(b)
             slot = self._acquire_staging(padded)
@@ -355,6 +364,7 @@ class SketchLimiter(RateLimiter):
                         return DispatchTicket(
                             result=self._deny_all(b, now_us))
                     step = self._get_ids_step() if premix else self._step
+                    window_us = self._window_us
                     sp.next("place")
                     args = (self._state,
                             *self._stage_operands(slot, padded),
@@ -390,21 +400,14 @@ class SketchLimiter(RateLimiter):
                 if not launched:
                     self._release_staging(padded, slot)
             t = DispatchTicket()
-            # The step's own outputs: retry/reset float math ran ON
-            # DEVICE at its end, so resolve does one bulk fetch, no
-            # NumPy per request and no second program.
+            # The step's own output, ONE int32 buffer: resolve makes one
+            # fetch and rebuilds the 64-bit and float columns in NumPy
+            # from it, now_us and the window the step was built for. A
+            # wire-lane ticket (the asyncio door's hashed lane) has its
+            # reply buffers packed there too (core/types.wire_pack).
             t.outs = outs
-            if wire:
-                # Wire-lane tickets additionally pack the response ON
-                # DEVICE (bit-packed allow mask + one int64 word array)
-                # so resolve fetches two compact buffers and the
-                # responder frames them with three slice memcpys
-                # (ADR-011). A program of its own: folding it into the
-                # step would add a compile per pad shape to prewarm.
-                from ratelimiter_tpu.ops import sketch_kernels
-
-                t.outs = sketch_kernels.pack_wire(*outs)
-                t.wire = True
+            t.wire = wire
+            t.window_us = window_us
             t.b = b
             t.limit = self.config.limit
             t.limits = limits
@@ -425,14 +428,16 @@ class SketchLimiter(RateLimiter):
         starve the shared device pool into a permanent deadlock (see
         _MeshPlacement._fence_dispatch)."""
 
-    def _retire_ticket(self, t: DispatchTicket, admitted: int) -> None:
+    def _retire_ticket(self, t: DispatchTicket, admitted: int,
+                       fetched: int = 0) -> None:
         """Once per launched ticket (t.slot is the sentinel): recycle the
         staging buffers — the step consumed the transfer once its result
         is ready (or failed) — and, in ONE lock acquisition, swap the
         ticket's offered mass out of the strict gate's in-flight
         pessimism for its actual admitted mass. A two-step swap would
         open a window where the batch counts as neither, letting a
-        concurrent launch slip past the budget."""
+        concurrent launch slip past the budget. ``fetched`` device
+        buffers join the always-on count under the same lock."""
         if t.slot is None:
             return
         self._release_staging(t.padded, t.slot)
@@ -440,56 +445,68 @@ class SketchLimiter(RateLimiter):
         with self._lock:
             self._inflight_mass -= int(t.ns.sum())
             self._note_mass_locked(admitted, t.now_us)
+            self._fetches += fetched
+
+    @property
+    def result_fetches(self) -> int:
+        """Device buffers resolve has asked the device for, one per array
+        leaf per addressable shard (cumulative, always on):
+        ``rate_limiter_result_fetches_total``. One a dispatch since the
+        step packs its result; a four-column result was four (seven
+        underneath on a TPU, a 64-bit array being two buffers)."""
+        return self._fetches
+
+    def _result_format(self) -> tuple:
+        """``(rows, unpack)`` of this rule's packed result buffer."""
+        from ratelimiter_tpu.ops import sketch_kernels
+
+        return sketch_kernels.WINDOW_ROWS, sketch_kernels.unpack_window
+
+    def _unpack(self, words: np.ndarray, t: DispatchTicket,
+                shards: int = 1, tail: int = 0) -> tuple:
+        """``(BatchResult's four columns, each shard's tail words)`` from
+        the fetched result buffer of ticket ``t``."""
+        from ratelimiter_tpu.ops import sketch_kernels
+
+        n_rows, unpack = self._result_format()
+        rows, tails = sketch_kernels.result_rows(words, n_rows,
+                                                 shards=shards, tail=tail)
+        return unpack(rows, t.b, t.now_us, t.window_us), tails
 
     def _resolve_ticket(self, t: DispatchTicket) -> BatchResult:
         if t.result is not None:
             return t.result
-        import jax
-
+        # One shard, or under the replicated mesh placement one a chip
+        # (the buffer is sharded like the batch).
+        shards = fetch_count(t.outs)
         try:
             # block_until_ready releases the GIL while the device drains,
             # so a completer thread resolving batch k never stalls the
             # thread launching batch k+1.
-            jax.block_until_ready(t.outs)
-            if t.wire:
-                bits, words = jax.device_get(t.outs)
-            else:
-                allowed, remaining, retry, reset_at = jax.device_get(t.outs)
+            t.outs.block_until_ready()
+            # "fetch": device ready -> NumPy columns built. ONE buffer
+            # (a shard per device) comes over in one call that blocks
+            # with the GIL released; the rest is NumPy on [:b].
+            with tracing.span("fetch", batch=t.b, trace_id=t.trace_id):
+                (allowed, remaining, retry, reset_at), _ = self._unpack(
+                    np.asarray(t.outs), t, shards)
         except BaseException:
             self._retire_ticket(t, 0)
             raise
-        b = t.b
+        wire_packed = None
         if t.wire:
-            # Device-packed wire buffers (sketch_kernels.pack_wire): the
-            # readback is B/8 + 3*B*8 bytes; host work is bit-unpack +
-            # three int64 slice VIEWS (floats recovered by bitcast view,
-            # not conversion).
-            padded = t.padded
-            allowed = np.unpackbits(bits, bitorder="little")[:b].astype(bool)
-            remaining = words[:b]
-            retry = words[padded:padded + b].view(np.float64)
-            reset_at = words[2 * padded:2 * padded + b].view(np.float64)
-            res = BatchResult(
-                allowed=allowed,
-                limit=t.limit,
-                remaining=remaining,
-                retry_after=retry,
-                reset_at=reset_at,
-                limits=t.limits,
-                # The packed buffers ride along so the wire encoder
-                # frames from them directly (no re-bit-packing).
-                wire_packed=(bits, words, padded),
-            )
-        else:
-            res = BatchResult(
-                allowed=allowed[:b],
-                limit=t.limit,
-                remaining=remaining[:b],
-                retry_after=retry[:b],
-                reset_at=reset_at[:b],
-                limits=t.limits,
-            )
-        self._retire_ticket(t, int(t.ns[res.allowed].sum()))
+            wire_packed, remaining, retry, reset_at = wire_pack(
+                allowed, remaining, retry, reset_at)
+        res = BatchResult(
+            allowed=allowed,
+            limit=t.limit,
+            remaining=remaining,
+            retry_after=retry,
+            reset_at=reset_at,
+            limits=t.limits,
+            wire_packed=wire_packed,
+        )
+        self._retire_ticket(t, int(t.ns[allowed].sum()), fetched=shards)
         t.result = res
         t.outs = None
         return res
@@ -546,8 +563,9 @@ class SketchLimiter(RateLimiter):
         jitted step, so the host's per-key work is one staging memcpy.
         The id keyspace is disjoint from the string-key space (different
         finalization); reset/policy control surfaces address string keys
-        only. ``wire=True`` additionally packs the response on device
-        (pack_wire) for the zero-copy responder path."""
+        only. ``wire=True`` has resolve also pack the reply's wire
+        buffers (core/types.wire_pack) for the zero-copy responder
+        path."""
         self._check_open()
         ids = np.asarray(ids, dtype=np.uint64)
         if ns is None:
@@ -999,6 +1017,11 @@ class SketchTokenBucketLimiter(SketchLimiter):
 
     def _sync_period(self, now_us: int) -> None:
         """No ring, no rollover: decay happens inside every step."""
+
+    def _result_format(self) -> tuple:
+        from ratelimiter_tpu.ops import bucket_kernels
+
+        return bucket_kernels.BUCKET_ROWS, bucket_kernels.unpack_bucket
 
     def _hier_counts(self) -> np.ndarray:
         """Bucket-backend scope counters are fixed-window: counts from a

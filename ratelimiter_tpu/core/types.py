@@ -97,9 +97,9 @@ class BatchResult:
     #: Per-request effective limits when policy overrides touched this
     #: batch (int64[B]); None means every request saw the uniform `limit`.
     limits: "np.ndarray | None" = None
-    #: Device-packed wire buffers ``(bits u8[padded/8], words
-    #: i64[3*padded], padded)`` when the dispatch was launched
-    #: ``wire=True`` (sketch_kernels.pack_wire, ADR-011):
+    #: Packed wire buffers ``(bits u8[padded/8], words i64[3*padded],
+    #: padded)`` when the dispatch was launched ``wire=True``
+    #: (``wire_pack`` below, at resolve; ADR-011):
     #: protocol.encode_result_hashed frames straight from these with
     #: slice memcpys instead of re-bit-packing the allow mask. A
     #: 4-tuple ``(bits, words, padded, row_off)`` is the row-window form
@@ -127,7 +127,7 @@ class BatchResult:
     def rows(self, off: int, count: int) -> "BatchResult":
         """A contiguous row-range VIEW of this result (the scatter-gather
         scheduler's per-frame slice of a coalesced window, ADR-013): all
-        arrays are numpy views, and device-packed wire buffers ride
+        arrays are numpy views, and packed wire buffers ride
         along as a row-offset form ``(bits, words, padded, off)`` so the
         wire encoder still frames the sub-range zero-copy
         (protocol.encode_result_hashed_views). ``fail_open`` is the
@@ -153,6 +153,26 @@ class BatchResult:
     @property
     def allow_count(self) -> int:
         return int(np.sum(self.allowed))
+
+
+def wire_pack(allowed: np.ndarray, remaining: np.ndarray,
+              retry_after: np.ndarray, reset_at: np.ndarray) -> tuple:
+    """The hashed wire lane's reply columns in the form its encoder
+    frames from (``BatchResult.wire_packed``): the allow mask bit-packed
+    and ``remaining | retry_after | reset_at`` in ONE int64 buffer, the
+    floats as their bit patterns. Returns ``(wire_packed, remaining,
+    retry_after, reset_at)`` — the three columns as VIEWS of that buffer,
+    so the encoder's memoryviews and the result's columns are the same
+    bytes. Every wire-lane resolve packs here, on the host, from the
+    step's one int32 result buffer (ADR-011 addendum)."""
+    b = allowed.shape[0]
+    words = np.empty(3 * b, dtype=np.int64)
+    cols = (words[:b], words[b:2 * b].view(np.float64),
+            words[2 * b:].view(np.float64))
+    cols[0][:] = remaining
+    cols[1][:] = retry_after
+    cols[2][:] = reset_at
+    return ((np.packbits(allowed, bitorder="little"), words, b), *cols)
 
 
 def batch_fail_open(n: int, limit: int, reset_at: float) -> BatchResult:
@@ -183,27 +203,31 @@ class DispatchTicket:
     ``result`` is already set and resolve just returns it.
     """
 
-    __slots__ = ("outs", "b", "limit", "limits", "ns", "now_us", "t_sec",
-                 "slot", "padded", "result", "meta", "wire", "trace_id",
-                 "audit", "t_door")
+    __slots__ = ("outs", "b", "limit", "limits", "ns", "now_us",
+                 "window_us", "t_sec", "slot", "padded", "result", "meta",
+                 "wire", "trace_id", "audit", "t_door")
 
     def __init__(self, result: "BatchResult | None" = None):
-        self.outs = None        # the step's own outputs, on device:
-        #                         (allowed, remaining, retry, reset) —
-        #                         no second program (ADR-010 addendum)
+        self.outs = None        # the step's own output, on device: ONE
+        #                         int32 buffer a device, the rule's packed
+        #                         rows (sketch_kernels.pack_window,
+        #                         bucket_kernels.pack_bucket) — no second
+        #                         program, one fetch (ADR-010 addenda)
         self.b = len(result) if result is not None else 0
         self.limit = result.limit if result is not None else 0
         self.limits = None      # host per-request override limits (or None)
         self.ns = None          # host ns[:b] (admitted-mass accounting)
-        self.now_us = 0
+        self.now_us = 0         # with window_us (the step's own, as
+        self.window_us = 0      # launched): what resolve rebuilds
+        #                         retry_after / reset_at from
         self.t_sec = 0.0
         self.slot = None        # staging buffer to recycle at resolve:
         #                         one uint64 [ids(P) | n(P) | now_us(1)]
         self.padded = 0
         self.result = result    # set once resolved (or pre-resolved)
         self.meta = None        # decorator/door bookkeeping rides along
-        self.wire = False       # outs are device-packed (bits, words)
-        #                         wire buffers (sketch_kernels.pack_wire)
+        self.wire = False       # resolve also packs the reply's wire
+        #                         buffers (wire_pack)
         self.trace_id = 0       # flight-recorder trace context (ADR-014);
         #                         0 = unsampled. Set by the serving doors
         #                         at launch so resolve-side spans (incl.

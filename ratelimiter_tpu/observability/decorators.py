@@ -423,6 +423,23 @@ class MetricsDecorator(LimiterDecorator):
                 "decides per slice before dispatch")
             reg.add_collect_hook(self._collect_router)
 
+        # Device->host fetches (the resolve half of a dispatch): device
+        # buffers resolve has asked the device for, from the backend's own
+        # always-on count — over rate_limiter_door_dispatches_total, the
+        # fetches a dispatch costs (1 since the step packs its result).
+        self._fetcher = base if hasattr(base, "result_fetches") else None
+        if self._fetcher is not None:
+            self._fetches_g = reg.gauge(
+                "rate_limiter_result_fetches_total",
+                "Device buffers resolve has fetched from the device "
+                "(cumulative): one per array leaf per addressable shard "
+                "of a dispatch's result")
+            reg.add_collect_hook(self._collect_fetches)
+
+    def _collect_fetches(self) -> None:
+        self._fetches_g.set(float(self._fetcher.result_fetches),
+                            shard=self._shard)
+
     def _collect_router(self) -> None:
         st = self._router.router_stats()
         self._coll_disp_g.set(float(st["dispatches"]), shard=self._shard)
@@ -465,6 +482,8 @@ class MetricsDecorator(LimiterDecorator):
             self.registry.remove_collect_hook(self._collect_consumers)
         if self._router is not None:
             self.registry.remove_collect_hook(self._collect_router)
+        if self._fetcher is not None:
+            self.registry.remove_collect_hook(self._collect_fetches)
         super().close()
 
     def _observe_envelope(self) -> None:

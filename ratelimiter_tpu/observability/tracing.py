@@ -53,12 +53,15 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import logging
 import os
 import threading
 import time
 from typing import Dict, List, Optional
 
 import numpy as np
+
+log = logging.getLogger("ratelimiter_tpu.tracing")
 
 #: Stage vocabulary (u8 codes in the record). Both doors + the mesh
 #: composite use these names; unknown names are rejected loudly so
@@ -107,6 +110,11 @@ STAGES = (
                   # arrays (between place and step)
     "writeback",  # each device's output shard installed as its slice's
                   # state leaf (between step and finish)
+    # The resolve half of a dispatch (SketchLimiter._resolve_ticket, the
+    # collective router's resolve), on the resolving thread:
+    "fetch",      # device ready -> BatchResult's NumPy columns built: the
+                  # one packed result buffer fetched (a shard a device),
+                  # the 64-bit and float columns rebuilt on the host
 )
 _STAGE_CODE: Dict[str, int] = {s: i for i, s in enumerate(STAGES) if s}
 
@@ -574,7 +582,14 @@ def profile(out_dir: str):
     try:
         yield clock_anchor()
     finally:
+        # Stopping collects and writes the trace, and that grows with the
+        # device programs captured (every op of every execution on every
+        # chip): minutes for a few seconds of a busy mesh. Logged, so
+        # whoever waits for /debug/profile can size their timeout.
+        t0 = time.monotonic()
         jax.profiler.stop_trace()
+        log.info("profile capture in %s: stop_trace took %.1fs", out_dir,
+                 time.monotonic() - t0)
 
 
 # ----------------------------------------------- current-trace context

@@ -47,6 +47,7 @@ from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ratelimiter_tpu.core.clock import MICROS
 from ratelimiter_tpu.core.config import Config
@@ -56,7 +57,10 @@ from ratelimiter_tpu.ops.segment import admit
 from ratelimiter_tpu.ops.sketch_kernels import (
     _columns,
     _pack_bits,
+    join_words,
+    pack_rows,
     split_staged,
+    split_words,
     unstage,
 )
 from ratelimiter_tpu.ops.sortmerge import row_gather, row_histogram
@@ -292,17 +296,35 @@ def _bucket_scan(state: State, h1s, h2s, ns, now0_us, dt_us, *, step_kw):
     return state, packed, denies
 
 
-def finish_bucket(allowed, remaining, retry_us, now_us, window_us: int):
-    """Result assembly for the debt sketch: retry-after = deficit /
-    refill rate already computed exactly by the step
-    (``tokenbucket.go:122-130``); reset_at is the reference's now + window
-    approximation (``tokenbucket.go:159-165``). A plain traced function
-    with the same contract as sketch_kernels.finish_window: the ONE
-    definition, shared by the serving step and ops/route_kernels.py."""
-    reset = (now_us + window_us).astype(jnp.float64) / 1e6
-    return (allowed, remaining.astype(jnp.int64),
-            retry_us.astype(jnp.float64) / 1e6,
-            jnp.broadcast_to(reset, allowed.shape))
+#: Rows of the debt sketch's packed result: allowed, remaining, and
+#: ``retry_us`` as its low and high 32-bit words.
+BUCKET_ROWS = 4
+
+
+def pack_bucket(allowed, remaining, retry_us):
+    """The debt sketch's result as it leaves the device: ``int32[4P] =
+    [allowed(P) | remaining(P) | retry_us low word(P) | retry_us high
+    word(P)]`` — the bucket twin of sketch_kernels.pack_window, with the
+    same contract: a plain traced function, the ONE definition, shared
+    by the serving step, the replicated mesh's and ops/route_kernels.py.
+    ``remaining`` is whole tokens in ``[0, limit_k]`` with ``limit_k *
+    1e6 < 2**42`` (dense_kernels._check_gates, SketchTokenBucketLimiter.
+    _policy_validate), so under 2**23: one word. ``retry_us`` is the
+    step's exact int64 (``tokenbucket.go:122-130``) — a deficit of up to
+    ``n`` tokens at the refill rate, or the cascade's window boundary —
+    and passes 2**32 us (71 minutes) on long windows, so it is split by
+    a mask and a shift, no 64-bit value and no float among the outputs."""
+    return pack_rows(allowed, remaining, *split_words(retry_us))
+
+
+def unpack_bucket(rows: np.ndarray, b: int, now_us: int, window_us: int):
+    """BatchResult's four columns from pack_bucket's rows, on the host
+    (see sketch_kernels.unpack_window): retry-after = the step's
+    ``retry_us`` / 1e6 in IEEE float64; reset_at is the reference's now +
+    window approximation (``tokenbucket.go:159-165``)."""
+    return (rows[0, :b].astype(bool), rows[1, :b].astype(np.int64),
+            join_words(rows[2, :b], rows[3, :b]).astype(np.float64) / 1e6,
+            np.full(b, (now_us + window_us) / 1e6))
 
 
 _STEP_CACHE: Dict[tuple, Tuple[Callable, Callable]] = {}
@@ -359,13 +381,12 @@ def _bucket_step_staged(state: State, staged, policy=None, hier=None, *,
         state, h1, h2, n, now_us, policy, hier, window_us=window_us,
         **step_kw)
     with jax.named_scope("finish"):
-        return state, finish_bucket(allowed, remaining, retry_us, now_us,
-                                    window_us)
+        return state, pack_bucket(allowed, remaining, retry_us)
 
 
 def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     """Jitted ``step(state, staged, policy[, hier])`` over one staging
-    buffer, returning ``(state, finish_bucket's four columns)`` — the
+    buffer, returning ``(state, pack_bucket's one buffer)`` — the
     bucket twin of sketch_kernels.build_hashed_step (ADR-011, ADR-010
     addendum)."""
     from ratelimiter_tpu.ops.sketch_kernels import _resolve_pallas

@@ -16,8 +16,10 @@ device
    (sketch_kernels._sketch_step / bucket_kernels._bucket_step) on the
    rows it owns, against its own slice state (sharded, not replicated —
    each device's shard IS that slice's counters), and
-5. all-to-all's the verdicts back to source order and assembles the
-   finish_window/finish_bucket result columns in frame order.
+5. all-to-all's the verdicts back to source order and packs them, in
+   frame order, into the rule's one int32 result buffer
+   (sketch_kernels.pack_window / bucket_kernels.pack_bucket) with a tail
+   of its own: this slice's admitted mass and the overflow flag.
 
 The host never argsorts, never builds index maps, never fans out
 sub-launches; resolve blocks on one ticket.
@@ -54,6 +56,10 @@ import jax.numpy as jnp
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.ops import ensure_x64
 from ratelimiter_tpu.parallel.mesh import AXIS
+
+#: Words each device appends to its shard of the packed result: admitted
+#: mass (low, high), overflow flag.
+ROUTED_TAIL = 3
 
 #: Empty bin slots travel with this ns sentinel so the destination can
 #: tell a routed row from bin padding without shipping an index column.
@@ -202,10 +208,13 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
     never recompiles — only a new L bucket does). Policy (and cascade)
     tables ride replicated, exactly as on the single-slice step.
 
-    Returns ``(new_mut, (allowed, remaining, retry, reset, mass), ovf)``
-    — the four finish columns in global frame order, the per-slice
-    admitted mass (n,), and the replicated overflow flag. On overflow
-    every state leaf is returned UNCHANGED."""
+    Returns ``(new_mut, words)``: ONE int32 buffer sharded over AXIS,
+    each device's shard the rule's packed rows over its L frame rows
+    (global frame order once the shards lie side by side —
+    sketch_kernels.result_rows) followed by ROUTED_TAIL words of its
+    own: this slice's admitted mass as (low, high) words and the
+    overflow flag (a pmax, the same on every device). On overflow every
+    state leaf is returned UNCHANGED."""
     from ratelimiter_tpu.parallel.mesh_kernels import _HIER_SPEC, shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -244,7 +253,6 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
         step_kw = dict(limit=limit, rate_num=num, rate_den=den, d=d, w=w,
                        iters=iters, tenants=tenants_, window_us=wus,
                        use_pallas=use_pallas)
-        window_us = wus
     key = (kind, mesh_key, statics, seed, premix, L, capacity)
     cached = _ROUTED_CACHE.get(key)
     if cached is not None:
@@ -273,6 +281,7 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
         return out
 
     def body(mut, ro, h64, ns, b, now_us, policy, hier=None):
+        from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
         from ratelimiter_tpu.ops.hashing import split_hash_dev, \
             splitmix64_dev
 
@@ -286,16 +295,12 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
             h = splitmix64_dev(h_own) if premix else h_own
             h1, h2 = split_hash_dev(h, seed)
             if kind == "sketch":
-                from ratelimiter_tpu.ops import sketch_kernels
-
                 new_state, (allowed, remaining, _est) = \
                     sketch_kernels._sketch_step(
                         state, h1, h2, ns_own, now_us, policy, hier,
                         **step_kw)
                 retry_col = None
             else:
-                from ratelimiter_tpu.ops import bucket_kernels
-
                 new_state, (allowed, remaining, retry_us) = \
                     bucket_kernels._bucket_step(
                         state, h1, h2, ns_own, now_us, policy, hier,
@@ -303,24 +308,22 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
                 retry_col = retry_us
         mass = jnp.sum(jnp.where(allowed, ns_own, 0)
                        .astype(jnp.int64)).reshape(1)
+        tail = (*sketch_kernels.split_words(mass), ovf.reshape(1))
         cols = [allowed.astype(jnp.uint8), remaining]
         if retry_col is not None:
             cols.append(retry_col)
         rets = _return_route(cols, order, binpos, keep)
         allowed_s = rets[0].astype(jnp.bool_)
         remaining_s = rets[1]
-        if kind == "sketch":
-            from ratelimiter_tpu.ops import sketch_kernels
-
-            fin = sketch_kernels.finish_window(
-                allowed_s, remaining_s, now_us, W)
-        else:
-            from ratelimiter_tpu.ops import bucket_kernels
-
-            fin = bucket_kernels.finish_bucket(
-                allowed_s, remaining_s, rets[2], now_us, window_us)
-        return (_rewrap_mut(new_state, mut, ovf), fin + (mass,),
-                ovf.astype(jnp.int32))
+        with jax.named_scope("finish"):
+            if kind == "sketch":
+                words = sketch_kernels.pack_window(allowed_s, remaining_s)
+            else:
+                words = bucket_kernels.pack_bucket(allowed_s, remaining_s,
+                                                   rets[2])
+            words = jnp.concatenate(
+                [words, sketch_kernels.pack_rows(*tail)])
+        return _rewrap_mut(new_state, mut, ovf), words
 
     # jax.jit names the compiled module after its function: a profile
     # shows jit_routed_sketch_step / jit_routed_bucket_step, not jit_body.
@@ -331,9 +334,7 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
     in_specs = [mut_spec, ro_spec, P(AXIS), P(AXIS), P(), P(), policy_spec]
     if tenants:
         in_specs.append(_HIER_SPEC)
-    out_specs = (mut_spec,
-                 (P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
-                 P())
+    out_specs = (mut_spec, P(AXIS))
     # check_vma=False for the same reason as mesh_kernels: ovf IS
     # replicated (a pmax result) but the checker cannot prove it, and
     # the sharded state outputs flow through sort/cumsum chains.

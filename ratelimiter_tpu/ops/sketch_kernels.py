@@ -47,6 +47,7 @@ from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ratelimiter_tpu.core.clock import to_micros
 from ratelimiter_tpu.core.config import Config
@@ -594,22 +595,88 @@ def _sketch_reset(state: State, h1, h2, now_us, *,
     return out
 
 
-def finish_window(allowed, remaining, now_us, window_us: int):
-    """Result assembly for windowed sketches (sliding and fixed):
-    retry-after is time to window reset (``fixedwindow.go:107-112``).
-    A plain traced function, the ONE definition of this arithmetic: the
-    serving step (build_hashed_step) and the collective router's step
-    (ops/route_kernels.py) both end with it, so resolve does one bulk
-    device→host fetch and no second program is launched (ADR-010
-    addendum). ``now_us`` is the dispatch's own timestamp operand, not
-    the step's skew-clamped copy; ``window_us`` is static. Returns
-    ``(allowed bool[B], remaining int64[B], retry f64[B], reset f64[B])``."""
-    cur_ws = (now_us // window_us) * window_us
-    reset = (cur_ws + window_us).astype(jnp.float64) / 1e6
-    retry = jnp.where(allowed, jnp.float64(0.0),
-                      (cur_ws + window_us - now_us).astype(jnp.float64) / 1e6)
-    return (allowed, remaining.astype(jnp.int64), retry,
-            jnp.broadcast_to(reset, allowed.shape))
+# ------------------------------------------------- the packed result
+#
+# What leaves the device per dispatch is ONE int32 buffer per device and
+# nothing else: rows of P words each, ``[row 0 (P) | row 1 (P) | ...]``,
+# row 0 the allow bit, row 1 ``remaining``; the debt sketch adds two
+# rows (bucket_kernels.pack_bucket). Every 64-bit or floating column of
+# BatchResult is rebuilt on the host from these integers and the
+# ticket's own ``now_us`` (unpack_window / bucket_kernels.unpack_bucket)
+# in IEEE float64 — a v5e has no 64-bit vectors, so an int64 or float64
+# output is two 32-bit buffers the host re-joins at every fetch, and its
+# float64 division is emulated (ADR-010 addendum 2). Under a mesh the
+# buffer is sharded by batch: every device holds the same rows over its
+# own P/n keys (plus, under the collective router, a tail of its own —
+# ops/route_kernels.py), and ``result_rows`` lays the shards side by
+# side again.
+
+#: Rows of the windowed rules' packed result: allowed, remaining.
+WINDOW_ROWS = 2
+
+
+def pack_rows(*rows):
+    """``int32[len(rows) * P]``: the rows one after the other, each
+    narrowed (or widened) to int32. The one concatenation both rules'
+    packers end with."""
+    return jnp.concatenate([r.astype(jnp.int32) for r in rows])
+
+
+def split_words(x):
+    """int64 -> its (low, high) 32-bit words as int32, by a mask and a
+    shift: a ``bitcast_convert_type`` on a 64-bit type is what the TPU's
+    X64 rewriter refuses (PERF.md §5)."""
+    low = jax.lax.bitcast_convert_type(
+        (x & 0xFFFFFFFF).astype(jnp.uint32), jnp.int32)
+    return low, (x >> 32).astype(jnp.int32)
+
+
+def join_words(low: np.ndarray, high: np.ndarray) -> np.ndarray:
+    """Host inverse of split_words: int64 from int32 word arrays."""
+    return (high.astype(np.int64) << 32) | low.astype(np.uint32)
+
+
+def pack_window(allowed, remaining):
+    """The windowed sketches' (sliding and fixed) result as it leaves the
+    device: ``int32[2P] = [allowed(P) | remaining(P)]``. A plain traced
+    function, the ONE definition of this format: the serving step
+    (build_hashed_step), the replicated mesh's (parallel/mesh_kernels.py)
+    and the collective router's (ops/route_kernels.py) all end with it.
+    ``remaining`` is the step's own int32, in ``[0, limit_k]`` where
+    ``limit_k`` is the config limit or the key's override — both < 2**24
+    (check_gates, SketchLimiter._policy_validate) — so one word holds
+    it. No retry or reset word is shipped: both are functions of
+    ``now_us`` and the allow bit alone (unpack_window)."""
+    return pack_rows(allowed, remaining)
+
+
+def result_rows(words: np.ndarray, rows: int, *, shards: int = 1,
+                tail: int = 0):
+    """Host side of pack_rows: the fetched ``int32[shards * (rows * Pl +
+    tail)]`` as ``(int32[rows, shards * Pl], int32[shards, tail])`` —
+    the rows in batch order over all shards, and each shard's tail words.
+    One shard: both are views of the fetch."""
+    per = words.shape[0] // shards
+    pl = (per - tail) // rows
+    by_shard = words.reshape(shards, per)
+    body = by_shard[:, :rows * pl].reshape(shards, rows, pl)
+    return (body.transpose(1, 0, 2).reshape(rows, shards * pl),
+            by_shard[:, rows * pl:])
+
+
+def unpack_window(rows: np.ndarray, b: int, now_us: int, window_us: int):
+    """BatchResult's four columns from pack_window's rows, on the host:
+    ``(allowed bool[b], remaining int64[b], retry_after f64[b], reset_at
+    f64[b])``. Retry-after is time to window reset
+    (``fixedwindow.go:107-112``): the arithmetic the step itself ran
+    until PR 29, now in IEEE float64 on two Python integers — ``now_us``
+    is the dispatch's own timestamp (the ticket's), not the step's
+    skew-clamped copy."""
+    allowed = rows[0, :b].astype(bool)
+    reset_us = now_us // window_us * window_us + window_us
+    return (allowed, rows[1, :b].astype(np.int64),
+            np.where(allowed, 0.0, (reset_us - now_us) / 1e6),
+            np.full(b, reset_us / 1e6))
 
 
 def _pack_bits(mask):
@@ -743,10 +810,11 @@ def _resolve_pallas(cfg: Config) -> bool:
 # The serving hot path stages ONE uint64 buffer per batch —
 # ``[ids(P) | n(P) | now_us(1)]``, one host→device transfer — and the
 # step does the rest ON DEVICE: it slices the buffer, narrows ``n``,
-# derives (h1, h2) (ops/hashing.split_hash_dev) and ends with the
-# retry/reset arithmetic, so a dispatch is one transfer and one program
-# launch and the host never runs per-key math after ingest (ADR-011,
-# ADR-010 addendum). ``premix=True`` additionally applies the splitmix64
+# derives (h1, h2) (ops/hashing.split_hash_dev) and ends by packing its
+# verdicts into one int32 buffer (pack_window), so a dispatch is one
+# transfer in, one program launch and one transfer out, and the host
+# never runs per-key hash math after ingest (ADR-011, ADR-010
+# addenda). ``premix=True`` additionally applies the splitmix64
 # finalizer in-step: the raw-u64-id wire lane (T_ALLOW_HASHED) ships
 # tenant ids untouched and the device does ALL the mixing.
 
@@ -771,20 +839,20 @@ def split_staged(h64, premix: bool, seed: int):
 
 
 def _sketch_step_staged(state: State, staged, policy=None, hier=None, *,
-                        seed: int, premix: bool, window_us: int, **step_kw):
+                        seed: int, premix: bool, **step_kw):
     h64, n, now_us = unstage(staged)
     h1, h2 = split_staged(h64, premix, seed)
     state, (allowed, remaining, _est) = _sketch_step(
         state, h1, h2, n, now_us, policy, hier, **step_kw)
     with jax.named_scope("finish"):
-        return state, finish_window(allowed, remaining, now_us, window_us)
+        return state, pack_window(allowed, remaining)
 
 
 def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     """Jitted ``step(state, staged, policy[, hier])`` over one staging
     buffer (see ``unstage``) of finalized 64-bit hashes (premix=False —
     string-key and pre-hashed traffic) or raw u64 ids (premix=True — the
-    hashed wire lane); returns ``(state, finish_window's four columns)``.
+    hashed wire lane); returns ``(state, pack_window's one buffer)``.
     Memoized per static config. Decision-identical to build_steps'
     (h1, h2) step by the split_hash host/device bit-equality
     (tests/test_hashing_device.py)."""
@@ -807,29 +875,13 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
         return cached
     step = jax.jit(
         named("sketch_step", _sketch_step_staged, seed=seed, premix=premix,
-              window_us=W, limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
+              limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
               iters=cfg.max_batch_admission_iters, weighted=weighted,
               conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
               use_pallas=use_pallas),
         donate_argnums=(0,))
     _HASHED_CACHE[key] = step
     return step
-
-
-@jax.jit
-def pack_wire(allowed, remaining, retry, reset):
-    """Device-side response packing for the hashed wire lane (ADR-011):
-    the allow mask bit-packs to B/8 bytes and remaining/retry/reset ride
-    ONE (3B,) int64 array (floats bitcast), so resolve fetches two
-    compact buffers and the responder's frame build is three slice
-    memcpys — no per-request host math, no per-request Python objects."""
-    bits = _pack_bits(allowed)
-    words = jnp.concatenate([
-        remaining.astype(jnp.int64),
-        jax.lax.bitcast_convert_type(retry.astype(jnp.float64), jnp.int64),
-        jax.lax.bitcast_convert_type(reset.astype(jnp.float64), jnp.int64),
-    ])
-    return bits, words
 
 
 def _migrate_window(state: State, now_us, *, sub_o: int, SWo: int, So: int,

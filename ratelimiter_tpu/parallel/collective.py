@@ -9,9 +9,9 @@ ONE jitted shard_map step over the slice mesh
 shard of the frame columns, computes owners on device, all-to-all's
 rows to their owning slices, runs the unchanged fused decision kernel
 against its own slice state, and all-to-all's the verdicts back to
-frame order. The host stages two columns and fetches four; it never
-argsorts, never builds index maps, never fans out sub-launches, and
-resolve blocks on ONE ticket.
+frame order. The host stages two columns and fetches one packed buffer
+(a shard a device); it never argsorts, never builds index maps, never
+fans out sub-launches, and resolve blocks on ONE ticket.
 
 Because the per-slice states stay exactly where the host router keeps
 them (``self.slices[i]._state``, assembled zero-copy into a global
@@ -44,7 +44,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from ratelimiter_tpu.algorithms.sketch import _pad_size
+from ratelimiter_tpu.algorithms.sketch import _pad_size, fetch_count
 from ratelimiter_tpu.core.clock import Clock, to_micros
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import StorageUnavailableError
@@ -52,6 +52,7 @@ from ratelimiter_tpu.core.types import (
     BatchResult,
     DispatchTicket,
     batch_fail_open,
+    wire_pack,
 )
 from ratelimiter_tpu.observability import tracing
 from ratelimiter_tpu.parallel.limiter import SlicedMeshLimiter
@@ -60,10 +61,12 @@ from ratelimiter_tpu.parallel.limiter import SlicedMeshLimiter
 class CollectiveDispatchTicket(DispatchTicket):
     """Ticket for one collective frame dispatch.
 
-    ``outs`` holds the device-side result tuple (allowed, remaining,
-    retry, reset, per-slice admitted mass, overflow flag). The original
-    frame columns ride along so the overflow fallback can re-dispatch
-    through the host router with the ORIGINAL decision timestamp."""
+    ``outs`` holds the device-side result: ONE int32 buffer sharded over
+    the mesh, each device's shard the rule's packed rows of its frame
+    rows and a tail of that slice's admitted mass and the overflow flag
+    (ops/route_kernels.build_routed_step). The original frame columns
+    ride along so the overflow fallback can re-dispatch through the host
+    router with the ORIGINAL decision timestamp."""
 
     __slots__ = ("arrays", "premix", "wire_lane")
 
@@ -115,6 +118,9 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
         #: resolving thread, so the fallbacks have a lock of their own.
         self.dispatches = 0
         self._fallbacks = {"overflow": 0, "strict": 0}
+        #: Device buffers this router's own resolve has fetched (the
+        #: slices count their own, result_fetches sums both).
+        self._fetches = 0
         self._stats_lock = threading.Lock()
         self._strict_gate = bool(getattr(self.slices[0], "_strict", False))
         self._cpu = self.mesh.devices.flat[0].platform == "cpu"
@@ -273,17 +279,18 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                     if hier is not None:
                         args = args + (hier,)
                     sp.next("step")
-                    new_mut, fin, ovf = step(*args)
+                    new_mut, words = step(*args)
                     if self._cpu:
                         # Same rationale as _MeshPlacement._fence_dispatch:
                         # xla:cpu collective rendezvous starve the shared
                         # device pool under concurrent executions — cap
                         # the stream at one while the dispatch locks are
                         # held.
-                        jax.block_until_ready((fin, ovf))
+                        jax.block_until_ready(words)
                     sp.next("writeback")
                     self._writeback(new_mut)
                     self.dispatches += 1
+                    window_us = self.slices[0]._window_us
                     sp.next("finish")
                     if premix:
                         from ratelimiter_tpu.ops.hashing import splitmix64
@@ -297,7 +304,8 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                     for s in reversed(self.slices):
                         s._lock.release()
             t = CollectiveDispatchTicket()
-            t.outs = fin + (ovf,)
+            t.outs = words
+            t.window_us = window_us
             t.b = b
             t.limit = self.config.limit
             t.limits = limits
@@ -333,14 +341,22 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
             return super().resolve(ticket)
         if ticket.result is not None:
             return ticket.result
-        import jax
+        from ratelimiter_tpu.ops import route_kernels, sketch_kernels
 
+        trace_id = getattr(ticket, "trace_id", 0)
         try:
-            with tracing.span("barrier", batch=ticket.b,
-                              trace_id=getattr(ticket, "trace_id", 0)):
-                jax.block_until_ready(ticket.outs)
-                allowed, remaining, retry, reset_at, mass, ovf = \
-                    jax.device_get(ticket.outs)
+            with tracing.span("barrier", batch=ticket.b, trace_id=trace_id):
+                ticket.outs.block_until_ready()
+                # "fetch", as on one chip (SketchLimiter._resolve_ticket):
+                # device ready -> NumPy columns built, over ONE buffer of
+                # a shard a device.
+                shards = fetch_count(ticket.outs)
+                with tracing.span("fetch", batch=ticket.b,
+                                  trace_id=trace_id):
+                    (allowed, remaining, retry, reset_at), tails = \
+                        self.slices[0]._unpack(
+                            np.asarray(ticket.outs), ticket, shards,
+                            route_kernels.ROUTED_TAIL)
         except Exception as exc:
             ticket.outs = None
             if self.config.fail_open:
@@ -351,8 +367,10 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                 return res
             raise StorageUnavailableError(
                 f"collective resolve failed: {exc}") from exc
+        with self._stats_lock:
+            self._fetches += shards
         ticket.outs = None
-        if int(ovf):
+        if tails[:, 2].any():
             # Bin overflow: the step left every state leaf untouched,
             # so re-dispatching the ORIGINAL frame (same rows, same
             # decision timestamp) through the host router admits each
@@ -364,11 +382,11 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
             sub = self._launch_split(arrays, ticket.ns, owners,
                                      ticket.t_sec, premix=ticket.premix,
                                      wire=ticket.wire_lane)
-            sub.trace_id = getattr(ticket, "trace_id", 0)
+            sub.trace_id = trace_id
             res = super().resolve(sub)
             ticket.result = res
             return res
-        b = ticket.b
+        mass = sketch_kernels.join_words(tails[:, 0], tails[:, 1])
         for i, s in enumerate(self.slices):
             admitted = int(mass[i])
             if admitted:
@@ -376,22 +394,18 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                     s._note_mass_locked(admitted, ticket.now_us)
         wire_packed = None
         if ticket.wire_lane:
-            # Host packbits from the frame-order columns — the same
-            # convention as the host router's cross-slice scatter-back
-            # (the device-side pack only exists on single-slice
-            # passthrough tickets).
-            words = np.empty(3 * b, dtype=np.int64)
-            words[0:b] = remaining[:b]
-            words[b:2 * b] = retry[:b].view(np.int64)
-            words[2 * b:3 * b] = reset_at[:b].view(np.int64)
-            wire_packed = (np.packbits(allowed[:b], bitorder="little"),
-                           words, b)
-        res = BatchResult(allowed=allowed[:b], limit=ticket.limit,
-                          remaining=remaining[:b], retry_after=retry[:b],
-                          reset_at=reset_at[:b], limits=ticket.limits,
+            wire_packed, remaining, retry, reset_at = wire_pack(
+                allowed, remaining, retry, reset_at)
+        res = BatchResult(allowed=allowed, limit=ticket.limit,
+                          remaining=remaining, retry_after=retry,
+                          reset_at=reset_at, limits=ticket.limits,
                           wire_packed=wire_packed)
         ticket.result = res
         return res
+
+    @property
+    def result_fetches(self) -> int:
+        return self._fetches + super().result_fetches
 
     # ------------------------------------------------ pipelined public API
 

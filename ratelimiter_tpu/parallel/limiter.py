@@ -40,7 +40,12 @@ from ratelimiter_tpu.algorithms.sketch import (
 from ratelimiter_tpu.core.clock import Clock
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import CheckpointError
-from ratelimiter_tpu.core.types import Algorithm, BatchResult, DispatchTicket
+from ratelimiter_tpu.core.types import (
+    Algorithm,
+    BatchResult,
+    DispatchTicket,
+    wire_pack,
+)
 from ratelimiter_tpu.observability import tracing
 from ratelimiter_tpu.parallel import mesh_kernels
 from ratelimiter_tpu.parallel.mesh import make_mesh
@@ -280,7 +285,7 @@ class MeshDispatchTicket(DispatchTicket):
     ``subs`` holds (slice_index, positions, slice_ticket) triples;
     resolve() scatters each slice's results back to the frame's original
     positions. A frame fully owned by one slice skips the split (its
-    slice ticket passes through, preserving the device-packed wire
+    slice ticket passes through, preserving the packed wire
     buffers). ``DispatchTicket.meta`` stays free for the decorator stack
     (the circuit breaker parks judgment state there)."""
 
@@ -385,7 +390,7 @@ class SlicedMeshLimiter(RateLimiter):
         dispatch per touched slice. ``arrays`` holds finalized hashes
         (premix=False) or raw ids (premix=True — the slice finalizes
         in-step). Single-owner frames pass through unsplit, preserving
-        the slice ticket's device-packed wire buffers."""
+        the slice ticket's packed wire buffers."""
         b = int(arrays.shape[0])
 
         def sub_launch(lim, a, n_arr):
@@ -426,7 +431,7 @@ class SlicedMeshLimiter(RateLimiter):
         t.b = b
         t.limit = self.config.limit
         t.t_sec = now
-        # Wire frames reassemble device-packed buffers at resolve (the
+        # Wire frames reassemble the packed buffers at resolve (the
         # scatter-back path) — only meaningful on the raw-id lane, the
         # one surface whose sub-launches pack on device.
         t.wire = bool(wire and premix)
@@ -557,19 +562,12 @@ class SlicedMeshLimiter(RateLimiter):
             raise err
         wire_packed = None
         if wire:
-            # Scatter-back of the device-packed wire buffers through the
-            # index maps (ADR-013): rebuild the frame-order packed form
-            # with three vectorized gathers + one packbits, so the wire
-            # encoder still frames from packed buffers (memoryview
-            # column slices, no per-row host math). The gather is the
-            # price of cross-slice reassembly; single-owner frames pass
-            # the slice's buffers through untouched above.
-            words = np.empty(3 * b, dtype=np.int64)
-            words[0:b] = remaining
-            words[b:2 * b] = retry.view(np.int64)
-            words[2 * b:3 * b] = reset_at.view(np.int64)
-            wire_packed = (np.packbits(allowed, bitorder="little"),
-                           words, b)
+            # The frame-order columns packed once more (ADR-013), so the
+            # wire encoder still frames from packed buffers (memoryview
+            # column slices, no per-row host math); single-owner frames
+            # pass the slice's own buffers through untouched above.
+            wire_packed, remaining, retry, reset_at = wire_pack(
+                allowed, remaining, retry, reset_at)
         res = BatchResult(allowed=allowed, limit=self.config.limit,
                           remaining=remaining, retry_after=retry,
                           reset_at=reset_at, fail_open=fail_open,
@@ -864,6 +862,12 @@ class SlicedMeshLimiter(RateLimiter):
 
     def memory_bytes(self) -> int:
         return sum(s.memory_bytes() for s in self.slices)
+
+    @property
+    def result_fetches(self) -> int:
+        """Device buffers the slices' resolves have fetched
+        (SketchLimiter.result_fetches), summed."""
+        return sum(s.result_fetches for s in self.slices)
 
     def in_window_admitted_mass(self) -> int:
         return sum(s.in_window_admitted_mass() for s in self.slices)

@@ -146,8 +146,8 @@ def build_mesh_steps(cfg: Config, mesh: Mesh, merge: str = "gather",
 # shards carry ONE uint64 per key and one per ``n``, the (h1, h2) split
 # — plus, with premix, the splitmix64 finalizer — runs inside the
 # shard_map'd body (elementwise, so sharding is preserved with no extra
-# collective), and the body ends with the single-chip steps' own finish
-# arithmetic on its shard. The operands stay three arrays: a buffer
+# collective), and the body ends with the single-chip steps' own packing
+# of its shard's verdicts (one int32 buffer a device). The operands stay three arrays: a buffer
 # with a scalar tail cannot be sharded by batch, so the mesh placement
 # stages the single-chip slot's three views itself
 # (_MeshPlacement._stage_operands).
@@ -155,17 +155,17 @@ def build_mesh_steps(cfg: Config, mesh: Mesh, merge: str = "gather",
 _MESH_HASHED_CACHE: Dict[tuple, Callable] = {}
 
 
-def _hashed_body(body, finish, seed: int, premix: bool, step_kw,
+def _hashed_body(body, pack, seed: int, premix: bool, step_kw,
                  hier_arity: bool = False):
     """Per-chip body over the staged views: ``n`` and ``now_us`` arrive
-    as uint64 (the slot's dtype) and narrow here; ``finish(allowed,
-    remaining, third, now_us)`` is the algorithm's finish arithmetic."""
+    as uint64 (the slot's dtype) and narrow here; ``pack(allowed,
+    remaining, third)`` is the algorithm's result packing."""
     def decide(state, h64, n, now_us, policy, hier):
         h1, h2 = sketch_kernels.split_staged(h64, premix, seed)
         now_us = now_us.astype(jnp.int64)
         state, outs = body(state, h1, h2, n.astype(jnp.int32), now_us,
                            policy, hier, step_kw=step_kw)
-        return state, finish(*outs, now_us)
+        return state, pack(*outs)
 
     if hier_arity:
         return decide
@@ -177,8 +177,8 @@ def build_mesh_hashed_step(cfg: Config, mesh: Mesh, merge: str = "gather",
                            *, premix: bool = False) -> Callable:
     """Jitted mesh ``step(state, h64, n, now_us, policy)`` — h64/n sharded
     over AXIS, state, now_us and policy replicated (build_mesh_steps'
-    contract) — returning ``(state, finish_window's four columns)``,
-    sharded like the batch."""
+    contract) — returning ``(state, pack_window's buffer)``, each
+    device's shard that buffer over its own keys."""
     if merge not in MERGE_MODES:
         raise ValueError(f"merge must be one of {MERGE_MODES}, got {merge!r}")
     W, sub_us, SW, S, limit = sketch_kernels.sketch_geometry(cfg)
@@ -218,12 +218,12 @@ def build_mesh_hashed_step(cfg: Config, mesh: Mesh, merge: str = "gather",
     mapped = shard_map(
         _hashed_body(
             body,
-            lambda allowed, remaining, _est, now_us:
-            sketch_kernels.finish_window(allowed, remaining, now_us, W),
+            lambda allowed, remaining, _est:
+            sketch_kernels.pack_window(allowed, remaining),
             seed, premix, step_kw, hier_arity=bool(tenants)),
         mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(state_spec, (P(AXIS),) * 4),
+        out_specs=(state_spec, P(AXIS)),
         check_vma=False,
     )
     step = jax.jit(mapped, donate_argnums=(0,))
@@ -263,13 +263,11 @@ def build_mesh_hashed_bucket_step(cfg: Config, mesh: Mesh,
     mapped = shard_map(
         _hashed_body(
             body,
-            lambda allowed, remaining, retry_us, now_us:
-            bucket_kernels.finish_bucket(allowed, remaining, retry_us,
-                                         now_us, wus),
+            bucket_kernels.pack_bucket,
             seed, premix, step_kw, hier_arity=bool(tenants)),
         mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(state_spec, (P(AXIS),) * 4),
+        out_specs=(state_spec, P(AXIS)),
         check_vma=False,
     )
     step = jax.jit(mapped, donate_argnums=(0,))

@@ -415,7 +415,7 @@ class MicroBatcher:
             # across segments is exactly sequential-dispatch order),
             # and reassemble host-side (fail_open ORs over segments,
             # same contract as the native BatchJoin; the merged result
-            # carries no device-packed wire buffers, so the encoder
+            # carries no packed wire buffers, so the encoder
             # takes its packbits path — one host re-pack on a frame
             # shape that is rare by construction).
             if self._pending_hashed or self._pending_fwd:
@@ -479,8 +479,8 @@ class MicroBatcher:
 
     def _launch_hashed_work(self, ids, ns, trace_id=0, t_q=0):
         """Hashed-frame launch stage (launch executor thread): same
-        in-flight window as _launch_work; wire=True device-packs the
-        response buffers (sketch_kernels.pack_wire)."""
+        in-flight window as _launch_work; wire=True has resolve pack the
+        response buffers (core/types.wire_pack)."""
         self._window.acquire()
         rec = tracing.RECORDER
         tq0 = tracing.now() if rec is not None else 0

@@ -94,6 +94,36 @@ log = logging.getLogger("ratelimiter_tpu.serving.http")
 #: /debug/profile upper bound: an on-demand jax.profiler capture holds a
 #: handler thread (and profiler overhead) for its whole duration.
 MAX_PROFILE_SECONDS = 30.0
+#: /debug/profile keeps its response alive with one byte this often
+#: while the capture and the trace's decoding run (_Heartbeat).
+PROFILE_HEARTBEAT_S = 5.0
+
+
+class _Heartbeat:
+    """One space every ``interval`` seconds on a response stream whose
+    headers are out and whose body is not ready, from a thread of its own,
+    until ``stop()`` (which waits for a write in progress): keeps a
+    client's socket timeout from firing on a server that is working."""
+
+    def __init__(self, wfile, interval: float):
+        self._wfile = wfile
+        self._interval = interval
+        self._done = threading.Event()
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="profile-heartbeat")
+        self._thread.start()
+
+    def _run(self) -> None:
+        while not self._done.wait(self._interval):
+            try:
+                self._wfile.write(b" ")
+                self._wfile.flush()
+            except OSError:         # the client went away: nothing to keep
+                return
+
+    def stop(self) -> None:
+        self._done.set()
+        self._thread.join()
 
 
 def _accepts_kw(fn, name: str) -> bool:
@@ -515,38 +545,70 @@ class HttpGateway:
                     self._send(409, {"error": "a profile capture is "
                                      "already running"})
                     return
-                try:
-                    import os
-                    import tempfile
-                    import time as _time
+                import contextlib
+                import os
+                import tempfile
+                import time as _time
 
-                    out_dir = tempfile.mkdtemp(prefix="rl_profile_")
-                    # NOTE: the first capture of a process pays several
-                    # seconds of profiler-server init on top of N —
-                    # budget the client timeout accordingly.
-                    with tracing.profile(out_dir) as anchor_ns:
+                reply = {"error": "profiler unavailable"}
+                beat = None
+                try:
+                    with contextlib.ExitStack() as capture:
+                        try:
+                            out_dir = tempfile.mkdtemp(prefix="rl_profile_")
+                            # NOTE: the first capture of a process pays
+                            # several seconds of profiler-server init on
+                            # top of N — budget the client timeout
+                            # accordingly.
+                            anchor_ns = capture.enter_context(
+                                tracing.profile(out_dir))
+                        except Exception as exc:  # noqa: BLE001 — profiler
+                            # is best-effort (unsupported platform,
+                            # concurrent capture by another tool): report,
+                            # never crash.
+                            log.exception("debug profile capture failed")
+                            self._send(503, {"error": "profiler "
+                                             f"unavailable: {exc}"})
+                            return
+                        # The capture runs: answer 200 now and keep the
+                        # connection alive while it does. Stopping a trace
+                        # decodes every op of every program execution
+                        # captured — a minute for 5 s of one busy chip,
+                        # more on four (PERF.md §7) — and a client's
+                        # socket timeout should measure whether the
+                        # server lives, not how much it traced: a space
+                        # every few seconds, then the JSON body (leading
+                        # whitespace is JSON), then the close that ends
+                        # it (no Content-Length).
+                        self.send_response(200)
+                        self.send_header("Content-Type", "application/json")
+                        self.send_header("Connection", "close")
+                        self.end_headers()
+                        self.close_connection = True
+                        beat = _Heartbeat(self.wfile, PROFILE_HEARTBEAT_S)
                         _time.sleep(seconds)
                     files = sorted(
                         os.path.relpath(os.path.join(root, f), out_dir)
                         for root, _, fs in os.walk(out_dir) for f in fs)
-                except Exception as exc:  # noqa: BLE001 — profiler is
-                    # best-effort (unsupported platform, concurrent
-                    # capture by another tool): report, never crash.
+                    reply = {"ok": True, "dir": out_dir,
+                             "seconds": seconds, "files": files,
+                             # tracing.now() at the start of the trace's
+                             # ratelimiter/clock_anchor TraceMe: the two
+                             # clocks' offset.
+                             "clock_anchor_mono_ns": anchor_ns}
+                except Exception as exc:  # noqa: BLE001 — stop_trace
+                    # failed after the 200 went out: say so in the body.
                     log.exception("debug profile capture failed")
-                    self._send(503, {"error": f"profiler unavailable: "
-                                     f"{exc}"})
-                    return
+                    reply = {"ok": False,
+                             "error": f"profiler unavailable: {exc}"}
                 finally:
                     gateway._profile_lock.release()
-                # Send OUTSIDE the capture try: a client that gave up
+                    if beat is not None:
+                        beat.stop()
+                # Written OUTSIDE the capture try: a client that gave up
                 # mid-capture must not be misreported as a profiler
                 # failure (the broken pipe surfaces in _handle's guard).
-                self._send(200, {"ok": True, "dir": out_dir,
-                                 "seconds": seconds, "files": files,
-                                 # tracing.now() at the start of the
-                                 # trace's ratelimiter/clock_anchor
-                                 # TraceMe: the two clocks' offset.
-                                 "clock_anchor_mono_ns": anchor_ns})
+                self.wfile.write(json.dumps(reply).encode())
 
             def _handle_debug_audit(self) -> None:
                 """Live accuracy observatory snapshot (ADR-016): the

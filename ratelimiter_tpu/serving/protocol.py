@@ -69,8 +69,8 @@ Responses:
                     u32 count, u8 allowed_bits[ceil(count/8)]
                     (little-endian bit order), then COLUMNAR
                     i64 remaining[count] | f64 retry[count] |
-                    f64 reset[count]. The response shape the device
-                    packs directly (sketch_kernels.pack_wire): the
+                    f64 reset[count]. The response shape resolve
+                    packs directly (core/types.wire_pack): the
                     server's encode is slice memcpys, the client's
                     parse is np.frombuffer views.
     ERROR    (255): u16 code, u16 msg_len, msg utf-8; for ALLOW_BATCH an
@@ -912,8 +912,8 @@ def parse_allow_hashed(body: bytes):
 
 def encode_result_hashed(req_id: int, res) -> bytes:
     """Columnar response from a BatchResult, as ONE bytes frame. Wire-lane
-    results arrive DEVICE-packed (BatchResult.wire_packed,
-    sketch_kernels.pack_wire) and frame via the shared view builder below
+    results arrive packed (BatchResult.wire_packed,
+    core/types.wire_pack) and frame via the shared view builder below
     (one join, no per-column re-packing); results without packed buffers
     (fail-open, pre-resolved, client-constructed) take the np.packbits
     path."""
@@ -939,7 +939,7 @@ def encode_result_hashed_views(req_id: int, res) -> list:
     """T_RESULT_HASHED frame as a writev-style buffer list (ADR-011
     residual, ISSUE-5 satellite): header + allow-mask bytes in one small
     bytes object, then the three value columns as zero-copy MEMORYVIEWS
-    straight over the device-fetched ``wire_packed`` words buffer. This
+    straight over the resolve's ``wire_packed`` words buffer. This
     is the SINGLE source of the packed framing (pad-bit masking, column
     offsets); encode_result_hashed joins these views for the one-buffer
     form. The ENCODER makes zero copies of the columns; downstream, the

@@ -16,8 +16,8 @@ limiter. This file holds it to what a deployment is held to, on a forced
   and shows on ``/metrics`` as
   ``rate_limiter_collective_fallbacks_total{reason="overflow"}``;
 * the launch is on ``tracing.span``: ``assemble``, ``place``, ``step``,
-  ``writeback`` (and ``route``, ``prep``, ``finish``, ``barrier``) under
-  the flight recorder.
+  ``writeback`` (and ``route``, ``prep``, ``finish``, ``barrier`` with
+  the resolve's ``fetch`` inside it) under the flight recorder.
 
 Each is parametrised over the hashed and the premix lane.
 """
@@ -184,7 +184,12 @@ def test_the_launch_is_on_the_span_primitive(pair, recorder, lane):
     assert by["route"]["t_start_ns"] <= by["prep"]["t_start_ns"]
     assert by["finish"]["t_end_ns"] <= by["route"]["t_end_ns"]
     coll.resolve(ticket)
-    assert [s["stage"] for s in recorder.dump()][-1] == "barrier"
+    # Resolve: the barrier, and inside it (after the wait) the one fetch.
+    barrier, fetch = recorder.dump()[-2:]
+    assert (barrier["stage"], fetch["stage"]) == ("barrier", "fetch")
+    assert barrier["t_start_ns"] <= fetch["t_start_ns"]
+    assert fetch["t_end_ns"] <= barrier["t_end_ns"]
+    assert fetch["batch"] == 203
 
 
 def test_spans_cost_nothing_with_tracing_off(pair):

@@ -1,17 +1,23 @@
-"""One transfer and one program launch per dispatch (ADR-010 addendum).
+"""One transfer in, one program launch and one transfer out per dispatch
+(ADR-010 addenda).
 
 The serving steps take ONE staged uint64 buffer ``[ids(P) | n(P) |
-now_us(1)]`` and end with the retry/reset arithmetic that used to be a
-program of its own (``finish_window`` / ``finish_bucket`` behind
-``_launch_finish``). Three things are held here:
+now_us(1)]`` and end by packing their verdicts into ONE int32 buffer a
+device (``sketch_kernels.pack_window`` / ``bucket_kernels.pack_bucket``);
+resolve fetches that buffer and rebuilds BatchResult's int64 and float64
+columns on the host. Held here:
 
-* the finished step's four outputs equal the (h1, h2) step followed by
-  the OLD finish arithmetic, bit for bit — the old programs are kept
-  below as the plain reference, operands traced exactly as they were;
+* the BatchResult rebuilt from the packed buffer equals the (h1, h2) step
+  followed by the OLD finish arithmetic — kept below as the plain NumPy
+  reference, in IEEE float64 — all four columns exact, both lanes, with and
+  without policy overrides, across a sub-window and the window boundary;
+* the step's output is exactly one array a device, int32: no 64-bit and
+  no float dtype leaves the device;
+* the boundary values survive the word format;
 * a launch makes exactly one jitted call and one explicit placement and
   no implicit transfer;
-* the mesh placement's own staging hook gives the single-chip limiter's
-  four columns.
+* a resolve records one ``fetch`` span and asks the device for one buffer a
+  device.
 """
 
 import contextlib
@@ -28,6 +34,7 @@ from ratelimiter_tpu.algorithms.sketch import (
     SketchTokenBucketLimiter,
 )
 from ratelimiter_tpu.core.clock import to_micros
+from ratelimiter_tpu.observability import tracing
 from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
 from ratelimiter_tpu.ops.hashing import split_hash, splitmix64
 
@@ -35,6 +42,11 @@ T0 = 1_700_000_000.25
 ALGOS = {"windowed": Algorithm.SLIDING_WINDOW,
          "fixed": Algorithm.FIXED_WINDOW,
          "bucket": Algorithm.TOKEN_BUCKET}
+#: Five instants: two in one sub-window, the next sub-window, the last
+#: second of the window (it ends at T0 + 39.75), and the next window.
+INSTANTS = (0.0, 0.3, 11.0, 39.5, 40.1)
+#: The largest limit a test key is given (the config's is 3).
+BIG = 50
 
 
 def _cfg(algo: str, **kw) -> Config:
@@ -44,91 +56,248 @@ def _cfg(algo: str, **kw) -> Config:
     return Config(**base)
 
 
-# The programs this PR removed, as they were: jitted on their own, with
-# now_us and window_us as traced int64 operands.
+def _cls(algo: str):
+    return SketchTokenBucketLimiter if algo == "bucket" else SketchLimiter
 
-@jax.jit
+
+# What the step itself computed until PR 29, as plain NumPy on the (h1,
+# h2) step's three outputs: integers in, IEEE float64 out.
+
 def _old_finish_window(allowed, remaining, now_us, window_us):
     cur_ws = (now_us // window_us) * window_us
-    reset = (cur_ws + window_us).astype(jnp.float64) / 1e6
-    retry = jnp.where(allowed, jnp.float64(0.0),
-                      (cur_ws + window_us - now_us).astype(jnp.float64) / 1e6)
-    return (allowed, remaining.astype(jnp.int64), retry,
-            jnp.broadcast_to(reset, allowed.shape))
+    reset = np.float64(cur_ws + window_us) / 1e6
+    retry = np.where(allowed, np.float64(0.0),
+                     np.float64(cur_ws + window_us - now_us) / 1e6)
+    return (allowed, remaining.astype(np.int64), retry,
+            np.broadcast_to(reset, allowed.shape))
 
 
-@jax.jit
 def _old_finish_bucket(allowed, remaining, retry_us, now_us, window_us):
-    reset = (now_us + window_us).astype(jnp.float64) / 1e6
-    return (allowed, remaining.astype(jnp.int64),
-            retry_us.astype(jnp.float64) / 1e6,
-            jnp.broadcast_to(reset, allowed.shape))
+    reset = np.float64(now_us + window_us) / 1e6
+    return (allowed, remaining.astype(np.int64),
+            retry_us.astype(np.float64) / 1e6,
+            np.broadcast_to(reset, allowed.shape))
 
 
-def _stage(ids: np.ndarray, ns: np.ndarray, now_us: int,
-           padded: int) -> np.ndarray:
+def _reference(algo, steps, state, ids, ns, now_us, padded, premix, cfg,
+               policy):
+    """The (h1, h2) step on ``state`` and the old finish arithmetic:
+    ``(state, the four columns over [:b])``."""
     b = ids.shape[0]
-    buf = np.zeros(2 * padded + 1, dtype=np.uint64)
-    buf[:b] = ids
-    buf[padded:padded + b] = ns
-    buf[2 * padded] = now_us
-    return buf
+    h1, h2 = split_hash(splitmix64(ids) if premix else ids, cfg.sketch.seed)
+    pad = lambda a, dt: np.concatenate(
+        [a, np.zeros(padded - b, a.dtype)]).astype(dt)
+    state, (allowed, remaining, third) = steps[0](
+        state, pad(h1, np.uint32), pad(h2, np.uint32), pad(ns, np.int32),
+        jnp.int64(now_us), policy)
+    allowed, remaining, third = (np.asarray(x)[:b]
+                                 for x in (allowed, remaining, third))
+    window_us = to_micros(cfg.window)
+    if algo == "bucket":
+        return state, _old_finish_bucket(allowed, remaining, third, now_us,
+                                         window_us)
+    return state, _old_finish_window(allowed, remaining, now_us, window_us)
 
 
-@pytest.mark.parametrize("b,padded", [(8, 8), (4096, 4096), (1003, 1024)],
-                         ids=["pad8", "pad4096", "ragged"])
+def _assert_columns_exact(got, want):
+    for name, w_ in zip(("allowed", "remaining", "retry_after", "reset_at"),
+                        want):
+        g = np.asarray(getattr(got, name))
+        w_ = np.ascontiguousarray(w_)
+        assert g.dtype == w_.dtype and g.shape == w_.shape, name
+        # Bit for bit: floats compared as their 64-bit patterns.
+        np.testing.assert_array_equal(g.view(np.uint8), w_.view(np.uint8),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("overrides", [False, True],
+                         ids=["no-overrides", "overrides"])
+@pytest.mark.parametrize("b", [8, 1003, 4096])
 @pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
 @pytest.mark.parametrize("algo", list(ALGOS))
-def test_finished_step_equals_step_then_old_finish(algo, premix, b, padded):
+def test_rebuilt_result_equals_step_then_old_finish(algo, premix, b,
+                                                    overrides):
     cfg = _cfg(algo)
-    window_us = to_micros(cfg.window)
     kernels = bucket_kernels if algo == "bucket" else sketch_kernels
     steps = kernels.build_steps(cfg)
-    fused = kernels.build_hashed_step(cfg, premix=premix)
-    ours, ref = kernels.init_state(cfg), kernels.init_state(cfg)
+    lim = _cls(algo)(cfg, ManualClock(T0))
+    hot = [f"hot:{i}" for i in range(4)]
+    if overrides:
+        # On the hashed lane these keys are in every frame (remaining
+        # reaches BIG - n); on the premix lane the table is there and no
+        # id matches it (overrides address string keys).
+        lim.set_override(hot[0], BIG)
+        lim.set_override(hot[1], 1)
+    with lim._lock:
+        policy = lim._policy_device()
+    ref = kernels.init_state(cfg)
+    padded = lim._padded_size(b)
 
-    rng = np.random.default_rng(b + 17 * premix)
+    rng = np.random.default_rng(b + 17 * premix + 5 * overrides)
     admitted = denied = 0
     period = None
-    # Three dispatches on one state: few keys against limit 3, so each
-    # holds admissions and denials, and the later ones meet counters the
-    # earlier ones wrote. The third crosses into the next sub-window.
-    for now_us in (to_micros(T0), to_micros(T0) + 1_234_567,
-                   to_micros(T0) + 11_000_003):
+    for dt in INSTANTS:
+        now = T0 + dt
+        now_us = to_micros(now)
         ids = rng.integers(1, max(2, b // 3), size=b).astype(np.uint64)
+        ids[:4] = lim._hash(hot)
         ns = rng.integers(1, 3, size=b).astype(np.int64)
         if algo != "bucket" and period != (
                 p := now_us // sketch_kernels.sketch_geometry(cfg)[1]):
             period = p                  # the host's _sync_period
-            ours = steps[2](ours, np.int64(p))
             ref = steps[2](ref, np.int64(p))
-        ours, got = fused(ours, _stage(ids, ns, now_us, padded))
-
-        h1, h2 = split_hash(splitmix64(ids) if premix else ids,
-                            cfg.sketch.seed)
-        pad = lambda a, dt: np.concatenate(
-            [a, np.zeros(padded - b, a.dtype)]).astype(dt)
-        ref, (allowed, remaining, third) = steps[0](
-            ref, pad(h1, np.uint32), pad(h2, np.uint32), pad(ns, np.int32),
-            jnp.int64(now_us))
-        if algo == "bucket":
-            want = _old_finish_bucket(allowed, remaining, third,
-                                      jnp.int64(now_us), jnp.int64(window_us))
-        else:
-            want = _old_finish_window(allowed, remaining, jnp.int64(now_us),
-                                      jnp.int64(window_us))
-
-        assert len(got) == 4
-        for g, w_ in zip(got, want):
-            g, w_ = np.asarray(g), np.asarray(w_)
-            assert g.dtype == w_.dtype and g.shape == (padded,)
-            # Bit for bit: floats compared as their 64-bit patterns.
-            np.testing.assert_array_equal(g.view(np.uint8), w_.view(np.uint8))
-        admitted += int(np.asarray(got[0])[:b].sum())
-        denied += b - int(np.asarray(got[0])[:b].sum())
+        got = (lim.allow_ids if premix else lim.allow_hashed)(
+            ids, ns, now=now)
+        ref, want = _reference(algo, steps, ref, ids, ns, now_us, padded,
+                               premix, cfg, policy)
+        _assert_columns_exact(got, want)
+        admitted += int(got.allowed.sum())
+        denied += b - int(got.allowed.sum())
     assert admitted and denied
-    for k in ours:
-        np.testing.assert_array_equal(np.asarray(ours[k]), np.asarray(ref[k]))
+    for k, v in lim._state.items():
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(ref[k]))
+    lim.close()
+
+
+# --------------------------------------------- what leaves the device
+
+
+def _launch(lim, premix, ids, **kw):
+    return (lim.launch_ids if premix else lim.launch_hashed)(ids, **kw)
+
+
+@pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
+@pytest.mark.parametrize("algo", list(ALGOS))
+def test_the_step_returns_one_int32_buffer(algo, premix):
+    """By the lowered program's own output types, and by the array the
+    ticket holds: state leaves aside, one array, int32, rows x P words."""
+    cfg = _cfg(algo)
+    kernels = bucket_kernels if algo == "bucket" else sketch_kernels
+    rows = (bucket_kernels.BUCKET_ROWS if algo == "bucket"
+            else sketch_kernels.WINDOW_ROWS)
+    lim = _cls(algo)(cfg, ManualClock(T0))
+    step = kernels.build_hashed_step(cfg, premix=premix)
+    with lim._lock:
+        policy = lim._policy_device()
+    staged = jax.ShapeDtypeStruct((2 * 64 + 1,), jnp.uint64)
+    out = step.lower(lim._state, staged, policy).out_info[1]
+    assert len(jax.tree_util.tree_leaves(out)) == 1, "one leaf, not a tuple"
+    assert out.dtype == jnp.int32 and out.shape == (rows * 64,)
+
+    ticket = _launch(lim, premix, np.arange(1, 40, dtype=np.uint64))
+    assert isinstance(ticket.outs, jax.Array)
+    assert ticket.outs.dtype == jnp.int32
+    assert ticket.outs.shape == (rows * ticket.padded,)
+    assert len(ticket.outs.addressable_shards) == 1
+    lim.resolve(ticket)
+    lim.close()
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
+@pytest.mark.parametrize("algo", ["windowed", "bucket"])
+def test_mesh_steps_return_one_int32_shard_a_device(algo):
+    """The replicated mesh's step and the collective router's: one array,
+    int32, one shard on each device and nothing else to fetch."""
+    from ratelimiter_tpu.core.config import MeshSpec
+    from ratelimiter_tpu.parallel import (
+        CollectiveMeshLimiter,
+        MeshSketchLimiter,
+        MeshTokenBucketLimiter,
+        make_mesh,
+    )
+
+    ids = np.arange(1, 300, dtype=np.uint64)
+    mesh = make_mesh(n_devices=8)
+    meshed = (MeshTokenBucketLimiter if algo == "bucket"
+              else MeshSketchLimiter)(_cfg(algo), ManualClock(T0), mesh=mesh)
+    coll = CollectiveMeshLimiter(
+        _cfg(algo, mesh=MeshSpec(devices=4, router="collective")),
+        ManualClock(T0), n_devices=4)
+    for lim, n in ((meshed, 8), (coll, 4)):
+        ticket = lim.launch_hashed(ids)
+        assert isinstance(ticket.outs, jax.Array)
+        assert ticket.outs.dtype == jnp.int32 and ticket.outs.ndim == 1
+        shards = ticket.outs.addressable_shards
+        assert len({s.device for s in shards}) == len(shards) == n
+        before = lim.result_fetches
+        res = lim.resolve(ticket)
+        assert lim.result_fetches - before == n
+        assert res.remaining.dtype == np.int64
+        assert res.retry_after.dtype == res.reset_at.dtype == np.float64
+        lim.close()
+
+
+# ------------------------------------------------------ boundary values
+
+
+@pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
+@pytest.mark.parametrize("algo", list(ALGOS))
+def test_remaining_zero_and_the_largest_limit_survive(algo, premix):
+    """``remaining`` at both ends of its range: 0 on the request that
+    takes a key's last unit, and the largest limit less one on the first
+    request of the key with the largest override (the config's own limit
+    on the premix lane, which no override reaches)."""
+    top = (1 << 24) - 1 if algo != "bucket" else 4_000_000
+    lim = _cls(algo)(_cfg(algo), ManualClock(T0))
+    lim.set_override("big", top)
+    big = lim._hash(["big"])[0]
+    want_first = 2 if premix else top - 1
+    ids = np.array([big, 7, 7, 7, 7], dtype=np.uint64)
+    res = lim.resolve(_launch(lim, premix, ids))
+    assert res.remaining.tolist() == [want_first, 2, 1, 0, 0]
+    assert res.allowed.tolist() == [True, True, True, True, False]
+    assert res.remaining.dtype == np.int64
+    if not premix:
+        assert res.limits.tolist() == [top, 3, 3, 3, 3]
+    lim.close()
+
+
+def test_bucket_retry_us_beyond_32_bits_survives_the_word_split():
+    """A 30-day window refills one token in 2.6e12 us (> 2**32): the
+    denial's retry-after crosses the low/high word split whole."""
+    window = 30 * 86400.0
+    cfg = _cfg("bucket", limit=1, window=window)
+    lim = SketchTokenBucketLimiter(cfg, ManualClock(T0))
+    ids = np.array([5, 5, 9], dtype=np.uint64)
+    res = lim.allow_hashed(ids)
+    assert res.allowed.tolist() == [True, False, True]
+    retry_us = int(round(res.retry_after[1] * 1e6))
+    assert retry_us == to_micros(window) and retry_us >= 1 << 32
+    assert res.retry_after[1] == np.float64(retry_us) / 1e6
+    lim.close()
+
+
+def test_pack_and_unpack_are_inverse_on_the_extremes():
+    """The word format alone, on values no small configuration reaches."""
+    retry_us = np.array([0, 1, (1 << 32) - 1, 1 << 32, (1 << 32) + 1,
+                         (1 << 53) - 1, (1 << 62) + 12345, 5],
+                        dtype=np.int64)
+    remaining = np.array([0, 1, (1 << 23) - 1, 4_398_046, 7, 0, 2, 3])
+    allowed = np.array([1, 0, 1, 1, 0, 0, 1, 0], dtype=bool)
+    words = np.asarray(jax.jit(bucket_kernels.pack_bucket)(
+        jnp.asarray(allowed), jnp.asarray(remaining), jnp.asarray(retry_us)))
+    assert words.dtype == np.int32 and words.shape == (4 * 8,)
+    rows, tails = sketch_kernels.result_rows(words, bucket_kernels.BUCKET_ROWS)
+    assert tails.shape == (1, 0) and np.shares_memory(rows, words)
+    np.testing.assert_array_equal(
+        sketch_kernels.join_words(rows[2], rows[3]), retry_us)
+    got = bucket_kernels.unpack_bucket(rows, 8, 1_000_000, 60_000_000)
+    np.testing.assert_array_equal(got[0], allowed)
+    np.testing.assert_array_equal(got[1], remaining)
+    np.testing.assert_array_equal(got[2], retry_us.astype(np.float64) / 1e6)
+    np.testing.assert_array_equal(got[3], np.full(8, 61.0))
+
+    # Sharded: each shard's rows then its tail; the rows come back in
+    # batch order over the shards.
+    one = np.arange(2 * 4 + 3, dtype=np.int32)
+    rows, tails = sketch_kernels.result_rows(
+        np.concatenate([one, one + 100]), 2, shards=2, tail=3)
+    assert rows.tolist() == [[0, 1, 2, 3, 100, 101, 102, 103],
+                             [4, 5, 6, 7, 104, 105, 106, 107]]
+    assert tails.tolist() == [[8, 9, 10], [108, 109, 110]]
+
+
+# ------------------------------------------------- one launch, one fetch
 
 
 def test_the_guard_is_live_on_this_backend():
@@ -146,12 +315,10 @@ def test_the_guard_is_live_on_this_backend():
 @pytest.mark.parametrize("algo", list(ALGOS))
 def test_a_launch_is_one_transfer_and_one_program(algo, premix, pinned,
                                                   monkeypatch):
-    cls = SketchTokenBucketLimiter if algo == "bucket" else SketchLimiter
     device = jax.devices()[-1] if pinned else None
-    lim = cls(_cfg(algo), ManualClock(T0), device=device)
-    launch = lim.launch_ids if premix else lim.launch_hashed
+    lim = _cls(algo)(_cfg(algo), ManualClock(T0), device=device)
     ids = np.arange(1, 6, dtype=np.uint64)
-    lim.resolve(launch(ids))           # compile, rotate, place the policy
+    lim.resolve(_launch(lim, premix, ids))  # compile, rotate, place the policy
 
     calls = {"step": 0, "put": 0}
     attr = "_ids_step" if premix else "_step"
@@ -172,19 +339,73 @@ def test_a_launch_is_one_transfer_and_one_program(algo, premix, pinned,
     monkeypatch.setattr(lim, attr, counted_step)
     monkeypatch.setattr(jax, "device_put", counted_put)
     with jax.transfer_guard("disallow"):
-        ticket = launch(ids)
+        ticket = _launch(lim, premix, ids)
     monkeypatch.undo()
 
     assert calls == {"step": 1, "put": 1}
-    # The ticket carries the step's own outputs: nothing ran after it.
-    assert ticket.outs is stepped[0] and len(ticket.outs) == 4
+    # The ticket carries the step's own output: nothing ran after it.
+    assert ticket.outs is stepped[0]
     assert ticket.slot.dtype == np.uint64
     assert ticket.slot.shape == (2 * ticket.padded + 1,)
+    assert ticket.window_us == to_micros(lim.config.window)
     if pinned:
-        assert all(o.devices() == {device} for o in ticket.outs)
+        assert ticket.outs.devices() == {device}
     out = lim.resolve(ticket)
     assert out.retry_after.dtype == np.float64
     assert out.remaining.dtype == np.int64
+    lim.close()
+
+
+@pytest.fixture
+def recorder():
+    rec = tracing.enable(capacity=256)
+    yield rec
+    tracing.disable()
+
+
+@pytest.mark.parametrize("wire", [False, True], ids=["columns", "wire"])
+@pytest.mark.parametrize("algo", list(ALGOS))
+def test_a_resolve_is_one_fetch_span_and_one_buffer(algo, wire, recorder):
+    lim = _cls(algo)(_cfg(algo), ManualClock(T0))
+    ids = np.arange(1, 30, dtype=np.uint64)
+    for k in range(1, 4):
+        ticket = lim.launch_ids(ids, wire=wire)
+        ticket.trace_id = 40 + k
+        res = lim.resolve(ticket)
+        assert lim.resolve(ticket) is res       # idempotent: no second fetch
+        assert lim.result_fetches == k
+        assert (res.wire_packed is not None) == wire
+        spans = [r for r in recorder.dump() if r["stage"] == "fetch"]
+        assert len(spans) == k
+        assert spans[-1]["batch"] == 29
+        assert int(spans[-1]["trace_id"]) == 40 + k
+    lim.close()
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
+@pytest.mark.parametrize("router", ["host", "collective"])
+def test_mesh_resolves_count_a_shard_a_device(router, recorder):
+    """The host router: one buffer per slice dispatch; the collective
+    router: four packed shards a frame and nothing else (mass and the
+    overflow flag ride in each shard's tail), one span a frame."""
+    from ratelimiter_tpu.core.config import MeshSpec
+    from ratelimiter_tpu.parallel import (
+        CollectiveMeshLimiter,
+        SlicedMeshLimiter,
+    )
+
+    cls = CollectiveMeshLimiter if router == "collective" \
+        else SlicedMeshLimiter
+    lim = cls(_cfg("windowed", mesh=MeshSpec(devices=4, router=router)),
+              ManualClock(T0), n_devices=4)
+    ids = np.arange(1, 400, dtype=np.uint64)
+    assert len(set(lim.owner_of_hash(ids).tolist())) == 4
+    for k in range(1, 3):
+        res = lim.resolve(lim.launch_hashed(ids))
+        assert 0 < res.allowed.sum() <= ids.shape[0]
+        assert lim.result_fetches == 4 * k <= 9 * k
+        spans = [r for r in recorder.dump() if r["stage"] == "fetch"]
+        assert len(spans) == (k if router == "collective" else 4 * k)
     lim.close()
 
 
@@ -228,4 +449,3 @@ def test_mesh_staging_hook_gives_the_single_chip_columns(algo, premix):
         meshed.clock.advance(1.5)
     single.close()
     meshed.close()
-

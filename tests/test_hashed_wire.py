@@ -2,7 +2,7 @@
 
 T_ALLOW_HASHED carries raw u64 key ids columnar; the server parses them
 as np.frombuffer views, stages them with one memcpy, hashes ON DEVICE,
-and answers columnar T_RESULT_HASHED (device-packed via pack_wire on the
+and answers columnar T_RESULT_HASHED (packed by wire_pack at resolve on the
 asyncio door). These tests pin the frame formats, the end-to-end
 equivalence with the direct limiter lane, and the error surface on both
 doors.
@@ -60,7 +60,7 @@ def test_parse_allow_hashed_rejects_malformed():
 def test_result_hashed_views_are_zero_copy_and_frame_identical():
     """ISSUE-5 satellite (the named ADR-011 residual): the writev-style
     reply builder must frame the three value columns as MEMORYVIEWS
-    straight over the device-fetched wire_packed words buffer — buffer
+    straight over the resolve's wire_packed words buffer — buffer
     identity asserted via np.shares_memory — with no intermediate
     per-frame bytes join, and the concatenation of the views must be
     byte-identical to the single-buffer encoder."""

@@ -146,7 +146,7 @@ class TestCoalescedOrderingOracle:
         """BatchResult.rows hands back numpy VIEWS over the window result
         (no copies on the scatter-back path) and re-bases the packed wire
         buffers by row offset so the encoder can frame the sub-range from
-        the same device-fetched words buffer."""
+        the same packed words buffer."""
         mesh = SlicedMeshLimiter(_cfg(), ManualClock(T0), n_devices=4)
         ids = np.arange(1, 257, dtype=np.uint64)
         res = mesh.resolve(mesh.launch_ids(ids, wire=True))
@@ -272,7 +272,7 @@ class TestCoalescedOrderingOracle:
             oracle.close()
         hot_decisions = out.allowed[big == hot]
         assert hot_decisions.sum() == 5 and bool(np.all(hot_decisions[:5]))
-        # The reassembled result has no device-packed buffers; the wire
+        # The reassembled result has no packed buffers; the wire
         # encoder's packbits fallback must still frame it losslessly.
         from ratelimiter_tpu.serving import protocol
 
