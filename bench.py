@@ -639,56 +639,6 @@ def measure_route_phases(B: int = INGEST_BATCH, n: int = 8,
                     "and return route run in-step on device (ADR-024)"}
 
 
-def measure_kernels_ab(*, seconds: float = 2.0, batch: int = 16384,
-                       depth: int = 4, width: int = 1 << 16) -> dict:
-    """``--accel`` block: pallas-vs-jnp dispatch rate on the serving hot
-    path (ADR-011) — the same pipelined launch/resolve loop for each
-    forced kernel choice. Off-TPU the pallas row times interpret mode;
-    on a TPU it reports resolve_kernels' InvalidConfigError (Mosaic
-    refuses the fused kernels) instead of silently falling back."""
-    from ratelimiter_tpu import create_limiter
-
-    rng = np.random.default_rng(0)
-    frames = [np.asarray(rng.integers(1, 1 << 40, size=batch), np.uint64)
-              for _ in range(4)]
-    out: dict = {}
-    for choice in ("jnp", "pallas"):
-        cfg = Config(
-            algorithm=Algorithm.SLIDING_WINDOW, limit=100, window=60.0,
-            max_batch_admission_iters=1,
-            sketch=SketchParams(depth=depth, width=width, sub_windows=60,
-                                conservative_update=True, kernels=choice))
-        try:
-            lim = create_limiter(cfg, backend="sketch")
-            lim.allow_hashed(frames[0])  # compile outside timed window
-            K = 4
-            tickets = [lim.launch_hashed(frames[j % 4]) for j in range(K)]
-            done = 0
-            k = 0
-            stop = time.perf_counter() + seconds
-            t0 = time.perf_counter()
-            while time.perf_counter() < stop:
-                lim.resolve(tickets.pop(0))
-                done += batch
-                tickets.append(lim.launch_hashed(frames[k % 4]))
-                k += 1
-            for t in tickets:
-                lim.resolve(t)
-                done += batch
-            elapsed = time.perf_counter() - t0
-            lim.close()
-            out[choice] = {
-                "decisions_per_sec": round(done / elapsed, 1)}
-        except Exception as exc:
-            out[choice] = {"error": str(exc)[:200]}
-    if ("decisions_per_sec" in out.get("pallas", {})
-            and "decisions_per_sec" in out.get("jnp", {})):
-        out["pallas_speedup"] = round(
-            out["pallas"]["decisions_per_sec"]
-            / max(out["jnp"]["decisions_per_sec"], 1.0), 2)
-    return out
-
-
 def measure_inflight_sweep(windows=(1, 2, 4, 8), *, seconds: float = 3.0,
                            log=lambda *a: None) -> list:
     """``--accel`` block: the pipelined-dispatch depth sweep (ADR-010)
@@ -741,8 +691,8 @@ def run_accel_preset(device_counts, *, seconds: float = 2.0,
                      e2e_seconds: float = 4.0,
                      log=lambda *a: None) -> dict:
     """``--accel`` (ROADMAP item 5): the whole real-accelerator proof
-    sweep as ONE command — kernels=pallas vs jnp on the serving hot
-    path, the ``--inflight`` pipelining sweep, the mesh scaling curve
+    sweep as ONE command — the ``--inflight`` pipelining sweep, the mesh
+    scaling curve
     (affine AND mixed) through BOTH routers (host ADR-013, collective
     ADR-024), and the route-phase host breakdown. Platform is
     auto-detected; run it on a TPU/GPU box and publish the JSON as
@@ -754,9 +704,6 @@ def run_accel_preset(device_counts, *, seconds: float = 2.0,
         "n_devices_visible": len(jax.devices()),
         "device_counts": [int(n) for n in device_counts],
     }
-    log("accel: kernels A/B (pallas vs jnp)")
-    out["kernels_ab"] = measure_kernels_ab(
-        seconds=seconds, batch=(1 << 16) if platform != "cpu" else 16384)
     log("accel: --inflight sweep")
     out["inflight_sweep"] = measure_inflight_sweep(
         seconds=e2e_seconds, log=log)
@@ -772,8 +719,7 @@ def run_accel_preset(device_counts, *, seconds: float = 2.0,
     out["route_phase_us"] = measure_route_phases(
         n=int(device_counts[-1]))
     out["harness"] = (
-        "bench.py --accel: kernels A/B via pipelined launch/resolve on "
-        "one sketch limiter; inflight sweep + mesh rows via real "
+        "bench.py --accel: inflight sweep + mesh rows via real "
         "--native servers driven by the C++ loadgen hashed lane; "
         "collective rows are --router collective (ADR-024)")
     return out
@@ -1354,9 +1300,8 @@ def main() -> None:
                          "comparison is the point)")
     ap.add_argument("--accel", action="store_true",
                     help="run ONLY the real-accelerator proof preset "
-                         "(ROADMAP item 5) and emit one JSON: kernels="
-                         "pallas vs jnp A/B, the --inflight pipelining "
-                         "sweep, the mesh scaling curve (affine AND "
+                         "(ROADMAP item 5) and emit one JSON: the "
+                         "--inflight pipelining sweep, the mesh scaling curve (affine AND "
                          "mixed) through BOTH routers, and the "
                          "route-phase breakdown. Auto-detects the "
                          "platform; also writes the JSON to "
@@ -1666,7 +1611,7 @@ def main() -> None:
                             sub_windows=60, conservative_update=True),
     )
     _, sub_us, _, _, _ = sketch_kernels.sketch_geometry(cfg)
-    _, _, sk_roll = sketch_kernels.build_steps(cfg)
+    _, sk_roll = sketch_kernels.build_controls(cfg)
 
     # ---------------------------------------------- phase A: throughput
     chunk = build_bench_chunk(cfg, B, n_keys, ZIPF_A)
@@ -1838,7 +1783,7 @@ def main() -> None:
         max_batch_admission_iters=1,
         sketch=SketchParams(depth=4, width=1 << 16, sub_windows=60,
                             conservative_update=True))
-    _, _, lit_roll = sketch_kernels.build_steps(lit_cfg)
+    _, lit_roll = sketch_kernels.build_controls(lit_cfg)
     serving_rps, step_latency_ms, rtt_warm_s, rtt_cold_s, compile_c = (
         serve_shape(lit_cfg, lit_roll))
     wide_rps, wide_step_ms, _, _, compile_c2 = serve_shape(cfg, sk_roll)

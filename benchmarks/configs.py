@@ -143,7 +143,7 @@ def config3(quick: bool = False, log=print) -> Dict:
                  sketch=SketchParams(depth=4, width=65536, sub_windows=60,
                                      conservative_update=True))
     _, sub_us, _, _, _ = sketch_kernels.sketch_geometry(cfg)
-    _, _, roll = sketch_kernels.build_steps(cfg)
+    _, roll = sketch_kernels.build_controls(cfg)
 
     # Saturation throughput at the literal geometry.
     chunk = build_bench_chunk(cfg, B, n_keys, 1.1)
@@ -235,7 +235,7 @@ def config3(quick: bool = False, log=print) -> Dict:
     def accuracy_run(rate, chunk_B, max_chunks, target_cov, cfg_run=None):
         cfg_a = cfg if cfg_run is None else cfg_run
         sub_us_a = sketch_kernels.sketch_geometry(cfg_a)[1]
-        roll_a = sketch_kernels.build_steps(cfg_a)[2]
+        roll_a = sketch_kernels.build_controls(cfg_a)[1]
         eval_chunk = build_eval_chunk(cfg_a, chunk_B, n_keys, 1.1)
         or_roll = build_oracle_rollover(cfg_a, n_keys)
         states = {"sk": roll_a(sketch_kernels.init_state(cfg_a),
@@ -346,7 +346,7 @@ def config4(quick: bool = False, log=print) -> Dict:
                  max_batch_admission_iters=1,
                  sketch=SketchParams(depth=4, width=65536, sub_windows=60))
     _, sub_us, _, _, _ = sketch_kernels.sketch_geometry(cfg)
-    roll = sketch_kernels.build_steps(cfg)[2]
+    roll = sketch_kernels.build_controls(cfg)[1]
     eval_chunk = build_eval_chunk(cfg, B, n_keys, 1.05)
     or_roll = build_oracle_rollover(cfg, n_keys)
     states = {"sk": roll(sketch_kernels.init_state(cfg),

@@ -133,7 +133,7 @@ def _device_step_us(cfg, backend: str, batch: int, card: int, *,
         else:
             scan = sketch_kernels.build_scan(cfg)
             _, sub_us, _, _, _ = sketch_kernels.sketch_geometry(cfg)
-            _, _, roll = sketch_kernels.build_steps(cfg)
+            _, roll = sketch_kernels.build_controls(cfg)
             state = roll(sketch_kernels.init_state(cfg),
                          jnp.int64(t0_us // sub_us))
         args = (h1s, h2s, ns)

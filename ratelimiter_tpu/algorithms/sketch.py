@@ -72,17 +72,7 @@ class SketchLimiter(RateLimiter):
         self._device = device
         from ratelimiter_tpu.ops import sketch_kernels
 
-        # The serving step takes ONE uint64 operand per key: the (h1, h2)
-        # split happens inside the jitted step (build_hashed_step,
-        # ADR-011) so the host stages raw hashes and never runs per-key
-        # hash math. reset/rollover keep the (h1, h2) kernels — rare
-        # control-plane dispatches.
-        _, self._reset_step, self._rollover = (
-            sketch_kernels.build_steps(self.config))
-        self._step = sketch_kernels.build_hashed_step(self.config)
-        # Lazy premix variant for the raw-u64-id wire lane (launch_ids):
-        # splitmix64 ALSO runs in-step there.
-        self._ids_step = None
+        self._install_steps(self.config)
         self._state = self._pin_state(sketch_kernels.init_state(self.config))
         self._window_us = to_micros(self.config.window)
         self._sub_us = sketch_kernels.sketch_geometry(self.config)[1]
@@ -315,17 +305,40 @@ class SketchLimiter(RateLimiter):
         with self._staging_lock:
             self._staging.setdefault(padded, []).append(slot)
 
-    def _get_ids_step(self):
-        """The premix (raw-u64-id) step variant, built lazily: splitmix64
-        AND the (h1, h2) split run in-step (ADR-011)."""
-        if self._ids_step is None:
-            self._ids_step = self._build_ids_step()
-        return self._ids_step
+    # ------------------------------------------------ compiled programs
 
-    def _build_ids_step(self):
+    @staticmethod
+    def _kernels():
+        """The module of this rule's step builders."""
         from ratelimiter_tpu.ops import sketch_kernels
 
-        return sketch_kernels.build_hashed_step(self.config, premix=True)
+        return sketch_kernels
+
+    def _build_step(self, cfg: Config, premix: bool):
+        """The compiled serving step for ``cfg`` — THE placement hook:
+        a mesh placement overrides this one method. The step takes ONE
+        staged uint64 buffer per batch and does the (h1, h2) split — and
+        under ``premix`` (the raw-u64-id wire lane, launch_ids) the
+        splitmix64 finalizer too — on the device (ADR-011), so the host
+        never runs per-key hash math."""
+        return self._kernels().build_hashed_step(cfg, premix=premix)
+
+    def _install_steps(self, cfg: Config) -> None:
+        """Swap in the programs compiled for ``cfg``. Called with
+        self._lock held (or from __init__). The premix step is built
+        lazily (_get_ids_step); reset (and the windowed rules' rollover)
+        are the rule's single-chip controls on every placement — rare
+        control-plane dispatches over (h1, h2) operands, replicated
+        computations on a mesh's replicated state."""
+        self._step = self._build_step(cfg, False)
+        self._ids_step = None
+        self._reset_step, *rollover = self._kernels().build_controls(cfg)
+        self._rollover = rollover[0] if rollover else None
+
+    def _get_ids_step(self):
+        if self._ids_step is None:
+            self._ids_step = self._build_step(self.config, True)
+        return self._ids_step
 
     def _launch_hashed(self, h64: np.ndarray, ns: np.ndarray,
                        now_us: int, t_sec: float, *, premix: bool = False,
@@ -773,14 +786,8 @@ class SketchLimiter(RateLimiter):
         """Dynamic limit: geometry (window/sub-windows/depth/width) is
         unchanged, so the state arrays carry over; only the compiled
         steps (which bake the limit) are swapped."""
-        from ratelimiter_tpu.ops import sketch_kernels
-
-        steps = sketch_kernels.build_steps(new_cfg)
-        step = sketch_kernels.build_hashed_step(new_cfg)
         with self._lock:
-            self._step = step
-            _, self._reset_step, self._rollover = steps
-            self._ids_step = None
+            self._install_steps(new_cfg)
             self._mass_budget = new_cfg.sketch.mass_budget(new_cfg.limit)
 
     def _apply_window(self, new_cfg: Config) -> None:
@@ -791,8 +798,6 @@ class SketchLimiter(RateLimiter):
         from ratelimiter_tpu.ops import sketch_kernels
 
         migrate = sketch_kernels.build_migrate(self.config, new_cfg)
-        steps = sketch_kernels.build_steps(new_cfg)
-        step = sketch_kernels.build_hashed_step(new_cfg)
         new_sub = sketch_kernels.sketch_geometry(new_cfg)[1]
         new_sw = sketch_kernels.sketch_geometry(new_cfg)[2]
         import jax.numpy as jnp
@@ -801,9 +806,7 @@ class SketchLimiter(RateLimiter):
         with self._lock:
             old_sub = self._sub_us
             self._state = migrate(self._state, jnp.int64(now_us))
-            self._step = step
-            _, self._reset_step, self._rollover = steps
-            self._ids_step = None
+            self._install_steps(new_cfg)
             self._window_us = to_micros(new_cfg.window)
             self._sub_us = new_sub
             self._ring_sw = new_sw
@@ -989,9 +992,7 @@ class SketchTokenBucketLimiter(SketchLimiter):
         self._device = device
         from ratelimiter_tpu.ops import bucket_kernels
 
-        _, self._reset_step = bucket_kernels.build_steps(self.config)
-        self._step = bucket_kernels.build_hashed_step(self.config)
-        self._ids_step = None
+        self._install_steps(self.config)
         self._state = self._pin_state(bucket_kernels.init_state(self.config))
         self._window_us = to_micros(self.config.window)
         self._seed = self.config.sketch.seed
@@ -1035,10 +1036,11 @@ class SketchTokenBucketLimiter(SketchLimiter):
             return np.zeros_like(counts)
         return counts
 
-    def _build_ids_step(self):
+    @staticmethod
+    def _kernels():
         from ratelimiter_tpu.ops import bucket_kernels
 
-        return bucket_kernels.build_hashed_step(self.config, premix=True)
+        return bucket_kernels
 
     def _note_mass_locked(self, admitted: int, now_us: int) -> None:
         """No mass watchdog for the debt sketch: debt decays continuously
@@ -1085,7 +1087,8 @@ class SketchTokenBucketLimiter(SketchLimiter):
 
         from ratelimiter_tpu.ops import bucket_kernels
 
-        _, num, den, d, w, _ = bucket_kernels._params(self.config)
+        kw = bucket_kernels.step_statics(self.config)
+        d, w = kw["d"], kw["w"]
         with self._lock:
             debt = self._state["debt"]
             rem_ref = self._state["rem"]
@@ -1096,7 +1099,7 @@ class SketchTokenBucketLimiter(SketchLimiter):
         # so the device refs feed it directly).
         decay, _ = bucket_kernels._decay(
             {"last": last_ref, "rem": rem_ref}, now_us,
-            rate_num=num, rate_den=den)
+            rate_num=kw["rate_num"], rate_den=kw["rate_den"])
         live_rows = np.asarray(jnp.sum(debt > decay, axis=1))
         occ_rows = live_rows / float(w)
         return {
@@ -1119,16 +1122,9 @@ class SketchTokenBucketLimiter(SketchLimiter):
         accrued refill, toward denying)."""
         import jax.numpy as jnp
 
-        from ratelimiter_tpu.core.clock import MICROS as _MICROS
-        from ratelimiter_tpu.ops import bucket_kernels
-
-        steps = bucket_kernels.build_steps(new_cfg)
-        step = bucket_kernels.build_hashed_step(new_cfg)
-        cap = new_cfg.limit * _MICROS
+        cap = new_cfg.limit * MICROS
         with self._lock:
-            self._step = step
-            _, self._reset_step = steps
-            self._ids_step = None
+            self._install_steps(new_cfg)
             self._state = dict(
                 self._state,
                 debt=jnp.minimum(self._state["debt"], cap),
@@ -1141,14 +1137,8 @@ class SketchTokenBucketLimiter(SketchLimiter):
         as the token-form backends). The decay remainder is denominated
         in the old rate fraction, so it resets (forfeits < 1 micro-token
         toward denying)."""
-        from ratelimiter_tpu.ops import bucket_kernels
-
-        steps = bucket_kernels.build_steps(new_cfg)
-        step = bucket_kernels.build_hashed_step(new_cfg)
         with self._lock:
-            self._step = step
-            _, self._reset_step = steps
-            self._ids_step = None
+            self._install_steps(new_cfg)
             self._window_us = to_micros(new_cfg.window)
             self._state = dict(
                 self._state,

@@ -53,12 +53,8 @@ def config_fingerprint(config: Config) -> str:
 
     The ``persistence`` spec is excluded: snapshot cadence / fsync policy
     are operational knobs, and a snapshot taken at one cadence must
-    restore under another. ``sketch.kernels`` is excluded for the same
-    reason (ADR-011): the Pallas/jnp selection changes WHICH compiled
-    kernels decide, not what the state means — the two paths are pinned
-    bit-identical, so a snapshot taken under either must restore under
-    the other. ``mesh`` (slice-parallel placement, ADR-012) is excluded
-    too: the device count is where state lives, not what it means — the
+    restore under another. ``mesh`` (slice-parallel placement, ADR-012)
+    is excluded too: the device count is where state lives, not what it means — the
     per-slice-count refusal lives in SlicedMeshLimiter.restore, which
     can NAME the mismatch instead of reporting an opaque fingerprint
     diff. Every OTHER field participates — changing this function's
@@ -68,8 +64,6 @@ def config_fingerprint(config: Config) -> str:
     fields = asdict(config)
     fields.pop("persistence", None)
     fields.pop("mesh", None)
-    if isinstance(fields.get("sketch"), dict):
-        fields["sketch"].pop("kernels", None)
     h = fields.get("hierarchy")
     if isinstance(h, dict) and not h.get("tenants"):
         # Hierarchy disabled is the pre-ADR-020 world: dropping the spec

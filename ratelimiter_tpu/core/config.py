@@ -67,21 +67,6 @@ class SketchParams:
     #: Either way ``overload_periods`` counts offending sub-windows and is
     #: exported via /metrics and healthz (docs/OPERATIONS.md §3).
     overload_policy: str = "warn"
-    #: Hot-loop kernel implementation (ADR-011):
-    #:   "auto"   (default) the jnp/XLA path on every platform: no fused
-    #:            kernel is selected until one has been compiled by
-    #:            Mosaic and raced on a chip (ADR-011 addendum);
-    #:   "pallas" force the fused kernels — off-TPU they run in Pallas
-    #:            interpret mode (the CI parity lane), which is
-    #:            bit-identical but slow: a correctness tool, not a
-    #:            serving configuration; on a TPU it is rejected when
-    #:            the limiter is built (Mosaic refuses the kernels);
-    #:   "jnp"    force the XLA reference path (the pre-ADR-011 kernels,
-    #:            kept as the parity oracle).
-    #: Decisions are bit-identical across the three (tier-1 enforced by
-    #: tests/test_pallas_parity.py). EXCLUDED from the checkpoint config
-    #: fingerprint — an execution knob, not state geometry.
-    kernels: str = "auto"
 
     def validate(self) -> None:
         if self.depth < 1 or self.depth > 16:
@@ -106,10 +91,6 @@ class SketchParams:
             raise InvalidConfigError(
                 f"overload_policy must be 'warn' or 'strict', "
                 f"got {self.overload_policy!r}")
-        if self.kernels not in ("auto", "pallas", "jnp"):
-            raise InvalidConfigError(
-                f"sketch kernels must be 'auto', 'pallas' or 'jnp', "
-                f"got {self.kernels!r}")
 
     # ------------------------------------------------- load-aware sizing
     #

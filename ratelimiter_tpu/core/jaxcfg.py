@@ -34,8 +34,7 @@ def configure() -> None:
 
 def on_tpu() -> bool:
     """True when the default backend is a TPU — selects the table-access
-    strategy (ops/sortmerge.py) and Pallas interpret mode
-    (ops/pallas_sketch.py)."""
+    strategy (ops/sortmerge.py)."""
     import jax
 
     return jax.default_backend() == "tpu"

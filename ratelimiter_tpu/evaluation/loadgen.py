@@ -62,11 +62,7 @@ def build_bench_chunk(cfg: Config, B: int, n_keys: int, alpha: float) -> Callabl
     generate B Zipf requests on device, decide them in one sketch step,
     return the packed allow bitmask + deny count. State is donated (stays
     resident in HBM)."""
-    from ratelimiter_tpu.core.types import Algorithm
-
-    W, sub_us, SW, S, limit = sketch_kernels.sketch_geometry(cfg)
-    d, w = cfg.sketch.depth, cfg.sketch.width
-    weighted = cfg.algorithm is not Algorithm.FIXED_WINDOW
+    step_kw = sketch_kernels.step_statics(cfg)
     seed = cfg.sketch.seed
 
     def chunk(state, counter0, now_us):
@@ -76,10 +72,7 @@ def build_bench_chunk(cfg: Config, B: int, n_keys: int, alpha: float) -> Callabl
         h2 = (h >> jnp.uint64(32)).astype(jnp.uint32) | jnp.uint32(1)
         n = jnp.ones((B,), jnp.int32)
         state, (allowed, _rem, _est) = sketch_kernels._sketch_step(
-            state, h1, h2, n, now_us,
-            limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-            iters=cfg.max_batch_admission_iters, weighted=weighted,
-            conservative=cfg.sketch.conservative_update)
+            state, h1, h2, n, now_us, **step_kw)
         packed = sketch_kernels._pack_bits(allowed)
         denies = jnp.sum(~allowed).astype(jnp.int32)
         return state, packed, denies

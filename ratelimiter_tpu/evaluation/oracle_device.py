@@ -36,14 +36,8 @@ def _next_pow2(n: int) -> int:
 
 def oracle_geometry(cfg: Config, n_keys: int) -> dict:
     """Step kwargs for the collision-free oracle twin of ``cfg``."""
-    from ratelimiter_tpu.core.types import Algorithm
-
-    W, sub_us, SW, S, limit = sketch_kernels.sketch_geometry(cfg)
-    return dict(limit=limit, sub_us=sub_us, SW=SW, S=S,
-                d=1, w=_next_pow2(n_keys),
-                iters=cfg.max_batch_admission_iters,
-                weighted=cfg.algorithm is not Algorithm.FIXED_WINDOW,
-                conservative=False)
+    return dict(sketch_kernels.step_statics(cfg), d=1, w=_next_pow2(n_keys),
+                conservative=False, hh=0, hh_thresh=0.0, tenants=0)
 
 
 def init_oracle_state(cfg: Config, n_keys: int) -> sketch_kernels.State:
@@ -66,17 +60,8 @@ def build_eval_chunk(cfg: Config, B: int, n_keys: int, alpha: float) -> Callable
     false_deny = sketch denied but the oracle allowed (the capped metric);
     false_allow = sketch allowed but the oracle denied.
     """
-    from ratelimiter_tpu.core.types import Algorithm
-
-    W, sub_us, SW, S, limit = sketch_kernels.sketch_geometry(cfg)
-    d, w = cfg.sketch.depth, cfg.sketch.width
-    weighted = cfg.algorithm is not Algorithm.FIXED_WINDOW
     seed = cfg.sketch.seed
-    hh, hh_thresh = sketch_kernels._hh_params(cfg)
-    sk_kw = dict(limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-                 iters=cfg.max_batch_admission_iters, weighted=weighted,
-                 conservative=cfg.sketch.conservative_update,
-                 hh=hh, hh_thresh=hh_thresh)
+    sk_kw = sketch_kernels.step_statics(cfg)
     or_kw = oracle_geometry(cfg, n_keys)
 
     def chunk(states, counter0, now_us):

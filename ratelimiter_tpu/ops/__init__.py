@@ -33,7 +33,6 @@ def ensure_x64() -> None:
             "limiter. The exact (host) backend works without it.")
 
 
-
 def named(name: str, fn, **static):
     """``functools.partial(fn, **static)`` under a name. ``jax.jit``
     names the compiled module after its function (``jit_<name>``) and a
@@ -42,3 +41,17 @@ def named(name: str, fn, **static):
     bound = partial(fn, **static)
     bound.__name__ = name
     return bound
+
+
+def memoized(cache: dict, statics: dict, extra: tuple, build):
+    """``build()`` once per (statics, extra), remembered in ``cache``.
+    The key is COMPUTED from the keyword mapping a program is built with
+    — its sorted items — plus what the builder binds besides (seed,
+    premix, mesh, ...), never spelled by hand next to it: a static the
+    step reads cannot be left out of the key, so ``update_limit`` can
+    never be served a program compiled for the old limit."""
+    key = (tuple(sorted(statics.items())), *extra)
+    built = cache.get(key)
+    if built is None:
+        built = cache[key] = build()
+    return built

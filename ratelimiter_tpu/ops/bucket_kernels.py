@@ -51,7 +51,7 @@ import numpy as np
 
 from ratelimiter_tpu.core.clock import MICROS
 from ratelimiter_tpu.core.config import Config
-from ratelimiter_tpu.ops import ensure_x64, named, policy_kernels
+from ratelimiter_tpu.ops import ensure_x64, memoized, named, policy_kernels
 from ratelimiter_tpu.ops.dense_kernels import _check_gates
 from ratelimiter_tpu.ops.segment import admit
 from ratelimiter_tpu.ops.sketch_kernels import (
@@ -126,7 +126,7 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
                  limit: int, rate_num: int, rate_den: int,
                  d: int, w: int, iters: int, tenants: int = 0,
                  window_us: int = 0,
-                 axis_name: str | None = None, use_pallas: bool = False):
+                 axis_name: str | None = None):
     """One batched decision step. Returns (state, (allowed, remaining,
     retry_us)) — the limiter-side retry/reset plumbing is shared with the
     other sketch paths.
@@ -141,24 +141,13 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
     with jax.named_scope("decay"):
         decay, rem = _decay(state, now_us, rate_num=rate_num,
                             rate_den=rate_den)
-    # Fused-kernel path (ADR-011): decay applies on the fly inside the
-    # kernels (the decayed slab never materializes) and columns derive
-    # in-kernel; collective merges stay on the reference path.
-    use_pallas = use_pallas and axis_name is None
     with jax.named_scope("estimate"):
-        if use_pallas:
-            from ratelimiter_tpu.ops import pallas_sketch
-
-            debt = None
-            cols = None
-            est = pallas_sketch.bucket_estimate(state["debt"], decay, h1, h2)
-        else:
-            debt = jnp.maximum(jnp.int64(0), state["debt"] - decay)
-            cols = _columns(h1, h2, d, w)                   # (B, d)
-            est = None
-            for r in range(d):
-                (e_r,) = row_gather((debt[r],), cols[:, r])
-                est = e_r if est is None else jnp.minimum(est, e_r)
+        debt = jnp.maximum(jnp.int64(0), state["debt"] - decay)
+        cols = _columns(h1, h2, d, w)                       # (B, d)
+        est = None
+        for r in range(d):
+            (e_r,) = row_gather((debt[r],), cols[:, r])
+            est = e_r if est is None else jnp.minimum(est, e_r)
 
     with jax.named_scope("admit"):
         if policy is not None:
@@ -217,22 +206,16 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
         cascade_retry = None
 
     with jax.named_scope("write_back"):
-        if use_pallas:
-            from ratelimiter_tpu.ops import pallas_sketch
-
-            debt, acc = pallas_sketch.bucket_update(
-                state["debt"], state["acc"], decay, h1, h2, consumed)
-        else:
-            hists = jnp.stack([row_histogram(cols[:, r], consumed, w)
-                               for r in range(d)])
-            if axis_name is not None:
-                # Multi-chip delta merge: replicated debt, psum of increments
-                # over ICI (same invariant as sketch_kernels' delta mode). The
-                # psum'd histogram IS the pod's local traffic, so `acc` stays
-                # export-correct on meshes too.
-                hists = jax.lax.psum(hists, axis_name)
-            debt = jnp.minimum(debt + hists, _DEBT_CAP)
-            acc = jnp.minimum(state["acc"] + hists, _DEBT_CAP)
+        hists = jnp.stack([row_histogram(cols[:, r], consumed, w)
+                           for r in range(d)])
+        if axis_name is not None:
+            # Multi-chip delta merge: replicated debt, psum of increments
+            # over ICI (same invariant as sketch_kernels' delta mode). The
+            # psum'd histogram IS the pod's local traffic, so `acc` stays
+            # export-correct on meshes too.
+            hists = jax.lax.psum(hists, axis_name)
+        debt = jnp.minimum(debt + hists, _DEBT_CAP)
+        acc = jnp.minimum(state["acc"] + hists, _DEBT_CAP)
 
     new_state = {"debt": debt,
                  "acc": acc,
@@ -327,59 +310,41 @@ def unpack_bucket(rows: np.ndarray, b: int, now_us: int, window_us: int):
             np.full(b, (now_us + window_us) / 1e6))
 
 
-_STEP_CACHE: Dict[tuple, Tuple[Callable, Callable]] = {}
-_SCAN_CACHE: Dict[tuple, Callable] = {}
+_BUILT: Dict[tuple, object] = {}
 
 
-def _params(cfg: Config) -> tuple:
-    W, num, den = _check_gates(cfg)
-    return (cfg.limit, num, den, cfg.sketch.depth, cfg.sketch.width,
-            cfg.max_batch_admission_iters)
-
-
-def _hier_params(cfg: Config) -> tuple:
-    """(tenants, window_us) for the cascade's fixed-window tenant
-    counters; (0, window_us) when the hierarchy is disabled."""
-    W, _, _ = _check_gates(cfg)
-    return cfg.hierarchy.tenants, W
-
-
-def build_steps(cfg: Config) -> Tuple[Callable, Callable]:
-    """Returns (step, reset) jitted callables, memoized per static config.
-    ``step`` accepts an optional trailing ``policy`` operand."""
-    from ratelimiter_tpu.ops.sketch_kernels import _resolve_pallas
-
+def step_statics(cfg: Config) -> dict:
+    """The static keyword arguments of ``_bucket_step`` for ``cfg`` —
+    the ONE derivation (see sketch_kernels.step_statics, whose contract
+    this twins): the refill rate in lowest terms, the geometry, and the
+    cascade's tenant count and fixed window. Raises RuntimeError
+    without 64-bit types."""
     ensure_x64()
-    limit, num, den, d, w, iters = _params(cfg)
-    tenants, wus = _hier_params(cfg)
-    use_pallas = _resolve_pallas(cfg)
-    key = (limit, num, den, d, w, iters, tenants, wus, use_pallas)
-    cached = _STEP_CACHE.get(key)
-    if cached is not None:
-        return cached
-    step = jax.jit(
-        named("bucket_step_split", _bucket_step, limit=limit, rate_num=num,
-              rate_den=den, d=d, w=w, iters=iters, tenants=tenants,
-              window_us=wus, use_pallas=use_pallas),
-        donate_argnums=(0,))
-    reset = jax.jit(
-        named("bucket_reset", _bucket_reset, rate_num=num, rate_den=den,
-              d=d, w=w),
-        donate_argnums=(0,))
-    _STEP_CACHE[key] = (step, reset)
-    return step, reset
+    W, num, den = _check_gates(cfg)
+    return dict(limit=cfg.limit, rate_num=num, rate_den=den,
+                d=cfg.sketch.depth, w=cfg.sketch.width,
+                iters=cfg.max_batch_admission_iters,
+                tenants=cfg.hierarchy.tenants, window_us=W)
 
 
-_HASHED_CACHE: Dict[tuple, Callable] = {}
+def build_controls(cfg: Config) -> Tuple[Callable]:
+    """Returns (reset,), jitted over the (h1, h2) operands and memoized
+    per static config; no rollover, decay is inside the step. A
+    replicated computation on a mesh's replicated state: the same
+    program serves every placement."""
+    kw = step_statics(cfg)
+    reset_kw = {k: kw[k] for k in ("rate_num", "rate_den", "d", "w")}
+    return memoized(_BUILT, reset_kw, ("controls",), lambda: (
+        jax.jit(named("bucket_reset", _bucket_reset, **reset_kw),
+                donate_argnums=(0,)),))
 
 
 def _bucket_step_staged(state: State, staged, policy=None, hier=None, *,
-                        seed: int, premix: bool, window_us: int, **step_kw):
+                        seed: int, premix: bool, **step_kw):
     h64, n, now_us = unstage(staged)
     h1, h2 = split_staged(h64, premix, seed)
     state, (allowed, remaining, retry_us) = _bucket_step(
-        state, h1, h2, n, now_us, policy, hier, window_us=window_us,
-        **step_kw)
+        state, h1, h2, n, now_us, policy, hier, **step_kw)
     with jax.named_scope("finish"):
         return state, pack_bucket(allowed, remaining, retry_us)
 
@@ -389,42 +354,17 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     buffer, returning ``(state, pack_bucket's one buffer)`` — the
     bucket twin of sketch_kernels.build_hashed_step (ADR-011, ADR-010
     addendum)."""
-    from ratelimiter_tpu.ops.sketch_kernels import _resolve_pallas
-
-    ensure_x64()
-    limit, num, den, d, w, iters = _params(cfg)
-    tenants, wus = _hier_params(cfg)
-    use_pallas = _resolve_pallas(cfg)
+    kw = step_statics(cfg)
     seed = cfg.sketch.seed
-    key = (limit, num, den, d, w, iters, tenants, wus, use_pallas, seed,
-           premix)
-    cached = _HASHED_CACHE.get(key)
-    if cached is not None:
-        return cached
-    step = jax.jit(
+    return memoized(_BUILT, kw, ("step", seed, premix), lambda: jax.jit(
         named("bucket_step", _bucket_step_staged, seed=seed, premix=premix,
-              limit=limit, rate_num=num, rate_den=den,
-              d=d, w=w, iters=iters, tenants=tenants, window_us=wus,
-              use_pallas=use_pallas),
-        donate_argnums=(0,))
-    _HASHED_CACHE[key] = step
-    return step
+              **kw),
+        donate_argnums=(0,)))
 
 
 def build_scan(cfg: Config) -> Callable:
     """Jitted multi-step runner, one dispatch for T batches (bench shape)."""
-    from ratelimiter_tpu.ops.sketch_kernels import _resolve_pallas
-
-    ensure_x64()
-    limit, num, den, d, w, iters = _params(cfg)
-    use_pallas = _resolve_pallas(cfg)
-    key = (limit, num, den, d, w, iters, use_pallas)
-    cached = _SCAN_CACHE.get(key)
-    if cached is not None:
-        return cached
-    step_kw = dict(limit=limit, rate_num=num, rate_den=den, d=d, w=w,
-                   iters=iters, use_pallas=use_pallas)
-    scan = jax.jit(named("bucket_scan", _bucket_scan, step_kw=step_kw),
-                   donate_argnums=(0,))
-    _SCAN_CACHE[key] = scan
-    return scan
+    kw = step_statics(cfg)
+    return memoized(_BUILT, kw, ("scan",), lambda: jax.jit(
+        named("bucket_scan", _bucket_scan, step_kw=kw),
+        donate_argnums=(0,)))

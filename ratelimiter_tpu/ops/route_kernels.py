@@ -54,7 +54,6 @@ import jax
 import jax.numpy as jnp
 
 from ratelimiter_tpu.core.config import Config
-from ratelimiter_tpu.ops import ensure_x64
 from ratelimiter_tpu.parallel.mesh import AXIS
 
 #: Words each device appends to its shard of the packed result: admitted
@@ -175,18 +174,33 @@ def state_layout(cfg: Config) -> Tuple[str, Tuple[str, ...],
         if cfg.hierarchy.tenants:
             mut += ["tn_counts", "tn_period"]
         return "bucket", tuple(mut), ()
-    from ratelimiter_tpu.ops import sketch_kernels
-
     mut = ["cur", "totals"]
     ro = ["slabs", "slab_period", "last_period"]
     if cfg.hierarchy.tenants:
         mut += ["tn_cur", "tn_totals"]
         ro += ["tn_slabs"]
-    hh, _ = sketch_kernels._hh_params(cfg)
-    if hh:
+    if cfg.sketch.hh_slots:
         mut += ["hh_owner", "hh_owner2", "hh_cur", "hh_totals", "hh_last"]
         ro += ["hh_slabs"]
     return "sketch", tuple(mut), tuple(ro)
+
+
+def step_rule(cfg: Config) -> Tuple[Callable, dict, Callable]:
+    """(step body, its static keyword arguments, packer) of ``cfg``'s
+    rule: with state_layout, the one table the programs that
+    wrap a step body on a mesh are built from (build_routed_step below,
+    parallel/mesh_kernels.build_mesh_hashed_step). ``packer(allowed,
+    remaining, third)`` takes the body's three outputs; the windowed
+    rules ship no third column (it is the estimate)."""
+    from ratelimiter_tpu.core.types import Algorithm
+    from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
+
+    if cfg.algorithm is Algorithm.TOKEN_BUCKET:
+        return (bucket_kernels._bucket_step,
+                bucket_kernels.step_statics(cfg), bucket_kernels.pack_bucket)
+    return (sketch_kernels._sketch_step, sketch_kernels.step_statics(cfg),
+            lambda allowed, remaining, *_est:
+            sketch_kernels.pack_window(allowed, remaining))
 
 
 #: Per-slice state leaves that are scalars on a slice (assembled as an
@@ -218,133 +232,84 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
     from ratelimiter_tpu.parallel.mesh_kernels import _HIER_SPEC, shard_map
     from jax.sharding import PartitionSpec as P
 
-    ensure_x64()
+    from ratelimiter_tpu.ops import memoized, sketch_kernels
+
     n = mesh.devices.size
+    step, step_kw, pack = step_rule(cfg)
     kind, mut_keys, ro_keys = state_layout(cfg)
     seed = cfg.sketch.seed
-    tenants = cfg.hierarchy.tenants
     mesh_key = (tuple(mesh.devices.flat), mesh.axis_names)
-    if kind == "sketch":
-        from ratelimiter_tpu.core.types import Algorithm
-        from ratelimiter_tpu.ops import sketch_kernels
 
-        W, sub_us, SW, S, limit = sketch_kernels.sketch_geometry(cfg)
-        d, w = cfg.sketch.depth, cfg.sketch.width
-        weighted = cfg.algorithm is not Algorithm.FIXED_WINDOW
-        cu = cfg.sketch.conservative_update
-        hh, hh_thresh = sketch_kernels._hh_params(cfg)
-        use_pallas = sketch_kernels._resolve_pallas(cfg)
-        statics = (limit, W, SW, d, w, cfg.max_batch_admission_iters,
-                   weighted, cu, hh, hh_thresh, tenants, use_pallas)
-        step_kw = dict(limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-                       iters=cfg.max_batch_admission_iters,
-                       weighted=weighted, conservative=cu, hh=hh,
-                       hh_thresh=hh_thresh, tenants=tenants,
-                       use_pallas=use_pallas)
-    else:
-        from ratelimiter_tpu.ops import bucket_kernels
+    def build():
+        C = capacity
 
-        limit, num, den, d, w, iters = bucket_kernels._params(cfg)
-        tenants_, wus = bucket_kernels._hier_params(cfg)
-        from ratelimiter_tpu.ops.sketch_kernels import _resolve_pallas
+        def _unwrap(mut, ro):
+            state = {}
+            for k in mut_keys:
+                state[k] = mut[k][0] if k in _SCALAR_LEAVES else mut[k]
+            for k in ro_keys:
+                state[k] = ro[k][0] if k in _SCALAR_LEAVES else ro[k]
+            return state
 
-        use_pallas = _resolve_pallas(cfg)
-        statics = (limit, num, den, d, w, iters, tenants_, wus, use_pallas)
-        step_kw = dict(limit=limit, rate_num=num, rate_den=den, d=d, w=w,
-                       iters=iters, tenants=tenants_, window_us=wus,
-                       use_pallas=use_pallas)
-    key = (kind, mesh_key, statics, seed, premix, L, capacity)
-    cached = _ROUTED_CACHE.get(key)
-    if cached is not None:
-        return cached
+        def _rewrap_mut(new_state, old_mut, ovf):
+            out = {}
+            for k in mut_keys:
+                v = new_state[k]
+                if k in _SCALAR_LEAVES:
+                    v = v.reshape(1)
+                # Overflow leaves the frame to the host router: EVERY
+                # state write is suppressed so the re-dispatch admits each
+                # row exactly once (no lost, no duplicated admission mass).
+                out[k] = jnp.where(ovf, old_mut[k], v)
+            return out
 
-    C = capacity
+        def body(mut, ro, h64, ns, b, now_us, policy, hier=None):
+            h_own, ns_own, order, binpos, keep, ovf_l = _route(
+                h64, ns, b, n, L, C, premix)
+            ovf = jax.lax.pmax(ovf_l.astype(jnp.int32), AXIS) > 0
+            state = _unwrap(mut, ro)
+            # The step's own scopes (hash_split, estimate, admit, ...) stay
+            # inside "decide".
+            with jax.named_scope("decide"):
+                h1, h2 = sketch_kernels.split_staged(h_own, premix, seed)
+                new_state, (allowed, remaining, third) = step(
+                    state, h1, h2, ns_own, now_us, policy, hier, **step_kw)
+            mass = jnp.sum(jnp.where(allowed, ns_own, 0)
+                           .astype(jnp.int64)).reshape(1)
+            tail = (*sketch_kernels.split_words(mass), ovf.reshape(1))
+            cols = [allowed.astype(jnp.uint8), remaining]
+            if kind == "bucket":
+                cols.append(third)  # retry_us; the windowed rules ship none
+            rets = _return_route(cols, order, binpos, keep)
+            with jax.named_scope("finish"):
+                words = jnp.concatenate(
+                    [pack(rets[0].astype(jnp.bool_), *rets[1:]),
+                     sketch_kernels.pack_rows(*tail)])
+            return _rewrap_mut(new_state, mut, ovf), words
 
-    def _unwrap(mut, ro):
-        state = {}
-        for k in mut_keys:
-            state[k] = mut[k][0] if k in _SCALAR_LEAVES else mut[k]
-        for k in ro_keys:
-            state[k] = ro[k][0] if k in _SCALAR_LEAVES else ro[k]
-        return state
+        # jax.jit names the compiled module after its function: a profile
+        # shows jit_routed_sketch_step / jit_routed_bucket_step, not
+        # jit_body.
+        body.__name__ = f"routed_{kind}_step"
 
-    def _rewrap_mut(new_state, old_mut, ovf):
-        out = {}
-        for k in mut_keys:
-            v = new_state[k]
-            if k in _SCALAR_LEAVES:
-                v = v.reshape(1)
-            # Overflow leaves the frame to the host router: EVERY state
-            # write is suppressed so the re-dispatch admits each row
-            # exactly once (no lost, no duplicated admission mass).
-            out[k] = jnp.where(ovf, old_mut[k], v)
-        return out
+        mut_spec = {k: P(AXIS) for k in mut_keys}
+        ro_spec = {k: P(AXIS) for k in ro_keys}
+        policy_spec = {"key": P(), "limit": P()}
+        in_specs = [mut_spec, ro_spec, P(AXIS), P(AXIS), P(), P(),
+                    policy_spec]
+        if step_kw["tenants"]:
+            in_specs.append(_HIER_SPEC)
+        # check_vma=False for the same reason as mesh_kernels: ovf IS
+        # replicated (a pmax result) but the checker cannot prove it, and
+        # the sharded state outputs flow through sort/cumsum chains.
+        mapped = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                           out_specs=(mut_spec, P(AXIS)), check_vma=False)
+        # No donation: the assembled global state aliases the slices' own
+        # pinned buffers (jax.make_array_from_single_device_arrays is
+        # zero-copy), and donating would invalidate them mid-writeback.
+        # The RO group (the big slab ring) is never an output, so the
+        # copy cost is bounded by the small mutated leaves.
+        return jax.jit(mapped)
 
-    def body(mut, ro, h64, ns, b, now_us, policy, hier=None):
-        from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
-        from ratelimiter_tpu.ops.hashing import split_hash_dev, \
-            splitmix64_dev
-
-        h_own, ns_own, order, binpos, keep, ovf_l = _route(
-            h64, ns, b, n, L, C, premix)
-        ovf = jax.lax.pmax(ovf_l.astype(jnp.int32), AXIS) > 0
-        state = _unwrap(mut, ro)
-        # The step's own scopes (hash_split, estimate, admit, ...) stay
-        # inside "decide".
-        with jax.named_scope("decide"):
-            h = splitmix64_dev(h_own) if premix else h_own
-            h1, h2 = split_hash_dev(h, seed)
-            if kind == "sketch":
-                new_state, (allowed, remaining, _est) = \
-                    sketch_kernels._sketch_step(
-                        state, h1, h2, ns_own, now_us, policy, hier,
-                        **step_kw)
-                retry_col = None
-            else:
-                new_state, (allowed, remaining, retry_us) = \
-                    bucket_kernels._bucket_step(
-                        state, h1, h2, ns_own, now_us, policy, hier,
-                        **step_kw)
-                retry_col = retry_us
-        mass = jnp.sum(jnp.where(allowed, ns_own, 0)
-                       .astype(jnp.int64)).reshape(1)
-        tail = (*sketch_kernels.split_words(mass), ovf.reshape(1))
-        cols = [allowed.astype(jnp.uint8), remaining]
-        if retry_col is not None:
-            cols.append(retry_col)
-        rets = _return_route(cols, order, binpos, keep)
-        allowed_s = rets[0].astype(jnp.bool_)
-        remaining_s = rets[1]
-        with jax.named_scope("finish"):
-            if kind == "sketch":
-                words = sketch_kernels.pack_window(allowed_s, remaining_s)
-            else:
-                words = bucket_kernels.pack_bucket(allowed_s, remaining_s,
-                                                   rets[2])
-            words = jnp.concatenate(
-                [words, sketch_kernels.pack_rows(*tail)])
-        return _rewrap_mut(new_state, mut, ovf), words
-
-    # jax.jit names the compiled module after its function: a profile
-    # shows jit_routed_sketch_step / jit_routed_bucket_step, not jit_body.
-    body.__name__ = f"routed_{kind}_step"
-    mut_spec = {k: P(AXIS) for k in mut_keys}
-    ro_spec = {k: P(AXIS) for k in ro_keys}
-    policy_spec = {"key": P(), "limit": P()}
-    in_specs = [mut_spec, ro_spec, P(AXIS), P(AXIS), P(), P(), policy_spec]
-    if tenants:
-        in_specs.append(_HIER_SPEC)
-    out_specs = (mut_spec, P(AXIS))
-    # check_vma=False for the same reason as mesh_kernels: ovf IS
-    # replicated (a pmax result) but the checker cannot prove it, and
-    # the sharded state outputs flow through sort/cumsum chains.
-    mapped = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=out_specs, check_vma=False)
-    # No donation: the assembled global state aliases the slices' own
-    # pinned buffers (jax.make_array_from_single_device_arrays is
-    # zero-copy), and donating would invalidate them mid-writeback. The
-    # RO group (the big slab ring) is never an output, so the copy cost
-    # is bounded by the small mutated leaves.
-    step = jax.jit(mapped)
-    _ROUTED_CACHE[key] = step
-    return step
+    return memoized(_ROUTED_CACHE, step_kw,
+                    (mesh_key, seed, premix, L, capacity), build)

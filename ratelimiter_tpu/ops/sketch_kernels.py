@@ -52,7 +52,7 @@ import numpy as np
 from ratelimiter_tpu.core.clock import to_micros
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import InvalidConfigError
-from ratelimiter_tpu.ops import ensure_x64, named, policy_kernels
+from ratelimiter_tpu.ops import ensure_x64, memoized, named, policy_kernels
 from ratelimiter_tpu.ops.segment import admit
 from ratelimiter_tpu.ops.sortmerge import row_gather, row_histogram, row_histogram_max
 
@@ -210,8 +210,7 @@ def _boundary_weight(state: State, p, now_us, *, sub_us: int, SW: int,
     rollover-boundary check (is the slab at slot p % S the period p-SW
     slab?) and its remaining-overlap weight. ``pre`` short-circuits with
     scan-hoisted values (see _sketch_scan); fixed-window mode returns
-    (0.0, None). Shared by the jnp and Pallas estimate paths so both see
-    the exact same scalar math."""
+    (0.0, None)."""
     if not weighted:
         return jnp.float32(0.0), None
     if pre is not None:
@@ -300,37 +299,18 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
                  limit: int, sub_us: int, SW: int, S: int, d: int, w: int,
                  iters: int, weighted: bool, conservative: bool,
                  hh: int = 0, hh_thresh: float = 0.0, tenants: int = 0,
-                 axis_name: str | None = None, pre=None, pre_hh=None,
-                 use_pallas: bool = False):
+                 axis_name: str | None = None, pre=None, pre_hh=None):
     # Precondition (host-enforced via _sync_period): state.last_period is
     # the period of now_us. Clamp defends against clock skew backwards —
     # the reference has the same NTP caveat (``docs/ALGORITHMS.md:162``).
     now_us = jnp.maximum(now_us, state["last_period"] * sub_us)
     p = state["last_period"]
 
-    # Fused-kernel path (ADR-011): columns derive INSIDE the Pallas
-    # kernels, so the (B, d) column matrix never materializes. Collective
-    # merges and the hh side table stay on the reference path (the psum'd
-    # histogram and private-cell reads are not fused).
-    use_pallas = use_pallas and axis_name is None and not hh
     with jax.named_scope("estimate"):
-        if use_pallas:
-            from ratelimiter_tpu.ops import pallas_sketch
-
-            cols = None
-            frac, boundary = _boundary_weight(state, p, now_us, sub_us=sub_us,
-                                              SW=SW, S=S, weighted=weighted,
-                                              pre=pre)
-            bop = (boundary if boundary is not None
-                   else jnp.zeros_like(state["totals"]))
-            est = jnp.maximum(
-                pallas_sketch.window_estimate(state["totals"], bop, frac,
-                                              h1, h2), 0.0)
-        else:
-            cols = _columns(h1, h2, d, w)                        # (B, d)
-            est, frac, boundary = _estimate(state, cols, p, now_us,
-                                            sub_us=sub_us, SW=SW, S=S,
-                                            weighted=weighted, pre=pre)
+        cols = _columns(h1, h2, d, w)                            # (B, d)
+        est, frac, boundary = _estimate(state, cols, p, now_us,
+                                        sub_us=sub_us, SW=SW, S=S,
+                                        weighted=weighted, pre=pre)
 
     if hh:
         # Heavy-hitter side table (ROADMAP v0.2): a promoted key's NEW
@@ -434,40 +414,28 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
             # can undercount rows whose dense read exceeds the min-estimate —
             # both break the never-over-admit direction. Vanilla sums never do.
             target = jnp.where(allowed & not_mine, est + (avail - seen) + n_f, 0.0)
-            if use_pallas:
-                from ratelimiter_tpu.ops import pallas_sketch
-
-                totals, cur = pallas_sketch.cu_update(
-                    state["totals"], state["cur"], bop, frac, h1, h2, target)
-            else:
-                deltas = []
-                for r in range(d):
-                    m_r = row_histogram_max(cols[:, r], target, w)
-                    read_r = state["totals"][r].astype(jnp.float32)
-                    if boundary is not None:
-                        read_r = read_r + frac * boundary[r].astype(jnp.float32)
-                    deltas.append(jnp.ceil(jnp.maximum(m_r - read_r, 0.0)))
-                hists = jnp.stack(deltas).astype(jnp.int32)
-                totals = state["totals"] + hists
-                cur = state["cur"] + hists
+            deltas = []
+            for r in range(d):
+                m_r = row_histogram_max(cols[:, r], target, w)
+                read_r = state["totals"][r].astype(jnp.float32)
+                if boundary is not None:
+                    read_r = read_r + frac * boundary[r].astype(jnp.float32)
+                deltas.append(jnp.ceil(jnp.maximum(m_r - read_r, 0.0)))
+            hists = jnp.stack(deltas).astype(jnp.int32)
+            totals = state["totals"] + hists
+            cur = state["cur"] + hists
         else:
             add = jnp.where(allowed & not_mine, n, 0).astype(jnp.int32)  # (B,)
-            if use_pallas:
-                from ratelimiter_tpu.ops import pallas_sketch
-
-                totals, cur = pallas_sketch.add_update(
-                    state["totals"], state["cur"], h1, h2, add)
-            else:
-                hists = jnp.stack([row_histogram(cols[:, r], add, w)
-                                   for r in range(d)])
-                if axis_name is not None:
-                    # Multi-chip delta merge: every chip adds the summed
-                    # histogram, keeping the replicated-state invariant (ICI
-                    # psum — the analog of all app servers sharing one Redis,
-                    # SURVEY.md §2.6).
-                    hists = jax.lax.psum(hists, axis_name)
-                totals = state["totals"] + hists
-                cur = state["cur"] + hists
+            hists = jnp.stack([row_histogram(cols[:, r], add, w)
+                               for r in range(d)])
+            if axis_name is not None:
+                # Multi-chip delta merge: every chip adds the summed
+                # histogram, keeping the replicated-state invariant (ICI
+                # psum — the analog of all app servers sharing one Redis,
+                # SURVEY.md §2.6).
+                hists = jax.lax.psum(hists, axis_name)
+            totals = state["totals"] + hists
+            cur = state["cur"] + hists
     # cur and totals share the same histogram so the "current sub-window
     # also counts in totals" invariant holds by construction.
 
@@ -747,65 +715,20 @@ def _sketch_scan(state: State, h1s, h2s, ns, now0_us, dt_us, *, step_kw):
     return state, packed, denies
 
 
-_STEP_CACHE: Dict[tuple, Callable] = {}
-
-
-def _hh_params(cfg: Config) -> tuple[int, float]:
-    """(hh_slots, promotion threshold in requests) for cfg; (0, 0) when the
-    side table is disabled."""
-    K = cfg.sketch.hh_slots
-    if not K:
-        return 0, 0.0
-    return K, max(1.0, float(cfg.limit) * cfg.sketch.hh_promote_fraction)
-
-
-def build_steps(cfg: Config) -> tuple[Callable, Callable, Callable]:
-    """Returns (step, reset, rollover) jitted callables; memoized per static
-    config. The host calls ``rollover(state, p)`` whenever the sub-window
-    period of the dispatch timestamp differs from the state's period (see
-    _rollover for why this is host-driven). ``step`` accepts an optional
-    trailing ``policy`` operand (the device-resident override table)."""
-    from ratelimiter_tpu.core.types import Algorithm
-
-    ensure_x64()
-
-    W, sub_us, SW, S, limit = sketch_geometry(cfg)
-    d, w = cfg.sketch.depth, cfg.sketch.width
-    weighted = cfg.algorithm is not Algorithm.FIXED_WINDOW
-    cu = cfg.sketch.conservative_update
-    hh, hh_thresh = _hh_params(cfg)
-    tenants = cfg.hierarchy.tenants
-    use_pallas = _resolve_pallas(cfg)
-    key = (limit, W, SW, d, w, cfg.max_batch_admission_iters, weighted, cu,
-           hh, hh_thresh, tenants, use_pallas)
-    cached = _STEP_CACHE.get(key)
-    if cached is not None:
-        return cached
-    step = jax.jit(
-        named("sketch_step_split", _sketch_step, limit=limit,
-              sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-              iters=cfg.max_batch_admission_iters, weighted=weighted,
-              conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
-              use_pallas=use_pallas),
-        donate_argnums=(0,))
-    reset = jax.jit(
-        named("sketch_reset", _sketch_reset, sub_us=sub_us, SW=SW, S=S,
-              d=d, w=w, weighted=weighted, hh=hh),
-        donate_argnums=(0,))
-    rollover = jax.jit(
-        named("sketch_rotate", _rollover, SW=SW, S=S), donate_argnums=(0,))
-    _STEP_CACHE[key] = (step, reset, rollover)
-    return step, reset, rollover
-
-
-def _resolve_pallas(cfg: Config) -> bool:
-    """Static kernel selection for this config (ADR-011)."""
-    from ratelimiter_tpu.ops import pallas_sketch
-
-    return pallas_sketch.resolve_kernels(cfg) == "pallas"
-
-
-# ------------------------------------------------- hashed-operand steps
+# ------------------------------------------------- the step's contract
+#
+# "Which compiled program decides a batch under this config" is answered
+# here and nowhere else. ``step_statics`` is the ONE derivation from a
+# Config to the static keyword arguments of ``_sketch_step`` (its twin:
+# bucket_kernels.step_statics); every builder of a program around that
+# body — build_hashed_step, build_controls and build_scan below, the
+# replicated mesh's (parallel/mesh_kernels.py) and the collective
+# router's (ops/route_kernels.py) — takes its statics from it and keys
+# its memo on that mapping's items (ops.memoized) plus what the builder
+# itself binds, so a field the step reads cannot be missing from a key.
+# How a table row is read or written (sort-merge or direct indexing) is
+# chosen inside the body from (B, w, platform), in
+# ops/sortmerge._use_sortmerge only.
 #
 # The serving hot path stages ONE uint64 buffer per batch —
 # ``[ids(P) | n(P) | now_us(1)]``, one host→device transfer — and the
@@ -818,7 +741,46 @@ def _resolve_pallas(cfg: Config) -> bool:
 # finalizer in-step: the raw-u64-id wire lane (T_ALLOW_HASHED) ships
 # tenant ids untouched and the device does ALL the mixing.
 
-_HASHED_CACHE: Dict[tuple, Callable] = {}
+_BUILT: Dict[tuple, object] = {}
+
+
+def step_statics(cfg: Config) -> dict:
+    """The static keyword arguments of ``_sketch_step`` for ``cfg``.
+    Raises InvalidConfigError for a TOKEN_BUCKET config (sketch_geometry)
+    and RuntimeError without 64-bit types (ensure_x64)."""
+    from ratelimiter_tpu.core.types import Algorithm
+
+    ensure_x64()
+    _, sub_us, SW, S, limit = sketch_geometry(cfg)
+    # The side table's slots and its promotion threshold in requests;
+    # (0, 0.0) when it is disabled.
+    hh = cfg.sketch.hh_slots
+    hh_thresh = (max(1.0, float(limit) * cfg.sketch.hh_promote_fraction)
+                 if hh else 0.0)
+    return dict(limit=limit, sub_us=sub_us, SW=SW, S=S,
+                d=cfg.sketch.depth, w=cfg.sketch.width,
+                iters=cfg.max_batch_admission_iters,
+                weighted=cfg.algorithm is not Algorithm.FIXED_WINDOW,
+                conservative=cfg.sketch.conservative_update,
+                hh=hh, hh_thresh=hh_thresh, tenants=cfg.hierarchy.tenants)
+
+
+def build_controls(cfg: Config) -> tuple[Callable, Callable]:
+    """Returns (reset, rollover) jitted callables over the (h1, h2)
+    operands — rare control-plane dispatches; memoized per static config.
+    The host calls ``rollover(state, p)`` whenever the sub-window period
+    of the dispatch timestamp differs from the state's period (see
+    _rollover for why this is host-driven). Both are replicated
+    computations on a mesh's replicated state: the same two programs
+    serve every placement."""
+    kw = step_statics(cfg)
+    reset_kw = {k: kw[k] for k in ("sub_us", "SW", "S", "d", "w",
+                                   "weighted", "hh")}
+    return memoized(_BUILT, reset_kw, ("controls",), lambda: (
+        jax.jit(named("sketch_reset", _sketch_reset, **reset_kw),
+                donate_argnums=(0,)),
+        jax.jit(named("sketch_rotate", _rollover, SW=kw["SW"], S=kw["S"]),
+                donate_argnums=(0,))))
 
 
 def unstage(staged):
@@ -853,35 +815,15 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     buffer (see ``unstage``) of finalized 64-bit hashes (premix=False —
     string-key and pre-hashed traffic) or raw u64 ids (premix=True — the
     hashed wire lane); returns ``(state, pack_window's one buffer)``.
-    Memoized per static config. Decision-identical to build_steps'
-    (h1, h2) step by the split_hash host/device bit-equality
+    Memoized per static config. Decision-identical to ``_sketch_step``
+    over host-split (h1, h2) by the split_hash host/device bit-equality
     (tests/test_hashing_device.py)."""
-    ensure_x64()
-
-    W, sub_us, SW, S, limit = sketch_geometry(cfg)
-    d, w = cfg.sketch.depth, cfg.sketch.width
-    from ratelimiter_tpu.core.types import Algorithm
-
-    weighted = cfg.algorithm is not Algorithm.FIXED_WINDOW
-    cu = cfg.sketch.conservative_update
-    hh, hh_thresh = _hh_params(cfg)
-    tenants = cfg.hierarchy.tenants
-    use_pallas = _resolve_pallas(cfg)
+    kw = step_statics(cfg)
     seed = cfg.sketch.seed
-    key = (limit, W, SW, d, w, cfg.max_batch_admission_iters, weighted, cu,
-           hh, hh_thresh, tenants, use_pallas, seed, premix)
-    cached = _HASHED_CACHE.get(key)
-    if cached is not None:
-        return cached
-    step = jax.jit(
+    return memoized(_BUILT, kw, ("step", seed, premix), lambda: jax.jit(
         named("sketch_step", _sketch_step_staged, seed=seed, premix=premix,
-              limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-              iters=cfg.max_batch_admission_iters, weighted=weighted,
-              conservative=cu, hh=hh, hh_thresh=hh_thresh, tenants=tenants,
-              use_pallas=use_pallas),
-        donate_argnums=(0,))
-    _HASHED_CACHE[key] = step
-    return step
+              **kw),
+        donate_argnums=(0,)))
 
 
 def _migrate_window(state: State, now_us, *, sub_o: int, SWo: int, So: int,
@@ -963,7 +905,7 @@ def build_migrate(old_cfg: Config, new_cfg: Config) -> Callable:
     if (old_cfg.sketch.depth, old_cfg.sketch.width) != (
             new_cfg.sketch.depth, new_cfg.sketch.width):
         raise InvalidConfigError("window migration cannot change geometry")
-    hh, _ = _hh_params(old_cfg)
+    hh = old_cfg.sketch.hh_slots
     # No donation: the ring shapes change (So != Sn in general), so the
     # old buffers cannot be reused anyway and donating only warns.
     return jax.jit(
@@ -971,33 +913,11 @@ def build_migrate(old_cfg: Config, new_cfg: Config) -> Callable:
               So=So, sub_n=sub_n, SWn=SWn, Sn=Sn, hh=hh))
 
 
-_SCAN_CACHE: Dict[tuple, Callable] = {}
-
-
 def build_scan(cfg: Config) -> Callable:
     """Jitted multi-step runner: ``scan(state, h1s, h2s, ns, now0_us, dt_us)
     -> (state, packed_masks, deny_counts)`` where the leading axis of
     h1s/h2s/ns is time. One device dispatch for T batches."""
-    from ratelimiter_tpu.core.types import Algorithm
-
-    ensure_x64()
-
-    W, sub_us, SW, S, limit = sketch_geometry(cfg)
-    d, w = cfg.sketch.depth, cfg.sketch.width
-    weighted = cfg.algorithm is not Algorithm.FIXED_WINDOW
-    cu = cfg.sketch.conservative_update
-    hh, hh_thresh = _hh_params(cfg)
-    use_pallas = _resolve_pallas(cfg)
-    key = (limit, W, SW, d, w, cfg.max_batch_admission_iters, weighted, cu,
-           hh, hh_thresh, use_pallas)
-    cached = _SCAN_CACHE.get(key)
-    if cached is not None:
-        return cached
-    step_kw = dict(limit=limit, sub_us=sub_us, SW=SW, S=S, d=d, w=w,
-                   iters=cfg.max_batch_admission_iters, weighted=weighted,
-                   conservative=cu, hh=hh, hh_thresh=hh_thresh,
-                   use_pallas=use_pallas)
-    scan = jax.jit(named("sketch_scan", _sketch_scan, step_kw=step_kw),
-                   donate_argnums=(0,))
-    _SCAN_CACHE[key] = scan
-    return scan
+    kw = step_statics(cfg)
+    return memoized(_BUILT, kw, ("scan",), lambda: jax.jit(
+        named("sketch_scan", _sketch_scan, step_kw=kw),
+        donate_argnums=(0,)))

@@ -62,8 +62,41 @@ def _warn_delta() -> None:
 
 
 class _MeshPlacement:
-    """Placement hooks shared by every mesh limiter: batch sharded over the
-    mesh axis, state and scalar operands replicated."""
+    """What placing a sketch limiter on a mesh changes, and all of it:
+    the batch is sharded over the mesh axis, state and scalar operands
+    are replicated, and the serving step is the shard_map'd one
+    (``_build_step``, the base class's one program hook). Reset and
+    rollover are replicated computations on replicated state — the base
+    class's single-chip controls, as they are.
+
+    Args (of every mesh limiter):
+        config: limiter configuration (validated as usual).
+        mesh: a 1-D ``jax.sharding.Mesh``; default = all visible devices.
+        merge: "gather" (bit-exact global sequencing via all_gather — the
+            default, and the only mode that preserves the reference's
+            strict never-over-admit contract) or "delta" (one psum per
+            step, <=1 step staleness: a key hammered from every chip in the
+            SAME step can be over-admitted up to n_chips * limit in that
+            step; converged and denying from the next step on). See
+            parallel/__init__ and docs/ADR/002 for the tradeoff.
+        clock: time source (tests inject ManualClock).
+    """
+
+    def __init__(self, config: Config, clock: Optional[Clock] = None, *,
+                 mesh=None, merge: str = "gather"):
+        if merge == "delta":
+            _warn_delta()
+        # Before the base constructor: it installs the steps, and the
+        # step hook below reads the mesh.
+        self.mesh = mesh if mesh is not None else make_mesh()
+        self.merge = merge
+        self.n_chips = int(np.prod(self.mesh.devices.shape))
+        super().__init__(config, clock)
+        self._state = mesh_kernels.replicate_state(self._state, self.mesh)
+
+    def _build_step(self, cfg: Config, premix: bool):
+        return mesh_kernels.build_mesh_hashed_step(
+            cfg, self.mesh, self.merge, premix=premix)
 
     def _padded_size(self, b: int) -> int:
         per_chip = _pad_size(max(1, -(-b // self.n_chips)))
@@ -111,65 +144,14 @@ class _MeshPlacement:
 
 
 class MeshSketchLimiter(_MeshPlacement, SketchLimiter):
-    """Sketch limiter whose dispatch spans every chip of a mesh.
+    """Sketch limiter whose dispatch spans every chip of a mesh
+    (arguments: _MeshPlacement)."""
 
-    Args:
-        config: limiter configuration (validated as usual).
-        mesh: a 1-D ``jax.sharding.Mesh``; default = all visible devices.
-        merge: "gather" (bit-exact global sequencing via all_gather — the
-            default, and the only mode that preserves the reference's
-            strict never-over-admit contract) or "delta" (one psum per
-            step, <=1 step staleness: a key hammered from every chip in the
-            SAME step can be over-admitted up to n_chips * limit in that
-            step; converged and denying from the next step on). See
-            parallel/__init__ and docs/ADR/002 for the tradeoff.
-        clock: time source (tests inject ManualClock).
-    """
-
-    def __init__(self, config: Config, clock: Optional[Clock] = None, *,
-                 mesh=None, merge: str = "gather"):
-        super().__init__(config, clock)
-        if merge == "delta":
-            _warn_delta()
-        self.mesh = mesh if mesh is not None else make_mesh()
-        self.merge = merge
-        self.n_chips = int(np.prod(self.mesh.devices.shape))
-        # Replace the single-chip step with the mesh step (hashed-operand
-        # form: the (h1, h2) split runs inside the shard_map'd body,
-        # ADR-011); reset/rollover stay the plain replicated kernels.
-        _, self._reset_step, self._rollover = (
-            mesh_kernels.build_mesh_steps(self.config, self.mesh, merge))
-        self._step = mesh_kernels.build_mesh_hashed_step(
-            self.config, self.mesh, merge)
-        self._ids_step = None
-        self._state = mesh_kernels.replicate_state(self._state, self.mesh)
-
-    def _build_ids_step(self):
-        return mesh_kernels.build_mesh_hashed_step(
-            self.config, self.mesh, self.merge, premix=True)
-
-    def _apply_config(self, new_cfg):
-        steps = mesh_kernels.build_mesh_steps(new_cfg, self.mesh, self.merge)
-        step = mesh_kernels.build_mesh_hashed_step(new_cfg, self.mesh,
-                                                   self.merge)
-        with self._lock:
-            self._step = step
-            _, self._reset_step, self._rollover = steps
-            self._ids_step = None
-
-    def _apply_window(self, new_cfg):
-        """Dynamic window on a mesh: migrate the (replicated) ring with
-        the plain kernel, then re-install the mesh-compiled steps and
-        re-replicate — the base hook alone would silently swap in
-        single-chip kernels and drop the merge contract."""
+    def _apply_window(self, new_cfg: Config) -> None:
+        """Dynamic window on a mesh: the ring is migrated by a plain
+        (unsharded) kernel, so place it replicated again."""
         super()._apply_window(new_cfg)
-        steps = mesh_kernels.build_mesh_steps(new_cfg, self.mesh, self.merge)
-        step = mesh_kernels.build_mesh_hashed_step(new_cfg, self.mesh,
-                                                   self.merge)
         with self._lock:
-            self._step = step
-            _, self._reset_step, self._rollover = steps
-            self._ids_step = None
             self._state = mesh_kernels.replicate_state(self._state, self.mesh)
 
 
@@ -178,65 +160,6 @@ class MeshTokenBucketLimiter(_MeshPlacement, SketchTokenBucketLimiter):
     sharded over chips, same merge modes and staleness contract as
     MeshSketchLimiter (the scalar decay is deterministic on replicated
     state, so only the debt increments need a collective)."""
-
-    def __init__(self, config: Config, clock: Optional[Clock] = None, *,
-                 mesh=None, merge: str = "gather"):
-        super().__init__(config, clock)
-        if merge == "delta":
-            _warn_delta()
-        self.mesh = mesh if mesh is not None else make_mesh()
-        self.merge = merge
-        self.n_chips = int(np.prod(self.mesh.devices.shape))
-        _, self._reset_step = mesh_kernels.build_mesh_bucket_steps(
-            self.config, self.mesh, merge)
-        self._step = mesh_kernels.build_mesh_hashed_bucket_step(
-            self.config, self.mesh, merge)
-        self._ids_step = None
-        self._state = mesh_kernels.replicate_state(self._state, self.mesh)
-
-    def _build_ids_step(self):
-        return mesh_kernels.build_mesh_hashed_bucket_step(
-            self.config, self.mesh, self.merge, premix=True)
-
-    def _apply_config(self, new_cfg):
-        import jax.numpy as jnp
-
-        from ratelimiter_tpu.core.clock import MICROS as _MICROS
-
-        steps = mesh_kernels.build_mesh_bucket_steps(new_cfg, self.mesh,
-                                                     self.merge)
-        step = mesh_kernels.build_mesh_hashed_bucket_step(
-            new_cfg, self.mesh, self.merge)
-        cap = new_cfg.limit * _MICROS
-        with self._lock:
-            self._step = step
-            _, self._reset_step = steps
-            self._ids_step = None
-            self._state = dict(
-                self._state,
-                debt=jnp.minimum(self._state["debt"], cap),
-                rem=self._place_replicated(jnp.asarray(0, jnp.int64)))
-
-    def _apply_window(self, new_cfg):
-        """Dynamic window on a mesh bucket: the window only sets the
-        refill rate, so rebuild the MESH steps (not the single-chip ones
-        the base hook installs) and reset the remainder replicated."""
-        import jax.numpy as jnp
-
-        from ratelimiter_tpu.core.clock import to_micros as _to_micros
-
-        steps = mesh_kernels.build_mesh_bucket_steps(new_cfg, self.mesh,
-                                                     self.merge)
-        step = mesh_kernels.build_mesh_hashed_bucket_step(
-            new_cfg, self.mesh, self.merge)
-        with self._lock:
-            self._step = step
-            _, self._reset_step = steps
-            self._ids_step = None
-            self._window_us = _to_micros(new_cfg.window)
-            self._state = dict(
-                self._state,
-                rem=self._place_replicated(jnp.asarray(0, jnp.int64)))
 
 
 # ===================================================================

@@ -266,9 +266,9 @@ def _merge_windowed(states: Sequence[Arrays],
 # ---------------------------------------------------------- token bucket
 
 def _bucket_rate(config) -> Tuple[int, int]:
-    from ratelimiter_tpu.ops import bucket_kernels
+    from ratelimiter_tpu.ops.dense_kernels import _check_gates
 
-    _, num, den, _, _, _ = bucket_kernels._params(config)
+    _, num, den = _check_gates(config)
     return num, den
 
 

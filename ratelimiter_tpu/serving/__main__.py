@@ -136,13 +136,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "them as top-K consumer analytics "
                          "(/healthz consumers, /debug/audit, "
                          "rate_limiter_top_consumer_mass)")
-    ap.add_argument("--kernels", default="auto",
-                    choices=("auto", "pallas", "jnp"),
-                    help="sketch hot-loop kernels (ADR-011): auto and "
-                         "jnp are the jnp/XLA path on every platform; "
-                         "pallas is the interpret-mode parity lane "
-                         "off-TPU and a start-up error on a TPU (Mosaic "
-                         "refuses the fused kernels)")
     # Hierarchical cascades + adaptive control (ADR-020).
     ap.add_argument("--tenants", type=int, default=0,
                     help="enable hierarchical cascades (ADR-020): tenant "
@@ -1015,8 +1008,8 @@ def _configure_jax(args) -> None:
 def _device_report(args, limiters) -> str:
     """The ``device=`` field of both banners and the startup log line:
     platform, device_kind and device count as JAX reports them in THIS
-    process, the kernel path resolve_kernels chose, and the device ids
-    each dispatch unit's state sits on — read off the arrays after
+    process, the (constant) kernel path, and the device ids each
+    dispatch unit's state sits on — read off the arrays after
     prewarm, not off what was asked for. It is how a caller (and
     chip_smoke.py) tells which device answers."""
     if args.backend == "exact":
@@ -1030,14 +1023,14 @@ def _device_report(args, limiters) -> str:
     placed = ["+".join(str(i) for i in sorted(
         {d.id for leaf in u._state.values() for d in leaf.devices()}))
         for u in units]
-    kernels = "jnp"
-    if args.backend in ("sketch", "mesh"):
-        from ratelimiter_tpu.ops import pallas_sketch
-
-        kernels = pallas_sketch.resolve_kernels(units[0].config)
     devs = jax.devices()
+    # ``kernels=jnp`` is a constant since PR 30 (one table-access path,
+    # chosen in ops/sortmerge._use_sortmerge): the word stays because
+    # chipbench/runner.py's _BANNER and chip_smoke.py's regex both
+    # require ``kernels=\w+`` and fail a run whose banner lacks it
+    # (ROADMAP D10).
     report = (f"device={devs[0].platform}/{devs[0].device_kind} "
-              f"x{len(devs)} kernels={kernels} "
+              f"x{len(devs)} kernels=jnp "
               f"slice_devices={','.join(placed)}")
     log = logging.getLogger("ratelimiter_tpu.serving")
     if devs[0].platform == "cpu" and not os.environ.get("JAX_PLATFORMS"):
@@ -1082,8 +1075,7 @@ async def amain(args) -> None:
         fail_open=args.fail_open,
         sketch=SketchParams(depth=args.sketch_depth, width=args.sketch_width,
                             sub_windows=args.sub_windows,
-                            hh_slots=args.hh_slots,
-                            kernels=args.kernels),
+                            hh_slots=args.hh_slots),
         persistence=PersistenceSpec(
             dir=args.snapshot_dir,
             snapshot_interval=args.snapshot_interval,

@@ -1,5 +1,5 @@
 """The rules the chip bring-up set (ISSUE 21): where the compile cache
-goes, which kernel path a TPU resolves, that the chip smoke's parent
+goes, which table access a TPU resolves, that the chip smoke's parent
 stays off JAX, that the remote-attachment era left no trace, and that a
 native binary is trusted only for the sources it was built from.
 
@@ -16,9 +16,7 @@ import sys
 import jax
 import pytest
 
-from ratelimiter_tpu import Algorithm, Config, SketchParams
 from ratelimiter_tpu.core import jaxcfg
-from ratelimiter_tpu.core.errors import InvalidConfigError
 from ratelimiter_tpu.native import build
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -59,37 +57,7 @@ def test_cache_dir_is_one_fixed_path_inside_the_checkout(monkeypatch):
             == calls["jax_compilation_cache_dir"])
 
 
-# ---------------------------------------------- (b) the kernel selection
-
-def _cfg(kernels: str, algorithm=Algorithm.TPU_SKETCH) -> Config:
-    return Config(algorithm=algorithm, limit=100, window=60.0,
-                  sketch=SketchParams(depth=4, width=65536, sub_windows=60,
-                                      kernels=kernels))
-
-
-def test_auto_never_picks_pallas_on_a_tpu(monkeypatch):
-    from ratelimiter_tpu.ops import pallas_sketch
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert jaxcfg.on_tpu()
-    # BASELINE config 3: a 1 MiB slab, the geometry the old selector
-    # handed to kernels Mosaic refuses.
-    assert pallas_sketch.resolve_kernels(_cfg("auto")) == "jnp"
-    assert pallas_sketch.resolve_kernels(
-        _cfg("auto", Algorithm.TOKEN_BUCKET)) == "jnp"
-    assert pallas_sketch.resolve_kernels(_cfg("jnp")) == "jnp"
-
-
-def test_forced_pallas_on_a_tpu_is_a_typed_error(monkeypatch):
-    from ratelimiter_tpu.ops import pallas_sketch
-
-    assert pallas_sketch.resolve_kernels(_cfg("pallas")) == "pallas"
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(InvalidConfigError, match="Pallas TPU lowering"):
-        pallas_sketch.resolve_kernels(_cfg("pallas"))
-    # Never interpreted on a TPU either.
-    assert not pallas_sketch._interpret()
-
+# ----------------------------------------- (b) the table-access selection
 
 def test_table_access_reads_the_same_platform_test(monkeypatch):
     from ratelimiter_tpu.ops.sortmerge import _use_sortmerge

@@ -214,7 +214,9 @@ class TestSketchTokenBucket:
         with pytest.raises(InvalidConfigError):
             sketch_kernels.sketch_geometry(cfg)
         with pytest.raises(InvalidConfigError):
-            sketch_kernels.build_steps(cfg)
+            sketch_kernels.build_hashed_step(cfg)
+        with pytest.raises(InvalidConfigError):
+            sketch_kernels.build_controls(cfg)
 
     def test_unweighted_n_greater_than_limit_never_admits(self):
         lim, _ = make(algo=Algorithm.TOKEN_BUCKET, limit=5, window=10.0)

@@ -21,6 +21,7 @@ columns on the host. Held here:
 """
 
 import contextlib
+from functools import partial
 
 import numpy as np
 import pytest
@@ -79,7 +80,7 @@ def _old_finish_bucket(allowed, remaining, retry_us, now_us, window_us):
             np.broadcast_to(reset, allowed.shape))
 
 
-def _reference(algo, steps, state, ids, ns, now_us, padded, premix, cfg,
+def _reference(algo, step, state, ids, ns, now_us, padded, premix, cfg,
                policy):
     """The (h1, h2) step on ``state`` and the old finish arithmetic:
     ``(state, the four columns over [:b])``."""
@@ -87,7 +88,7 @@ def _reference(algo, steps, state, ids, ns, now_us, padded, premix, cfg,
     h1, h2 = split_hash(splitmix64(ids) if premix else ids, cfg.sketch.seed)
     pad = lambda a, dt: np.concatenate(
         [a, np.zeros(padded - b, a.dtype)]).astype(dt)
-    state, (allowed, remaining, third) = steps[0](
+    state, (allowed, remaining, third) = step(
         state, pad(h1, np.uint32), pad(h2, np.uint32), pad(ns, np.int32),
         jnp.int64(now_us), policy)
     allowed, remaining, third = (np.asarray(x)[:b]
@@ -119,7 +120,11 @@ def test_rebuilt_result_equals_step_then_old_finish(algo, premix, b,
                                                     overrides):
     cfg = _cfg(algo)
     kernels = bucket_kernels if algo == "bucket" else sketch_kernels
-    steps = kernels.build_steps(cfg)
+    # The reference: the un-jitted step body over host-split (h1, h2),
+    # under the one derivation of its statics.
+    body = (bucket_kernels._bucket_step if algo == "bucket"
+            else sketch_kernels._sketch_step)
+    step = jax.jit(partial(body, **kernels.step_statics(cfg)))
     lim = _cls(algo)(cfg, ManualClock(T0))
     hot = [f"hot:{i}" for i in range(4)]
     if overrides:
@@ -145,10 +150,10 @@ def test_rebuilt_result_equals_step_then_old_finish(algo, premix, b,
         if algo != "bucket" and period != (
                 p := now_us // sketch_kernels.sketch_geometry(cfg)[1]):
             period = p                  # the host's _sync_period
-            ref = steps[2](ref, np.int64(p))
+            ref = kernels.build_controls(cfg)[1](ref, np.int64(p))
         got = (lim.allow_ids if premix else lim.allow_hashed)(
             ids, ns, now=now)
-        ref, want = _reference(algo, steps, ref, ids, ns, now_us, padded,
+        ref, want = _reference(algo, step, ref, ids, ns, now_us, padded,
                                premix, cfg, policy)
         _assert_columns_exact(got, want)
         admitted += int(got.allowed.sum())

@@ -85,7 +85,7 @@ def test_eval_chunk_counts_disagreements():
     n_keys = 256
     B = 512
     chunk = build_eval_chunk(cfg, B, n_keys, 1.1)
-    roll_sk = sketch_kernels.build_steps(cfg)[2]
+    roll_sk = sketch_kernels.build_controls(cfg)[1]
     roll_or = build_oracle_rollover(cfg, n_keys)
     sub_us = sketch_kernels.sketch_geometry(cfg)[1]
     states = {"sk": roll_sk(sketch_kernels.init_state(cfg), jnp.int64(T0 // sub_us)),

@@ -215,7 +215,8 @@ class TestTokenBucketReshard:
 
         cfg = _mesh(_cfg(limit=10, algorithm=Algorithm.TOKEN_BUCKET),
                     ManualClock(0.0), 1).config
-        _, num, den, _, _, _ = bucket_kernels._params(cfg)
+        kw = bucket_kernels.step_statics(cfg)
+        num, den = kw["rate_num"], kw["rate_den"]
         import jax.numpy as jnp
 
         for elapsed, rem in [(0, 0), (123456, 17), (10**9, den - 1),

@@ -2,8 +2,11 @@
 micro-batching server): equivalence to single-step dispatches, bit-packing,
 and the sub-window-boundary precondition (ADVICE r1)."""
 
+from functools import partial
+
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ratelimiter_tpu import Algorithm, Config, SketchParams
@@ -20,7 +23,7 @@ def _cfg(**kw):
 
 def _fresh(cfg, now_us):
     _, sub_us, _, _, _ = sketch_kernels.sketch_geometry(cfg)
-    _, _, roll = sketch_kernels.build_steps(cfg)
+    _, roll = sketch_kernels.build_controls(cfg)
     return roll(sketch_kernels.init_state(cfg), jnp.int64(now_us // sub_us))
 
 
@@ -35,7 +38,8 @@ T0 = 1_700_000_000 * 1_000_000
 
 def test_scan_equals_sequential_steps():
     cfg = _cfg()
-    step, _, _ = sketch_kernels.build_steps(cfg)
+    step = jax.jit(partial(sketch_kernels._sketch_step,
+                           **sketch_kernels.step_statics(cfg)))
     scan = sketch_kernels.build_scan(cfg)
     T, B = 4, 8
     rng = np.random.default_rng(3)

@@ -126,8 +126,8 @@ def test_full_sketch_step_with_forced_sortmerge(force_sortmerge):
     from ratelimiter_tpu.core.types import Algorithm
     from ratelimiter_tpu.ops import sketch_kernels
 
-    # build_steps memoizes per-config; use a geometry unique to this test so
-    # the cached kernel was traced with the forced strategy.
+    # The step builders memoize per config; use a geometry unique to this
+    # test so the cached kernel was traced with the forced strategy.
     cfg = Config(algorithm=Algorithm.SLIDING_WINDOW, limit=5, window=6.0,
                  key_prefix="sm",
                  sketch=SketchParams(depth=3, width=32, sub_windows=6,
