@@ -287,6 +287,9 @@ class SketchLimiter(RateLimiter):
         self._inflight_mass = 0
         # Device buffers resolve has fetched (result_fetches).
         self._fetches = 0
+        # Dispatches launched while the override table held an entry
+        # (override_lookup_dispatches).
+        self._override_lookups = 0
 
     def _acquire_staging(self, padded: int) -> np.ndarray:
         with self._staging_lock:
@@ -393,16 +396,19 @@ class SketchLimiter(RateLimiter):
                     sp.next("finish")
                     # Inside the lock: a concurrent set/delete_override
                     # rebuilds the table's sorted views, and a torn read
-                    # would mis-index. Raw-id launches finalize host-side
-                    # ONLY when overrides exist (the common empty-table
-                    # case stays hash-free on the host).
-                    if premix:
-                        from ratelimiter_tpu.ops.hashing import splitmix64
+                    # would mis-index. Only a table with an entry sent
+                    # the step through its lookup (counted), and only
+                    # then are per-row limits assembled host-side (the
+                    # common empty-table case stays hash-free here).
+                    limits = None
+                    if len(self._policy_table):
+                        self._override_lookups += 1
+                        if premix:
+                            from ratelimiter_tpu.ops.hashing import splitmix64
 
-                        limits = (self._policy_limits(splitmix64(h64))
-                                  if len(self._policy_table) else None)
-                    else:
-                        limits = self._policy_limits(h64)
+                            limits = self._policy_limits(splitmix64(h64))
+                        else:
+                            limits = self._policy_limits(h64)
                     self._inflight_mass += int(ns.sum())
                 launched = True
             finally:
@@ -468,6 +474,17 @@ class SketchLimiter(RateLimiter):
         step packs its result; a four-column result was four (seven
         underneath on a TPU, a 64-bit array being two buffers)."""
         return self._fetches
+
+    @property
+    def override_lookup_dispatches(self) -> int:
+        """Dispatches launched while the override table held an entry,
+        i.e. whose step ran the per-row lookup and not the branch that
+        skips it (policy_kernels.limit_for_rows) — cumulative, always on,
+        counted under the launch's own lock:
+        ``rate_limiter_override_lookup_dispatches_total``. The host's
+        count of entries, not the device's predicate: they differ only
+        for a table whose one entry packs to exactly PAD_KEY."""
+        return self._override_lookups
 
     def _result_format(self) -> tuple:
         """``(rows, unpack)`` of this rule's packed result buffer."""

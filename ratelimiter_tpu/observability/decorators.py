@@ -423,10 +423,14 @@ class MetricsDecorator(LimiterDecorator):
                 "decides per slice before dispatch")
             reg.add_collect_hook(self._collect_router)
 
-        # Device->host fetches (the resolve half of a dispatch): device
-        # buffers resolve has asked the device for, from the backend's own
-        # always-on count — over rate_limiter_door_dispatches_total, the
-        # fetches a dispatch costs (1 since the step packs its result).
+        # The sketch backends' own always-on dispatch counts, exported at
+        # scrape over rate_limiter_door_dispatches_total. Device->host
+        # fetches (the resolve half of a dispatch): device buffers resolve
+        # has asked the device for — 1 a dispatch since the step packs
+        # its result. Override lookups (the device step's one
+        # data-dependent branch): dispatches launched while the override
+        # table held an entry, whose step ran the per-row binary search;
+        # 0 on a deployment with no override.
         self._fetcher = base if hasattr(base, "result_fetches") else None
         if self._fetcher is not None:
             self._fetches_g = reg.gauge(
@@ -434,11 +438,19 @@ class MetricsDecorator(LimiterDecorator):
                 "Device buffers resolve has fetched from the device "
                 "(cumulative): one per array leaf per addressable shard "
                 "of a dispatch's result")
-            reg.add_collect_hook(self._collect_fetches)
+            self._override_g = reg.gauge(
+                "rate_limiter_override_lookup_dispatches_total",
+                "Dispatches launched while the per-key override table "
+                "held an entry (cumulative): their device step ran the "
+                "override lookup, the others skipped it")
+            reg.add_collect_hook(self._collect_dispatch_counts)
 
-    def _collect_fetches(self) -> None:
+    def _collect_dispatch_counts(self) -> None:
         self._fetches_g.set(float(self._fetcher.result_fetches),
                             shard=self._shard)
+        self._override_g.set(
+            float(self._fetcher.override_lookup_dispatches),
+            shard=self._shard)
 
     def _collect_router(self) -> None:
         st = self._router.router_stats()
@@ -483,7 +495,7 @@ class MetricsDecorator(LimiterDecorator):
         if self._router is not None:
             self.registry.remove_collect_hook(self._collect_router)
         if self._fetcher is not None:
-            self.registry.remove_collect_hook(self._collect_fetches)
+            self.registry.remove_collect_hook(self._collect_dispatch_counts)
         super().close()
 
     def _observe_envelope(self) -> None:

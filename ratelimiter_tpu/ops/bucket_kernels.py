@@ -151,10 +151,8 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
 
     with jax.named_scope("admit"):
         if policy is not None:
-            q = policy_kernels.pack_halves(h1, h2)
-            pidx, pfound = policy_kernels.lookup_i64(policy["key"], q)
-            cap = jnp.where(pfound, policy["limit"][pidx],
-                            jnp.int64(limit)) * MICROS
+            cap = policy_kernels.limit_for_rows(
+                policy, h1, h2, limit, jnp.int64) * MICROS
         else:
             cap = limit * MICROS
         avail = jnp.maximum(jnp.int64(0), cap - est)        # micro-tokens

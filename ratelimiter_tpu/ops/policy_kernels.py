@@ -8,6 +8,18 @@ overridden keys is still decided in ONE fused dispatch — no per-key host
 lookup, no dynamic shapes, no recompiles when entries change (only the
 array *contents* change; capacity is the compiled shape).
 
+What a probe costs on the chip: a TPU runs a gather as a sequential
+loop over its rows (ops/sortmerge.py), and a 64-bit gather moves two
+32-bit words on a chip without 64-bit vectors. The descent below is
+log2(capacity) + 1 dependent probes of ``int64[B]`` plus one gather of
+the limit column — 12-13 serialized int64 gathers a row at the default
+capacity of 1,024, more than the rule's own state costs a row (d gathers
++ d scatters). So the sketch steps take their per-row limit from
+``limit_for_rows``, which runs the descent under a device-side branch
+only when the table holds an entry (PERF.md §6, PR 31, has the
+measured size); an empty table, the common deployment, costs one scalar
+read.
+
 Key domain: each backend reduces a key to an int64 "search key" host-side
 at override-set time (policy/table.py):
 
@@ -66,6 +78,38 @@ def lookup_i64(table_keys, queries):
     safe = jnp.maximum(idx, 0)
     found = (idx >= 0) & (table_keys[safe] == queries)
     return safe, found
+
+
+def limit_for_rows(policy, h1, h2, default: int, dtype):
+    """Per-row effective limit ``dtype[B]`` for the sketch steps: the
+    override of each row's (h1, h2) key, ``default`` where it has none.
+
+    The ONE definition both rules call. The descent runs inside a
+    ``lax.cond`` on the table's occupancy, read from the device copy
+    itself: the table is sorted with its PAD_KEY rows last
+    (policy/table.py), so ``policy["key"][0] == PAD_KEY`` iff no row can
+    change an answer — an empty table, or one whose only entry packs to
+    exactly PAD_KEY, which the descent resolves to the LAST padding row
+    and therefore to ``default`` as well (module docstring). Both arms
+    give the same values in every case; the empty arm gathers nothing.
+    One compiled step per shape either way, and nothing on the host to
+    go out of step with the device copy when set_override /
+    delete_override race a launch (ADR-008 addendum).
+
+    Never wrap a step in ``vmap``: a batched cond runs both arms.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    def lookup():
+        pidx, pfound = lookup_i64(policy["key"], pack_halves(h1, h2))
+        return jnp.where(pfound, policy["limit"][pidx],
+                         jnp.int64(default)).astype(dtype)
+
+    def no_entries():
+        return jnp.full(h1.shape, default, dtype)
+
+    return jax.lax.cond(policy["key"][0] != PAD_KEY, lookup, no_entries)
 
 
 def lookup_host(table_keys: np.ndarray, queries: np.ndarray,

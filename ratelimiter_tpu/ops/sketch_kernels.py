@@ -342,13 +342,14 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
         if policy is not None:
             # Per-key limit overrides (policy engine): the search key is the
             # device-side packing of the (h1, h2) halves the columns already
-            # ride on, so the lookup costs log2(capacity) tiny gathers and no
-            # extra operand. Limits are validated < 2^24 at override-set time
-            # (the same f32-exactness gate as the base limit).
-            q = policy_kernels.pack_halves(h1, h2)
-            pidx, pfound = policy_kernels.lookup_i64(policy["key"], q)
-            lim_f = jnp.where(pfound, policy["limit"][pidx],
-                              jnp.int64(limit)).astype(jnp.float32)
+            # ride on, so the lookup needs no extra operand — but it is
+            # log2(capacity) + 2 serialized int64 gathers a row, more than
+            # the d gathers + d scatters of the rule itself, so it runs only
+            # when the table holds an entry (limit_for_rows). Limits are
+            # validated < 2^24 at override-set time (the same f32-exactness
+            # gate as the base limit).
+            lim_f = policy_kernels.limit_for_rows(
+                policy, h1, h2, limit, jnp.float32)
         else:
             lim_f = jnp.float32(limit)
         avail = jnp.maximum(lim_f - est, 0.0)

@@ -121,6 +121,9 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
         #: Device buffers this router's own resolve has fetched (the
         #: slices count their own, result_fetches sums both).
         self._fetches = 0
+        #: Frames this router launched while the override table held an
+        #: entry (the slices count their own dispatches).
+        self._override_lookups = 0
         self._stats_lock = threading.Lock()
         self._strict_gate = bool(getattr(self.slices[0], "_strict", False))
         self._cpu = self.mesh.devices.flat[0].platform == "cpu"
@@ -292,14 +295,16 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                     self.dispatches += 1
                     window_us = self.slices[0]._window_us
                     sp.next("finish")
-                    if premix:
-                        from ratelimiter_tpu.ops.hashing import splitmix64
+                    limits = None
+                    if len(self.slices[0]._policy_table):
+                        self._override_lookups += 1
+                        if premix:
+                            from ratelimiter_tpu.ops.hashing import splitmix64
 
-                        limits = (self.slices[0]._policy_limits(
-                            splitmix64(arrays))
-                            if len(self.slices[0]._policy_table) else None)
-                    else:
-                        limits = self.slices[0]._policy_limits(arrays)
+                            limits = self.slices[0]._policy_limits(
+                                splitmix64(arrays))
+                        else:
+                            limits = self.slices[0]._policy_limits(arrays)
                 finally:
                     for s in reversed(self.slices):
                         s._lock.release()
@@ -406,6 +411,10 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
     @property
     def result_fetches(self) -> int:
         return self._fetches + super().result_fetches
+
+    @property
+    def override_lookup_dispatches(self) -> int:
+        return self._override_lookups + super().override_lookup_dispatches
 
     # ------------------------------------------------ pipelined public API
 

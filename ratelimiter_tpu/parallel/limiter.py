@@ -792,6 +792,12 @@ class SlicedMeshLimiter(RateLimiter):
         (SketchLimiter.result_fetches), summed."""
         return sum(s.result_fetches for s in self.slices)
 
+    @property
+    def override_lookup_dispatches(self) -> int:
+        """Slice dispatches launched while the override table held an
+        entry (SketchLimiter.override_lookup_dispatches), summed."""
+        return sum(s.override_lookup_dispatches for s in self.slices)
+
     def in_window_admitted_mass(self) -> int:
         return sum(s.in_window_admitted_mass() for s in self.slices)
 

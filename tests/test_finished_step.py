@@ -38,6 +38,7 @@ from ratelimiter_tpu.core.clock import to_micros
 from ratelimiter_tpu.observability import tracing
 from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
 from ratelimiter_tpu.ops.hashing import split_hash, splitmix64
+from tests.parent_lookup import inline_lookup
 
 T0 = 1_700_000_000.25
 ALGOS = {"windowed": Algorithm.SLIDING_WINDOW,
@@ -454,3 +455,197 @@ def test_mesh_staging_hook_gives_the_single_chip_columns(algo, premix):
         meshed.clock.advance(1.5)
     single.close()
     meshed.close()
+
+
+# ------------------------- the override lookup's occupancy branch (PR 31)
+
+
+@contextlib.contextmanager
+def _inline_lookup_programs(monkeypatch):
+    """Every step built and traced inside is the parent's program: the
+    helper swapped for the inline lookup, the builders' memos empty (and
+    the real ones back afterwards)."""
+    from ratelimiter_tpu.ops import policy_kernels as pk, route_kernels
+    from ratelimiter_tpu.parallel import mesh_kernels
+
+    with monkeypatch.context() as m:
+        m.setattr(pk, "limit_for_rows", inline_lookup)
+        for mod in (sketch_kernels, bucket_kernels, mesh_kernels):
+            m.setattr(mod, "_BUILT", {})
+        m.setattr(route_kernels, "_ROUTED_CACHE", {})
+        yield
+
+
+def _mesh_limiter(algo, kind):
+    from ratelimiter_tpu.core.config import MeshSpec
+    from ratelimiter_tpu.parallel import (
+        CollectiveMeshLimiter,
+        MeshSketchLimiter,
+        MeshTokenBucketLimiter,
+        make_mesh,
+    )
+
+    if kind == "collective":
+        return CollectiveMeshLimiter(
+            _cfg(algo, mesh=MeshSpec(devices=4, router="collective")),
+            ManualClock(T0), n_devices=4)
+    cls = MeshTokenBucketLimiter if algo == "bucket" else MeshSketchLimiter
+    return cls(_cfg(algo), ManualClock(T0), mesh=make_mesh(n_devices=4),
+               merge=kind)
+
+
+def _mesh_run(algo, kind, overrides):
+    """Three frames through a fresh mesh limiter: every result column and
+    every state leaf of every dispatch unit at the end."""
+    lim = _mesh_limiter(algo, kind)
+    hot = [f"hot:{i}" for i in range(4)]
+    if overrides:
+        lim.set_override(hot[0], BIG)
+        lim.set_override(hot[1], 1)
+        lim.set_override("never:seen", BIG)
+    rng = np.random.default_rng(11)
+    cols = []
+    for i, dt in enumerate(INSTANTS[:3]):
+        ids = rng.integers(1, 90, size=403).astype(np.uint64)
+        # Spread over the frame, so that no (source, destination) bin of
+        # the collective router overflows: the routed step must decide.
+        ids[0:400:25] = lim._hash(hot[:1])[0]
+        ids[1:400:100] = lim._hash(hot)
+        if i == 2:
+            ids[200] = lim._hash(["never:seen"])[0]
+        ns = rng.integers(1, 3, size=403).astype(np.int64)
+        res = lim.allow_hashed(ids, ns, now=T0 + dt)
+        cols += [np.asarray(getattr(res, c)) for c in
+                 ("allowed", "remaining", "retry_after", "reset_at")]
+        cols.append(None if res.limits is None else np.asarray(res.limits))
+    units = lim.sub_limiters() if hasattr(lim, "sub_limiters") else [lim]
+    leaves = [np.asarray(v) for u in units
+              for _, v in sorted(u._state.items())]
+    lookups = lim.override_lookup_dispatches
+    if kind == "collective":
+        assert lim.router_stats()["fallbacks"] == 0
+    lim.close()
+    return cols, leaves, lookups
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
+@pytest.mark.parametrize("overrides", [False, True],
+                         ids=["empty", "overrides"])
+@pytest.mark.parametrize("kind", ["gather", "delta", "collective"])
+@pytest.mark.parametrize("algo", ["windowed", "bucket"])
+def test_mesh_steps_equal_their_inline_lookup_programs(algo, kind, overrides,
+                                                       monkeypatch):
+    """The replicated mesh (both merges) and the routed step inside
+    shard_map: the table is a replicated operand, every chip takes the
+    same branch, and the answers and states are the parent program's."""
+    with _inline_lookup_programs(monkeypatch):
+        want_cols, want_leaves, _ = _mesh_run(algo, kind, overrides)
+    got_cols, got_leaves, lookups = _mesh_run(algo, kind, overrides)
+    assert len(got_cols) == len(want_cols) == 15
+    for g, w_ in zip(got_cols, want_cols):
+        if w_ is None:
+            assert g is None
+        else:
+            assert g.dtype == w_.dtype
+            np.testing.assert_array_equal(g.view(np.uint8), w_.view(np.uint8))
+    assert len(got_leaves) == len(want_leaves)
+    for g, w_ in zip(got_leaves, want_leaves):
+        np.testing.assert_array_equal(g, w_)
+    allowed = np.concatenate(got_cols[0::5])
+    assert allowed.any() and not allowed.all()
+    if overrides:
+        # The hottest key is under BIG: its first row has more left than
+        # the config's whole limit.
+        assert got_cols[1][0] > 3 and got_cols[4] is not None
+    # One count a dispatch (a frame) with an entry, none without.
+    assert lookups == (3 if overrides else 0)
+
+
+@pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
+@pytest.mark.parametrize("algo", list(ALGOS))
+def test_the_lookup_counter_follows_the_table(algo, premix):
+    """+0 a dispatch on an empty table, +1 with an entry, +0 again after
+    the last delete_override; the same compiled program throughout."""
+    lim = _cls(algo)(_cfg(algo), ManualClock(T0))
+    step = lim._get_ids_step() if premix else lim._step
+    ids = np.arange(1, 40, dtype=np.uint64)
+
+    def dispatch(k):
+        before = lim.override_lookup_dispatches
+        for _ in range(k):
+            lim.resolve(_launch(lim, premix, ids))
+        return lim.override_lookup_dispatches - before
+
+    assert lim.override_lookup_dispatches == 0
+    assert dispatch(2) == 0
+    programs = step._cache_size()
+    lim.set_override("vip", BIG)
+    assert dispatch(3) == 3
+    lim.set_override("vip2", 1)
+    assert lim.delete_override("vip")
+    assert dispatch(1) == 1
+    assert lim.delete_override("vip2")
+    assert dispatch(2) == 0
+    assert lim.override_lookup_dispatches == 4
+    # The first override compiled nothing: one program a shape as before
+    # (the memoized step is shared, so the count is this process's).
+    assert step._cache_size() == programs >= 1
+    lim.close()
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
+@pytest.mark.parametrize("router", ["host", "collective"])
+def test_mesh_lookup_counter_is_a_count_a_dispatch_or_a_frame(router):
+    """Host router: one a slice dispatch (the slices' own counts,
+    summed). Collective router: one a frame, the router's own."""
+    from ratelimiter_tpu.core.config import MeshSpec
+    from ratelimiter_tpu.parallel import (
+        CollectiveMeshLimiter,
+        SlicedMeshLimiter,
+    )
+
+    cls = CollectiveMeshLimiter if router == "collective" \
+        else SlicedMeshLimiter
+    lim = cls(_cfg("windowed", mesh=MeshSpec(devices=4, router=router)),
+              ManualClock(T0), n_devices=4)
+    ids = np.arange(1, 400, dtype=np.uint64)
+    assert len(set(lim.owner_of_hash(ids).tolist())) == 4
+    per_frame = 1 if router == "collective" else 4
+    lim.resolve(lim.launch_hashed(ids))
+    assert lim.override_lookup_dispatches == 0
+    lim.set_override("vip", BIG)
+    for k in range(1, 3):
+        lim.resolve(lim.launch_hashed(ids))
+        assert lim.override_lookup_dispatches == per_frame * k
+    assert lim.delete_override("vip")
+    lim.resolve(lim.launch_hashed(ids))
+    assert lim.override_lookup_dispatches == per_frame * 2
+    lim.close()
+
+
+def test_metrics_export_the_lookup_counter_at_scrape():
+    from ratelimiter_tpu.observability import MetricsDecorator
+    from ratelimiter_tpu.observability.metrics import Registry
+
+    reg = Registry()
+    base = SketchLimiter(_cfg("windowed"), ManualClock(T0))
+    lim = MetricsDecorator(base, registry=reg)
+    name = "rate_limiter_override_lookup_dispatches_total"
+
+    def scraped():
+        lines = [ln for ln in reg.render().splitlines()
+                 if ln.startswith(name)]
+        assert len(lines) == 1
+        return float(lines[0].split()[-1])
+
+    ids = np.arange(1, 9, dtype=np.uint64)
+    lim.allow_hashed(ids)
+    assert scraped() == 0.0
+    lim.set_override("vip", BIG)
+    lim.allow_hashed(ids)
+    lim.allow_hashed(ids)
+    assert scraped() == 2.0
+    lim.delete_override("vip")
+    lim.allow_hashed(ids)
+    assert scraped() == 2.0
+    lim.close()

@@ -27,6 +27,7 @@ from ratelimiter_tpu import (
 from ratelimiter_tpu.core.config import PolicySpec
 from ratelimiter_tpu.ops import policy_kernels as pk
 from ratelimiter_tpu.policy import PolicyTable
+from tests.parent_lookup import inline_lookup
 
 T0 = 1_700_000_000.0
 BACKENDS = ("exact", "dense", "sketch")
@@ -405,3 +406,318 @@ class TestX64Hygiene:
             env={**os.environ, "JAX_PLATFORMS": "cpu"},
             capture_output=True, text=True, timeout=120)
         assert out.returncode == 0, out.stdout + out.stderr
+
+
+# ------------------------------------- the step's occupancy branch (PR 31)
+#
+# The sketch steps take their per-row limit from
+# policy_kernels.limit_for_rows: today's binary search under a lax.cond
+# on the table's occupancy. Held here: the step with the helper equals
+# the step with the lookup written inline as it was (PR 30), bit for bit
+# in the packed verdicts (allowed, remaining, the bucket's retry_us) and
+# in every state leaf — both rules, both lanes, three batch sizes, four
+# table states, on one device, on the replicated mesh and under the
+# collective router; the lowered programs keep every gather on the table
+# inside that one conditional; and the counter says when it engages.
+
+BRANCH_ALGOS = {"windowed": Algorithm.SLIDING_WINDOW,
+                "bucket": Algorithm.TOKEN_BUCKET}
+TABLES = ("empty", "one", "full", "emptied")
+#: Override limits: far above and below the config's 3.
+BIG, SMALL = 50, 1
+HOT, HOT2, NEVER = "hot:0", "hot:1", "never:seen"
+
+
+def _branch_limiter(algo):
+    """``(cfg, limiter)`` of the small geometry these tests step."""
+    from ratelimiter_tpu import SketchParams
+
+    cfg = Config(algorithm=BRANCH_ALGOS[algo], limit=3, window=60.0,
+                 sketch=SketchParams(depth=3, width=512, sub_windows=6))
+    return cfg, create_limiter(cfg, backend="sketch", clock=ManualClock(T0))
+
+
+def _fill(lim, table):
+    """Bring ``lim``'s override table to one of the four states."""
+    if table == "one":
+        lim.set_override(HOT, BIG)
+    elif table == "full":
+        lim.set_override(HOT, BIG)
+        lim.set_override(HOT2, SMALL)
+        lim.set_override(NEVER, BIG)
+        for i in range(lim.config.policy.capacity - 3):
+            lim.set_override(f"filler:{i}", 7 + i % 5)
+        assert len(lim._policy_table) == lim.config.policy.capacity
+    elif table == "emptied":
+        for key in (HOT, HOT2, NEVER):
+            lim.set_override(key, BIG)
+        for key in (HOT, HOT2, NEVER):
+            assert lim.delete_override(key)
+
+
+def _frames(lim, b, premix, seed):
+    """Three instants of ``b`` ids: a quarter of each frame is the
+    hottest key, a second hot key rides along, and the never-seen key
+    arrives in the last frame only. On the premix lane the ids are the
+    raw preimages of the same hashes (the step mixes them on device)."""
+    from ratelimiter_tpu.ops.hashing import splitmix64_inv
+
+    rng = np.random.default_rng(seed)
+    hot, hot2, never = (int(x) for x in lim._hash([HOT, HOT2, NEVER]))
+    for i, dt in enumerate((0.0, 0.3, 11.0)):
+        ids = rng.integers(1, 1 << 62, size=b).astype(np.uint64)
+        ids[: max(2, b // 4)] = hot
+        ids[-2:] = hot2
+        if i == 2:
+            ids[b // 2] = never
+        ns = rng.integers(1, 3, size=b).astype(np.int64)
+        yield dt, (splitmix64_inv(ids) if premix else ids), ns
+
+
+class TestOccupancyBranch:
+    @pytest.mark.parametrize("table", TABLES)
+    @pytest.mark.parametrize("b", [8, 1003, 4096])
+    @pytest.mark.parametrize("premix", [False, True],
+                             ids=["hashed", "premix"])
+    @pytest.mark.parametrize("algo", list(BRANCH_ALGOS))
+    def test_step_equals_step_with_theinline_lookup(self, algo, premix, b,
+                                                     table, monkeypatch):
+        from functools import partial
+
+        import jax
+
+        from ratelimiter_tpu.core.clock import to_micros
+        from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
+
+        cfg, lim = _branch_limiter(algo)
+        kernels, body, rows = (
+            (bucket_kernels, bucket_kernels._bucket_step_staged,
+             bucket_kernels.BUCKET_ROWS) if algo == "bucket" else
+            (sketch_kernels, sketch_kernels._sketch_step_staged,
+             sketch_kernels.WINDOW_ROWS))
+        _fill(lim, table)
+        with lim._lock:
+            policy = lim._policy_device()
+        # The predicate is the device copy's own first row.
+        assert (int(policy["key"][0]) == pk.PAD_KEY) == (
+            table in ("empty", "emptied"))
+        # The serving program itself against the same body traced with
+        # the inline lookup (every call: a new shape traces again).
+        new = kernels.build_hashed_step(cfg, premix=premix)
+        old_jit = jax.jit(partial(body, seed=cfg.sketch.seed, premix=premix,
+                                  **kernels.step_statics(cfg)))
+
+        def old(*args):
+            with monkeypatch.context() as m:
+                m.setattr(pk, "limit_for_rows", inline_lookup)
+                return old_jit(*args)
+
+        padded = lim._padded_size(b)
+        s_new, s_old = kernels.init_state(cfg), kernels.init_state(cfg)
+        period = None
+        hot_remaining = []
+        admitted = denied = 0
+        for dt, ids, ns in _frames(lim, b, premix, seed=b + premix):
+            now_us = to_micros(T0 + dt)
+            if algo != "bucket" and period != (
+                    p := now_us // sketch_kernels.sketch_geometry(cfg)[1]):
+                period = p              # the host's _sync_period
+                roll = kernels.build_controls(cfg)[1]
+                s_new, s_old = roll(s_new, np.int64(p)), roll(s_old,
+                                                              np.int64(p))
+            slot = np.zeros(2 * padded + 1, np.uint64)
+            slot[:b] = ids
+            slot.view(np.int64)[padded:padded + b] = ns
+            slot.view(np.int64)[2 * padded] = now_us
+            s_new, w_new = new(s_new, slot, policy)
+            s_old, w_old = old(s_old, slot, policy)
+            w_new, w_old = np.asarray(w_new), np.asarray(w_old)
+            assert w_new.dtype == np.int32
+            assert w_new.shape == (rows * padded,)
+            np.testing.assert_array_equal(w_new, w_old)
+            for k in s_old:
+                np.testing.assert_array_equal(np.asarray(s_new[k]),
+                                              np.asarray(s_old[k]), err_msg=k)
+            allowed = w_new[:b] != 0
+            remaining = w_new[padded:padded + b]
+            admitted += int(allowed.sum())
+            denied += b - int(allowed.sum())
+            hot_remaining.append(int(remaining[0]))
+        assert admitted and denied
+        # The override decided something: the hottest key's first row has
+        # BIG - n left under it, at most limit - 1 without.
+        if table in ("one", "full"):
+            assert hot_remaining[0] > cfg.limit
+        else:
+            assert hot_remaining[0] < cfg.limit
+        lim.close()
+
+    def test_a_lone_entry_that_packs_to_pad_key_reads_the_default(self):
+        """The one table the predicate calls empty though it holds a row:
+        the descent lands on the LAST padding row for such a key (module
+        docstring), so both arms answer the default."""
+        import jax.numpy as jnp
+
+        P = 8
+        key = np.full(P, pk.PAD_KEY, np.int64)
+        limit = np.full(P, 3, np.int64)
+        limit[0] = BIG                  # the entry's own row
+        policy = {"key": jnp.asarray(key), "limit": jnp.asarray(limit)}
+        ones = np.full(4, 0x7FFFFFFF, np.uint32), np.full(4, 0xFFFFFFFF,
+                                                           np.uint32)
+        h1, h2 = jnp.asarray(ones[0]), jnp.asarray(ones[1])
+        assert int(pk.pack_halves_host(*ones)[0]) == pk.PAD_KEY
+        got = pk.limit_for_rows(policy, h1, h2, 3, jnp.int64)
+        want = inline_lookup(policy, h1, h2, 3, jnp.int64)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got), np.full(4, 3))
+
+    @pytest.mark.parametrize("dtype", ["float32", "int64"])
+    def test_the_helper_returns_the_callers_dtype_in_both_arms(self, dtype):
+        import jax.numpy as jnp
+
+        lim, _ = make("sketch", limit=3)
+        h1 = jnp.arange(5, dtype=jnp.uint32)
+        for fill in (False, True):
+            if fill:
+                lim.set_override(HOT, BIG)
+            with lim._lock:
+                policy = lim._policy_device()
+            got = pk.limit_for_rows(policy, h1, h1, 3, jnp.dtype(dtype))
+            assert got.dtype == jnp.dtype(dtype) and got.shape == (5,)
+            np.testing.assert_array_equal(np.asarray(got), np.full(5, 3))
+        lim.close()
+
+
+# Every device program the benchmark's four configs launch, by
+# tools/lowered_steps.py's names (33 at PR 30 and now): the serving step
+# on both lanes, the replicated mesh's in both merges, the controls, and
+# the routed step of the collective config.
+_CONFIGS = ("bucket-c3", "cms-wide", "mesh4-c3", "mesh4-c3-coll")
+_LANES = ("hashed", "premix")
+SERVING = ([f"{c}.{lane}" for c in _CONFIGS for lane in _LANES]
+           + [f"{c}.mesh-{m}.{lane}" for c in _CONFIGS
+              for m in ("gather", "delta") for lane in _LANES]
+           + [f"mesh4-c3-coll.routed-{lane}" for lane in _LANES])
+CONTROLS = ([f"{c}.reset" for c in _CONFIGS]
+            + [f"{c}.rotate" for c in _CONFIGS if c != "bucket-c3"])
+
+
+def _spans(text, opener):
+    """(start, end) of every ``opener(...) ({ ... })`` op of MLIR text:
+    from the op's name to the parenthesis that closes its regions."""
+    out, at = [], text.find(opener)
+    while at != -1:
+        i = text.index("({", at)
+        depth = 0
+        for j in range(i, len(text)):
+            depth += text[j] in "({"
+            depth -= text[j] in ")}"
+            if depth == 0:
+                break
+        out.append((at, j))
+        at = text.find(opener, j)
+    return out
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """name -> StableHLO text, lowered once on this process's CPU mesh."""
+    import importlib.util
+    import warnings
+    from pathlib import Path
+
+    import jax
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices")
+    repo = Path(__file__).resolve().parent.parent
+    spec = importlib.util.spec_from_file_location(
+        "lowered_steps", repo / "tools" / "lowered_steps.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        return {name: fn.lower(*args).as_text()
+                for name, fn, args in tool._programs(repo)}
+
+
+class TestLoweredPrograms:
+
+    def _table_gathers(self, text):
+        import re
+
+        capacity = PolicySpec().capacity
+        return [m.start() for m in re.finditer(
+            r'"stablehlo\.gather"\([^\n]*: \(tensor<%dxi64>, ' % capacity,
+            text)]
+
+    def test_one_program_a_shape_as_before(self, lowered):
+        assert sorted(lowered) == sorted(SERVING + CONTROLS)
+        assert len(lowered) == 33
+
+    @pytest.mark.parametrize("name", SERVING)
+    def test_every_table_gather_is_inside_the_one_conditional(self, lowered,
+                                                              name):
+        text = lowered[name]
+        gathers = self._table_gathers(text)
+        # log2(1024) + 1 probes, the equality read and the limit column.
+        assert len(gathers) == 13
+        cases = [(a, z) for a, z in _spans(text, '"stablehlo.case"')
+                 if any(a < g < z for g in gathers)]
+        assert len(cases) == 1, "exactly one conditional holds the lookup"
+        a, z = cases[0]
+        assert all(a < g < z for g in gathers), \
+            "a gather on the override table outside the conditional"
+        # The predicate is the table's own first key against PAD_KEY.
+        head = text[:a]
+        assert str(pk.PAD_KEY) in head[head.rindex("stablehlo.slice"):]
+        # Two arms; the other one gathers nothing.
+        arms = text[a:z].split("}, {")
+        assert len(arms) == 2
+        assert sum("stablehlo.gather" in arm for arm in arms) == 1
+
+    @pytest.mark.parametrize("name", CONTROLS)
+    def test_controls_take_no_table(self, lowered, name):
+        assert self._table_gathers(lowered[name]) == []
+        assert '"stablehlo.case"' not in lowered[name]
+
+    @pytest.mark.parametrize("algo", list(BRANCH_ALGOS))
+    def test_the_reference_of_these_tests_is_the_unconditional_lookup(
+            self, algo, monkeypatch):
+        """The inline form the equality tests compare against really is
+        the parent's: the same 13 gathers, no conditional around them."""
+        from functools import partial
+
+        import jax
+
+        from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
+
+        cfg, lim = _branch_limiter(algo)
+        kernels, body = ((bucket_kernels, bucket_kernels._bucket_step_staged)
+                         if algo == "bucket" else
+                         (sketch_kernels, sketch_kernels._sketch_step_staged))
+        with lim._lock:
+            policy = lim._policy_device()
+        monkeypatch.setattr(pk, "limit_for_rows", inline_lookup)
+        text = jax.jit(partial(body, seed=0, premix=False,
+                               **kernels.step_statics(cfg))).lower(
+            kernels.init_state(cfg), np.zeros(2 * 64 + 1, np.uint64),
+            policy).as_text()
+        gathers = self._table_gathers(text)
+        assert len(gathers) == 13
+        assert not any(a < g < z for g in gathers
+                       for a, z in _spans(text, '"stablehlo.case"'))
+        lim.close()
+
+    def test_no_vmap_wraps_a_step(self):
+        """A batched cond runs both arms: nothing in the program may
+        vmap a step body."""
+        from pathlib import Path
+
+        import ratelimiter_tpu
+
+        root = Path(ratelimiter_tpu.__file__).parent
+        hits = [str(p) for p in root.rglob("*.py")
+                if "vmap(" in p.read_text()]
+        assert hits == []
