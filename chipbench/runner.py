@@ -30,12 +30,14 @@ import re
 import select
 import shutil
 import signal
+import statistics
 import subprocess
 import sys
 import threading
 import time
 import urllib.request
 
+from chipbench import bytes as need
 from chipbench import layers, probe, promtext
 from chipbench.layers import prewarm_s
 from chipbench.wire import Wire
@@ -48,6 +50,15 @@ PROFILE_S = 1.0 if REHEARSAL else 5.0   # the traced stretch of the window
 TRACED_MIN_S = 16.0        # a traced window holds profiler start-up + 5 s
 WARMUP_S = 3.0             # the cell's own traffic, counted as set-up
 DRAIN_S = 2.0              # open loop: a frame unanswered by then has failed
+#: /debug/profile answers 200 when the capture starts and then a space
+#: every 5 s until its body is ready (``jax.profiler.stop_trace()`` costs
+#: ~25 ms a captured program execution). A capture is waited for while
+#: those bytes keep coming: silent for this long, the server is taken
+#: for dead ...
+HEARTBEAT_GAP_S = 30.0
+#: ... and however alive, no longer than this: 4 x the largest capture
+#: on record (104.7 s, mesh4-coll-mixed, PR 31).
+CAPTURE_CEILING_S = 420.0
 
 #: Every field of a traffic file, with its default.
 TRAFFIC_DEFAULTS = {
@@ -120,6 +131,10 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
                              f"chipbench/loadgen yet")
     if traffic["loop"] == "open" and not traffic["rate"]:
         raise RunFailure("an open-loop mix needs a rate")
+    try:
+        need.model_of(config)
+    except need.NoByteModel as exc:
+        raise RunFailure(f"configuration {entry['config']}: {exc}") from exc
     return {"name": workload, "chips": entry["chips"], "config": config,
             "traffic": traffic, "config_name": entry["config"],
             "traffic_name": entry["traffic"], "manifest": manifest}
@@ -266,14 +281,49 @@ def serving(cell: dict, out_dir: str, trace: bool):
 
 # --------------------------------------------------------------- the trace
 
-def fetch_profile(http_port: int, box: dict) -> None:
+def fetch_profile(http_port: int, box: dict, clock=time.monotonic) -> None:
+    """Ask for the capture and wait for its body for as long as the
+    gateway's heartbeat keeps arriving, up to CAPTURE_CEILING_S. Leaves
+    ``profile`` or ``error`` in ``box``, and ``waited_s`` either way."""
+    t0 = clock()
     try:
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{http_port}/debug/profile"
-                f"?seconds={PROFILE_S:g}", timeout=120) as resp:
-            box["profile"] = json.loads(resp.read())
+                f"?seconds={PROFILE_S:g}", timeout=HEARTBEAT_GAP_S) as resp:
+            body = bytearray()
+            while True:
+                chunk = resp.read1(65536)     # returns with what has come
+                if not chunk:
+                    break
+                body += chunk
+                if clock() - t0 > CAPTURE_CEILING_S:
+                    raise TimeoutError(
+                        f"still no body after {clock() - t0:.0f} s of "
+                        f"heartbeats (ceiling {CAPTURE_CEILING_S:g} s)")
+        box["profile"] = json.loads(body)
     except Exception as exc:  # noqa: BLE001 — reported by the caller
         box["error"] = repr(exc)
+    box["waited_s"] = clock() - t0
+
+
+def capture_of(box: dict, thread) -> dict:
+    """What /debug/profile answered, once ``thread`` (fetch_profile) has
+    ended; RunFailure where there is no capture to reduce. A traced run
+    never goes on to a result line without its device metrics."""
+    patience = CAPTURE_CEILING_S + 2 * HEARTBEAT_GAP_S   # fetch_profile's own
+    thread.join(timeout=patience)
+    if thread.is_alive():
+        raise RunFailure(f"/debug/profile (stop_trace) has not answered "
+                         f"{patience:g} s after the capture was asked for")
+    waited = f"waited {box.get('waited_s', float('nan')):.0f} s"
+    if "error" in box:
+        raise RunFailure(f"/debug/profile (stop_trace) failed, {waited}: "
+                         f"{box['error']}")
+    profile = box.get("profile")
+    if not isinstance(profile, dict) or not profile.get("ok"):
+        raise RunFailure(f"/debug/profile (stop_trace) gave no capture, "
+                         f"{waited}: {profile!r}")
+    return profile
 
 
 def reduce_trace(profile: dict, out_dir: str):
@@ -317,9 +367,23 @@ def admitted_cap(cfg: dict, run_s: float) -> int:
     return limit * (int(run_s // window) + 1)
 
 
+def holes(per_second: list) -> dict:
+    """The seconds of the window whose completions are under half the
+    run's median second: a run that stood still for a stretch reads as a
+    slower program (PERF.md section 6, PR 31) and this line says so. It
+    changes no result."""
+    done = [s["completed"] for s in per_second]
+    if not done:
+        return {"median_per_s": None, "seconds": [], "held_s": 0}
+    median = statistics.median(done)
+    slow = [i for i, n in enumerate(done) if n < median / 2]
+    return {"median_per_s": median, "seconds": slow, "held_s": len(slow)}
+
+
 def result_line(correct: bool, gen: dict, metrics: dict, device: dict,
-                breakdown=None) -> dict:
-    """The one object the driver reads."""
+                breakdown=None, compared=None) -> dict:
+    """The one object the driver reads. ``compared`` (name -> (number,
+    its limit)) comes last."""
     failed = (gen["policy"] + gen["error_decisions"]
               + (gen["unanswered"] if gen["loop"] == "open" else 0))
     line = {"correct": bool(correct), "attempted": gen["sent"],
@@ -329,6 +393,9 @@ def result_line(correct: bool, gen: dict, metrics: dict, device: dict,
             "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if compared is not None:
+        line["compared"] = {name: {"value": value, "limit": limit}
+                            for name, (value, limit) in compared.items()}
     return line
 
 
@@ -368,7 +435,8 @@ def drive(cell: dict, srv: Server, binary: str, seed: int, seconds: float,
     """Warm-up and window: one generator process on a schedule both sides
     know (CLOCK_MONOTONIC). Traced, the window also holds the /metrics
     scrapes at its two ends and the profile. Returns the generator's
-    JSON, the scrapes and what /debug/profile answered."""
+    JSON, the scrapes and what /debug/profile answered (None where the
+    server has no gateway: never in a traced run of ``run``)."""
     start_at = time.monotonic() + 0.3
     t_win0 = start_at + WARMUP_S
     t_win1 = t_win0 + seconds
@@ -385,7 +453,8 @@ def drive(cell: dict, srv: Server, binary: str, seed: int, seconds: float,
                 scrapes["start"] = (time.monotonic(), wire.metrics())
             if srv.http_port:
                 prof_thread = threading.Thread(
-                    target=fetch_profile, args=(srv.http_port, box))
+                    target=fetch_profile, args=(srv.http_port, box),
+                    daemon=True)
                 time.sleep(max(0.0, t_win0 + 1.0 - time.monotonic()))
                 prof_thread.start()
             time.sleep(max(0.0, t_win1 - 0.05 - time.monotonic()))
@@ -397,12 +466,12 @@ def drive(cell: dict, srv: Server, binary: str, seed: int, seconds: float,
         if gen_proc.poll() is None:
             gen_proc.kill()
             gen_proc.wait()
-        if prof_thread is not None:
-            prof_thread.join(timeout=150)
     if gen_proc.returncode != 0:
         raise RunFailure(f"the load generator exited "
                          f"{gen_proc.returncode}: {gen_err[-2000:]}")
-    return json.loads(gen_out.strip().splitlines()[-1]), scrapes, box
+    profile = capture_of(box, prof_thread) if prof_thread is not None \
+        else None
+    return json.loads(gen_out.strip().splitlines()[-1]), scrapes, profile
 
 
 def run(args) -> int:
@@ -460,10 +529,20 @@ def run(args) -> int:
         probe_s = time.monotonic() - t_probe
 
         # (5)+(6) warm-up and the window: one generator process.
-        gen, scrapes, box = drive(cell, srv, binary, args.seed, seconds,
-                                  trace)
+        gen, scrapes, profile = drive(cell, srv, binary, args.seed, seconds,
+                                      trace)
         with open(os.path.join(out_dir, "loadgen.json"), "w") as fh:
             json.dump(gen, fh)
+        if trace:
+            if profile is None:
+                raise RunFailure("a traced run needs /debug/profile and the "
+                                 "server's banner names no http port")
+            # The two scrapes the readers see, as they came (the
+            # `scrape_s` apart that the `scrapes` line gives).
+            for end in ("start", "end"):
+                with open(os.path.join(out_dir, f"metrics_window_{end}.txt"),
+                          "w") as fh:
+                    fh.write(scrapes[end][1])
         setup_s = gen["t_window_start"] - T_SPAWN
 
         # (7) after the window.
@@ -484,20 +563,35 @@ def run(args) -> int:
     run_s = gen["run_s"] + DRAIN_S
     cap = admitted_cap(cfg, run_s)
     worst = max(gen["top_allowed"], default=0)
-    check(worst <= cap, f"a hot key was allowed {worst} times in "
-          f"{run_s:g} s; the rule admits at most {cap}", failures)
-    check(cold["cold_false_deny_pct"] <= 1.0 and cold["policy"] == 0,
-          f"{cold['denied']} of {cold['sent']} never-seen keys denied "
-          f"({cold['policy']} by policy)", failures)
     policy, errors = promtext.policy_answered(samples), \
         promtext.dispatch_errors(samples)
-    check(policy <= gen["all"]["policy"] and errors <= gen["all"]["error_frames"],
-          f"server metrics: {policy:g} decisions answered by policy, "
-          f"{errors:g} dispatch errors; the generator saw "
-          f"{gen['all']['policy']} and {gen['all']['error_frames']}", failures)
     client_done = probed["sent"] + gen["all"]["completed"] + cold["sent"]
-    check(served >= client_done, f"the server counted {served} decisions, "
-          f"the client completed {client_done}", failures)
+    # Each number `correct` rests on, its limit, and what it means when it
+    # is over (the probe stops at its first reply that differs from the
+    # reference, so it counts 0 or 1).
+    held = [
+        ("probe_replies_differing", 0 if probed["sent"] else 1, 0, None),
+        ("hot_key_allowed_max", worst, cap,
+         f"a hot key was allowed {worst} times in {run_s:g} s; the rule "
+         f"admits at most {cap}"),
+        ("cold_false_deny_pct", cold["cold_false_deny_pct"], 1.0,
+         f"{cold['denied']} of {cold['sent']} never-seen keys denied"),
+        ("cold_policy_answers", cold["policy"], 0,
+         f"{cold['policy']} never-seen keys answered by policy"),
+        ("policy_answers_unseen", max(0, policy - gen["all"]["policy"]), 0,
+         f"server metrics: {policy:g} decisions answered by policy, the "
+         f"generator saw {gen['all']['policy']}"),
+        ("dispatch_errors_unseen",
+         max(0, errors - gen["all"]["error_frames"]), 0,
+         f"server metrics: {errors:g} dispatch errors, the generator saw "
+         f"{gen['all']['error_frames']}"),
+        ("decisions_server_short", max(0, client_done - served), 0,
+         f"the server counted {served} decisions, the client completed "
+         f"{client_done}"),
+    ]
+    for _, value, limit, what in held[1:]:       # the probe said its own
+        check(value <= limit, what, failures)
+    compared = {name: (value, limit) for name, value, limit, _ in held}
     check(gen["completed"] > 0, "no decision completed in the window",
           failures)
     check(not gen["io_failed"], "a generator connection broke", failures)
@@ -514,6 +608,7 @@ def run(args) -> int:
     say("per_second", slices=[
         {k: s[k] for k in (("completed", "frames", "pending_frames")
                            if REHEARSAL else s)} for s in gen["per_second"]])
+    say("holes", **holes(gen["per_second"]))
     say("checks", cold=cold, server_decisions=served,
         client_decisions=client_done, policy_answered=policy,
         dispatch_errors=errors, failures=failures)
@@ -527,17 +622,18 @@ def run(args) -> int:
     correct = not failures
     breakdown = None
     if trace:
-        reduced = None
-        if "profile" in box:
-            reduced = reduce_trace(box["profile"], out_dir)
-        elif "error" in box:
-            raise RunFailure(f"/debug/profile failed: {box['error']}")
+        reduced = reduce_trace(profile, out_dir)
+        if not REHEARSAL and not (reduced and reduced["n_devices"]
+                                  and reduced["busy_s"] > 0):
+            raise RunFailure("the capture holds no operation on a device: "
+                             "no device metric can be read from it")
         sources = {
             "cell": cell, "loadgen": gen, "trace": reduced, "peaks": peaks,
             "server_log": server_log,
             "metrics_start": promtext.parse(scrapes["start"][1]),
             "metrics_end": promtext.parse(scrapes["end"][1]),
             "scrape_s": scrapes["end"][0] - scrapes["start"][0]}
+        say("scrapes", scrape_s=sources["scrape_s"])
         metrics = per_layer(cell, sources)
         if reduced:
             device.update(busy_s=reduced["busy_s"],
@@ -558,8 +654,12 @@ def run(args) -> int:
               f"{'passed' if correct else 'FAILED'}; no accelerator, so no "
               f"result")
         return 3 if correct else 1
-    print(json.dumps(result_line(correct, gen, metrics, device, breakdown)),
-          flush=True)
+    print(json.dumps(result_line(correct, gen, metrics, device, breakdown,
+                                 compared)), flush=True)
+    for what in failures:
+        sys.stderr.write(f"chipbench: not correct: {what}\n")
+    for name, (value, limit) in compared.items():
+        sys.stderr.write(f"compared {name} = {value:g} (limit {limit:g})\n")
     return 0
 
 

@@ -7,10 +7,11 @@ import re
 
 import pytest
 
+from chipbench import bytes as need
 from chipbench import layers, runner
 
 ROOT = runner.ROOT
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+NAME = need.NAME
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
@@ -77,37 +78,99 @@ def test_every_cell_reports_what_the_contract_asks(bench):
     assert used == {c["name"] for c in bench["configs"]}
 
 
-def test_configs_are_files_with_their_geometry_in_the_flags(bench):
-    for c in bench["configs"]:
-        assert c["file"].startswith("chipbench/configs/")
-        with open(os.path.join(ROOT, c["file"])) as fh:
-            cfg = json.load(fh)
-        assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
-        for need in ("server_flags", "algorithm", "limit", "window_s",
-                     "depth", "width", "key_population", "chips",
-                     "guarantees", "assumed", "rehearsal"):
-            assert need in cfg, (c["name"], need)
-        for flags, geo in ((cfg["server_flags"], cfg),
-                           (cfg["rehearsal"]["server_flags"],
-                            {**cfg, **cfg["rehearsal"]})):
-            flag = {a: b for a, b in zip(flags, flags[1:] + [""])
-                    if a.startswith("--")}
-            assert "--native" in flag
+#: What every configuration file carries, whatever its state is.
+CONFIG_KEYS = ("server_flags", "algorithm", "limit", "window_s",
+               "key_population", "chips", "guarantees", "assumed",
+               "rehearsal")
+#: Backends whose state is a count-min sketch: their geometry is
+#: depth x width, and their false-deny bound follows from it.
+SKETCH_BACKENDS = ("sketch", "mesh")
+
+
+def flag_values(flags: list) -> dict:
+    """``--flag value`` pairs of a server's flag list (a flag without a
+    value maps to whatever follows it; only its presence is read)."""
+    return {a: b for a, b in zip(flags, flags[1:] + [""])
+            if a.startswith("--")}
+
+
+def check_config(entry: dict, root: str) -> None:
+    """One ``configs`` entry of a manifest against its file. No branch
+    names a configuration: what differs between families hangs on the
+    value of ``--backend`` and on the file's own ``byte_model`` and
+    ``geometry_flags``."""
+    assert entry["file"].startswith("chipbench/configs/")
+    with open(os.path.join(root, entry["file"])) as fh:
+        cfg = json.load(fh)
+    assert cfg["source"] == entry["source"]
+    assert cfg["reduced"] == entry["reduced"]
+    for key in CONFIG_KEYS:
+        assert key in cfg, (entry["name"], key)
+    try:
+        need.model_of(cfg)      # none named: the sketch's, on depth x width
+    except need.NoByteModel as exc:
+        raise AssertionError(f"{entry['name']}: {exc}") from exc
+    assert "--snapshot-dir" not in cfg["server_flags"]
+    backend = flag_values(cfg["server_flags"]).get("--backend", "sketch")
+    for flags, geo in ((cfg["server_flags"], cfg),
+                       (cfg["rehearsal"]["server_flags"],
+                        {**cfg, **cfg["rehearsal"]})):
+        flag = flag_values(flags)
+        assert "--native" in flag
+        assert flag.get("--backend", "sketch") == backend
+        assert int(flag["--limit"]) == geo["limit"]
+        assert float(flag["--window"]) == geo["window_s"]
+        assert flag["--algorithm"] == geo["algorithm"]
+        if backend in SKETCH_BACKENDS:
             assert int(flag["--sketch-depth"]) == geo["depth"]
             assert int(flag["--sketch-width"]) == geo["width"]
-            assert int(flag["--limit"]) == geo["limit"]
-            assert float(flag["--window"]) == geo["window_s"]
-            assert flag["--algorithm"] == geo["algorithm"]
-            # width/4 keys per slice: the false-deny bound at any rate.
+            # width/4 keys per slice IS guarantees.false_deny: with every
+            # key saturated a never-seen key is denied with probability
+            # (1 - e^-0.25)^depth, under 1 % from depth 3, at any rate.
             assert geo["key_population"] == geo["width"] // 4 * geo["chips"]
-        assert "--snapshot-dir" not in cfg["server_flags"]
+            continue
+        pairs = cfg.get("geometry_flags")
+        assert pairs, (
+            f"{entry['name']}: a backend that is no sketch names its "
+            f"geometry_flags (server flag -> configuration key)")
+        for name, key in pairs.items():
+            assert name.startswith("--") and name in flag, (name, flags)
+            assert float(flag[name]) == geo[key], (name, key)
+    # Another state gives another bound, or none: an exact table denies no
+    # never-seen key while it has room. A file that still promises
+    # false_deny says how its population bounds it.
+    if backend not in SKETCH_BACKENDS and "false_deny" in cfg["guarantees"]:
+        assert cfg.get("false_deny_bound"), (
+            f"{entry['name']}: guarantees.false_deny without "
+            f"false_deny_bound (how key_population bounds it)")
 
 
-def test_manifest_matches_the_reader_files(bench):
+def test_configs_are_files_with_their_geometry_in_the_flags(bench):
+    for c in bench["configs"]:
+        check_config(c, ROOT)
+
+
+def test_a_configuration_added_without_the_programs_tests_is_set_apart(bench):
+    """tools/lowered_steps.py lowers every ``chipbench/configs/*.json``
+    as a sketch and tier-1 (tests/test_policy.py::TestLoweredPrograms)
+    pins that set of files to PR 22-27's four. A PR that may not edit
+    tests/ or tools/ puts its configuration under ``configs/added/``,
+    where that glob does not look (README, "Adding a configuration")."""
+    pinned = {"bucket-c3", "cms-wide", "mesh4-c3", "mesh4-c3-coll"}
+    top = {f[:-5] for f in os.listdir(os.path.join(runner.HERE, "configs"))
+           if f.endswith(".json")}
+    assert top == pinned
+    for c in bench["configs"]:
+        where = "chipbench/configs/" if c["name"] in pinned \
+            else "chipbench/configs/added/"
+        assert c["file"] == f"{where}{c['name']}.json"
+
+
+def check_readers_match(bench: dict, root: str) -> None:
     """Every per-layer entry has its reader, with the same META, and its
     cells are those the reader's predicate picks."""
     readers = {m.META["name"]: m.META for m in layers.load()}
-    cells = {w["name"]: runner.load_cell(w["name"])
+    cells = {w["name"]: runner.load_cell(w["name"], root)
              for w in bench["workloads"]}
     assert {m["name"] for m in bench["per_layer"]} == set(readers)
     for m in bench["per_layer"]:
@@ -116,6 +179,10 @@ def test_manifest_matches_the_reader_files(bench):
             assert m[key] == meta[key], (m["name"], key)
         on = [n for n, c in cells.items() if meta["applies"](c)]
         assert m.get("workloads", list(cells)) == on, m["name"]
+
+
+def test_manifest_matches_the_reader_files(bench):
+    check_readers_match(bench, ROOT)
 
 
 def test_traffic_files_use_known_fields(bench):

@@ -9,6 +9,8 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 from chipbench import layers, runner
 
 ROOT = runner.ROOT
@@ -26,8 +28,9 @@ def rehearse(root, workload, trace, seconds="2"):
     return done, {ln["line"]: ln for ln in lines if "line" in ln}
 
 
-def test_rehearsal_of_one_cell_stays_off_jax_and_prints_no_result():
-    done, lines = rehearse(ROOT, "wide-hashed-sat", 0)
+@pytest.mark.parametrize("workload", ["wide-hashed-sat", "c3-hashed-sat"])
+def test_rehearsal_of_one_cell_stays_off_jax_and_prints_no_result(workload):
+    done, lines = rehearse(ROOT, workload, 0)
     assert done.returncode == 3, done.stderr[-3000:]
     # The runner asserts "jax" not in sys.modules before its last line.
     assert lines["rehearsal"]["correct"] is True
@@ -38,28 +41,56 @@ def test_rehearsal_of_one_cell_stays_off_jax_and_prints_no_result():
     last = done.stdout.strip().splitlines()[-1]
     assert not last.startswith("{") and "no result" in last
     assert "decisions_per_s" not in json.dumps(lines["loadgen"])
+    assert lines["holes"]["held_s"] == len(lines["holes"]["seconds"])
 
 
-def test_dry_addition_of_config_mix_metric_and_cell(tmp_path):
+def checkout_copy(tmp_path):
     copy = tmp_path / "checkout"
     copy.mkdir()
     shutil.copytree(os.path.join(ROOT, "chipbench"), copy / "chipbench",
                     ignore=shutil.ignore_patterns("out", "__pycache__"))
     os.symlink(os.path.join(ROOT, "ratelimiter_tpu"), copy / "ratelimiter_tpu")
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), copy / "BENCHMARK.json")
+    return copy
+
+
+def test_the_control_a_server_that_breaks_the_stated_limit_is_not_correct(
+        tmp_path):
+    """The rest of a run with the timed path broken underneath: the
+    configuration states limit 100 and the server is started with 120, so
+    an answer is altered where it is produced (every key's 101st to 120th
+    request is allowed). The probe's exact comparison sees it, `correct`
+    is false and the run exits 1 with no result."""
+    copy = checkout_copy(tmp_path)
+    path = copy / "chipbench/configs/added/cms-c3.json"
+    cfg = json.loads(path.read_text())
+    flags = cfg["rehearsal"]["server_flags"]
+    flags[flags.index("--limit") + 1] = "120"
+    path.write_text(json.dumps(cfg))
+    done, lines = rehearse(str(copy), "c3-hashed-sat", 0)
+    assert done.returncode == 1, done.stderr[-3000:]
+    assert lines["rehearsal"]["correct"] is False
+    failures = lines["checks"]["failures"]
+    assert any("the reference says" in f for f in failures), failures
+    assert not done.stdout.strip().splitlines()[-1].startswith("{")
+
+
+def test_dry_addition_of_config_mix_metric_and_cell(tmp_path):
+    copy = checkout_copy(tmp_path)
     before = {p: p.read_bytes() for p in (copy / "chipbench").rglob("*")
               if p.is_file() and ".build" not in p.parts}
 
     with open(os.path.join(ROOT, "chipbench/configs/cms-wide.json")) as fh:
         cfg = json.load(fh)
-    cfg["source"] = "dry addition: config 3's literal geometry"
-    cfg.update(depth=4, width=65536, key_population=16384)
+    cfg["source"] = "dry addition: a geometry no configuration has"
+    cfg.update(depth=4, width=32768, key_population=8192)
     cfg["rehearsal"] = {"width": 4096, "key_population": 1024,
                         "server_flags": [
                             "--native", "--backend", "sketch", "--algorithm", "tpu_sketch",
                             "--limit", "100", "--window", "60",
                             "--sketch-depth", "4", "--sketch-width", "4096",
                             "--sub-windows", "60", "--max-batch", "256"]}
-    (copy / "chipbench/configs/cms-c3.json").write_text(json.dumps(cfg))
+    (copy / "chipbench/configs/cms-dry.json").write_text(json.dumps(cfg))
     mix = {"lane": "hashed", "frame_keys": 4096, "loop": "open",
            "rate": 400000, "arrival": "uniform", "connections": 8,
            "rehearsal": {"frame_keys": 128, "rate": 20000}}
@@ -75,10 +106,10 @@ def test_dry_addition_of_config_mix_metric_and_cell(tmp_path):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     old = json.loads(json.dumps(bench))
-    bench["configs"].append({"name": "cms-c3", "source": cfg["source"],
-                             "file": "chipbench/configs/cms-c3.json",
+    bench["configs"].append({"name": "cms-dry", "source": cfg["source"],
+                             "file": "chipbench/configs/cms-dry.json",
                              "reduced": ["key_population"], "why": "dry"})
-    bench["workloads"].append({"name": "c3-hashed-r80", "config": "cms-c3",
+    bench["workloads"].append({"name": "c3-hashed-r80", "config": "cms-dry",
                                "traffic": "hashed-open", "chips": 1,
                                "why": "dry"})
     bench["per_layer"].append({"name": "allowed_share", "unit": "ratio",
