@@ -1,71 +1,87 @@
 """Dense device backend: exact semantics, slot-addressed HBM state.
 
 The TPU answer to "Redis holds a key per user" (reference
-``docs/ARCHITECTURE.md:458-469``): keys are assigned integer slots host-side
-at ingest (the analog of Redis's keyspace hash), state lives in dense int64
-arrays in device memory, and every decision batch is one fused jitted call
-(ops/dense_kernels.py). Exactness matches the oracle bit-for-bit; capacity is
-bounded by the configured slot count (the sketch backend lifts that bound at
-the price of approximation).
+``docs/ARCHITECTURE.md:458-469``): state lives in dense int64 columns in
+device memory, one row a key, and the keyspace directory that maps a key
+to its row lives there too (ops/directory.py, ADR-027) — lookup and
+insertion run inside the decision step, keyed by the 64-bit id the lane
+carries (string keys: the bulk hash of the prefixed key). Every decision
+batch is one transfer, one fused jitted call (ops/dense_kernels.py,
+``jit_dense_step``) and one fetch, through the hashed and pipelined
+surface the sketch backends use (algorithms/hashed_lane.py): the host
+holds no key -> slot map and runs no per-key loop. Exactness matches the
+oracle bit-for-bit, per 64-bit id; capacity is bounded by the configured
+entry count (the sketch backend lifts that bound at the price of
+approximation).
 
 Failure semantics (reference ADR-002, ``interface.go:65-69``): any dispatch
-failure — including slot exhaustion, the analog of Redis OOM — resolves per
-Config.fail_open: allow with the fail_open flag set (the reference swallows
-the error the same way, ``tokenbucket.go:100-112``) or raise
-StorageUnavailableError.
+failure resolves per Config.fail_open: allow with the fail_open flag set
+(the reference swallows the error the same way, ``tokenbucket.go:100-112``)
+or raise StorageUnavailableError. Directory exhaustion, the analog of
+Redis OOM, is the same failure for the rows it concerns: a row whose key
+finds no entry within the probe bound touches no state, is counted
+(``directory_stats()["unplaced"]``) and is answered by that policy —
+never by another key's row.
+
+Reclaim: an entry idle for two windows equals a fresh one, so a device
+pass of its own (``jit_dense_reclaim``) gives such entries up — when a
+launch finds the directory nearly full, on ``prune()``, after ``reset``
+and at the end of the server's prewarm.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional
+from typing import Optional
 
 import numpy as np
 
 from ratelimiter_tpu.algorithms.base import RateLimiter
+from ratelimiter_tpu.algorithms.hashed_lane import HashedLane
 from ratelimiter_tpu.core.clock import Clock, MICROS, to_micros
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import StorageUnavailableError
-from ratelimiter_tpu.core.types import (
-    Algorithm,
-    BatchResult,
-    Result,
-    batch_fail_open,
-)
+from ratelimiter_tpu.core.types import Algorithm, BatchResult, DispatchTicket
+from ratelimiter_tpu.observability import tracing
 
-_MIN_PAD = 8
-
-
-def _pad_size(n: int) -> int:
-    """Next power of two >= n (>= _MIN_PAD): bounds the number of distinct
-    batch shapes XLA compiles (first compile is slow; shapes are cached)."""
-    size = _MIN_PAD
-    while size < n:
-        size *= 2
-    return size
+#: The occupancy (of capacity, counting every unit of cost in flight as a
+#: new key) above which a launch first runs the reclaim pass ...
+_RECLAIM_ABOVE = 0.875
+#: ... at most once in this share of a window: a directory that stays
+#: full of live keys is not swept on every dispatch.
+_RECLAIM_EVERY = 0.125
 
 
-class DenseLimiter(RateLimiter):
+class DenseLimiter(HashedLane, RateLimiter):
+    _lane_name = "dense"
+    #: The capacity may be a constructor argument the config lacks.
+    state_from_config = False
+
     def __init__(self, config: Config, clock: Optional[Clock] = None,
                  capacity: Optional[int] = None):
         super().__init__(config, clock)
         # Import lazily so the exact backend works without JAX present.
-        from ratelimiter_tpu.ops import dense_kernels
+        from ratelimiter_tpu.ops import dense_kernels, directory
 
         self._capacity = int(capacity if capacity is not None
                              else self.config.dense.capacity)
+        self._device = None
         self._window_us = to_micros(self.config.window)
-        self._step = dense_kernels.build_step(self.config)
-        self._state = dense_kernels.init_state(
-            self.config.algorithm, self._capacity, self.config.limit)
-        self._fresh_row = {
-            k: np.asarray(v[-1]) for k, v in self._state.items()
-        }  # padding row == pristine per-slot state, used to reset slots
-        self._slots: Dict[str, int] = {}
-        self._free: List[int] = list(range(self._capacity - 1, -1, -1))
-        self._last_used = np.zeros(self._capacity, dtype=np.int64)  # us
+        self._result_tail = directory.TAIL_WORDS
+        self._install_steps(self.config)
+        self._state = dense_kernels.init_directory_state(self.config,
+                                                         self._capacity)
         self._lock = threading.Lock()
+        self._init_staging()
         self._injected_failure: Optional[Exception] = None
+        # The directory's always-on counts (directory_stats): entries it
+        # holds as the host knows them (inserts reported at resolve, less
+        # what reclaim and reset freed) and the cumulative sums of the
+        # step's tail words.
+        self._entries = 0
+        self._dir = {"lookups": 0, "probes": 0, "inserts": 0, "unplaced": 0,
+                     "reclaimed": 0, "reclaim_passes": 0}
+        self._next_reclaim_us = 0
         # Policy engine: overrides resolved in-kernel (binary search over
         # the device-resident table, ops/policy_kernels.py). Entries are
         # re-gated through the same overflow checks as the base config.
@@ -79,20 +95,70 @@ class DenseLimiter(RateLimiter):
         self._policy_dev = None
         self._policy_dev_version = -1
 
-    def _policy_key(self, key: str) -> int:
-        from ratelimiter_tpu.ops.hashing import hash_strings_u64
+    # ------------------------------------------------ compiled programs
 
-        h = hash_strings_u64([self.config.format_key(key)])
-        return int(h.view(np.int64)[0])
+    def _install_steps(self, cfg: Config) -> None:
+        """Swap in the programs compiled for ``cfg`` (memoized per static
+        config). Called with self._lock held, or from __init__. The
+        premix step is built lazily (_get_ids_step)."""
+        from ratelimiter_tpu.ops import dense_kernels
+
+        self._step = dense_kernels.build_hashed_step(cfg, self._capacity)
+        self._ids_step = None
+        self._reclaim_step, self._forget_step, self._clear_rem_step = \
+            dense_kernels.build_controls(cfg, self._capacity)
+        self._fresh = np.asarray(
+            dense_kernels.fresh_row(cfg.algorithm, cfg.limit), np.int64)
+
+    def _get_ids_step(self):
+        if self._ids_step is None:
+            from ratelimiter_tpu.ops import dense_kernels
+
+            self._ids_step = dense_kernels.build_hashed_step(
+                self.config, self._capacity, premix=True)
+        return self._ids_step
+
+    def _result_format(self) -> tuple:
+        from ratelimiter_tpu.ops import dense_kernels
+
+        return dense_kernels.DENSE_ROWS, dense_kernels.unpack_dense
+
+    # The state is ``cols int64[K, C+1]`` (ops/dense_kernels.COLUMNS) and
+    # the directory's keys; the table-sized control updates below name
+    # their columns.
+
+    def _columns(self) -> dict:
+        from ratelimiter_tpu.ops.dense_kernels import COLUMNS
+
+        return dict(zip(COLUMNS[self.config.algorithm], self._state["cols"]))
+
+    def _set_columns(self, **columns) -> None:
+        from ratelimiter_tpu.ops.dense_kernels import column
+
+        cols = self._state["cols"]
+        for name, values in columns.items():
+            cols = cols.at[column(self.config.algorithm, name)].set(values)
+        self._state = dict(self._state, cols=cols)
+
+    def _policy_key(self, key: str) -> int:
+        # The key's directory id, bit-cast: the step searches the table
+        # with the id it probes the directory with.
+        return int(self._hash([key]).view(np.int64)[0])
+
+    def _policy_limits(self, h64: np.ndarray):
+        """Host-side per-request effective limits for result assembly
+        (None when no override exists)."""
+        if not len(self._policy_table):
+            return None
+        return self._policy_table.limits_for(
+            np.asarray(h64, np.uint64).view(np.int64))
 
     def _policy_device(self):
         """Device copy of the override table, rebuilt when the host table's
         version moved. Lock must be held."""
-        import jax.numpy as jnp
-
         t = self._policy_table
         if self._policy_dev is None or self._policy_dev_version != t.version:
-            self._policy_dev = {k: jnp.asarray(v)
+            self._policy_dev = {k: self._place_replicated(v)
                                 for k, v in t.host_arrays().items()}
             self._policy_dev_version = t.version
         return self._policy_dev
@@ -103,10 +169,8 @@ class DenseLimiter(RateLimiter):
         Lock held by the caller."""
         if self.config.algorithm is not Algorithm.TOKEN_BUCKET:
             return
-        slot = self._slots.get(self.config.format_key(key))
-        if slot is not None and "rem" in self._state:
-            self._state = dict(
-                self._state, rem=self._state["rem"].at[slot].set(0))
+        self._state, _ = self._clear_rem_step(
+            self._state, self._hash([key]), np.ones(1, bool), self._fresh)
 
     def _apply_config(self, new_cfg: Config) -> None:
         """Dynamic limit: swap in the step compiled for the new limit
@@ -119,20 +183,14 @@ class DenseLimiter(RateLimiter):
 
         from ratelimiter_tpu.ops import dense_kernels
 
-        new_step = dense_kernels.build_step(new_cfg)
         with self._lock:
-            self._step = new_step
+            self._install_steps(new_cfg)
             if self.config.algorithm is Algorithm.TOKEN_BUCKET:
                 delta = (new_cfg.limit - self.config.limit) * MICROS
                 cap = new_cfg.limit * MICROS
-                self._state = dict(
-                    self._state,
-                    tokens=jnp.clip(self._state["tokens"] + delta, 0, cap),
-                    rem=jnp.zeros_like(self._state["rem"]),
-                )
-                self._fresh_row = dict(self._fresh_row,
-                                       tokens=np.asarray(cap, dtype=np.int64),
-                                       rem=np.asarray(0, dtype=np.int64))
+                self._set_columns(
+                    tokens=jnp.clip(self._columns()["tokens"] + delta, 0, cap),
+                    rem=0)
 
     def _apply_window(self, new_cfg: Config) -> None:
         """Dynamic window: slot-state re-bucketing, same contract as the
@@ -145,10 +203,7 @@ class DenseLimiter(RateLimiter):
         a handful of elementwise selects over the slot arrays."""
         import jax.numpy as jnp
 
-        from ratelimiter_tpu.ops import dense_kernels
-
         W_new = to_micros(new_cfg.window)
-        new_step = dense_kernels.build_step(new_cfg)
         with self._lock:
             # Grid anchors INSIDE the lock: sampling the clock before
             # acquiring it races a concurrent dispatch's window roll, and
@@ -159,24 +214,25 @@ class DenseLimiter(RateLimiter):
             cur_old = (now_us // W_old) * W_old
             p_now = now_us // W_new
             new_start = p_now * W_new
-            self._step = new_step
+            self._install_steps(new_cfg)
             algo = self.config.algorithm
             if algo is Algorithm.FIXED_WINDOW:
                 # The live old window's span always reaches into the
                 # current new-grid window (now < cur_old + W_old), so a
                 # live count is always carried; stale slots zero.
-                live = self._state["win_start"] == cur_old
-                self._state = dict(
-                    self._state,
-                    count=jnp.where(live, self._state["count"], 0),
+                was = self._columns()
+                live = was["win_start"] == cur_old
+                self._set_columns(
+                    count=jnp.where(live, was["count"], 0),
                     win_start=jnp.where(live, jnp.int64(new_start), 0))
             elif algo in (Algorithm.SLIDING_WINDOW, Algorithm.TPU_SKETCH):
-                ws = self._state["win_start"]
+                was = self._columns()
+                ws = was["win_start"]
                 on_cur = ws == cur_old
                 on_prev = ws == cur_old - W_old
-                curr = jnp.where(on_cur, self._state["curr"], 0)
-                prev = jnp.where(on_cur, self._state["prev"],
-                                 jnp.where(on_prev, self._state["curr"], 0))
+                curr = jnp.where(on_cur, was["curr"], 0)
+                prev = jnp.where(on_cur, was["prev"],
+                                 jnp.where(on_prev, was["curr"], 0))
                 # The old curr bucket's span always overlaps the current
                 # new window (same argument as FW above) -> new curr.
                 # Old prev lands by its span end: current window, the
@@ -185,182 +241,144 @@ class DenseLimiter(RateLimiter):
                 new_curr = curr + (prev if q_prev >= p_now else 0)
                 new_prev = prev if q_prev == p_now - 1 else jnp.zeros_like(prev)
                 keep = (new_curr > 0) | (new_prev > 0)
-                self._state = dict(
-                    self._state,
+                self._set_columns(
                     curr=jnp.where(keep, new_curr, 0),
                     prev=jnp.where(keep, new_prev, 0),
                     win_start=jnp.where(keep, jnp.int64(new_start), 0))
             else:  # token bucket: rate changes (baked into the new step),
                 self._window_us = W_new  # levels/last stand, remainder
-                self._state = dict(      # resets (< 1 micro-token, toward
-                    self._state,         # denying).
-                    rem=jnp.zeros_like(self._state["rem"]))
-                return
+                self._set_columns(rem=0)  # resets (< 1 micro-token,
+                return                    # toward denying).
             self._window_us = W_new
 
-    # ------------------------------------------------------------ slot admin
 
-    def _assign_slots(self, keys: List[str], now_us: int) -> np.ndarray:
-        """Key -> slot for a whole batch. The mapping itself is a host dict
-        (O(1) amortized per key — the keyspace directory, like Redis's own
-        hash table); the *device* work is batched: all slots newly claimed
-        by this batch are zeroed in ONE fused update, not one eager op per
-        key."""
-        sids = np.empty(len(keys), dtype=np.int32)
-        fresh: List[int] = []
-        for i, key in enumerate(keys):
-            fkey = self.config.format_key(key)
-            slot = self._slots.get(fkey)
-            if slot is None:
-                if not self._free:
-                    self._prune_locked(now_us)
-                if not self._free:
-                    raise StorageUnavailableError(
-                        f"dense store full ({self._capacity} slots); "
-                        "prune idle keys or use the sketch backend")
-                slot = self._free.pop()
-                self._slots[fkey] = slot
-                fresh.append(slot)
-            sids[i] = slot
-            self._last_used[slot] = now_us
-        if fresh:
-            self._zero_slots(fresh)
-        return sids
+    # ------------------------------------------------------------ dispatch
+    #
+    # Launch and resolve are HashedLane's; below is what the directory
+    # adds on either side of the step.
 
-    def _zero_slots(self, slots: List[int]) -> None:
-        """Restore slots to pristine state (count 0 / full bucket) before
-        reuse — one fused scatter per call, however many slots."""
-        idx = np.asarray(slots, dtype=np.int32)
-        self._state = {
-            k: v.at[idx].set(self._fresh_row[k]) for k, v in self._state.items()
-        }
+    def _gate_locked(self, b: int, now_us: int) -> Optional[BatchResult]:
+        """Run the reclaim pass first when this batch could fill the
+        directory: what is in flight (the lane's offered mass, at least
+        its rows) and every row of this batch counted as new keys,
+        against ``_RECLAIM_ABOVE`` of the capacity."""
+        if (self._entries + self._inflight_mass + b
+                > _RECLAIM_ABOVE * self._capacity
+                and now_us >= self._next_reclaim_us):
+            self._reclaim_locked(now_us)
+        return None
 
-    def _prune_locked(self, now_us: int) -> int:
-        """Free slots idle for >= 2 windows — the TTL analog (SURVEY.md
-        §2.4.9). Lock must be held."""
-        horizon = now_us - 2 * self._window_us
-        dropped = 0
-        for fkey, slot in list(self._slots.items()):
-            if self._last_used[slot] <= horizon:
-                del self._slots[fkey]
-                self._free.append(slot)
-                self._zero_slots([slot])
-                dropped += 1
-        return dropped
+    def _step_args(self, slot: np.ndarray, padded: int) -> tuple:
+        return (self._state, *self._stage_operands(slot, padded),
+                self._policy_device())
+
+    def _note_tail_locked(self, t: DispatchTicket, tails) -> None:
+        lookups, probes, inserts, unplaced = (int(x) for x in tails[0])
+        d = self._dir
+        d["lookups"] += lookups
+        d["probes"] += probes
+        d["inserts"] += inserts
+        d["unplaced"] += unplaced
+        self._entries += inserts
+        t.unplaced = unplaced
+
+    def _resolve_ticket(self, t: DispatchTicket) -> BatchResult:
+        res = super()._resolve_ticket(t)
+        if t.unplaced:
+            # Rows whose key found no entry: the step gave them no slot
+            # (allowed, nothing remaining, as a fail-open answer reads);
+            # the policy answers them, as it answers any storage failure.
+            if not self.config.fail_open:
+                raise StorageUnavailableError(
+                    f"dense store full: {t.unplaced} of {t.b} rows found "
+                    f"no entry among {self._capacity} "
+                    f"(probe bound {self.config.dense.probe_bound}); "
+                    "prune idle keys, raise the capacity or use the "
+                    "sketch backend")
+            res.fail_open = True
+        return res
+
+    # ------------------------------------------------------------- reclaim
+
+    def _reclaim_locked(self, now_us: int) -> int:
+        """One reclaim pass (ops/dense_kernels._dense_reclaim) on the
+        dispatch stream, waited for: entries idle for two windows — the
+        TTL analog (SURVEY.md §2.4.9) — are given up. Lock must be held."""
+        with tracing.span("reclaim"):
+            self._state, freed = self._reclaim_step(
+                self._state, np.int64(now_us), self._fresh)
+            freed = int(freed)
+        self._entries -= freed
+        self._dir["reclaimed"] += freed
+        self._dir["reclaim_passes"] += 1
+        self._next_reclaim_us = now_us + int(_RECLAIM_EVERY * self._window_us)
+        return freed
 
     def prune(self, now: Optional[float] = None) -> int:
         t_us = to_micros(self.clock.now() if now is None else float(now))
         with self._lock:
-            return self._prune_locked(t_us)
+            return self._reclaim_locked(t_us)
 
     def key_count(self) -> int:
         with self._lock:
-            return len(self._slots)
+            return self._entries
 
-    # -------------------------------------------------------------- dispatch
-
-    def _dispatch(self, keys: List[str], ns: np.ndarray, now: float) -> BatchResult:
-        import jax.numpy as jnp
-
-        from ratelimiter_tpu.ops.hashing import hash_strings_u64
-
-        now_us = to_micros(now)
+    def directory_stats(self) -> dict:
+        """The directory's always-on counts: ``entries`` and ``capacity``
+        now; cumulative ``lookups`` (rows), ``probes`` (buckets examined),
+        ``inserts`` (keys), ``unplaced`` (rows answered by policy for want
+        of an entry) as the steps' results reported them at resolve;
+        ``reclaimed`` entries over ``reclaim_passes``."""
         with self._lock:
-            if self._injected_failure is not None:
-                raise self._injected_failure
-            sids = self._assign_slots(keys, now_us)
-            b = len(keys)
-            padded = _pad_size(b)
-            sid_arr = np.full(padded, self._capacity, dtype=np.int32)  # padding slot
-            n_arr = np.zeros(padded, dtype=np.int64)
-            sid_arr[:b] = sids
-            n_arr[:b] = ns
-            # Policy search keys: only worth hashing when overrides exist
-            # (an all-zero query vector misses the padded table anyway).
-            keyq = np.zeros(padded, dtype=np.int64)
-            limits_arr = None
-            if len(self._policy_table):
-                h64 = hash_strings_u64(
-                    [self.config.format_key(k) for k in keys])
-                keyq[:b] = h64.view(np.int64)
-                limits_arr = self._policy_table.limits_for(keyq[:b])
-            self._state, (allowed, remaining, retry_us, reset_us) = self._step(
-                self._state, jnp.asarray(sid_arr), jnp.asarray(n_arr),
-                jnp.int64(now_us), self._policy_device(), jnp.asarray(keyq))
-        allowed = np.asarray(allowed)[:b]
-        remaining = np.asarray(remaining)[:b]
-        retry_us = np.asarray(retry_us)[:b]
-        reset_us = np.asarray(reset_us)[:b]
-        return BatchResult(
-            allowed=allowed,
-            limit=self.config.limit,
-            remaining=np.maximum(remaining, 0),
-            retry_after=(retry_us / MICROS).astype(np.float64),
-            reset_at=(reset_us / MICROS).astype(np.float64),
-            limits=limits_arr,
-        )
-
-    def _allow_batch(self, keys: list, ns: np.ndarray, now: float) -> BatchResult:
-        try:
-            return self._dispatch(keys, ns, now)
-        except Exception as exc:
-            if self.config.fail_open:
-                # Reference swallows the error on fail-open
-                # (``tokenbucket.go:100-112``).
-                reset_at = now + float(self.config.window)
-                return batch_fail_open(len(keys), self.config.limit, reset_at)
-            if isinstance(exc, StorageUnavailableError):
-                raise
-            raise StorageUnavailableError(f"device dispatch failed: {exc}") from exc
-
-    def _allow_n(self, key: str, n: int, now: float) -> Result:
-        return self._allow_batch([key], np.array([n], dtype=np.int64), now).result(0)
+            return dict(self._dir, entries=self._entries,
+                        capacity=self._capacity)
 
     # ----------------------------------------------------------------- reset
 
     def _reset(self, key: str) -> None:
-        fkey = self.config.format_key(key)
+        """Forget the key: its entry becomes a tombstone and its row
+        pristine; the sweep that follows turns the tombstone (and any
+        idle entry) back into a free one."""
         with self._lock:
-            slot = self._slots.pop(fkey, None)
-            if slot is not None:
-                self._free.append(slot)
-                self._zero_slots([slot])
+            self._state, found = self._forget_step(
+                self._state, self._hash([key]), np.ones(1, bool),
+                self._fresh)
+            self._entries -= int(found)
+            self._reclaim_locked(to_micros(self.clock.now()))
 
     def _close(self) -> None:
         # State buffers are owned by this limiter; drop the references and
         # let the device allocator reclaim. Shared clocks/meshes are not
         # touched (divergence from reference Close(), SURVEY.md §2.4.13).
         self._state = {}
-        self._slots.clear()
-        self._free.clear()
 
     # ------------------------------------------------- checkpoint/restore
 
     def capture_state(self):
-        """Lock-held device→host transfer of state buffers + the host
-        slot map; serialization/writing happen in the caller, off-lock.
-        Format/staleness contract: ratelimiter_tpu/checkpoint.py."""
+        """Lock-held device→host transfer of the state columns and the
+        directory's keys (``state_dir_keys``); serialization/writing
+        happen in the caller, off-lock. Format/staleness contract:
+        ratelimiter_tpu/checkpoint.py."""
         self._check_open()
         with self._lock:
             arrays = {f"state_{k}": np.asarray(v)
                       for k, v in self._state.items()}
-            arrays["slot_keys"] = np.array(list(self._slots.keys()), dtype=str)
-            arrays["slot_ids"] = np.array(list(self._slots.values()),
-                                          dtype=np.int32)
-            arrays["last_used"] = self._last_used.copy()
             arrays.update(self._policy_table.snapshot_arrays())
             extra = {"saved_at": self.clock.now(), "capacity": self._capacity}
         return "dense", arrays, extra
 
     def restore(self, path: str) -> None:
-        """Replace device state and slot map with the snapshot. Elapsed-time
-        catch-up is automatic (window roll / token refill key off absolute
-        timestamps); keys idle across the gap are reclaimed by the usual
-        prune horizon."""
+        """Replace device state and directory with the snapshot.
+        Elapsed-time catch-up is automatic (window roll / token refill key
+        off absolute timestamps); keys idle across the gap are reclaimed by
+        the usual horizon. A snapshot of another capacity, or of another
+        bucket width (``DenseParams.lanes``: an entry's place depends on
+        it), is refused."""
         import jax
 
         from ratelimiter_tpu.checkpoint import load_state
         from ratelimiter_tpu.core.errors import CheckpointError
+        from ratelimiter_tpu.ops import directory
 
         self._check_open()
         arrays, meta = load_state(path, "dense", self.config)
@@ -370,33 +388,21 @@ class DenseLimiter(RateLimiter):
                 f"limiter capacity {self._capacity}")
         with self._lock:
             self._policy_table.restore_arrays(arrays)  # pops policy_* columns
-        state_keys = {f"state_{k}" for k in self._state}
-        expected = state_keys | {"slot_keys", "slot_ids", "last_used"}
+        expected = {f"state_{k}" for k in self._state}
         if set(arrays) != expected:
             raise CheckpointError(
                 f"{path}: state arrays {sorted(arrays)} != expected "
                 f"{sorted(expected)}")
+        keys = arrays["state_dir_keys"]
+        if keys.shape != self._state["dir_keys"].shape:
+            raise CheckpointError(
+                f"{path}: directory of shape {keys.shape} != this "
+                f"limiter's {self._state['dir_keys'].shape}")
         with self._lock:
+            self._policy_dev = None
             self._state = {
                 k: jax.device_put(arrays[f"state_{k}"], v.sharding)
                 for k, v in self._state.items()
             }
-            ids = arrays["slot_ids"]
-            self._slots = {str(k): int(s)
-                           for k, s in zip(arrays["slot_keys"], ids)}
-            taken = set(int(s) for s in ids)
-            self._free = [s for s in range(self._capacity - 1, -1, -1)
-                          if s not in taken]
-            self._last_used = arrays["last_used"].astype(np.int64).copy()
-
-    # ------------------------------------------------------- fault injection
-
-    def inject_failure(self, exc: Optional[Exception] = None) -> None:
-        """Test hook: make every subsequent dispatch fail (the analog of
-        miniredis ``mr.Close()`` mid-test, SURVEY.md §4.2.3). Pass None to
-        heal."""
-        self._injected_failure = exc if exc is not None else RuntimeError(
-            "injected backend failure")
-
-    def heal(self) -> None:
-        self._injected_failure = None
+            self._entries = int(np.count_nonzero(
+                (keys != directory.EMPTY) & (keys != directory.TOMB)))

@@ -6,7 +6,8 @@ validate config and return the interface type. Here one factory selects both
 the algorithm (Config.algorithm) and the state backend:
 
 * ``exact``  — host dict, exact semantics, the oracle (algorithms/exact.py).
-* ``dense``  — JAX device arrays, slot-addressed exact state, batched kernels.
+* ``dense``  — JAX device arrays, a state row a key behind a device-resident
+  key directory, batched kernels on the hashed, pipelined lane.
 * ``sketch`` — count-min sketch + sub-window decay on device; approximate,
   unbounded keys (the BASELINE.json north star).
 * ``mesh``   — slice-parallel serving over every visible device (ADR-012):

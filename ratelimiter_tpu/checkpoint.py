@@ -39,7 +39,7 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
-from ratelimiter_tpu.core.config import Config
+from ratelimiter_tpu.core.config import Config, DenseParams
 from ratelimiter_tpu.core.errors import CheckpointError
 
 FORMAT_VERSION = 1
@@ -71,6 +71,16 @@ def config_fingerprint(config: Config) -> str:
         # When ENABLED, the cascade geometry shapes the tn_* state
         # arrays, so it must participate like any other geometry field.
         fields.pop("hierarchy", None)
+    d = fields.get("dense")
+    if isinstance(d, dict):
+        # The directory's geometry (ADR-027) at its defaults is the world
+        # before it: dropped, so that no sketch or exact snapshot is
+        # stranded by two fields they never read. Set otherwise, it
+        # decides where an entry sits and participates.
+        for name, default in (("lanes", DenseParams.lanes),
+                              ("probe_bound", DenseParams.probe_bound)):
+            if d.get(name) == default:
+                d.pop(name)
     payload = json.dumps(
         {**fields, "algorithm": str(config.algorithm)},
         sort_keys=True, default=str)

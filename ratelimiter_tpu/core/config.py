@@ -448,13 +448,29 @@ class MeshSpec:
 class DenseParams:
     """Geometry of the dense (exact, slot-addressed) device backend."""
 
-    #: Maximum number of distinct live keys; key -> slot assignment happens
-    #: host-side at ingest.
+    #: Maximum number of distinct live keys: the entries of the device-
+    #: resident key -> slot directory (ops/directory.py; lookup and
+    #: insertion run inside the decision step).
     capacity: int = 1 << 16
+    #: Entries a directory bucket holds — what one probe reads in one row
+    #: gather. The width used is gcd(capacity, lanes): 128 (a vector
+    #: register's lanes) for any capacity that is a multiple of 128.
+    lanes: int = 128
+    #: Buckets a lookup examines, from the key's home bucket on, before a
+    #: row is left unplaced (answered by the fail-open/closed policy).
+    probe_bound: int = 8
 
     def validate(self) -> None:
         if self.capacity < 1:
             raise InvalidConfigError(f"dense capacity must be positive, got {self.capacity}")
+        if self.capacity >= 1 << 30:
+            raise InvalidConfigError(
+                f"dense capacity must be under 2**30 (slots are int32, and the write-back keeps the indices past it for the rows it drops), "
+                f"got {self.capacity}")
+        if self.lanes < 1 or self.probe_bound < 1:
+            raise InvalidConfigError(
+                f"dense lanes and probe_bound must be positive, got "
+                f"{self.lanes} and {self.probe_bound}")
 
 
 @dataclass(frozen=True)

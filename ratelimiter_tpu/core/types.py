@@ -205,7 +205,7 @@ class DispatchTicket:
 
     __slots__ = ("outs", "b", "limit", "limits", "ns", "now_us",
                  "window_us", "t_sec", "slot", "padded", "result", "meta",
-                 "wire", "trace_id", "audit", "t_door")
+                 "wire", "trace_id", "audit", "t_door", "unplaced")
 
     def __init__(self, result: "BatchResult | None" = None):
         self.outs = None        # the step's own output, on device: ONE
@@ -237,6 +237,9 @@ class DispatchTicket:
         #                         the completer's spans callback records
         #                         the "enter" and "leave" stages from
         #                         them (ADR-014 addendum)
+        self.unplaced = 0       # dense backend: rows whose key found no
+        #                         directory entry (the step's tail word),
+        #                         answered by the fail-open/closed policy
         self.audit = None       # (h64, ns) pinned by the native door's
         #                         launch callbacks ONLY while the live
         #                         auditor is on (ADR-016), so resolve can

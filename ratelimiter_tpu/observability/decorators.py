@@ -445,6 +445,43 @@ class MetricsDecorator(LimiterDecorator):
                 "override lookup, the others skipped it")
             reg.add_collect_hook(self._collect_dispatch_counts)
 
+        # The dense backend's device-resident key directory (ADR-027): its
+        # always-on counts, the steps' own tail words summed at resolve.
+        self._directory = base if hasattr(base, "directory_stats") else None
+        if self._directory is not None:
+            self._dir_gauges = {
+                name: reg.gauge(f"rate_limiter_directory_{name}", text)
+                for name, text in (
+                    ("lookups_total",
+                     "Decision rows the dense backend's key directory "
+                     "resolved to an entry or left unplaced (cumulative)"),
+                    ("probes_total",
+                     "Directory buckets examined for those rows "
+                     "(cumulative): one a row when its key sits in its "
+                     "home bucket"),
+                    ("inserts_total",
+                     "Keys the directory inserted (cumulative): a key's "
+                     "first decision, or its first after a reclaim"),
+                    ("unplaced_total",
+                     "Decision rows whose key found no entry within the "
+                     "probe bound and were answered by the fail-open/"
+                     "closed policy (cumulative); 0 on a directory with "
+                     "room"),
+                    ("reclaimed_total",
+                     "Entries the reclaim pass gave up, idle for two "
+                     "windows (cumulative)"),
+                    ("entries",
+                     "Keys the directory holds now"),
+                    ("capacity",
+                     "Entries the directory has (--dense-capacity)"))}
+            reg.add_collect_hook(self._collect_directory)
+
+    def _collect_directory(self) -> None:
+        st = self._directory.directory_stats()
+        for name, gauge in self._dir_gauges.items():
+            gauge.set(float(st[name.removesuffix("_total")]),
+                      shard=self._shard)
+
     def _collect_dispatch_counts(self) -> None:
         self._fetches_g.set(float(self._fetcher.result_fetches),
                             shard=self._shard)

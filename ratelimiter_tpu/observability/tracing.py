@@ -110,11 +110,15 @@ STAGES = (
                   # arrays (between place and step)
     "writeback",  # each device's output shard installed as its slice's
                   # state leaf (between step and finish)
-    # The resolve half of a dispatch (SketchLimiter._resolve_ticket, the
+    # The resolve half of a dispatch (HashedLane._resolve_ticket, the
     # collective router's resolve), on the resolving thread:
     "fetch",      # device ready -> BatchResult's NumPy columns built: the
                   # one packed result buffer fetched (a shard a device),
                   # the 64-bit and float columns rebuilt on the host
+    # The dense backend's key directory (ADR-027), under the limiter's
+    # lock, inside "prep" when a launch triggers it:
+    "reclaim",    # the table-sized reclaim pass enqueued AND waited for
+                  # (its count of freed entries is fetched)
 )
 _STAGE_CODE: Dict[str, int] = {s: i for i, s in enumerate(STAGES) if s}
 

@@ -3,10 +3,13 @@
 These are the TPU-native replacements for the reference's three Lua scripts
 (SURVEY.md §2.2): where Redis executes one interpreted script per request
 under a global lock, each kernel here decides a whole batch in one jitted
-XLA call — gather state for the batch's slots, sequence same-slot requests
-with ops.segment.admit, scatter the consumed amounts back. State lives in
-HBM across calls (donated buffers); time is an explicit int64-microsecond
-operand (SURVEY.md §2.4.14).
+XLA call — resolve each row's key to its slot in the device-resident
+directory (ops/directory.py: lookup and insertion inside this same
+program), gather state for the batch's slots, sequence same-slot requests
+with ops.segment.admit, scatter the touched rows back IN PLACE into the
+donated columns. Nothing a dispatch does scales with the capacity: the
+work is the batch's. State lives in HBM across calls (donated buffers);
+time is an explicit int64-microsecond operand (SURVEY.md §2.4.14).
 
 The integer recurrences are bit-identical to algorithms/exact.py (see its
 module docstring for the micro-token / window-scaled representations), with
@@ -14,23 +17,37 @@ an int64-overflow gate checked at build time: configs too large for the
 exact-integer path (limits or windows beyond the gates below) raise at
 construction rather than silently losing precision.
 
-State layout (arrays have capacity+1 rows; the last row is the padding slot
-batches are padded into — padding requests carry n=0 and are discarded on
-the host):
+State layout: ``cols:int64[K, C+1]``, the rule's K columns stacked
+(``COLUMNS``), one slot a column index; the last slot is the padding slot
+that padding rows and rows the directory could not place are sent to —
+they carry n=0 and are discarded on the host. Stacked, a batch's rows
+are read by ONE gather and written by ONE scatter: on the TPU a gather
+or scatter costs by the index, not by the element (PERF.md §6, PR 33:
+three scatters 1.15 ms, one 0.1).
 
-* fixed window:  count:int64[C+1], win_start:int64[C+1] (us)
-* sliding:       curr:int64[C+1], prev:int64[C+1], win_start:int64[C+1]
-* token bucket:  tokens:int64[C+1] (micro-tokens), rem:int64[C+1]
-                 (refill remainder), last:int64[C+1] (us)
+* fixed window:  count, win_start (us)
+* sliding:       curr, prev, win_start (us)
+* token bucket:  tokens (micro-tokens), rem (refill remainder), last (us)
+* the directory: dir_keys:int64[NB, W], NB * W == C (ops/directory.py);
+                 entry (b, l) is slot b * W + l. An entry that holds no
+                 key always has a pristine state column, so inserting a
+                 key writes the key and nothing else.
 
-Per-key policy overrides (ratelimiter_tpu/policy/): each step optionally
-takes ``(policy, keyq)`` — the device-resident sorted override table and
-the batch's int64 search keys. A vectorized binary search
-(ops/policy_kernels.lookup_i64) resolves each request's effective
-(limit, window, refill rate) INSIDE the fused step, so mixed
-default/override batches still cost one dispatch. With ``policy=None``
-the compiled graph is identical to the pre-policy kernels. Because
-windows become per-request, retry/reset leave the host: each step
+The serving step (``build_hashed_step``, module ``jit_dense_step``) takes
+ONE staged uint64 buffer ``[ids | n | now_us]`` (sketch_kernels.unstage)
+and the device-resident override table, and returns the new state and ONE
+int32 buffer (``pack_dense``): the four result columns and four counts of
+the directory. ``build_step`` is the slot-addressed core alone (the scan
+benchmark's and the tests' shape).
+
+Per-key policy overrides (ratelimiter_tpu/policy/): the table rides every
+dispatch, and a vectorized binary search (ops/policy_kernels.lookup_i64)
+resolves each request's effective (limit, window, refill rate) INSIDE the
+fused step — under a ``lax.cond`` on the table's first key, so a
+deployment with no override pays one scalar read (the branch
+policy_kernels.limit_for_rows takes for the sketches). With
+``policy=None`` the parameters are the config's, baked static. Because
+windows become per-request, retry/reset leave the host: each rule
 returns (new_state, (allowed, remaining, retry_us, reset_us)) with
 reset_us the absolute reset/refill timestamp.
 
@@ -53,24 +70,109 @@ from ratelimiter_tpu.core.clock import MICROS, to_micros
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import InvalidConfigError
 from ratelimiter_tpu.core.types import Algorithm
-from ratelimiter_tpu.ops import ensure_x64, policy_kernels
+from ratelimiter_tpu.ops import (
+    directory,
+    ensure_x64,
+    memoized,
+    named,
+    policy_kernels,
+)
 from ratelimiter_tpu.ops.segment import admit
 
 State = Dict[str, jnp.ndarray]
+#: The rows of ``cols``, per rule, in order.
+COLUMNS = {Algorithm.FIXED_WINDOW: ("count", "win_start"),
+           Algorithm.SLIDING_WINDOW: ("curr", "prev", "win_start"),
+           Algorithm.TPU_SKETCH: ("curr", "prev", "win_start"),
+           Algorithm.TOKEN_BUCKET: ("tokens", "rem", "last")}
 #: allowed, remaining, retry_us, reset_us (per request)
 Outputs = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]
 
 
 def _resolve(policy, keyq, names, defaults):
-    """Per-request effective parameters: ``defaults`` (python ints, baked
-    static) when no policy table rides the dispatch, else the binary-search
-    lookup over the device-resident table for each of ``names``."""
+    """Per-request effective parameters and the predicate they hang on:
+    ``(defaults, None)`` — python ints, baked static — when no policy
+    table rides the dispatch, else ``(int64[B] columns, overridden)``
+    from the binary-search lookup over the device-resident table, under
+    the branch policy_kernels.limit_for_rows takes: the table is sorted
+    with its PAD_KEY rows last, so a first key of PAD_KEY
+    (``overridden`` false) means no row can change an answer and the
+    13-gather descent is skipped. Both arms give the same values in every
+    case."""
     if policy is None:
-        return defaults
-    idx, found = policy_kernels.lookup_i64(policy["key"], keyq)
-    return tuple(
-        jnp.where(found, policy[name][idx], jnp.int64(default))
-        for name, default in zip(names, defaults))
+        return defaults, None
+
+    def lookup():
+        idx, found = policy_kernels.lookup_i64(policy["key"], keyq)
+        return tuple(
+            jnp.where(found, policy[name][idx], jnp.int64(default))
+            for name, default in zip(names, defaults))
+
+    def no_entries():
+        return tuple(jnp.full(keyq.shape, default, jnp.int64)
+                     for default in defaults)
+
+    overridden = policy["key"][0] != policy_kernels.PAD_KEY
+    return jax.lax.cond(overridden, lookup, no_entries), overridden
+
+
+def _divmod_rows(x, d):
+    """Floor ``(x // d, x % d)`` of int64 rows by int64 rows ``d > 0``,
+    as a 64-pass restoring division in a loop. XLA's own vector int64
+    division is the same passes unrolled, and on the TPU each such op
+    costs ~9 s of compile (PERF.md §6, PR 33): three of them a program
+    and eleven pad shapes made the dense step a quarter of an hour to
+    compile cold. Exact for every int64 ``x`` but the minimum."""
+    neg = x < 0
+    n = jnp.where(neg, -x, x).astype(jnp.uint64)
+    du = d.astype(jnp.uint64)
+    one = jnp.uint64(1)
+
+    def body(i, c):
+        q, r = c
+        bit = (63 - i).astype(jnp.uint64)
+        r = (r << one) | ((n >> bit) & one)
+        ge = r >= du
+        return q | (ge.astype(jnp.uint64) << bit), jnp.where(ge, r - du, r)
+
+    zero = jnp.zeros(n.shape, jnp.uint64)
+    q, r = jax.lax.fori_loop(0, 64, body, (zero, zero))
+    q, r = q.astype(jnp.int64), r.astype(jnp.int64)
+    odd = neg & (r != 0)
+    return (jnp.where(neg, -q - odd.astype(jnp.int64), q),
+            jnp.where(odd, d - r, r))
+
+
+def _divmod(x, d, static: int, overridden):
+    """Floor ``(x // d, x % d)`` by a rule parameter: ``d`` is the python
+    int ``static`` itself without a policy table (``overridden`` None),
+    else per-row values that all equal ``static`` unless ``overridden``.
+    So the division by the constant — which the compiler turns into
+    multiplies — is what runs on a deployment with no override, and the
+    by-rows loop only where an entry can change a divisor."""
+    if overridden is None:
+        return x // d, x % d
+    x = jnp.broadcast_to(x, d.shape)
+    return jax.lax.cond(overridden, lambda: _divmod_rows(x, d),
+                        lambda: (x // static, x % static))
+
+
+def _write_once(state: State, sid, last, *columns) -> State:
+    """The state with ``columns`` (one int64[B] a row of ``cols``, in
+    order) written at the batch's slots, each touched slot ONCE and all
+    columns by one scatter: a request that is the last of its slot in the
+    batch (``admit(..., tails=True)``) keeps its slot, every other one
+    gets an index of its own past the end, which ``mode="drop"``
+    discards. No two indices are equal, so the scatter is told so."""
+    at = jnp.where(last, sid, _PAST + jax.lax.iota(jnp.int32, sid.shape[0]))
+    rows = jnp.stack([jnp.broadcast_to(c, sid.shape) for c in columns])
+    return {**state, "cols": state["cols"].at[:, at].set(
+        rows, mode="drop", unique_indices=True)}
+
+
+#: Where dropped scatter indices start: past any column (step_statics
+#: refuses a capacity that reaches it).
+_PAST = 1 << 30
 
 
 def _bcast(x, like):
@@ -111,37 +213,41 @@ def _check_gates(cfg: Config) -> tuple[int, int, int]:
     return W, num, den
 
 
-def _scale_to_micro(x_winscale: jnp.ndarray, window_us: int) -> jnp.ndarray:
-    """floor(x * MICROS / window_us) without int64 overflow, for
-    x <= limit*window_us < 2^61. Exactness of comparisons is preserved:
+def _scale_to_micro(x_winscale: jnp.ndarray, W, window_us: int,
+                    overridden) -> jnp.ndarray:
+    """floor(x * MICROS / W) without int64 overflow, for
+    x <= limit*W < 2^61. Exactness of comparisons is preserved:
     n*MICROS <= floor(x*MICROS/W)  <=>  n*W <= x  for integer n."""
-    q, r = x_winscale // window_us, x_winscale % window_us
-    return q * MICROS + (r * MICROS) // window_us
+    q, r = _divmod(x_winscale, W, window_us, overridden)
+    return q * MICROS + _divmod(r * MICROS, W, window_us, overridden)[0]
 
 
 # --------------------------------------------------------------- fixed window
 
 def _fixed_window_step(state: State, sid, n, now_us, policy=None, keyq=None,
                        *, limit, window_us, iters):
-    lim, W = _resolve(policy, keyq, ("limit", "window_us"),
-                      (limit, window_us))
-    cur_ws = (now_us // W) * W  # per-request grid when windows are per-key
-    count = state["count"][sid]
-    stale = state["win_start"][sid] != cur_ws
+    (lim, W), over = _resolve(policy, keyq, ("limit", "window_us"),
+                              (limit, window_us))
+    # per-request grid when windows are per-key
+    cur_ws = _divmod(now_us, W, window_us, over)[0] * W
+    count, win_start = state["cols"][:, sid]      # one gather
+    stale = win_start != cur_ws
     count_eff = jnp.where(stale, 0, count)
 
     n_units = n * MICROS
     avail_units = (lim - count_eff) * MICROS
-    allowed, seen, consumed = admit(sid, n_units, avail_units, iters)
+    with jax.named_scope("admit"):
+        allowed, seen, consumed, last = admit(sid, n_units, avail_units,
+                                              iters, tails=True)
 
-    ncap = state["count"].shape[0]
-    base = state["count"].at[sid].set(count_eff)  # roll stale windows to 0
-    delta = jnp.zeros((ncap,), jnp.int64).at[sid].add(consumed)
-    new_state = {
-        "count": base + delta // MICROS,
-        "win_start": state["win_start"].at[sid].set(
-            jnp.broadcast_to(cur_ws, count.shape)),
-    }
+    with jax.named_scope("write_back"):
+        # Touched rows only, in place, each slot once: its count with
+        # stale windows rolled to 0, plus what the batch consumed of it
+        # (whole requests; ``seen - consumed`` of a slot's last request
+        # is what the slot has left).
+        new_state = _write_once(
+            state, sid, last,
+            count_eff + (avail_units - (seen - consumed)) // MICROS, cur_ws)
     remaining = (seen - jnp.where(allowed, n_units, 0)) // MICROS
     reset_us = _bcast(cur_ws + W, remaining)
     retry_us = jnp.where(allowed, 0, reset_us - now_us)
@@ -152,12 +258,10 @@ def _fixed_window_step(state: State, sid, n, now_us, policy=None, keyq=None,
 
 def _sliding_window_step(state: State, sid, n, now_us, policy=None, keyq=None,
                          *, limit, window_us, iters):
-    lim, W = _resolve(policy, keyq, ("limit", "window_us"),
-                      (limit, window_us))
-    cur_ws = (now_us // W) * W
-    ws = state["win_start"][sid]
-    curr = state["curr"][sid]
-    prev = state["prev"][sid]
+    (lim, W), over = _resolve(policy, keyq, ("limit", "window_us"),
+                              (limit, window_us))
+    cur_ws = _divmod(now_us, W, window_us, over)[0] * W
+    curr, prev, ws = state["cols"][:, sid]        # one gather
     current = ws == cur_ws
     rolled_one = ws == cur_ws - W
     curr_eff = jnp.where(current, curr, 0)
@@ -165,19 +269,17 @@ def _sliding_window_step(state: State, sid, n, now_us, policy=None, keyq=None,
 
     elapsed = now_us - cur_ws
     free_scaled = lim * W - prev_eff * (W - elapsed) - curr_eff * W
-    avail_units = _scale_to_micro(free_scaled, W)
+    avail_units = _scale_to_micro(free_scaled, W, window_us, over)
     n_units = n * MICROS
-    allowed, seen, consumed = admit(sid, n_units, avail_units, iters)
+    with jax.named_scope("admit"):
+        allowed, seen, consumed, last = admit(sid, n_units, avail_units,
+                                              iters, tails=True)
 
-    ncap = state["curr"].shape[0]
-    curr_base = state["curr"].at[sid].set(curr_eff)
-    delta = jnp.zeros((ncap,), jnp.int64).at[sid].add(consumed)
-    new_state = {
-        "curr": curr_base + delta // MICROS,
-        "prev": state["prev"].at[sid].set(prev_eff),
-        "win_start": state["win_start"].at[sid].set(
-            jnp.broadcast_to(cur_ws, curr.shape)),
-    }
+    with jax.named_scope("write_back"):
+        new_state = _write_once(
+            state, sid, last,
+            curr_eff + (avail_units - (seen - consumed)) // MICROS,
+            prev_eff, cur_ws)
     remaining = (seen - jnp.where(allowed, n_units, 0)) // MICROS
     reset_us = _bcast(cur_ws + W, remaining)
     retry_us = jnp.where(allowed, 0, reset_us - now_us)
@@ -188,38 +290,38 @@ def _sliding_window_step(state: State, sid, n, now_us, policy=None, keyq=None,
 
 def _token_bucket_step(state: State, sid, n, now_us, policy=None, keyq=None,
                        *, limit, window_us, rate_num, rate_den, iters):
-    lim, W, num, den = _resolve(
+    (lim, W, num, den), over = _resolve(
         policy, keyq, ("limit", "window_us", "rate_num", "rate_den"),
         (limit, window_us, rate_num, rate_den))
     cap = lim * MICROS
-    tokens = state["tokens"][sid]
-    rem = state["rem"][sid]
-    last = state["last"][sid]
+    with jax.named_scope("refill"):
+        tokens, rem, last = state["cols"][:, sid]     # one gather
 
-    elapsed = jnp.maximum(0, now_us - last)
-    full = elapsed >= W  # time-to-full from any level <= window
-    acc = jnp.where(full, 0, elapsed) * num + rem
-    tokens_r = tokens + acc // den
-    rem_r = acc % den
-    capped = full | (tokens_r >= cap)
-    tokens_eff = jnp.where(capped, cap, tokens_r)
-    rem_eff = jnp.where(capped, 0, rem_r)
+        elapsed = jnp.maximum(0, now_us - last)
+        full = elapsed >= W  # time-to-full from any level <= window
+        acc = jnp.where(full, 0, elapsed) * num + rem
+        refill, rem_r = _divmod(acc, den, rate_den, over)
+        tokens_r = tokens + refill
+        capped = full | (tokens_r >= cap)
+        tokens_eff = jnp.where(capped, cap, tokens_r)
+        rem_eff = jnp.where(capped, 0, rem_r)
 
     n_units = n * MICROS
-    allowed, seen, consumed = admit(sid, n_units, tokens_eff, iters)
+    with jax.named_scope("admit"):
+        allowed, seen, consumed, tail = admit(sid, n_units, tokens_eff,
+                                              iters, tails=True)
 
-    ncap = state["tokens"].shape[0]
-    tokens_base = state["tokens"].at[sid].set(tokens_eff)
-    delta = jnp.zeros((ncap,), jnp.int64).at[sid].add(consumed)
-    new_state = {
-        "tokens": tokens_base - delta,
-        "rem": state["rem"].at[sid].set(rem_eff),
-        "last": state["last"].at[sid].set(now_us),
-    }
+    with jax.named_scope("write_back"):
+        # Touched rows only, in place, each slot once: the refilled level
+        # less what the batch consumed of it, which is what the slot's
+        # last request saw less what it took itself.
+        new_state = _write_once(state, sid, tail, seen - consumed,
+                                rem_eff, now_us)
     remaining = (seen - jnp.where(allowed, n_units, 0)) // MICROS
     # Reference ``tokenbucket.go:122-130``: deficit/rate, ceil'd (exact.py).
     deficit = jnp.maximum(0, n_units - seen)
-    retry_us = jnp.where(allowed, 0, -((-deficit * den) // num))
+    retry_us = jnp.where(
+        allowed, 0, -_divmod(-deficit * den, num, rate_num, over)[0])
     # Reference reset_at approximation: now + time to fill the whole bucket
     # from empty (``tokenbucket.go:161-165``) == now + window.
     reset_us = _bcast(now_us + W, remaining)
@@ -228,22 +330,27 @@ def _token_bucket_step(state: State, sid, n, now_us, policy=None, keyq=None,
 
 # ------------------------------------------------------------------- factory
 
+def fresh_row(algorithm: Algorithm, limit: int) -> Tuple[int, ...]:
+    """The pristine value of each column of ``COLUMNS[algorithm]``: what
+    ``init_state`` fills with, what an entry that holds no key has, and
+    what a reclaimed one goes back to. Window counters are zero; token
+    buckets are full with last=0: the first touch sees elapsed >= window
+    and saturates at capacity, which is exactly the reference's
+    or-capacity default for absent keys (``tokenbucket.go:31-33``) — and
+    with a policy override, the step's per-request cap clamp makes the
+    first touch saturate at the KEY'S capacity, so fresh overridden keys
+    burst to their own limit."""
+    if algorithm is Algorithm.TOKEN_BUCKET:
+        return (limit * MICROS, 0, 0)
+    return (0,) * len(COLUMNS[algorithm])
+
+
 def init_state(algorithm: Algorithm, capacity: int, limit: int) -> State:
-    """Fresh state with capacity+1 rows (last = padding slot). Token buckets
-    start full with last=0: the first touch sees elapsed >= window and
-    saturates at capacity, which is exactly the reference's or-capacity
-    default for absent keys (``tokenbucket.go:31-33``) — and with a policy
-    override, the step's per-request cap clamp makes the first touch
-    saturate at the KEY'S capacity, so fresh overridden keys burst to
-    their own limit."""
+    """Fresh ``cols`` of capacity+1 slots (last = padding slot), every
+    slot ``fresh_row``."""
     ensure_x64()
-    n = capacity + 1
-    z = lambda: jnp.zeros((n,), jnp.int64)
-    if algorithm is Algorithm.FIXED_WINDOW:
-        return {"count": z(), "win_start": z()}
-    if algorithm in (Algorithm.SLIDING_WINDOW, Algorithm.TPU_SKETCH):
-        return {"curr": z(), "prev": z(), "win_start": z()}
-    return {"tokens": jnp.full((n,), limit * MICROS, jnp.int64), "rem": z(), "last": z()}
+    fresh = jnp.asarray(fresh_row(algorithm, limit), jnp.int64)
+    return {"cols": jnp.tile(fresh[:, None], (1, capacity + 1))}
 
 
 #: Compiled steps memoized by their static parameters: limiter instances with
@@ -281,6 +388,207 @@ def build_step(cfg: Config) -> Callable[[State, jnp.ndarray, jnp.ndarray, jnp.nd
     step = jax.jit(_step_fn(cfg), donate_argnums=(0,))
     _STEP_CACHE[cache_key] = step
     return step
+
+
+# ------------------------------------------------- the served step
+
+#: Rows of the dense step's packed result: allowed, remaining, ``retry_us``
+#: as its two words, ``reset_us - now_us`` as its two words.
+DENSE_ROWS = 6
+
+#: The column whose value says when an entry was last touched (what the
+#: reclaim pass compares with its horizon), per rule.
+_STAMP = {Algorithm.FIXED_WINDOW: "win_start",
+          Algorithm.SLIDING_WINDOW: "win_start",
+          Algorithm.TPU_SKETCH: "win_start",
+          Algorithm.TOKEN_BUCKET: "last"}
+
+
+def column(algorithm: Algorithm, name: str) -> int:
+    """The row of ``cols`` that holds ``name``."""
+    return COLUMNS[algorithm].index(name)
+
+
+def init_directory_state(cfg: Config, capacity: int) -> State:
+    """``init_state`` plus an empty directory of ``capacity`` entries."""
+    geo = directory.geometry(capacity, cfg.dense.lanes, cfg.dense.probe_bound)
+    return {**init_state(cfg.algorithm, capacity, cfg.limit),
+            "dir_keys": directory.init_keys(geo["nb"], geo["w"])}
+
+
+def step_statics(cfg: Config, capacity: int) -> dict:
+    """The static keyword arguments of ``_dense_step_staged`` for ``cfg``
+    on a table of ``capacity`` entries — the ONE derivation (see
+    sketch_kernels.step_statics): the rule's parameters and the
+    directory's geometry. Every builder's memo key is computed from it."""
+    ensure_x64()
+    if not 0 < capacity < _PAST:
+        raise InvalidConfigError(
+            f"dense capacity must be in [1, 2**30), got {capacity}")
+    W, num, den = _check_gates(cfg)
+    kw = dict(algorithm=cfg.algorithm, limit=cfg.limit, window_us=W,
+              iters=cfg.max_batch_admission_iters,
+              **directory.geometry(capacity, cfg.dense.lanes,
+                                   cfg.dense.probe_bound))
+    if cfg.algorithm is Algorithm.TOKEN_BUCKET:
+        kw.update(rate_num=num, rate_den=den)
+    return kw
+
+
+def _rule(algorithm: Algorithm):
+    if algorithm is Algorithm.FIXED_WINDOW:
+        return _fixed_window_step
+    if algorithm in (Algorithm.SLIDING_WINDOW, Algorithm.TPU_SKETCH):
+        return _sliding_window_step
+    if algorithm is Algorithm.TOKEN_BUCKET:
+        return _token_bucket_step
+    raise InvalidConfigError(f"unsupported algorithm {algorithm}")
+
+
+def pack_dense(allowed, remaining, retry_us, reset_in_us, tail):
+    """The dense step's result as it leaves the device: ``int32[6P + 4] =
+    [allowed | remaining | retry_us low, high | (reset_us - now_us) low,
+    high | lookups, probes, inserts, unplaced]`` — sketch_kernels.
+    pack_rows' format with the directory's counts as its tail words
+    (sketch_kernels.result_rows reads both). ``remaining`` is whole
+    requests in ``[0, limit_k]``, under 2**22 by the gates; the two
+    durations are exact int64 and pass 2**32 us on long windows."""
+    from ratelimiter_tpu.ops.sketch_kernels import pack_rows, split_words
+
+    return jnp.concatenate([
+        pack_rows(allowed, remaining, *split_words(retry_us),
+                  *split_words(reset_in_us)),
+        jnp.stack(tail).astype(jnp.int32)])
+
+
+def unpack_dense(rows, b: int, now_us: int, window_us: int):
+    """BatchResult's four columns from pack_dense's rows, on the host:
+    the step's exact integer microseconds over 1e6 in IEEE float64, as
+    algorithms/exact.py computes them."""
+    import numpy as np
+
+    from ratelimiter_tpu.ops.sketch_kernels import join_words
+
+    return (rows[0, :b].astype(bool), rows[1, :b].astype(np.int64),
+            join_words(rows[2, :b], rows[3, :b]).astype(np.float64) / MICROS,
+            (now_us + join_words(rows[4, :b], rows[5, :b])) / MICROS)
+
+
+def _dense_step_staged(state: State, staged, policy, *, premix: bool,
+                       algorithm, nb: int, w: int, pb: int, **rule_kw):
+    """One dispatch: directory, rule, packed result. ``staged`` is
+    sketch_kernels.unstage's buffer of finalized 64-bit key hashes, or —
+    ``premix`` — of raw ids the step finalizes with splitmix64 itself."""
+    from ratelimiter_tpu.ops.hashing import splitmix64_dev
+    from ratelimiter_tpu.ops.sketch_kernels import unstage
+
+    ids, n, now_us = unstage(staged)
+    cap = nb * w
+    if premix:
+        with jax.named_scope("hash_split"):
+            ids = splitmix64_dev(ids)
+    keyq = ids.astype(jnp.int64)            # the override table's key
+    valid = n > 0                           # padding rows carry n = 0
+    dir_keys, slot, placed, claimed, probes = directory.probe(
+        state["dir_keys"], directory.canon(ids), valid,
+        nb=nb, w=w, pb=pb, insert=True)
+    # A row without an entry goes to the padding slot with n = 0: it
+    # reads and writes nothing of any key.
+    sid = jnp.where(placed, slot, cap)
+    state, (allowed, remaining, retry_us, reset_us) = _rule(algorithm)(
+        state, sid, jnp.where(placed, n, 0).astype(jnp.int64), now_us,
+        policy, keyq, **rule_kw)
+    with jax.named_scope("finish"):
+        unplaced = valid & ~placed
+        tail = (jnp.sum(valid, dtype=jnp.int32), probes,
+                directory.distinct(slot, claimed),
+                jnp.sum(unplaced, dtype=jnp.int32))
+        zero = jnp.int64(0)
+        return {**state, "dir_keys": dir_keys}, pack_dense(
+            allowed | unplaced,
+            jnp.where(unplaced, zero, jnp.maximum(remaining, zero)),
+            jnp.where(unplaced, zero, retry_us), reset_us - now_us, tail)
+
+
+_BUILT: Dict[tuple, object] = {}
+
+
+def build_hashed_step(cfg: Config, capacity: int, *,
+                      premix: bool = False) -> Callable:
+    """Jitted ``step(state, staged, policy)`` -> ``(state, pack_dense's
+    one buffer)``, module ``jit_dense_step``; state is donated."""
+    kw = step_statics(cfg, capacity)
+    return memoized(_BUILT, kw, ("step", premix), lambda: jax.jit(
+        named("dense_step", _dense_step_staged, premix=premix, **kw),
+        donate_argnums=(0,)))
+
+
+def _dense_reclaim(state: State, now_us, fresh, *, stamp: int, nb: int,
+                   w: int, pb: int, horizon_us: int):
+    """The table-sized pass (``jit_dense_reclaim``): entries idle for the
+    horizon (by row ``stamp`` of ``cols``) are given up and their slots
+    made pristine (``fresh int64[K]``), tombstones no live key walked
+    past become EMPTY. Returns ``(state, entries freed)``."""
+    cap = nb * w
+    with jax.named_scope("reclaim"):
+        cols = state["cols"]
+        dir_keys, freed = directory.reclaim(
+            state["dir_keys"], cols[stamp, :cap].reshape(nb, w), now_us,
+            nb=nb, w=w, pb=pb, horizon_us=horizon_us)
+        flat = jnp.concatenate([freed.reshape(cap), jnp.zeros((1,), bool)])
+        return ({"cols": jnp.where(flat[None, :], fresh[:, None], cols),
+                 "dir_keys": dir_keys}, jnp.sum(freed, dtype=jnp.int32))
+
+
+def _dense_forget(state: State, ids, valid, fresh, *, clear, nb: int,
+                  w: int, pb: int):
+    """Find each key (no insertion). ``clear`` None (reset): its entry
+    becomes a tombstone and its slot pristine (``fresh int64[K]``);
+    ``clear`` a row of ``cols``: that column of its slot is zeroed (the
+    token bucket's refill remainder, when an override changed the rate it
+    is denominated in). Returns ``(state, keys found)``."""
+    cap = nb * w
+    _, slot, found, _, _ = directory.probe(
+        state["dir_keys"], directory.canon(ids), valid,
+        nb=nb, w=w, pb=pb, insert=False)
+    at = jnp.where(found, slot, cap + 1)           # out of range: dropped
+    cols, dir_keys = state["cols"], state["dir_keys"]
+    if clear is not None:
+        cols = cols.at[clear, at].set(0, mode="drop")
+    else:
+        dir_keys = dir_keys.at[jnp.where(found, slot // w, nb),
+                               slot % w].set(jnp.int64(directory.TOMB),
+                                             mode="drop")
+        cols = cols.at[:, at].set(
+            jnp.broadcast_to(fresh[:, None], (fresh.shape[0], at.shape[0])),
+            mode="drop")
+    return ({"cols": cols, "dir_keys": dir_keys},
+            jnp.sum(found, dtype=jnp.int32))
+
+
+def build_controls(cfg: Config, capacity: int) -> Tuple[Callable, ...]:
+    """``(reclaim, forget, clear_rem)``, jitted and memoized per static
+    config: ``reclaim(state, now_us, fresh)``, ``forget(state, ids,
+    valid, fresh)`` and ``clear_rem(state, ids, valid, fresh)``, each
+    returning ``(state, count)`` with the state donated. Control-plane
+    programs: the decision step never runs them."""
+    kw = step_statics(cfg, capacity)
+    geo = {k: kw[k] for k in ("nb", "w", "pb")}
+    reclaim_kw = dict(geo, stamp=column(cfg.algorithm, _STAMP[cfg.algorithm]),
+                      horizon_us=2 * kw["window_us"])
+    rem = (column(cfg.algorithm, "rem")
+           if cfg.algorithm is Algorithm.TOKEN_BUCKET else 0)
+    return (
+        memoized(_BUILT, reclaim_kw, ("reclaim",), lambda: jax.jit(
+            named("dense_reclaim", _dense_reclaim, **reclaim_kw),
+            donate_argnums=(0,))),
+        memoized(_BUILT, geo, ("forget",), lambda: jax.jit(
+            named("dense_forget", _dense_forget, clear=None, **geo),
+            donate_argnums=(0,))),
+        memoized(_BUILT, geo, ("clear", rem), lambda: jax.jit(
+            named("dense_clear_rem", _dense_forget, clear=rem, **geo),
+            donate_argnums=(0,))),
+    )
 
 
 def _dense_scan(state: State, sids, ns, now0_us, dt_us, *, fn):

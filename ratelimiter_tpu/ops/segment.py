@@ -92,7 +92,8 @@ def admit(
     n_units: jnp.ndarray,    # [B] requested amount (>=0; 0 = padding)
     avail_units: jnp.ndarray,  # [B] per-request available quota (equal within a slot)
     iters: int,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    tails: bool = False,
+) -> tuple[jnp.ndarray, ...]:
     """Greedy-in-batch-order admission.
 
     Returns (in original request order):
@@ -104,6 +105,14 @@ def admit(
                     retry-after math.
         consumed_units: [B] — n_units where allowed else 0 (original order;
                     callers fold this into state by sid).
+        last:       bool[B], only with ``tails`` — the request is the LAST
+                    of its slot in the batch. ``seen - consumed`` of that
+                    request is what the slot has left after the whole
+                    batch, so a caller can write each touched slot ONCE,
+                    by a scatter whose indices are unique (the dense
+                    backend's write-back). It rides the sort that restores
+                    the original order; without ``tails`` the traced
+                    program is what it was.
     """
     B = sid.shape[0]
     iota = jax.lax.iota(jnp.int32, B)
@@ -149,10 +158,17 @@ def admit(
         allowed, seen = _solve(_segment_exclusive_cumsum)
 
     # Restore original order with a second sort keyed by the carried index.
-    _, allowed_i, seen_o = jax.lax.sort(
-        (orig, allowed.astype(jnp.int32), seen), num_keys=1, is_stable=True)
+    back = (orig, allowed.astype(jnp.int32), seen)
+    if tails:
+        seg_tail = jnp.concatenate(
+            [s[1:] != s[:-1], jnp.ones((1,), dtype=bool)])
+        back = back + (seg_tail.astype(jnp.int32),)
+    _, allowed_i, seen_o, *tail_i = jax.lax.sort(back, num_keys=1,
+                                                 is_stable=True)
     allowed_o = allowed_i.astype(bool)
     consumed_o = jnp.where(allowed_o, n_units, zero)
+    if tails:
+        return allowed_o, seen_o, consumed_o, tail_i[0].astype(bool)
     return allowed_o, seen_o, consumed_o
 
 

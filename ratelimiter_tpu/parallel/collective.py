@@ -44,7 +44,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from ratelimiter_tpu.algorithms.sketch import _pad_size, fetch_count
+from ratelimiter_tpu.algorithms.hashed_lane import _pad_size, fetch_count
 from ratelimiter_tpu.core.clock import Clock, to_micros
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import StorageUnavailableError

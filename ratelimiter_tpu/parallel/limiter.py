@@ -32,10 +32,10 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ratelimiter_tpu.algorithms.base import RateLimiter
+from ratelimiter_tpu.algorithms.hashed_lane import _pad_size
 from ratelimiter_tpu.algorithms.sketch import (
     SketchLimiter,
     SketchTokenBucketLimiter,
-    _pad_size,
 )
 from ratelimiter_tpu.core.clock import Clock
 from ratelimiter_tpu.core.config import Config

@@ -14,7 +14,13 @@ import os
 import signal
 import time
 
-from ratelimiter_tpu import Algorithm, Config, SketchParams, create_limiter
+from ratelimiter_tpu import (
+    Algorithm,
+    Config,
+    DenseParams,
+    SketchParams,
+    create_limiter,
+)
 from ratelimiter_tpu.observability import (
     CircuitBreakerDecorator,
     LoggingDecorator,
@@ -129,6 +135,15 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--sketch-depth", type=int, default=4)
     ap.add_argument("--sketch-width", type=int, default=65536)
     ap.add_argument("--sub-windows", type=int, default=60)
+    ap.add_argument("--dense-capacity", type=int,
+                    default=DenseParams.capacity, metavar="N",
+                    help="--backend dense: entries of the device-resident "
+                         "key directory = the most distinct live keys "
+                         "(32 B of device memory a key with the token "
+                         "bucket's three columns); a multiple of 128 "
+                         "keeps a probe to one vector row. Size it to "
+                         "about twice the active keys: rows whose key "
+                         "finds no entry are answered by --fail-open")
     ap.add_argument("--hh-slots", type=int, default=0,
                     help="heavy-hitter side table slots (0 = off; power "
                          "of two >= 16): promoted hot keys get exact "
@@ -967,10 +982,16 @@ def _prewarm(limiter, max_batch: int) -> None:
     top = 2 * max_batch
     targets = undecorated(limiter).sub_limiters()
     for tgt in targets:
+        # The dense backend's directory holds a key per id it has seen.
+        keyed = hasattr(undecorated(tgt), "directory_stats")
         size = 8
         while True:
             size = min(size, top)
             h = np.arange(size, dtype=np.uint64) + (1 << 62)
+            if keyed:
+                # A compile needs the shape, not distinct keys: eight
+                # made-up ids a lane, whatever the directory's capacity.
+                h = (h & np.uint64(7)) + np.uint64(1 << 62)
             tgt.allow_hashed(h, now=0.0)
             if hasattr(undecorated(tgt), "allow_ids"):
                 # The hashed wire lane's premix step (splitmix64 in-jit,
@@ -981,6 +1002,10 @@ def _prewarm(limiter, max_batch: int) -> None:
             if size >= top:
                 break
             size *= 2
+        if keyed:
+            # Give up the made-up ids above (sent at now=0, so idle for
+            # good) before the server serves.
+            tgt.prune()
     und = undecorated(limiter)
     if hasattr(und, "prewarm_routed"):
         # Collective router (ADR-024): the shard_map'd all_to_all step is
@@ -1032,6 +1057,8 @@ def _device_report(args, limiters) -> str:
     report = (f"device={devs[0].platform}/{devs[0].device_kind} "
               f"x{len(devs)} kernels=jnp "
               f"slice_devices={','.join(placed)}")
+    if args.backend == "dense":
+        report += f" dense_capacity={args.dense_capacity}"
     log = logging.getLogger("ratelimiter_tpu.serving")
     if devs[0].platform == "cpu" and not os.environ.get("JAX_PLATFORMS"):
         # JAX found no accelerator and fell back on its own: say so
@@ -1076,6 +1103,7 @@ async def amain(args) -> None:
         sketch=SketchParams(depth=args.sketch_depth, width=args.sketch_width,
                             sub_windows=args.sub_windows,
                             hh_slots=args.hh_slots),
+        dense=DenseParams(capacity=args.dense_capacity),
         persistence=PersistenceSpec(
             dir=args.snapshot_dir,
             snapshot_interval=args.snapshot_interval,

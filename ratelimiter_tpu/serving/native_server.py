@@ -150,9 +150,10 @@ class NativeRateLimitServer:
         self._depth = 0
         self._depth_lock = threading.Lock()
 
-        # Sketch-family limiters expose the hashed fast path; detect once
-        # on the UNDECORATED backend (decorators delegate the whole
-        # hashed surface, so hasattr on the stack is always true).
+        # The device backends (the sketch family, the dense backend)
+        # expose the hashed fast path; detect once on the UNDECORATED
+        # backend (decorators delegate the whole hashed surface, so
+        # hasattr on the stack is always true).
         from ratelimiter_tpu.observability.decorators import undecorated as _u
 
         self._fast = hasattr(_u(limiter), "allow_hashed")
@@ -185,7 +186,7 @@ class NativeRateLimitServer:
         from ratelimiter_tpu.observability.decorators import undecorated
 
         base = undecorated(limiter)
-        if shards > 1 and not self._fast:
+        if shards > 1 and not getattr(base, "state_from_config", False):
             # Clones are rebuilt from (config, clock) alone; backends with
             # extra constructor state (e.g. the dense backend's capacity
             # override) would silently diverge between shards.

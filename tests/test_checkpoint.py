@@ -202,6 +202,16 @@ class TestValidation:
         assert lim.key_count() == 40
         lim.save(path)
         lim.close()
+        # The keyspace directory lives on the device (ADR-027): its key
+        # column is saved with the state columns, one entry a key; the
+        # host's old slot map (slot_keys / slot_ids / last_used) is gone.
+        with np.load(path) as snap:
+            assert {"state_dir_keys", "state_cols"} <= set(snap.files)
+            assert snap["state_cols"].shape == (3, 65)   # tokens, rem, last
+            assert not {"slot_keys", "slot_ids", "last_used"} & set(
+                snap.files)
+            assert snap["state_dir_keys"].size == 64
+            assert np.count_nonzero(snap["state_dir_keys"]) == 40
         lim2 = create_limiter(cfg, backend="dense", clock=ManualClock(T0),
                               capacity=64)
         lim2.restore(path)

@@ -29,14 +29,26 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ratelimiter_tpu import Algorithm, Config, ManualClock, SketchParams
+from ratelimiter_tpu import (
+    Algorithm,
+    Config,
+    DenseParams,
+    ManualClock,
+    SketchParams,
+)
+from ratelimiter_tpu.algorithms.dense import DenseLimiter
 from ratelimiter_tpu.algorithms.sketch import (
     SketchLimiter,
     SketchTokenBucketLimiter,
 )
 from ratelimiter_tpu.core.clock import to_micros
 from ratelimiter_tpu.observability import tracing
-from ratelimiter_tpu.ops import bucket_kernels, sketch_kernels
+from ratelimiter_tpu.ops import (
+    bucket_kernels,
+    dense_kernels,
+    directory,
+    sketch_kernels,
+)
 from ratelimiter_tpu.ops.hashing import split_hash, splitmix64
 from tests.parent_lookup import inline_lookup
 
@@ -51,14 +63,27 @@ INSTANTS = (0.0, 0.3, 11.0, 39.5, 40.1)
 BIG = 50
 
 
+#: The dense backend rides the same lane (algorithms/hashed_lane.py):
+#: the tests that hold the lane itself — one transfer, one program, one
+#: int32 buffer, one fetch, the lookup counter — take it as further cases.
+DENSE = {"dense-bucket": Algorithm.TOKEN_BUCKET,
+         "dense-fixed": Algorithm.FIXED_WINDOW,
+         "dense-window": Algorithm.SLIDING_WINDOW}
+DENSE_CAPACITY = 256
+LANES = list(ALGOS) + list(DENSE)
+
+
 def _cfg(algo: str, **kw) -> Config:
-    base = dict(algorithm=ALGOS[algo], limit=3, window=60.0,
-                sketch=SketchParams(depth=3, width=512, sub_windows=6))
+    base = dict(algorithm={**ALGOS, **DENSE}[algo], limit=3, window=60.0,
+                sketch=SketchParams(depth=3, width=512, sub_windows=6),
+                dense=DenseParams(capacity=DENSE_CAPACITY, lanes=16))
     base.update(kw)
     return Config(**base)
 
 
 def _cls(algo: str):
+    if algo in DENSE:
+        return DenseLimiter
     return SketchTokenBucketLimiter if algo == "bucket" else SketchLimiter
 
 
@@ -173,27 +198,35 @@ def _launch(lim, premix, ids, **kw):
 
 
 @pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
-@pytest.mark.parametrize("algo", list(ALGOS))
+@pytest.mark.parametrize("algo", LANES)
 def test_the_step_returns_one_int32_buffer(algo, premix):
     """By the lowered program's own output types, and by the array the
-    ticket holds: state leaves aside, one array, int32, rows x P words."""
+    ticket holds: state leaves aside, one array, int32, rows x P words
+    (and, dense, the directory's four tail words)."""
     cfg = _cfg(algo)
-    kernels = bucket_kernels if algo == "bucket" else sketch_kernels
-    rows = (bucket_kernels.BUCKET_ROWS if algo == "bucket"
-            else sketch_kernels.WINDOW_ROWS)
+    tail = 0
+    if algo in DENSE:
+        rows, tail = dense_kernels.DENSE_ROWS, directory.TAIL_WORDS
+        step = dense_kernels.build_hashed_step(cfg, DENSE_CAPACITY,
+                                               premix=premix)
+    elif algo == "bucket":
+        rows = bucket_kernels.BUCKET_ROWS
+        step = bucket_kernels.build_hashed_step(cfg, premix=premix)
+    else:
+        rows = sketch_kernels.WINDOW_ROWS
+        step = sketch_kernels.build_hashed_step(cfg, premix=premix)
     lim = _cls(algo)(cfg, ManualClock(T0))
-    step = kernels.build_hashed_step(cfg, premix=premix)
     with lim._lock:
         policy = lim._policy_device()
     staged = jax.ShapeDtypeStruct((2 * 64 + 1,), jnp.uint64)
     out = step.lower(lim._state, staged, policy).out_info[1]
     assert len(jax.tree_util.tree_leaves(out)) == 1, "one leaf, not a tuple"
-    assert out.dtype == jnp.int32 and out.shape == (rows * 64,)
+    assert out.dtype == jnp.int32 and out.shape == (rows * 64 + tail,)
 
     ticket = _launch(lim, premix, np.arange(1, 40, dtype=np.uint64))
     assert isinstance(ticket.outs, jax.Array)
     assert ticket.outs.dtype == jnp.int32
-    assert ticket.outs.shape == (rows * ticket.padded,)
+    assert ticket.outs.shape == (rows * ticket.padded + tail,)
     assert len(ticket.outs.addressable_shards) == 1
     lim.resolve(ticket)
     lim.close()
@@ -316,13 +349,15 @@ def test_the_guard_is_live_on_this_backend():
             double(np.arange(4))
 
 
-@pytest.mark.parametrize("pinned", [False, True], ids=["default", "pinned"])
 @pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
-@pytest.mark.parametrize("algo", list(ALGOS))
+@pytest.mark.parametrize("algo, pinned", [
+    pytest.param(a, p, id=f"{a}-{'pinned' if p else 'default'}")
+    for a in LANES for p in (False, True) if not (p and a in DENSE)])
 def test_a_launch_is_one_transfer_and_one_program(algo, premix, pinned,
                                                   monkeypatch):
     device = jax.devices()[-1] if pinned else None
-    lim = _cls(algo)(_cfg(algo), ManualClock(T0), device=device)
+    lim = _cls(algo)(_cfg(algo), ManualClock(T0),
+                     **({"device": device} if pinned else {}))
     ids = np.arange(1, 6, dtype=np.uint64)
     lim.resolve(_launch(lim, premix, ids))  # compile, rotate, place the policy
 
@@ -370,7 +405,7 @@ def recorder():
 
 
 @pytest.mark.parametrize("wire", [False, True], ids=["columns", "wire"])
-@pytest.mark.parametrize("algo", list(ALGOS))
+@pytest.mark.parametrize("algo", LANES)
 def test_a_resolve_is_one_fetch_span_and_one_buffer(algo, wire, recorder):
     lim = _cls(algo)(_cfg(algo), ManualClock(T0))
     ids = np.arange(1, 30, dtype=np.uint64)
@@ -562,7 +597,7 @@ def test_mesh_steps_equal_their_inline_lookup_programs(algo, kind, overrides,
 
 
 @pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
-@pytest.mark.parametrize("algo", list(ALGOS))
+@pytest.mark.parametrize("algo", LANES)
 def test_the_lookup_counter_follows_the_table(algo, premix):
     """+0 a dispatch on an empty table, +1 with an entry, +0 again after
     the last delete_override; the same compiled program throughout."""

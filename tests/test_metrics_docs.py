@@ -2,7 +2,7 @@
 monitoring CONTRACT, so it must match what servers actually export —
 in BOTH directions.
 
-Four real server binaries (spawned concurrently) cover the
+Five real server binaries (spawned concurrently) cover the
 backend-conditional families:
 
 * a fully-featured windowed-sketch member (fleet + audit + hh +
@@ -15,7 +15,9 @@ backend-conditional families:
   families plus the multi-ring network-engine families (ADR-026:
   engine info, syscall ledger, writev batch factor);
 * a mesh member behind the collective router (ADR-024) — its dispatch
-  and fallback counters.
+  and fallback counters;
+* a dense (exact, a row a key) token-bucket server behind the native
+  door — the device-resident key directory's families (ADR-027).
 
 Direction 1: every `rate_limiter_*` name written in OPERATIONS §3 must
 exist in the union scrape (a documented name may also be a PREFIX of a
@@ -76,8 +78,8 @@ def _families(text: str) -> set:
 class TestMetricNameDrift:
     def test_operations_section3_matches_scrape_both_directions(
             self, tmp_path):
-        ports = [free_port() for _ in range(4)]
-        https = [free_port() for _ in range(4)]
+        ports = [free_port() for _ in range(5)]
+        https = [free_port() for _ in range(5)]
         cfgpath = os.path.join(str(tmp_path), "fleet.json")
         with open(cfgpath, "w", encoding="utf-8") as f:
             json.dump({"buckets": 32, "epoch": 1, "hosts": [
@@ -118,6 +120,12 @@ class TestMetricNameDrift:
                     "--http-port", str(https[3])],
                    {"XLA_FLAGS":
                     "--xla_force_host_platform_device_count=2"}),
+            # 5: the dense backend behind the native door (the key
+            # directory's counters).
+            _spawn(["--algorithm", "token_bucket", "--backend", "dense",
+                    "--dense-capacity", "4096", "--native",
+                    "--port", str(ports[4]),
+                    "--http-port", str(https[4])]),
         ]
         try:
             for proc in procs:

@@ -34,6 +34,12 @@ from ratelimiter_tpu.serving import MicroBatcher
 T0 = 1_700_000_000.0
 
 
+#: The backends built on the hashed, pipelined lane (algorithms/
+#: hashed_lane.py): every test that holds the lane itself runs on both.
+LANE_BACKENDS = ("sketch", "dense")
+on_both = pytest.mark.parametrize("backend", LANE_BACKENDS)
+
+
 def _mk(limit=5, algo=Algorithm.SLIDING_WINDOW, backend="sketch", **kw):
     cfg = Config(algorithm=algo, limit=limit, window=60.0,
                  sketch=SketchParams(depth=3, width=512, sub_windows=6),
@@ -44,12 +50,15 @@ def _mk(limit=5, algo=Algorithm.SLIDING_WINDOW, backend="sketch", **kw):
 # ------------------------------------------------------ limiter-level API
 
 class TestLaunchResolve:
-    def test_interleaved_same_key_matches_single_dispatch_oracle(self):
+    @on_both
+    def test_interleaved_same_key_matches_single_dispatch_oracle(
+            self, backend):
         """K batches of the same hot key launched back to back WITHOUT
         resolving in between must decide exactly like the synchronous
         path: the 6th unit request on a limit-5 key is denied no matter
         which in-flight window it rode in."""
-        lim, oracle = _mk(limit=5), _mk(limit=5)
+        lim = _mk(limit=5, backend=backend)
+        oracle = _mk(limit=5, backend=backend)
         frames = [["hot", "hot"], ["hot", "cold"], ["hot", "hot"],
                   ["cold", "hot"]]
         tickets = [lim.launch_batch(f) for f in frames]     # all in flight
@@ -59,11 +68,13 @@ class TestLaunchResolve:
         lim.close()
         oracle.close()
 
-    def test_resolve_order_does_not_matter(self):
+    @on_both
+    def test_resolve_order_does_not_matter(self, backend):
         """Resolving newest-first returns the same per-ticket decisions:
         ordering lives in the device-side state chain, not in the resolve
         calls."""
-        lim, oracle = _mk(limit=3), _mk(limit=3)
+        lim = _mk(limit=3, backend=backend)
+        oracle = _mk(limit=3, backend=backend)
         frames = [["k"], ["k"], ["k"], ["k"], ["k"]]
         tickets = [lim.launch_batch(f) for f in frames]
         for t in reversed(tickets):
@@ -74,16 +85,18 @@ class TestLaunchResolve:
         lim.close()
         oracle.close()
 
-    def test_resolve_is_idempotent(self):
-        lim = _mk()
+    @on_both
+    def test_resolve_is_idempotent(self, backend):
+        lim = _mk(backend=backend)
         t = lim.launch_batch(["a"])
         first = lim.resolve(t)
         assert lim.resolve(t) is first
         lim.close()
 
-    def test_token_bucket_pipelined_matches_oracle(self):
-        lim = _mk(limit=4, algo=Algorithm.TOKEN_BUCKET)
-        oracle = _mk(limit=4, algo=Algorithm.TOKEN_BUCKET)
+    @on_both
+    def test_token_bucket_pipelined_matches_oracle(self, backend):
+        lim = _mk(limit=4, algo=Algorithm.TOKEN_BUCKET, backend=backend)
+        oracle = _mk(limit=4, algo=Algorithm.TOKEN_BUCKET, backend=backend)
         frames = [["k", "k"], ["k", "k"], ["k"]]
         tickets = [lim.launch_batch(f) for f in frames]
         got = [lim.resolve(t).allowed.tolist() for t in tickets]
@@ -111,12 +124,13 @@ class TestLaunchResolve:
         assert out.remaining.dtype == np.int64
         lim.close()
 
-    def test_staging_buffers_recycle(self):
+    @on_both
+    def test_staging_buffers_recycle(self, backend):
         """Launch→resolve→launch at one batch shape reuses the SAME
         staging buffer (the per-dispatch np.zeros allocations are gone);
         overlapping launches get distinct buffers. A slot is one uint64
         buffer [ids(P) | n(P) | now_us(1)]."""
-        lim = _mk(limit=1000)
+        lim = _mk(limit=1000, backend=backend)
         t1 = lim.launch_batch(["a", "b"])
         assert t1.slot.dtype == np.uint64
         assert t1.slot.shape == (2 * t1.padded + 1,)
@@ -132,8 +146,9 @@ class TestLaunchResolve:
         lim.resolve(t3)
         lim.close()
 
-    def test_launch_fail_open_and_fail_closed(self):
-        lim = _mk(limit=5, fail_open=True)
+    @on_both
+    def test_launch_fail_open_and_fail_closed(self, backend):
+        lim = _mk(limit=5, fail_open=True, backend=backend)
         lim.resolve(lim.launch_batch(["warm", "up"]))   # seed the pool
         pool = sum(len(v) for v in lim._staging.values())
         lim.inject_failure()
@@ -149,7 +164,7 @@ class TestLaunchResolve:
         lim.heal()
         lim.close()
 
-        lim2 = _mk(limit=5, fail_open=False)
+        lim2 = _mk(limit=5, fail_open=False, backend=backend)
         lim2.inject_failure()
         with pytest.raises(StorageUnavailableError):
             lim2.launch_batch(["x"])
@@ -214,13 +229,14 @@ class TestLaunchResolve:
 # ------------------------------------------------------- snapshot quiesce
 
 class TestSnapshotDuringInflight:
-    def test_capture_waits_for_inflight_launches(self, tmp_path):
+    @on_both
+    def test_capture_waits_for_inflight_launches(self, backend, tmp_path):
         """capture_state while dispatches are in flight must quiesce the
         pipeline: the data dependence on the donated state chain means
         the captured arrays reflect EVERY launched step. Restoring the
         snapshot into a fresh limiter reproduces the post-launch
         counters exactly."""
-        lim = _mk(limit=10)
+        lim = _mk(limit=10, backend=backend)
         t1 = lim.launch_batch(["hot"] * 4)
         t2 = lim.launch_batch(["hot"] * 4)
         path = str(tmp_path / "mid.npz")
@@ -229,7 +245,7 @@ class TestSnapshotDuringInflight:
         assert lim.resolve(t1).allowed.tolist() == [True] * 4
         assert lim.resolve(t2).allowed.tolist() == [True] * 4
 
-        restored = _mk(limit=10)
+        restored = _mk(limit=10, backend=backend)
         restored.restore(path)
         # 8 units consumed in the snapshot: exactly 2 admits left.
         out = restored.allow_batch(["hot"] * 4)
@@ -245,12 +261,14 @@ def _run(coro):
 
 
 class TestPipelinedBatcher:
-    def test_interleaved_frames_match_oracle(self):
+    @on_both
+    def test_interleaved_frames_match_oracle(self, backend):
         """Same-key frames submitted through the pipelined micro-batcher
         (inflight=4) decide exactly like sequential single dispatches on
         a fresh limiter — coalescing and overlap change the batching, not
         the decisions."""
-        lim, oracle = _mk(limit=7), _mk(limit=7)
+        lim = _mk(limit=7, backend=backend)
+        oracle = _mk(limit=7, backend=backend)
         frames = [["hot", "a", "hot"], ["hot", "hot"], ["b", "hot"],
                   ["hot", "hot", "hot"]]
 

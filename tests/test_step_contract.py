@@ -273,3 +273,134 @@ def test_the_option_and_the_field_are_gone():
     cfg = _cfg("window")
     assert (sketch_kernels.step_statics(replace(cfg, fail_open=False))
             == sketch_kernels.step_statics(cfg))
+
+
+# ------------------------------------- the dense step keeps the contract
+#
+# The dense backend's served step (ops/dense_kernels.build_hashed_step,
+# module ``jit_dense_step``) joined the hashed lane in ISSUE 33: one
+# derivation of its statics (the rule's parameters and the directory's
+# geometry), every builder keyed on it.
+
+from ratelimiter_tpu import DenseParams  # noqa: E402
+from ratelimiter_tpu.ops import dense_kernels, directory  # noqa: E402
+
+DENSE_RULES = {"fixed": Algorithm.FIXED_WINDOW,
+               "sliding": Algorithm.SLIDING_WINDOW,
+               "bucket": Algorithm.TOKEN_BUCKET}
+DENSE_CAPACITY = 96
+
+
+def _dense_cfg(rule: str, **kw) -> Config:
+    dense = dict(capacity=DENSE_CAPACITY, lanes=8, probe_bound=5)
+    dense.update(kw.pop("dense", {}))
+    base = dict(algorithm=DENSE_RULES[rule], limit=11, window=42.0,
+                dense=DenseParams(**dense))
+    base.update(kw)
+    return Config(**base)
+
+
+def _dense_step(premix, cfg, capacity=DENSE_CAPACITY):
+    return dense_kernels.build_hashed_step(cfg, capacity, premix=premix)
+
+
+DENSE_CHANGES = {
+    "limit": dict(limit=12),
+    "window": dict(window=84.0),
+    "admission_iters": dict(max_batch_admission_iters=1),
+    "lanes": dict(dense=dict(lanes=4)),
+    "probe_bound": dict(dense=dict(probe_bound=3)),
+}
+
+
+@pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
+@pytest.mark.parametrize("rule", list(DENSE_RULES))
+def test_dense_what_no_step_reads_recompiles_nothing(rule, premix):
+    first = _dense_step(premix, _dense_cfg(rule))
+    assert _dense_step(premix, _dense_cfg(rule)) is first
+    for other in (
+            dict(fail_open=False),
+            dict(persistence=PersistenceSpec(dir="/nonexistent",
+                                             snapshot_interval=7.0)),
+            dict(sketch=SketchParams(depth=2, width=256)),
+            dict(key_prefix="another"),
+            # The table's size is the limiter's, not the config's field.
+            dict(dense=dict(capacity=4096)),
+            # A bound past the number of buckets is the number of buckets.
+            dict(dense=dict(lanes=48, probe_bound=2)),):
+        got = _dense_step(premix, _dense_cfg(rule, **other))
+        assert (got is first) == ("lanes" not in other.get("dense", {})), \
+            other
+    assert _dense_step(not premix, _dense_cfg(rule)) is not first
+
+
+@pytest.mark.parametrize("field", list(DENSE_CHANGES) + ["capacity"])
+@pytest.mark.parametrize("rule", list(DENSE_RULES))
+def test_dense_a_field_the_step_reads_gives_another_program(rule, field):
+    first = _dense_step(False, _dense_cfg(rule))
+    if field == "capacity":
+        build = partial(_dense_step, False, _dense_cfg(rule), 192)
+    else:
+        build = partial(_dense_step, False,
+                        _dense_cfg(rule, **DENSE_CHANGES[field]))
+    changed = build()
+    assert changed is not first
+    assert build() is changed
+    assert _dense_step(False, _dense_cfg(rule)) is first
+
+
+@pytest.mark.parametrize("rule", list(DENSE_RULES))
+def test_dense_statics_are_the_bodys_keywords(rule):
+    import inspect
+
+    cfg = _dense_cfg(rule)
+    kw = dense_kernels.step_statics(cfg, DENSE_CAPACITY)
+    keywords = lambda fn: {n for n, p in  # noqa: E731
+                           inspect.signature(fn).parameters.items()
+                           if p.kind is p.KEYWORD_ONLY}
+    body = dense_kernels._rule(cfg.algorithm)
+    assert set(kw) <= keywords(dense_kernels._dense_step_staged) | \
+        keywords(body)
+    assert keywords(body) <= set(kw)
+    geo = directory.geometry(DENSE_CAPACITY, 8, 5)
+    assert geo == dict(nb=12, w=8, pb=5)
+    assert {k: kw[k] for k in geo} == geo
+    assert (dense_kernels.step_statics(replace(cfg, fail_open=False),
+                                       DENSE_CAPACITY) == kw)
+
+
+@pytest.mark.parametrize("rule", list(DENSE_RULES))
+def test_dense_controls_are_keyed_on_what_they_read(rule):
+    """reclaim reads the geometry, the rule's stamp column and the
+    window (its horizon); forget and clear_rem the geometry alone."""
+    build = partial(dense_kernels.build_controls, capacity=DENSE_CAPACITY)
+    first = build(_dense_cfg(rule))
+    assert len(first) == 3
+    same = build(_dense_cfg(rule, limit=12, max_batch_admission_iters=1))
+    assert all(a is b for a, b in zip(same, first))
+    window = build(_dense_cfg(rule, window=84.0))
+    assert window[0] is not first[0]
+    assert window[1] is first[1] and window[2] is first[2]
+    assert all(a is not b for a, b in zip(
+        build(_dense_cfg(rule, dense=dict(lanes=4))), first))
+
+
+def test_the_dense_banner_names_its_capacity_and_still_matches():
+    from ratelimiter_tpu.algorithms.dense import DenseLimiter
+    from ratelimiter_tpu.serving.__main__ import (
+        _device_report,
+        build_parser,
+    )
+
+    args = build_parser().parse_args(
+        ["--backend", "dense", "--dense-capacity", "4096"])
+    assert args.dense_capacity == 4096
+    assert build_parser().parse_args([]).dense_capacity \
+        == DenseParams.capacity == 65536
+    lim = DenseLimiter(_dense_cfg("bucket"), ManualClock(T0))
+    report = _device_report(args, [lim])
+    assert report.endswith(" dense_capacity=4096")
+    m = _pattern_in(REPO / "chipbench/runner.py").search(
+        f"serving(native) x {report} http:1")
+    assert m and m["slices"] == str(jax.devices()[0].id)
+    lim.close()
