@@ -122,6 +122,35 @@ def _decay(state: State, now_us, *, rate_num: int, rate_den: int):
     return decay, acc % rate_den
 
 
+def _debt_histograms(cols, tokens, w: int):
+    """``int64[d, w]`` micro-token debt a batch adds to each cell:
+    ``H[r, c] = MICROS * sum(tokens[cols[:, r] == c])`` — what
+    ``row_histogram(cols[:, r], tokens.astype(int64) * MICROS, w)`` gives
+    for each row, equal to it in wrapping int64 arithmetic for every
+    integer ``tokens``, built from 32-bit scatter-adds only. On the TPU a
+    scatter-add costs by the index, and an int64 one many times a 32-bit
+    one (ADR-004 addendum), so whole tokens are scattered as 32-bit limbs
+    and widened over the dense slab: ``tokens = sum(limb_s << s)``, every
+    limb but the top one under ``2**bits`` with ``B * (2**bits - 1) <
+    2**31``, so no cell's limb sum (nor the sort-merge form's running
+    sum) can wrap whatever the batch holds; the top limb keeps the sign.
+    The limb count is a trace-time function of the shapes alone: two for
+    int32 ``tokens`` up to B = 32,768."""
+    B, d = cols.shape
+    bits = 31 - (B - 1).bit_length()
+    total = jnp.iinfo(tokens.dtype).bits
+    hists = None
+    for s in range(0, total, bits):
+        limb = tokens >> s
+        if s + bits < total:
+            limb = limb & ((1 << bits) - 1)
+        limb = limb.astype(jnp.int32)
+        h = jnp.stack([row_histogram(cols[:, r], limb, w)
+                       for r in range(d)]).astype(jnp.int64) << s
+        hists = h if hists is None else hists + h
+    return hists * MICROS
+
+
 def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
                  limit: int, rate_num: int, rate_den: int,
                  d: int, w: int, iters: int, tenants: int = 0,
@@ -158,7 +187,7 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
         avail = jnp.maximum(jnp.int64(0), cap - est)        # micro-tokens
         n_units = n.astype(jnp.int64) * MICROS
         sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
-        allowed, seen, consumed = admit(sid, n_units, avail, iters)
+        allowed, seen, _ = admit(sid, n_units, avail, iters)
 
     tn_hist = None
     if tenants and hier is not None:
@@ -186,7 +215,6 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
                 sid, jnp.where(allowed_casc, n_units, jnp.int64(0))),
             lambda: seen)
         allowed = allowed_casc
-        consumed = jnp.where(allowed, n_units, jnp.int64(0))
         if axis_name is not None:
             tn_hist = jax.lax.psum(tn_hist, axis_name)
         tn_out = {"tn_counts": counts + tn_hist,
@@ -204,8 +232,8 @@ def _bucket_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
         cascade_retry = None
 
     with jax.named_scope("write_back"):
-        hists = jnp.stack([row_histogram(cols[:, r], consumed, w)
-                           for r in range(d)])
+        # Under the FINAL mask (the cascade's, where it ran).
+        hists = _debt_histograms(cols, jnp.where(allowed, n, 0), w)
         if axis_name is not None:
             # Multi-chip delta merge: replicated debt, psum of increments
             # over ICI (same invariant as sketch_kernels' delta mode). The

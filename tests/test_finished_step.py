@@ -41,7 +41,7 @@ from ratelimiter_tpu.algorithms.sketch import (
     SketchLimiter,
     SketchTokenBucketLimiter,
 )
-from ratelimiter_tpu.core.clock import to_micros
+from ratelimiter_tpu.core.clock import MICROS, to_micros
 from ratelimiter_tpu.observability import tracing
 from ratelimiter_tpu.ops import (
     bucket_kernels,
@@ -51,6 +51,7 @@ from ratelimiter_tpu.ops import (
 )
 from ratelimiter_tpu.ops.hashing import split_hash, splitmix64
 from tests.parent_lookup import inline_lookup
+from tests.parent_writeback import int64_histograms
 
 T0 = 1_700_000_000.25
 ALGOS = {"windowed": Algorithm.SLIDING_WINDOW,
@@ -496,19 +497,37 @@ def test_mesh_staging_hook_gives_the_single_chip_columns(algo, premix):
 
 
 @contextlib.contextmanager
-def _inline_lookup_programs(monkeypatch):
-    """Every step built and traced inside is the parent's program: the
-    helper swapped for the inline lookup, the builders' memos empty (and
-    the real ones back afterwards)."""
-    from ratelimiter_tpu.ops import policy_kernels as pk, route_kernels
+def _parent_programs(monkeypatch, module, name, reference):
+    """Every step built and traced inside is the parent's program:
+    ``module.name`` swapped for the test-side ``reference``, the
+    builders' memos empty (and the real ones back afterwards)."""
+    from ratelimiter_tpu.ops import route_kernels
     from ratelimiter_tpu.parallel import mesh_kernels
 
     with monkeypatch.context() as m:
-        m.setattr(pk, "limit_for_rows", inline_lookup)
+        m.setattr(module, name, reference)
         for mod in (sketch_kernels, bucket_kernels, mesh_kernels):
             m.setattr(mod, "_BUILT", {})
         m.setattr(route_kernels, "_ROUTED_CACHE", {})
         yield
+
+
+def _assert_runs_equal(got, want):
+    """Two ``_mesh_run``s: every result column bit for bit (floats as
+    their 64-bit patterns) and every state leaf."""
+    (got_cols, got_leaves, _), (want_cols, want_leaves, _) = got, want
+    assert len(got_cols) == len(want_cols) == 15
+    for g, w_ in zip(got_cols, want_cols):
+        if w_ is None:
+            assert g is None
+        else:
+            assert g.dtype == w_.dtype
+            np.testing.assert_array_equal(g.view(np.uint8), w_.view(np.uint8))
+    assert len(got_leaves) == len(want_leaves)
+    for g, w_ in zip(got_leaves, want_leaves):
+        np.testing.assert_array_equal(g, w_)
+    allowed = np.concatenate(got_cols[0::5])
+    assert allowed.any() and not allowed.all()
 
 
 def _mesh_limiter(algo, kind):
@@ -529,13 +548,15 @@ def _mesh_limiter(algo, kind):
                merge=kind)
 
 
-def _mesh_run(algo, kind, overrides):
+def _mesh_run(algo, kind, overrides, heavy=0):
     """Three frames through a fresh mesh limiter: every result column and
-    every state leaf of every dispatch unit at the end."""
+    every state leaf of every dispatch unit at the end. ``heavy``: the
+    hottest key's override, and its first row asks for nearly all of it
+    at once (more than 2**22 tokens)."""
     lim = _mesh_limiter(algo, kind)
     hot = [f"hot:{i}" for i in range(4)]
     if overrides:
-        lim.set_override(hot[0], BIG)
+        lim.set_override(hot[0], heavy or BIG)
         lim.set_override(hot[1], 1)
         lim.set_override("never:seen", BIG)
     rng = np.random.default_rng(11)
@@ -549,6 +570,8 @@ def _mesh_run(algo, kind, overrides):
         if i == 2:
             ids[200] = lim._hash(["never:seen"])[0]
         ns = rng.integers(1, 3, size=403).astype(np.int64)
+        if heavy:
+            ns[0] = heavy - 90_000
         res = lim.allow_hashed(ids, ns, now=T0 + dt)
         cols += [np.asarray(getattr(res, c)) for c in
                  ("allowed", "remaining", "retry_after", "reset_at")]
@@ -573,27 +596,53 @@ def test_mesh_steps_equal_their_inline_lookup_programs(algo, kind, overrides,
     """The replicated mesh (both merges) and the routed step inside
     shard_map: the table is a replicated operand, every chip takes the
     same branch, and the answers and states are the parent program's."""
-    with _inline_lookup_programs(monkeypatch):
-        want_cols, want_leaves, _ = _mesh_run(algo, kind, overrides)
-    got_cols, got_leaves, lookups = _mesh_run(algo, kind, overrides)
-    assert len(got_cols) == len(want_cols) == 15
-    for g, w_ in zip(got_cols, want_cols):
-        if w_ is None:
-            assert g is None
-        else:
-            assert g.dtype == w_.dtype
-            np.testing.assert_array_equal(g.view(np.uint8), w_.view(np.uint8))
-    assert len(got_leaves) == len(want_leaves)
-    for g, w_ in zip(got_leaves, want_leaves):
-        np.testing.assert_array_equal(g, w_)
-    allowed = np.concatenate(got_cols[0::5])
-    assert allowed.any() and not allowed.all()
+    from ratelimiter_tpu.ops import policy_kernels as pk
+
+    with _parent_programs(monkeypatch, pk, "limit_for_rows", inline_lookup):
+        want = _mesh_run(algo, kind, overrides)
+    got = _mesh_run(algo, kind, overrides)
+    _assert_runs_equal(got, want)
+    got_cols, _, lookups = got
     if overrides:
         # The hottest key is under BIG: its first row has more left than
         # the config's whole limit.
         assert got_cols[1][0] > 3 and got_cols[4] is not None
     # One count a dispatch (a frame) with an entry, none without.
     assert lookups == (3 if overrides else 0)
+
+
+# ------------------- the bucket's write-back in 32-bit token units (PR 34)
+
+
+#: The largest override the bucket takes (its micro-tokens stay under
+#: 2**42): a row asking for nearly all of it needs the second limb at
+#: these frame sizes.
+HEAVY = 4_398_046
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
+@pytest.mark.parametrize("heavy", [0, HEAVY], ids=["light", "heavy"])
+@pytest.mark.parametrize("kind", ["gather", "delta", "collective"])
+def test_mesh_bucket_steps_equal_their_int64_write_back_programs(
+        kind, heavy, monkeypatch):
+    """The replicated mesh (delta: the psum runs on the widened int64
+    histograms, as it did) and the routed bucket step: the answers and
+    every slice's state are those of the programs that scatter-add int64
+    micro-tokens (tests/parent_writeback.py; tests/test_bucket_writeback.py
+    holds the single-device step)."""
+    with _parent_programs(monkeypatch, bucket_kernels, "_debt_histograms",
+                          int64_histograms):
+        want = _mesh_run("bucket", kind, True, heavy)
+    got = _mesh_run("bucket", kind, True, heavy)
+    _assert_runs_equal(got, want)
+    got_cols, got_leaves, _ = got
+    debt = max(int(g.max()) for g in got_leaves if g.ndim == 2)
+    if heavy:
+        # The heavy row was admitted once, and its cells hold it.
+        assert got_cols[0][0] and not got_cols[5][0]
+        assert debt >= (heavy - 90_000) * MICROS
+    else:
+        assert 0 < debt < 400 * MICROS
 
 
 @pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
