@@ -761,6 +761,13 @@ inline size_t pending_count(const Pending& p) {
   return p.hashed ? p.ids.size() : p.keys.size();
 }
 
+// RESET / METRICS / DCN_PUSH ride the decision queue as Pendings whose
+// one `n` is a negative marker: they hold no decision row (the io
+// thread enqueues them with 0 keys).
+inline bool is_control(const Pending& p) {
+  return !p.hashed && p.ns.size() == 1 && p.ns[0] < 0;
+}
+
 // The dispatch currently being decided, shared between the dispatcher
 // and the SLO watcher. Whoever flips `answered` first owns the response.
 struct InFlight {
@@ -869,6 +876,12 @@ struct Server {
   std::atomic<uint64_t> stage_complete_ns{0};
   std::atomic<uint64_t> stage_respond_ns{0};
   std::atomic<uint64_t> stage_batches{0};
+  // What coalescing adds (same dispatches as stage_batches): Pendings
+  // drained into them — a wire frame, or the part of one a dispatch
+  // took (a carved head counts once, its continuation once in the
+  // next) — and frames the dispatcher cut at the max_batch boundary.
+  std::atomic<uint64_t> stage_frames{0};
+  std::atomic<uint64_t> carved_frames{0};
   double started_at = 0.0;
 
   std::thread slo_thread;
@@ -1498,6 +1511,7 @@ void completer_main(Server* s, uint32_t shard) {
       s->stage_device_ns.fetch_add(t_v1 - t_v0);
       s->stage_complete_ns.fetch_add(mono_ns() - t_v1);
       s->stage_batches.fetch_add(1);
+      s->stage_frames.fetch_add(e.items.size());
       r.items = std::move(e.items);
       {
         std::lock_guard<std::mutex> g(s->rmx);
@@ -1752,6 +1766,7 @@ void dispatch_group(Server* s, uint32_t shard, std::vector<Pending>&& group,
   if (run_io && t_d0 >= run_io) s->stage_io_ns.fetch_add(t_d0 - run_io);
   s->stage_dispatch_ns.fetch_add(mono_ns() - t_d0);
   s->stage_batches.fetch_add(1);
+  s->stage_frames.fetch_add(group.size());
   r.items = std::move(group);
   {
     std::lock_guard<std::mutex> g(s->rmx);
@@ -1927,7 +1942,12 @@ void dispatcher_main(Server* s, uint32_t shard) {
         // may wait on OUR forward legs) in one dispatch — the shared
         // barrier would couple the forward reply to a peer's progress.
         if (!run.empty() && front.fwd != run.back().fwd) break;
-        size_t nk = pending_count(front);
+        // A control item takes none of the run's room. Counted as the
+        // row its key slot looks like, one /metrics scrape or reset
+        // left 65,535 rows for whole 4,096-id frames: that run and —
+        // under a closed loop, whose queue never empties — EVERY run
+        // after it carved a frame (PERF.md section 6, PR 35).
+        size_t nk = is_control(front) ? 0 : pending_count(front);
         size_t room = s->max_batch - run_keys;
         // Cut BEFORE crossing max_batch (never overshoot the largest
         // prewarmed pad shape). Mid-run, string Pendings cut whole
@@ -1970,6 +1990,7 @@ void dispatcher_main(Server* s, uint32_t shard) {
           // deposit (both still belong to this thread here), so
           // remaining cannot reach zero while a segment is outstanding.
           j->remaining.fetch_add(1);
+          s->carved_frames.fetch_add(1);
           Pending head{front.conn, front.req_id, front.is_batch, {}, {}};
           head.hashed = front.hashed;
           head.join = j;
@@ -2005,12 +2026,10 @@ void dispatcher_main(Server* s, uint32_t shard) {
     // point + columnar response encoding, ADR-011).
     std::vector<Pending> decisions, hashed;
     for (auto& p : run) {
-      if (!p.hashed && p.ns.size() == 1 && p.ns[0] == -1) {
-        handle_reset(s, shard, p);
-      } else if (!p.hashed && p.ns.size() == 1 && p.ns[0] == -2) {
-        handle_metrics(s, p);
-      } else if (!p.hashed && p.ns.size() == 1 && p.ns[0] == -3) {
-        handle_dcn(s, p);
+      if (is_control(p)) {
+        if (p.ns[0] == -1) handle_reset(s, shard, p);
+        else if (p.ns[0] == -2) handle_metrics(s, p);
+        else handle_dcn(s, p);  // -3
       } else if (p.hashed) {
         hashed.push_back(std::move(p));
       } else {
@@ -3223,13 +3242,15 @@ PyObject* server_stats(PyObject* self, PyObject* Py_UNUSED(ignored)) {
   // count — enough to derive mean per-stage cost without any Python
   // callback in the loop.
   PyObject* stage_ns = Py_BuildValue(
-      "{s:K,s:K,s:K,s:K,s:K,s:K}",
+      "{s:K,s:K,s:K,s:K,s:K,s:K,s:K,s:K}",
       "io", (unsigned long long)ps->s->stage_io_ns.load(),
       "dispatch", (unsigned long long)ps->s->stage_dispatch_ns.load(),
       "device", (unsigned long long)ps->s->stage_device_ns.load(),
       "complete", (unsigned long long)ps->s->stage_complete_ns.load(),
       "respond", (unsigned long long)ps->s->stage_respond_ns.load(),
-      "batches", (unsigned long long)ps->s->stage_batches.load());
+      "batches", (unsigned long long)ps->s->stage_batches.load(),
+      "frames", (unsigned long long)ps->s->stage_frames.load(),
+      "carved", (unsigned long long)ps->s->carved_frames.load());
   if (stage_ns == nullptr) {
     Py_DECREF(per_shard);
     Py_DECREF(per_quar);

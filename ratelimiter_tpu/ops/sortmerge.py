@@ -43,10 +43,16 @@ def _use_sortmerge(B: int, w: int) -> bool:
     """Static strategy choice (trace-time). Sort-merge pays two sorts of
     (w + B) — every sort carries the whole table — while direct indexing
     pays ~7-10 ns per batch element, sequential-on-TPU. Measured on v5e
-    (d=3, w=2^20, full step): direct wins 2.2x at B=64K, ties near B=256K,
-    sort-merge wins 1.7x at B=1M. Crossover is where B's serialized gather
-    cost overtakes the table-dominated sort cost, i.e. B ~ w/2. CPU/GPU
-    backends have native gather/scatter — always direct there."""
+    in July (d=3, w=2^20, full step): direct wins 2.2x at B=64K, ties
+    near B=256K, sort-merge wins 1.7x at B=1M; crossover is where B's
+    serialized gather cost overtakes the table-dominated sort cost, i.e.
+    B ~ w/2. On this round's program the direct side of that comparison
+    at B = 64K is measured (PERF.md section 5, PR 35, the served step at
+    d=3, w=2^20): 3,678 us, of which the indexed accesses are 45.7 ns a
+    row (six 32-bit gathers / scatter-adds at 7.6 ns) on 126 us of fixed
+    cost; the sort-merge side and the crossover have not been run since
+    July (no cell has B >= w/2). CPU/GPU backends have native
+    gather/scatter — always direct there."""
     return on_tpu() and B >= max(64, w // 2)
 
 

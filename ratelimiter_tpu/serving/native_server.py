@@ -1129,3 +1129,18 @@ class NativeRateLimitServer:
             "rate_limiter_door_dispatches_total",
             "Batched dispatches the native door has completed "
             "(cumulative)").set(stage["batches"])
+        # What coalescing adds, over the same dispatches: frames_total /
+        # dispatches_total is the frames a dispatch took.
+        self.registry.gauge(
+            "rate_limiter_door_frames_total",
+            "Queued requests the native door's coalescer has drained "
+            "into completed dispatches (cumulative): a wire frame, or "
+            "the part of one a dispatch took — a frame cut at the "
+            "max_batch boundary counts once in each dispatch that took "
+            "a part of it").set(stage["frames"])
+        self.registry.gauge(
+            "rate_limiter_door_carved_frames_total",
+            "Frames the native door's coalescer has cut at the max_batch "
+            "boundary (cumulative): the head filled one dispatch, the "
+            "rest opened the next, the reply still goes out as one "
+            "frame").set(stage["carved"])
