@@ -61,13 +61,12 @@ class DenseLimiter(HashedLane, RateLimiter):
                  capacity: Optional[int] = None):
         super().__init__(config, clock)
         # Import lazily so the exact backend works without JAX present.
-        from ratelimiter_tpu.ops import dense_kernels, directory
+        from ratelimiter_tpu.ops import dense_kernels
 
         self._capacity = int(capacity if capacity is not None
                              else self.config.dense.capacity)
         self._device = None
         self._window_us = to_micros(self.config.window)
-        self._result_tail = directory.TAIL_WORDS
         self._install_steps(self.config)
         self._state = dense_kernels.init_directory_state(self.config,
                                                          self._capacity)
@@ -271,6 +270,11 @@ class DenseLimiter(HashedLane, RateLimiter):
     def _step_args(self, slot: np.ndarray, padded: int) -> tuple:
         return (self._state, *self._stage_operands(slot, padded),
                 self._policy_device())
+
+    def _tail_words(self, padded: int) -> int:
+        from ratelimiter_tpu.ops import directory
+
+        return directory.TAIL_WORDS
 
     def _note_tail_locked(self, t: DispatchTicket, tails) -> None:
         lookups, probes, inserts, unplaced = (int(x) for x in tails[0])

@@ -24,7 +24,7 @@ that mixes this in provides:
 ``_step_args(slot, padded)``   the step's operands;
 ``_policy_limits(h64)``        per-row override limits for the result;
 ``_result_format()``           ``(rows, unpack)`` of its packed buffer,
-                               ``_result_tail`` its tail words;
+                               ``_tail_words(padded)`` its tail words;
 ``_note_mass_locked``, ``_note_tail_locked``   what resolve reports back.
 """
 
@@ -69,8 +69,6 @@ class HashedLane:
 
     #: Names this backend in the errors a failed dispatch raises.
     _lane_name = "sketch"
-    #: Tail words of the packed result buffer (after the rows).
-    _result_tail = 0
     #: Whether ``type(self)(config, clock=clock)`` rebuilds an equal
     #: limiter (the native door clones its dispatch shards that way).
     state_from_config = True
@@ -253,6 +251,11 @@ class HashedLane:
     def _note_mass_locked(self, admitted: int, now_us: int) -> None:
         """Resolve's report of a ticket's admitted mass (lock held)."""
 
+    def _tail_words(self, padded: int) -> int:
+        """Tail words of the packed result of a step over ``padded``
+        rows (after the rows): what the step was built with."""
+        return 0
+
     def _note_tail_locked(self, t: DispatchTicket, tails) -> None:
         """Resolve's report of the result buffer's tail words, one row a
         shard (lock held)."""
@@ -316,6 +319,7 @@ class HashedLane:
         # One shard, or under the replicated mesh placement one a chip
         # (the buffer is sharded like the batch).
         shards = fetch_count(t.outs)
+        tail = self._tail_words(t.padded)
         try:
             # block_until_ready releases the GIL while the device drains,
             # so a completer thread resolving batch k never stalls the
@@ -326,7 +330,7 @@ class HashedLane:
             # with the GIL released; the rest is NumPy on [:b].
             with tracing.span("fetch", batch=t.b, trace_id=t.trace_id):
                 (allowed, remaining, retry, reset_at), tails = self._unpack(
-                    np.asarray(t.outs), t, shards, self._result_tail)
+                    np.asarray(t.outs), t, shards, tail)
         except BaseException:
             self._retire_ticket(t, 0)
             raise
@@ -344,7 +348,7 @@ class HashedLane:
             wire_packed=wire_packed,
         )
         self._retire_ticket(t, int(t.ns[allowed].sum()), fetched=shards,
-                            tails=tails if self._result_tail else None)
+                            tails=tails if tail else None)
         t.result = res
         t.outs = None
         return res

@@ -30,7 +30,7 @@ from ratelimiter_tpu.algorithms.base import RateLimiter
 from ratelimiter_tpu.algorithms.hashed_lane import HashedLane
 from ratelimiter_tpu.core.clock import Clock, MICROS, to_micros
 from ratelimiter_tpu.core.config import Config
-from ratelimiter_tpu.core.types import BatchResult
+from ratelimiter_tpu.core.types import BatchResult, DispatchTicket
 from ratelimiter_tpu.ops.hashing import split_hash
 
 log = logging.getLogger("ratelimiter_tpu")
@@ -278,6 +278,35 @@ class SketchLimiter(HashedLane, RateLimiter):
         from ratelimiter_tpu.ops import sketch_kernels
 
         return sketch_kernels.WINDOW_ROWS, sketch_kernels.unpack_window
+
+    # The windowed step's table-access counts (table_access_stats): the
+    # tail words of the programs that access the table once a run of
+    # equal keys (ops/sortmerge._use_run_dedup), summed at resolve.
+    _access_rows = 0
+    _access_runs = 0
+
+    def _tail_words(self, padded: int) -> int:
+        from ratelimiter_tpu.ops import sketch_kernels
+
+        return sketch_kernels.step_tail_words(padded,
+                                              self.config.sketch.width)
+
+    def _note_tail_locked(self, t: DispatchTicket, tails) -> None:
+        rows, runs = (int(x) for x in tails[0])
+        self._access_rows += rows
+        self._access_runs += runs
+
+    @property
+    def table_access_stats(self) -> dict:
+        """Cumulative, always on: ``rows`` the steps that access the
+        table once a run of equal keys have decided (padded rows: what
+        the per-row body would have accessed, d gathers and d scatters
+        each) and the ``runs`` they accessed instead —
+        ``rate_limiter_sketch_rows_total`` /
+        ``rate_limiter_sketch_access_runs_total``. Both stay 0 where no
+        dispatch is large enough to carry the mechanism."""
+        with self._lock:
+            return {"rows": self._access_rows, "runs": self._access_runs}
 
     def _over_budget_locked(self, now_us: int) -> bool:
         """Prune + check the admitted-mass ledger; counts/warns once per
@@ -620,6 +649,8 @@ class SketchTokenBucketLimiter(SketchLimiter):
         from ratelimiter_tpu.ops import bucket_kernels
 
         return bucket_kernels.BUCKET_ROWS, bucket_kernels.unpack_bucket
+
+    _tail_words = HashedLane._tail_words    # the bucket's step ships none
 
     def _hier_counts(self) -> np.ndarray:
         """Bucket-backend scope counters are fixed-window: counts from a

@@ -445,6 +445,25 @@ class MetricsDecorator(LimiterDecorator):
                 "override lookup, the others skipped it")
             reg.add_collect_hook(self._collect_dispatch_counts)
 
+        # The windowed sketch's table accesses: rows decided by the steps
+        # that access the table once a run of equal keys, and the runs
+        # they accessed (the steps' own tail words, summed at resolve).
+        self._accesses = (base if hasattr(base, "table_access_stats")
+                          else None)
+        if self._accesses is not None:
+            self._access_rows_g = reg.gauge(
+                "rate_limiter_sketch_rows_total",
+                "Padded decision rows of the windowed sketch steps that "
+                "access the table once a run of equal keys (cumulative); "
+                "0 where no dispatch is large enough to carry the "
+                "mechanism")
+            self._access_runs_g = reg.gauge(
+                "rate_limiter_sketch_access_runs_total",
+                "Runs of equal keys those steps read and wrote the table "
+                "for (cumulative): d gathers and d scatters a run where "
+                "the per-row step makes them a row")
+            reg.add_collect_hook(self._collect_table_accesses)
+
         # The dense backend's device-resident key directory (ADR-027): its
         # always-on counts, the steps' own tail words summed at resolve.
         self._directory = base if hasattr(base, "directory_stats") else None
@@ -481,6 +500,11 @@ class MetricsDecorator(LimiterDecorator):
         for name, gauge in self._dir_gauges.items():
             gauge.set(float(st[name.removesuffix("_total")]),
                       shard=self._shard)
+
+    def _collect_table_accesses(self) -> None:
+        st = self._accesses.table_access_stats
+        self._access_rows_g.set(float(st["rows"]), shard=self._shard)
+        self._access_runs_g.set(float(st["runs"]), shard=self._shard)
 
     def _collect_dispatch_counts(self) -> None:
         self._fetches_g.set(float(self._fetcher.result_fetches),
@@ -533,6 +557,8 @@ class MetricsDecorator(LimiterDecorator):
             self.registry.remove_collect_hook(self._collect_router)
         if self._fetcher is not None:
             self.registry.remove_collect_hook(self._collect_dispatch_counts)
+        if self._accesses is not None:
+            self.registry.remove_collect_hook(self._collect_table_accesses)
         super().close()
 
     def _observe_envelope(self) -> None:

@@ -87,6 +87,48 @@ def _segment_exclusive_cumsum_exact_f32(x: jnp.ndarray,
     return seg.astype(x.dtype)
 
 
+def admit_sorted(nn: jnp.ndarray, av: jnp.ndarray, seg_head: jnp.ndarray,
+                 iters: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The admission itself, on rows ALREADY in slot-sorted order (batch
+    order inside a slot): ``(allowed, seen)`` in that same order.
+    ``seg_head`` marks each slot's first row. :func:`admit` is the sort,
+    this, and the sort back; a caller that has sorted the batch for a
+    purpose of its own (the windowed step's run-merged table accesses,
+    ops/sketch_kernels.py) calls this and keeps its order."""
+    zero = jnp.zeros((), nn.dtype)
+
+    def _solve(excl_cumsum):
+        allowed = jnp.ones(nn.shape, dtype=bool)
+        for _ in range(iters):
+            cons = excl_cumsum(jnp.where(allowed, nn, zero), seg_head)
+            allowed = cons + nn <= av
+        # Safety intersection: subset of the last mask, checked against that
+        # mask's own consumption -> never over-admits (module docstring).
+        cons = excl_cumsum(jnp.where(allowed, nn, zero), seg_head)
+        allowed = allowed & (cons + nn <= av)
+        # Consumption under the final mask, for consistent per-request views.
+        cons = excl_cumsum(jnp.where(allowed, nn, zero), seg_head)
+        seen = av - cons
+        return allowed, seen
+
+    if jnp.issubdtype(nn.dtype, jnp.floating):
+        # f32 exactness guard (2^24 precondition): the fast f32 cumsum is
+        # only exact while every partial sum of consumption is an exactly
+        # representable integer, i.e. total batch consumption < 2^24. The
+        # total is data-dependent, so the guard is a runtime cond, not a
+        # trace-time assert: mega-batches whose cumulative cost crosses
+        # 2^24 take the int32 limb-exact path instead of silently
+        # mis-admitting. Floating n_units must be integer-valued request
+        # counts (the sketch path's contract).
+        total = jnp.sum(nn.astype(jnp.int64))
+        return jax.lax.cond(
+            total < _F32_EXACT,
+            lambda: _solve(_segment_exclusive_cumsum),
+            lambda: _solve(_segment_exclusive_cumsum_exact_f32),
+        )
+    return _solve(_segment_exclusive_cumsum)
+
+
 def admit(
     sid: jnp.ndarray,        # int32[B] slot/segment id per request
     n_units: jnp.ndarray,    # [B] requested amount (>=0; 0 = padding)
@@ -124,38 +166,7 @@ def admit(
         [jnp.ones((1,), dtype=bool), s[1:] != s[:-1]])
 
     zero = jnp.zeros((), nn.dtype)
-
-    def _solve(excl_cumsum):
-        allowed = jnp.ones(s.shape, dtype=bool)
-        for _ in range(iters):
-            cons = excl_cumsum(jnp.where(allowed, nn, zero), seg_head)
-            allowed = cons + nn <= av
-        # Safety intersection: subset of the last mask, checked against that
-        # mask's own consumption -> never over-admits (module docstring).
-        cons = excl_cumsum(jnp.where(allowed, nn, zero), seg_head)
-        allowed = allowed & (cons + nn <= av)
-        # Consumption under the final mask, for consistent per-request views.
-        cons = excl_cumsum(jnp.where(allowed, nn, zero), seg_head)
-        seen = av - cons
-        return allowed, seen
-
-    if jnp.issubdtype(nn.dtype, jnp.floating):
-        # f32 exactness guard (2^24 precondition): the fast f32 cumsum is
-        # only exact while every partial sum of consumption is an exactly
-        # representable integer, i.e. total batch consumption < 2^24. The
-        # total is data-dependent, so the guard is a runtime cond, not a
-        # trace-time assert: mega-batches whose cumulative cost crosses
-        # 2^24 take the int32 limb-exact path instead of silently
-        # mis-admitting. Floating n_units must be integer-valued request
-        # counts (the sketch path's contract).
-        total = jnp.sum(nn.astype(jnp.int64))
-        allowed, seen = jax.lax.cond(
-            total < _F32_EXACT,
-            lambda: _solve(_segment_exclusive_cumsum),
-            lambda: _solve(_segment_exclusive_cumsum_exact_f32),
-        )
-    else:
-        allowed, seen = _solve(_segment_exclusive_cumsum)
+    allowed, seen = admit_sorted(nn, av, seg_head, iters)
 
     # Restore original order with a second sort keyed by the carried index.
     back = (orig, allowed.astype(jnp.int32), seen)

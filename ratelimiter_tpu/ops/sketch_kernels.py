@@ -43,7 +43,7 @@ virtual-time tests are exact (SURVEY.md §4.3).
 
 from __future__ import annotations
 
-from typing import Callable, Dict
+from typing import Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +53,8 @@ from ratelimiter_tpu.core.clock import to_micros
 from ratelimiter_tpu.core.config import Config
 from ratelimiter_tpu.core.errors import InvalidConfigError
 from ratelimiter_tpu.ops import ensure_x64, memoized, named, policy_kernels
-from ratelimiter_tpu.ops.segment import admit
+from ratelimiter_tpu.ops import sortmerge
+from ratelimiter_tpu.ops.segment import admit, admit_sorted
 from ratelimiter_tpu.ops.sortmerge import row_gather, row_histogram, row_histogram_max
 
 State = Dict[str, jnp.ndarray]
@@ -236,21 +237,34 @@ def _boundary_weight(state: State, p, now_us, *, sub_us: int, SW: int,
 
 
 def _estimate(state: State, cols, p, now_us, *, sub_us: int, SW: int, S: int,
-              weighted: bool = True, pre=None):
+              weighted: bool = True, pre=None, runs=None):
     """Min-over-rows window estimate at the given (B, d) columns, via
     sort-merge reads (ops/sortmerge.py — no gathers on the hot path).
     ``weighted`` adds the boundary sub-window scaled by its remaining
     overlap fraction (sliding semantics); fixed-window mode reads totals
-    alone.
+    alone. With ``runs`` (a batch cut into runs of equal keys, direct
+    indexing regime) ``cols`` is not read: the table is read once a run
+    and the estimate comes back in the runs' sorted order.
 
     Returns (est, frac, boundary): the (B,) min-estimate plus the scalar
     boundary weight and the dense (d, w) boundary slab (None when not
     weighted) so the conservative-update write path can reuse them."""
     from ratelimiter_tpu.ops.sortmerge import _use_sortmerge
 
-    d = cols.shape[1]
+    d, w = state["totals"].shape
+    if runs is not None:
+        frac, boundary = _boundary_weight(state, p, now_us, sub_us=sub_us,
+                                          SW=SW, S=S, weighted=weighted,
+                                          pre=pre)
+        rows = tuple(state["totals"][r] for r in range(d))
+        if weighted:
+            # The dense pre-combination of the per-row branch below, a
+            # row at a time.
+            rows = tuple(t_r.astype(jnp.float32)
+                         + frac * boundary[r].astype(jnp.float32)
+                         for r, t_r in enumerate(rows))
+        return _run_estimate(rows, runs, w), frac, boundary
     B = cols.shape[0]
-    w = state["totals"].shape[1]
     if weighted:
         frac, boundary = _boundary_weight(state, p, now_us, sub_us=sub_us,
                                           SW=SW, S=S, weighted=True, pre=pre)
@@ -286,6 +300,110 @@ def _estimate(state: State, cols, p, now_us, *, sub_us: int, SW: int, S: int,
     return jnp.maximum(est, 0.0), frac, boundary  # (B,), scalar, (d, w)|None
 
 
+# ------------------------------------- one table access a run of equal keys
+#
+# On the programs ``sortmerge._use_run_dedup`` names, the step sorts the
+# batch by h1 FIRST (the sort ``admit`` would make), cuts the sorted rows
+# into runs of equal (h1, h2) and makes its d gathers and d scatters once
+# per run, over the compacted list of run heads, in chunks of
+# ``sortmerge.run_chunk(B)`` with a data-dependent trip count: what the
+# table accesses cost follows the runs a dispatch holds, not its padded
+# rows (ops/sortmerge.py has the plumbing and the numbers). Nothing is
+# approximate: a run's rows read the same d cells, a scatter-max keeps
+# the run's maximum and an integer scatter-add the run's sum, so every
+# decision and every state cell is the per-row body's, bit for bit
+# (tests/test_run_dedup.py).
+
+
+class _Runs(NamedTuple):
+    """A batch in h1-sorted order (stable: batch order inside an h1
+    segment), cut into runs of equal (h1, h2)."""
+    h1: jnp.ndarray        # uint32[B], sorted
+    h2: jnp.ndarray        # uint32[B], carried
+    n: jnp.ndarray         # int32[B], carried
+    orig: jnp.ndarray      # int32[B]: the row's index in the batch
+    seg_head: jnp.ndarray  # bool[B]: first row of its h1 segment (admit's)
+    head: jnp.ndarray      # bool[B]: first row of its (h1, h2) run
+    count: jnp.ndarray     # int32[]: runs in the batch
+    key: jnp.ndarray       # int32[B]: sortmerge.heads_first's key
+    h1c: jnp.ndarray       # uint32[Bp]: the run heads' halves first, in
+    h2c: jnp.ndarray       # sorted order, zero-padded to whole chunks
+    chunk: int             # static: heads an access loop visits at a time
+
+
+def _chunk_pad(x, C: int):
+    return jnp.pad(x, (0, -x.shape[0] % C))
+
+
+@jax.named_scope("run_sort")
+def _sorted_runs(h1, h2, n) -> _Runs:
+    B = h1.shape[0]
+    sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
+    s_sid, s_h2, s_n, orig = jax.lax.sort(
+        (sid, h2, n, jax.lax.iota(jnp.int32, B)), num_keys=1, is_stable=True)
+    s_h1 = jax.lax.bitcast_convert_type(s_sid, jnp.uint32)
+    seg_head = jnp.concatenate(
+        [jnp.ones((1,), dtype=bool), s_sid[1:] != s_sid[:-1]])
+    head = sortmerge.run_heads(seg_head, s_h2)
+    key, h1c, h2c = sortmerge.heads_first(head, s_h1, s_h2)
+    C = sortmerge.run_chunk(B)
+    return _Runs(s_h1, s_h2, s_n, orig, seg_head, head,
+                 jnp.sum(head, dtype=jnp.int32), key,
+                 _chunk_pad(h1c, C), _chunk_pad(h2c, C), C)
+
+
+def _run_chunks(runs: _Runs, d: int, w: int, carry, visit):
+    """``carry`` after ``visit(carry, cols, at, start)`` over every chunk
+    of run heads that holds one: ``cols`` the chunk's (C, d) columns,
+    ``at(x)`` the chunk's slice of a compact array, ``start`` its offset.
+    ceil(count / C) iterations."""
+    C = runs.chunk
+
+    def body(k, carry):
+        at = lambda x: jax.lax.dynamic_slice_in_dim(x, k * C, C)
+        return visit(carry, _columns(at(runs.h1c), at(runs.h2c), d, w),
+                     at, k * C)
+
+    return jax.lax.fori_loop(0, (runs.count + (C - 1)) // C, body, carry)
+
+
+@jax.named_scope("run_gather")
+def _run_estimate(rows, runs: _Runs, w: int):
+    """f32[B], sorted order: min over the d ``rows`` of ``row[col_r]``,
+    read once per run and spread to the run's rows. ``rows`` are d
+    separate (w,) arrays: a row sliced off a (d, w) table inside the loop
+    is a copy of the row every iteration (22 us each at w = 2**20)."""
+    d = len(rows)
+
+    def visit(est_c, cols, at, start):
+        est = None
+        for r, row in enumerate(rows):
+            e_r = row[cols[:, r]].astype(jnp.float32)
+            est = e_r if est is None else jnp.minimum(est, e_r)
+        return jax.lax.dynamic_update_slice_in_dim(est_c, est, start, 0)
+
+    est_c = _run_chunks(runs, d, w,
+                        jnp.zeros(runs.h1c.shape, jnp.float32), visit)
+    return sortmerge.spread_heads(runs.key, jnp.maximum(est_c, 0.0),
+                                  runs.count)
+
+
+@jax.named_scope("run_scatter")
+def _run_write(vals_c, runs: _Runs, d: int, w: int, how: str):
+    """d dense (w,) rows: ``zeros.at[col_r].max`` (or ``.add``) of one
+    value a run — ``vals_c`` in heads-first order, neutral (zero) from
+    ``runs.count`` on."""
+    vals_c = _chunk_pad(vals_c, runs.chunk)
+
+    def visit(rows, cols, at, _start):
+        v = at(vals_c)
+        return tuple(getattr(row.at[cols[:, r]], how)(v)
+                     for r, row in enumerate(rows))
+
+    zeros = tuple(jnp.zeros((w,), vals_c.dtype) for _ in range(d))
+    return _run_chunks(runs, d, w, zeros, visit)
+
+
 def _hh_boundary_slab(state: State, p, *, SW: int, S: int):
     """The side table's boundary sub-window column vector (K,). Validity is
     carried by ``frac`` (0 when the boundary period is absent), exactly as
@@ -299,18 +417,44 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
                  limit: int, sub_us: int, SW: int, S: int, d: int, w: int,
                  iters: int, weighted: bool, conservative: bool,
                  hh: int = 0, hh_thresh: float = 0.0, tenants: int = 0,
-                 axis_name: str | None = None, pre=None, pre_hh=None):
+                 axis_name: str | None = None, pre=None, pre_hh=None,
+                 counted: bool = False):
+    """One batch against the windowed sketch: ``(state, (allowed,
+    remaining, est))`` in batch order. ``counted`` adds a third element,
+    the table-access runs of this batch (int32 scalar) on the programs
+    that access the table once a run, None on the others."""
     # Precondition (host-enforced via _sync_period): state.last_period is
     # the period of now_us. Clamp defends against clock skew backwards —
     # the reference has the same NTP caveat (``docs/ALGORITHMS.md:162``).
     now_us = jnp.maximum(now_us, state["last_period"] * sub_us)
     p = state["last_period"]
 
+    # One table access a run of equal keys (section above): the batch is
+    # sorted here, once, for the accesses AND the admission, and the body
+    # below runs on the sorted rows — everything per row in it is either
+    # elementwise or an order-free scatter — until its three results are
+    # sorted back. The cascade sequences rows of DIFFERENT keys in batch
+    # order, so with it the body keeps the batch's order and only the
+    # accesses take the sorted one (two more sorts; no cell enables it).
+    runs = in_batch_order = to_sorted = None
+    if sortmerge._use_run_dedup(h1.shape[0], w):
+        runs = _sorted_runs(h1, h2, n)
+        in_batch_order = bool(tenants) and hier is not None
+        if not in_batch_order:
+            h1, h2, n = runs.h1, runs.h2, runs.n
+
     with jax.named_scope("estimate"):
-        cols = _columns(h1, h2, d, w)                            # (B, d)
+        cols = None if runs is not None else _columns(h1, h2, d, w)  # (B, d)
         est, frac, boundary = _estimate(state, cols, p, now_us,
                                         sub_us=sub_us, SW=SW, S=S,
-                                        weighted=weighted, pre=pre)
+                                        weighted=weighted, pre=pre,
+                                        runs=runs)
+        if in_batch_order:
+            _, est, rank = jax.lax.sort(
+                (runs.orig, est, jax.lax.iota(jnp.int32, est.shape[0])),
+                num_keys=1, is_stable=False)
+            to_sorted = lambda x: jax.lax.sort(
+                (rank, x), num_keys=1, is_stable=False)[1]
 
     if hh:
         # Heavy-hitter side table (ROADMAP v0.2): a promoted key's NEW
@@ -354,8 +498,11 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
             lim_f = jnp.float32(limit)
         avail = jnp.maximum(lim_f - est, 0.0)
         n_f = n.astype(jnp.float32)
-        sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
-        allowed, seen, _ = admit(sid, n_f, avail, iters)
+        if runs is not None and not in_batch_order:
+            allowed, seen = admit_sorted(n_f, avail, runs.seg_head, iters)
+        else:
+            sid = jax.lax.bitcast_convert_type(h1, jnp.int32)
+            allowed, seen, _ = admit(sid, n_f, avail, iters)
 
     tn_hist = None
     if tenants and hier is not None:
@@ -415,9 +562,16 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
             # can undercount rows whose dense read exceeds the min-estimate —
             # both break the never-over-admit direction. Vanilla sums never do.
             target = jnp.where(allowed & not_mine, est + (avail - seen) + n_f, 0.0)
+            if runs is not None:
+                if in_batch_order:
+                    target = to_sorted(target)
+                maxima = _run_write(
+                    sortmerge.run_maxima_first(target, runs.head, runs.count),
+                    runs, d, w, "max")
             deltas = []
             for r in range(d):
-                m_r = row_histogram_max(cols[:, r], target, w)
+                m_r = (maxima[r] if runs is not None
+                       else row_histogram_max(cols[:, r], target, w))
                 read_r = state["totals"][r].astype(jnp.float32)
                 if boundary is not None:
                     read_r = read_r + frac * boundary[r].astype(jnp.float32)
@@ -427,8 +581,15 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
             cur = state["cur"] + hists
         else:
             add = jnp.where(allowed & not_mine, n, 0).astype(jnp.int32)  # (B,)
-            hists = jnp.stack([row_histogram(cols[:, r], add, w)
-                               for r in range(d)])
+            if runs is not None:
+                if in_batch_order:
+                    add = to_sorted(add)
+                hists = jnp.stack(_run_write(
+                    sortmerge.run_sums_first(add, runs.head, runs.count),
+                    runs, d, w, "add"))
+            else:
+                hists = jnp.stack([row_histogram(cols[:, r], add, w)
+                                   for r in range(d)])
             if axis_name is not None:
                 # Multi-chip delta merge: every chip adds the summed
                 # histogram, keeping the replicated-state invariant (ICI
@@ -506,7 +667,16 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
 
     remaining = jnp.maximum(
         jnp.floor(seen - jnp.where(allowed, n_f, 0.0)), 0.0).astype(jnp.int32)
-    return new_state, (allowed, remaining, est)
+    if runs is not None and not in_batch_order:
+        with jax.named_scope("run_unsort"):
+            _, allowed, remaining, est = jax.lax.sort(
+                (runs.orig, allowed.astype(jnp.int32), remaining, est),
+                num_keys=1, is_stable=False)
+            allowed = allowed.astype(bool)
+    outs = (allowed, remaining, est)
+    if counted:
+        return new_state, outs, None if runs is None else runs.count
+    return new_state, outs
 
 
 def _sketch_reset(state: State, h1, h2, now_us, *,
@@ -582,6 +752,18 @@ def _sketch_reset(state: State, h1, h2, now_us, *,
 
 #: Rows of the windowed rules' packed result: allowed, remaining.
 WINDOW_ROWS = 2
+
+#: Tail words of the serving step's packed result on the programs that
+#: access the table once a run of equal keys (sortmerge._use_run_dedup):
+#: the padded rows the step decided — the accesses a table row the
+#: per-row body makes — and the runs it accessed instead.
+RUN_TAIL_WORDS = 2
+
+
+def step_tail_words(padded: int, width: int) -> int:
+    """Tail words of ``build_hashed_step``'s result for a padded batch:
+    read off the same static predicate the step's body reads."""
+    return RUN_TAIL_WORDS if sortmerge._use_run_dedup(padded, width) else 0
 
 
 def pack_rows(*rows):
@@ -805,10 +987,16 @@ def _sketch_step_staged(state: State, staged, policy=None, hier=None, *,
                         seed: int, premix: bool, **step_kw):
     h64, n, now_us = unstage(staged)
     h1, h2 = split_staged(h64, premix, seed)
-    state, (allowed, remaining, _est) = _sketch_step(
-        state, h1, h2, n, now_us, policy, hier, **step_kw)
+    state, (allowed, remaining, _est), run_count = _sketch_step(
+        state, h1, h2, n, now_us, policy, hier, counted=True, **step_kw)
     with jax.named_scope("finish"):
-        return state, pack_window(allowed, remaining)
+        packed = pack_window(allowed, remaining)
+        if run_count is None:
+            return state, packed
+        # The access counts ride home in the one buffer (RUN_TAIL_WORDS):
+        # no second fetch.
+        return state, jnp.concatenate([packed, jnp.stack(
+            [jnp.int32(h1.shape[0]), run_count])])
 
 
 def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:

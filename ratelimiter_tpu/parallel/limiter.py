@@ -32,7 +32,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from ratelimiter_tpu.algorithms.base import RateLimiter
-from ratelimiter_tpu.algorithms.hashed_lane import _pad_size
+from ratelimiter_tpu.algorithms.hashed_lane import HashedLane, _pad_size
 from ratelimiter_tpu.algorithms.sketch import (
     SketchLimiter,
     SketchTokenBucketLimiter,
@@ -101,6 +101,9 @@ class _MeshPlacement:
     def _padded_size(self, b: int) -> int:
         per_chip = _pad_size(max(1, -(-b // self.n_chips)))
         return per_chip * self.n_chips
+
+    #: The shard_map'd step packs its shard's rows and no tail.
+    _tail_words = HashedLane._tail_words
 
     def _stage_operands(self, buf: np.ndarray, padded: int) -> tuple:
         # The slot's three views, placed explicitly in one call: ids and
@@ -791,6 +794,13 @@ class SlicedMeshLimiter(RateLimiter):
         """Device buffers the slices' resolves have fetched
         (SketchLimiter.result_fetches), summed."""
         return sum(s.result_fetches for s in self.slices)
+
+    @property
+    def table_access_stats(self) -> dict:
+        """The slices' own table-access counts
+        (SketchLimiter.table_access_stats), summed."""
+        stats = [s.table_access_stats for s in self.slices]
+        return {k: sum(st[k] for st in stats) for k in ("rows", "runs")}
 
     @property
     def override_lookup_dispatches(self) -> int:

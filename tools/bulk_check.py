@@ -44,10 +44,24 @@ them); one fetch a dispatch; no dispatch answered by policy.
 
 The last stdout line is one JSON object with the counts; exit 0 when
 every bound holds, 1 when one does not, 3 for a passed rehearsal.
+
+The column check of two checkouts on these same runs (one process a side:
+a chip belongs to one process at a time):
+
+    python3 tools/bulk_check.py --repo PARENT --dump a.npz
+    python3 tools/bulk_check.py --dump b.npz
+    python3 tools/bulk_check.py --compare a.npz b.npz
+
+``--repo`` imports ``ratelimiter_tpu`` from another checkout (this file
+and the reference stay this checkout's); ``--dump`` writes every
+dispatch's four result columns and, after every instant, a digest of
+every state leaf; ``--compare`` counts the columns and digests that
+differ and exits 0 when none does.
 """
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import os
@@ -132,10 +146,11 @@ def make_limiter(geo: dict):
     return create_limiter(cfg, backend="sketch", clock=ManualClock(T0))
 
 
-def run_instant(lim, runs: list, now: float, tally) -> int:
+def run_instant(lim, runs: list, now: float, tally, dump=None) -> int:
     """Launch every (lane, ids) of ``runs`` at ``now`` before resolving
     any — at most INFLIGHT tickets in flight — then resolve in order and
-    hold each against the plain rule. Returns rows answered by policy."""
+    hold each against the plain rule. Returns rows answered by policy.
+    ``dump`` (a dict) is given every result column."""
     from ratelimiter_tpu.ops.hashing import splitmix64
 
     policy = 0
@@ -150,7 +165,37 @@ def run_instant(lim, runs: list, now: float, tally) -> int:
             policy += int(res.fail_open) * h.size
             tally.frames += 1
             cc.against_reference(h, res, now, tally)
+            if dump is not None:
+                for name in cc.COLUMNS:
+                    dump[f"col.{tally.frames:03d}.{name}"] = np.asarray(
+                        getattr(res, name))
     return policy
+
+
+def state_digests(lim, tag: str, dump: dict) -> None:
+    with lim._lock:
+        leaves = dict(lim._state)
+    for leaf, v in sorted(leaves.items()):
+        dump[f"leaf.{tag}.{leaf}"] = np.frombuffer(
+            hashlib.sha256(np.asarray(v).tobytes()).digest(), np.uint8)
+
+
+def compare(a_path: str, b_path: str) -> int:
+    a, b = np.load(a_path), np.load(b_path)
+    names = sorted(set(a.files) | set(b.files))
+    bad = [k for k in names if k not in a.files or k not in b.files
+           or not np.array_equal(a[k], b[k])]
+    cols = [k for k in names if k.startswith("col.")]
+    leaves = [k for k in names if k.startswith("leaf.")]
+    print(json.dumps({
+        "result_columns": len(cols),
+        "result_columns_differing": sum(k.startswith("col.") for k in bad),
+        "state_digests": len(leaves),
+        "state_digests_differing": sum(k.startswith("leaf.") for k in bad),
+        "decisions": int(sum(a[k].size for k in cols
+                             if k.endswith(".allowed") and k in a.files)),
+        "first_differing": bad[:5]}))
+    return 1 if bad else 0
 
 
 def main(argv=None) -> int:
@@ -159,7 +204,17 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--published", action="store_true",
                     help="rehearse at the published widths on the CPU")
+    ap.add_argument("--repo", help="import ratelimiter_tpu from this "
+                                   "checkout instead of this file's")
+    ap.add_argument("--dump", metavar="NPZ",
+                    help="write every result column and state digest")
+    ap.add_argument("--compare", nargs=2, metavar="NPZ")
     args = ap.parse_args(argv)
+    if args.compare:
+        return compare(*args.compare)
+    if args.repo:
+        sys.path.insert(0, os.path.abspath(args.repo))
+    dump = {} if args.dump else None
     import jax
 
     from ratelimiter_tpu.core import jaxcfg
@@ -184,8 +239,11 @@ def main(argv=None) -> int:
                 carved += n
                 runs += [(lane, ids) for ids in lane_runs]
             sizes |= {int(ids.size) for _, ids in runs}
-            policy += run_instant(lim, runs, T0 + dt, tally)
+            policy += run_instant(lim, runs, T0 + dt, tally, dump)
+            if dump is not None:
+                state_digests(lim, f"{dt:.3f}", dump)
         fetches = lim.result_fetches
+        accesses = getattr(lim, "table_access_stats", None)
     finally:
         lim.close()
     out = tally.as_dict()
@@ -195,7 +253,10 @@ def main(argv=None) -> int:
     denials_pct = 100.0 * out["ref_allowed_denials"] / out["decisions"]
     ok = (out["over_admitted"] == 0 and collisions_pct <= 1.0
           and fetches == dispatches and policy == 0)
+    if dump is not None:
+        np.savez(args.dump, **dump)
     print(json.dumps({"ok": ok, "rehearsal": rehearsal, "device": device,
+                      "table_accesses": accesses,
                       "geometry": geo, "run_rows": sorted(sizes),
                       "dispatches": dispatches, "fetches": fetches,
                       "tickets_in_flight": INFLIGHT,

@@ -15,6 +15,14 @@ program plus ``index.json`` (name -> jit module name, sha256). It reads
 only the limiter's placement hooks and ``_step`` / ``_reset_step`` /
 ``_rollover``, which every checkout since PR 26 has.
 
+``--batch N`` lowers the steps of an N-row dispatch instead of the
+rehearsal's 256, ``--published`` at the configurations' published widths,
+and ``--as-tpu`` with the strategy predicates of ops/sortmerge.py
+answering as they do on the chip (``on_tpu`` patched in that module
+only: the text is still this backend's StableHLO, which is what differs
+between two checkouts when a predicate picks another body) — together,
+the programs a large dispatch launches on the chip (ISSUE 36).
+
 The second form exits 0 when the two directories hold the same programs
 with the same texts and module names, 1 (naming each difference) when
 not. A refactor of the step's builders that claims "no compiled program
@@ -33,6 +41,7 @@ import sys
 from pathlib import Path
 
 B = 256  # the rehearsal's --max-batch: one padded frame
+PUBLISHED = False
 
 
 def _configs(repo: Path):
@@ -40,7 +49,8 @@ def _configs(repo: Path):
 
     for path in sorted((repo / "chipbench" / "configs").glob("*.json")):
         c = json.loads(path.read_text())
-        c.update(c.get("rehearsal", {}))
+        if not PUBLISHED:
+            c.update(c.get("rehearsal", {}))
         flags = c["server_flags"]
         router = (flags[flags.index("--router") + 1]
                   if "--router" in flags else "host")
@@ -121,7 +131,7 @@ def _programs(repo: Path):
                        step, args)
 
 
-def write(repo: Path, out: Path) -> int:
+def write(repo: Path, out: Path, as_tpu: bool = False) -> int:
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=4")
@@ -129,6 +139,10 @@ def write(repo: Path, out: Path) -> int:
     from ratelimiter_tpu.core import jaxcfg
 
     jaxcfg.configure()
+    if as_tpu:
+        from ratelimiter_tpu.ops import sortmerge
+
+        sortmerge.on_tpu = lambda: True
     out.mkdir(parents=True, exist_ok=True)
     index = {}
     for name, fn, args in _programs(repo):
@@ -165,17 +179,25 @@ def compare(a: Path, b: Path) -> int:
 
 
 def main() -> int:
+    global B, PUBLISHED
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--repo", type=Path,
                     default=Path(__file__).resolve().parent.parent)
     ap.add_argument("--out", type=Path)
     ap.add_argument("--compare", nargs=2, type=Path, metavar="DIR")
+    ap.add_argument("--batch", type=int, default=B,
+                    help="rows of the dispatch whose steps are lowered")
+    ap.add_argument("--published", action="store_true",
+                    help="the configurations' published widths")
+    ap.add_argument("--as-tpu", action="store_true",
+                    help="ops/sortmerge.py's predicates answer as on a TPU")
     args = ap.parse_args()
     if args.compare:
         return compare(*args.compare)
     if args.out is None:
         ap.error("--out or --compare")
-    return write(args.repo.resolve(), args.out)
+    B, PUBLISHED = args.batch, args.published
+    return write(args.repo.resolve(), args.out, args.as_tpu)
 
 
 if __name__ == "__main__":
