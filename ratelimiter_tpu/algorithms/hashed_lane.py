@@ -236,7 +236,10 @@ class HashedLane:
             t.t_sec = t_sec
             t.slot = slot
             t.padded = padded
-            return t
+        # Where "prep" opened and "finish" closed: a door that stamps
+        # its own callback records what lies around them.
+        t.t_lane = (sp.t_open, sp.t_close)
+        return t
 
     def _fence_dispatch(self, outs) -> None:
         """Complete a just-launched step before the dispatch lock drops.
@@ -325,12 +328,16 @@ class HashedLane:
             # so a completer thread resolving batch k never stalls the
             # thread launching batch k+1.
             t.outs.block_until_ready()
-            # "fetch": device ready -> NumPy columns built. ONE buffer
+            # "fetch": device ready -> np.asarray returned. ONE buffer
             # (a shard per device) comes over in one call that blocks
-            # with the GIL released; the rest is NumPy on [:b].
-            with tracing.span("fetch", batch=t.b, trace_id=t.trace_id):
+            # with the GIL released and then waits to get it back;
+            # "unpack": the rest, NumPy on [:b].
+            with tracing.span("fetch", batch=t.b,
+                              trace_id=t.trace_id) as sp:
+                words = np.asarray(t.outs)
+                sp.next("unpack")
                 (allowed, remaining, retry, reset_at), tails = self._unpack(
-                    np.asarray(t.outs), t, shards, tail)
+                    words, t, shards, tail)
         except BaseException:
             self._retire_ticket(t, 0)
             raise

@@ -205,7 +205,8 @@ class DispatchTicket:
 
     __slots__ = ("outs", "b", "limit", "limits", "ns", "now_us",
                  "window_us", "t_sec", "slot", "padded", "result", "meta",
-                 "wire", "trace_id", "audit", "t_door", "unplaced")
+                 "wire", "trace_id", "audit", "t_door", "t_lane",
+                 "unplaced")
 
     def __init__(self, result: "BatchResult | None" = None):
         self.outs = None        # the step's own output, on device: ONE
@@ -232,11 +233,16 @@ class DispatchTicket:
         #                         0 = unsampled. Set by the serving doors
         #                         at launch so resolve-side spans (incl.
         #                         mesh per-slice spans) link to the frame.
-        self.t_door = None      # recorder on: (enter, leave) monotonic ns
-        #                         of the native door's launch callback;
-        #                         the completer's spans callback records
-        #                         the "enter" and "leave" stages from
-        #                         them (ADR-014 addendum)
+        self.t_door = None      # recorder on: (enter, descend, leave)
+        #                         monotonic ns of the native door's
+        #                         launch callback; the completer's spans
+        #                         callback records the "enter" and
+        #                         "leave" stages from them (ADR-014
+        #                         addendum)
+        self.t_lane = None      # the lane's launch: (first, last) stamp
+        #                         of its prep ... finish span, (0, 0)
+        #                         with the recorder off; "descend" and
+        #                         "ascend" are what t_door holds around it
         self.unplaced = 0       # dense backend: rows whose key found no
         #                         directory entry (the step's tail word),
         #                         answered by the fail-open/closed policy

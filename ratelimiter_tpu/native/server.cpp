@@ -78,6 +78,7 @@
 #include <netinet/in.h>
 #include <netinet/tcp.h>
 #include <poll.h>
+#include <pthread.h>
 #include <sys/epoll.h>
 #include <sys/eventfd.h>
 #include <sys/mman.h>
@@ -86,6 +87,7 @@
 #include <sys/syscall.h>
 #include <sys/uio.h>
 #include <sys/un.h>
+#include <time.h>
 #include <unistd.h>
 
 #include "shm_ring.h"
@@ -166,6 +168,151 @@ inline uint64_t mono_ns() {
   clock_gettime(CLOCK_MONOTONIC, &ts);
   return (uint64_t)ts.tv_sec * 1000000000ull + (uint64_t)ts.tv_nsec;
 }
+
+// Thread-state books (ADR-014 addendum, "the threads themselves"): what
+// a dispatcher or completer thread is doing, as wall time per state,
+// always on. The states of a thread TILE its loop: every instant between
+// the thread's start and now is in exactly one, so the states' sum is
+// the thread's wall and what is not waiting is read off directly.
+// A transition is one clock read and four plain stores: the sum of the
+// state being left and the packed word (stamp << 4 | new state), inside
+// a sequence lock. The packed word is what lets stats() add the state
+// in progress (a dispatcher parked ten seconds on an empty queue is ten
+// seconds of idle NOW, not at its next wake-up). Only the owning thread
+// writes its book.
+enum ThreadState : uint32_t {
+  TS_IDLE = 0,    // nothing it may drain / nothing in flight
+  TS_GATHER,      // dispatcher: drain stamp -> PyGILState_Ensure called
+  TS_GIL,         // inside PyGILState_Ensure
+  TS_PYTHON,      // Ensure returned -> PyGILState_Release returned
+  TS_SLOT,        // dispatcher: inside cv_space.wait (window full)
+  TS_OTHER,       // the rest of the loop, so that the sum is the wall
+  TS_COUNT
+};
+
+struct ThreadBook {
+  std::atomic<uint64_t> ns[TS_COUNT]{};
+  std::atomic<uint64_t> cur{0};  // (state-entry stamp << 4) | state; 0 = not running
+  // Sequence lock over (ns, cur): odd while the owner is between its
+  // add and its store, so a reader never pairs a sum that already
+  // holds a segment with the stamp that segment started at.
+  std::atomic<uint64_t> seq{0};
+
+  void publish(uint64_t add_to, uint64_t add_ns, uint64_t next_cur) {
+    uint64_t v = seq.load(std::memory_order_relaxed);
+    seq.store(v + 1, std::memory_order_relaxed);
+    std::atomic_thread_fence(std::memory_order_release);
+    ns[add_to].store(ns[add_to].load(std::memory_order_relaxed) + add_ns,
+                     std::memory_order_relaxed);
+    cur.store(next_cur, std::memory_order_relaxed);
+    seq.store(v + 2, std::memory_order_release);
+  }
+  void begin() { publish(TS_OTHER, 0, (mono_ns() << 4) | TS_OTHER); }
+  // Enter `next` at `now` (a stamp the caller already took); `last`
+  // closes the state in progress for good (the thread is leaving).
+  void at(ThreadState next, uint64_t now, bool last = false) {
+    uint64_t c = cur.load(std::memory_order_relaxed);
+    uint64_t since = c >> 4;
+    if (now < since) now = since;  // never run the tiling backwards
+    publish(c & 15, now - since, last ? 0 : (now << 4) | next);
+  }
+  void to(ThreadState next) { at(next, mono_ns()); }
+  void end() { at(TS_OTHER, mono_ns(), true); }
+  // A consistent reading: the sums plus the state in progress up to
+  // `now`. Retries while the owner is inside publish().
+  void read(uint64_t out[TS_COUNT], uint64_t now) const {
+    for (;;) {
+      uint64_t s0 = seq.load(std::memory_order_acquire);
+      for (int i = 0; i < TS_COUNT; ++i)
+        out[i] = ns[i].load(std::memory_order_relaxed);
+      uint64_t c = cur.load(std::memory_order_relaxed);
+      std::atomic_thread_fence(std::memory_order_acquire);
+      if ((s0 & 1) || seq.load(std::memory_order_relaxed) != s0) continue;
+      if (c != 0 && now > (c >> 4)) out[c & 15] += now - (c >> 4);
+      return;
+    }
+  }
+};
+
+// The calling thread's book; null on every thread that keeps none (io,
+// responder, SLO watcher, Python's own), where the calls below do
+// nothing.
+thread_local ThreadBook* tl_book = nullptr;
+// A dispatcher's or completer's main keeps one of these on its stack.
+struct BookScope {
+  explicit BookScope(ThreadBook* b) { tl_book = b; b->begin(); }
+  ~BookScope() { tl_book->end(); tl_book = nullptr; }
+  BookScope(const BookScope&) = delete;
+  BookScope& operator=(const BookScope&) = delete;
+};
+inline void book_to(ThreadState st) { if (tl_book) tl_book->to(st); }
+inline void book_at(ThreadState st, uint64_t now) { if (tl_book) tl_book->at(st, now); }
+
+// The ONE way a door thread takes the interpreter: the wait inside
+// PyGILState_Ensure is the thread's `gil` state, everything up to
+// PyGILState_Release returning is `python`, then `other`.
+struct GilHold {
+  PyGILState_STATE g;
+  GilHold() {
+    book_to(TS_GIL);
+    g = PyGILState_Ensure();
+    book_to(TS_PYTHON);
+  }
+  ~GilHold() {
+    PyGILState_Release(g);
+    book_to(TS_OTHER);
+  }
+  GilHold(const GilHold&) = delete;
+  GilHold& operator=(const GilHold&) = delete;
+};
+
+// CPU clocks of the door's threads, by role. A thread notes its clock
+// when it starts (pthread_getcpuclockid) and stats() reads the clocks
+// at scrape: nothing on a hot path. A thread that exits leaves its last
+// reading in `done_ns` (its clock id dies with it).
+enum ThreadRole : uint32_t { TR_IO = 0, TR_DISPATCHER, TR_COMPLETER, TR_RESPONDER, TR_COUNT };
+
+struct CpuClocks {
+  std::mutex mx;
+  std::vector<clockid_t> live[TR_COUNT];
+  uint64_t done_ns[TR_COUNT] = {0, 0, 0, 0};
+
+  static uint64_t read_clock(clockid_t cid) {
+    struct timespec ts;
+    if (clock_gettime(cid, &ts) != 0) return 0;
+    return (uint64_t)ts.tv_sec * 1000000000ull + (uint64_t)ts.tv_nsec;
+  }
+  void total(uint64_t out[TR_COUNT]) {
+    std::lock_guard<std::mutex> g(mx);
+    for (int r = 0; r < TR_COUNT; ++r) {
+      out[r] = done_ns[r];
+      for (clockid_t cid : live[r]) out[r] += read_clock(cid);
+    }
+  }
+  // Registers the calling thread for its lifetime (RAII on its stack).
+  struct Member {
+    CpuClocks* c;
+    ThreadRole role;
+    clockid_t cid{};
+    bool ok = false;
+    Member(CpuClocks* c_, ThreadRole role_) : c(c_), role(role_) {
+      ok = pthread_getcpuclockid(pthread_self(), &cid) == 0;
+      if (!ok) return;
+      std::lock_guard<std::mutex> g(c->mx);
+      c->live[role].push_back(cid);
+    }
+    ~Member() {
+      if (!ok) return;
+      std::lock_guard<std::mutex> g(c->mx);
+      auto& v = c->live[role];
+      for (size_t i = 0; i < v.size(); ++i)
+        if (v[i] == cid) { v.erase(v.begin() + i); break; }
+      c->done_ns[role] += read_clock(cid);
+    }
+    Member(const Member&) = delete;
+    Member& operator=(const Member&) = delete;
+  };
+};
 
 // Keys are UTF-8 strings at the protocol level (the asyncio server
 // decodes them and rejects invalid byte sequences); validate here so
@@ -882,6 +1029,13 @@ struct Server {
   // next) — and frames the dispatcher cut at the max_batch boundary.
   std::atomic<uint64_t> stage_frames{0};
   std::atomic<uint64_t> carved_frames{0};
+  // Thread-state seconds and thread CPU clocks (see ThreadBook): one
+  // book a dispatcher and a completer thread, made at start(), summed
+  // over dispatch units by stats()["thread_ns"]; stats()["thread_cpu_ns"]
+  // reads the four roles' clocks.
+  std::vector<std::unique_ptr<ThreadBook>> dispatcher_books;
+  std::vector<std::unique_ptr<ThreadBook>> completer_books;
+  CpuClocks cpu_clocks;
   double started_at = 0.0;
 
   std::thread slo_thread;
@@ -1277,11 +1431,12 @@ bool decide_core(Server* s, uint32_t shard, std::vector<Pending>& items,
     // Only empty ALLOW_BATCH frames: nothing to decide (and empty
     // buffers would reach Python as None through Py_BuildValue y#).
     r.limit = s->limit.load();
+    book_to(TS_OTHER);
     return true;
   }
 
   {
-    PyGILState_STATE g = PyGILState_Ensure();
+    GilHold gil;
     PyObject* args = Py_BuildValue(
         "(Iy#y#y#y#K)", (unsigned int)shard,
         blob.data(), (Py_ssize_t)blob.size(),
@@ -1300,7 +1455,6 @@ bool decide_core(Server* s, uint32_t shard, std::vector<Pending>& items,
       parse_result_tuple(res, total, r, "decide");
       Py_DECREF(res);
     }
-    PyGILState_Release(g);
   }
 
   r.total = total;
@@ -1321,11 +1475,12 @@ PyObject* launch_core(Server* s, uint32_t shard, std::vector<Pending>& items,
   *total_out = total;
   if (total == 0) {
     r.limit = s->limit.load();
+    book_to(TS_OTHER);
     return nullptr;  // err_code == 0: empty frame, answered directly
   }
   PyObject* ticket = nullptr;
   {
-    PyGILState_STATE g = PyGILState_Ensure();
+    GilHold gil;
     PyObject* args = Py_BuildValue(
         "(Iy#y#y#y#K)", (unsigned int)shard,
         blob.data(), (Py_ssize_t)blob.size(),
@@ -1338,7 +1493,6 @@ PyObject* launch_core(Server* s, uint32_t shard, std::vector<Pending>& items,
     if (ticket == nullptr)
       r.err_code = fetch_py_error(r.err_msg, "launch callback failed",
                                   E_STORAGE_UNAVAILABLE);
-    PyGILState_Release(g);
   }
   return ticket;
 }
@@ -1369,10 +1523,11 @@ bool decide_hashed_core(Server* s, uint32_t shard,
   r.hashed = true;
   if (total == 0) {
     r.limit = s->limit.load();
+    book_to(TS_OTHER);
     return true;
   }
   {
-    PyGILState_STATE g = PyGILState_Ensure();
+    GilHold gil;
     PyObject* args = Py_BuildValue(
         "(Iy#y#K)", (unsigned int)shard,
         (const char*)ids.data(), (Py_ssize_t)(ids.size() * 8),
@@ -1388,7 +1543,6 @@ bool decide_hashed_core(Server* s, uint32_t shard,
       parse_result_tuple(res, total, r, "decide_hashed");
       Py_DECREF(res);
     }
-    PyGILState_Release(g);
   }
   r.total = total;
   return r.err_code == 0;
@@ -1405,11 +1559,12 @@ PyObject* launch_hashed_core(Server* s, uint32_t shard,
   r.hashed = true;
   if (total == 0) {
     r.limit = s->limit.load();
+    book_to(TS_OTHER);
     return nullptr;  // err_code == 0: empty frame, answered directly
   }
   PyObject* ticket = nullptr;
   {
-    PyGILState_STATE g = PyGILState_Ensure();
+    GilHold gil;
     PyObject* args = Py_BuildValue(
         "(Iy#y#K)", (unsigned int)shard,
         (const char*)ids.data(), (Py_ssize_t)(ids.size() * 8),
@@ -1420,7 +1575,6 @@ PyObject* launch_hashed_core(Server* s, uint32_t shard,
     if (ticket == nullptr)
       r.err_code = fetch_py_error(r.err_msg, "launch_hashed callback failed",
                                   E_STORAGE_UNAVAILABLE);
-    PyGILState_Release(g);
   }
   return ticket;
 }
@@ -1432,6 +1586,8 @@ PyObject* launch_hashed_core(Server* s, uint32_t shard,
 // every ticket reference released.
 void completer_main(Server* s, uint32_t shard) {
   Server::PipeQ& q = *s->pipeqs[shard];
+  CpuClocks::Member cpu(&s->cpu_clocks, TR_COMPLETER);
+  BookScope book(s->completer_books[shard].get());
   s->live_completers.fetch_add(1);
   struct Depart {
     Server* s;
@@ -1457,10 +1613,17 @@ void completer_main(Server* s, uint32_t shard) {
     std::deque<Server::InflightEntry> batch;
     {
       std::unique_lock<std::mutex> lk(q.mx);
-      q.cv_items.wait(lk, [&] {
+      auto wake = [&] {
         return !q.entries.empty() ||
                (s->stop.load() && s->live_dispatchers.load() == 0);
-      });
+      };
+      if (!wake()) {
+        // Nothing in flight: the completer's `idle` state is exactly
+        // this wait (a ticket already queued costs no clock read).
+        book_to(TS_IDLE);
+        q.cv_items.wait(lk, wake);
+        book_to(TS_OTHER);
+      }
       if (q.entries.empty()) return;  // stopped, launchers gone, drained
       batch.swap(q.entries);
       q.resolving += batch.size();
@@ -1470,7 +1633,7 @@ void completer_main(Server* s, uint32_t shard) {
       r.hashed = e.hashed;
       uint64_t t_v0 = mono_ns(), t_v1 = t_v0;
       {
-        PyGILState_STATE g = PyGILState_Ensure();
+        GilHold gil;
         PyObject* res = PyObject_CallFunction(
             s->cb_resolve, "IO", (unsigned int)shard, e.ticket);
         Py_DECREF(e.ticket);
@@ -1496,7 +1659,6 @@ void completer_main(Server* s, uint32_t shard) {
           if (sres == nullptr) PyErr_Clear();
           else Py_DECREF(sres);
         }
-        PyGILState_Release(g);
       }
       r.total = e.total;
       if (r.err_code == 0) {
@@ -1647,6 +1809,7 @@ bool run_decide(Server* s, std::vector<Pending>& items,
   uint64_t trace = 0;
   for (const auto& p : items)
     if (p.trace_id) { trace = p.trace_id; break; }
+  book_to(TS_GATHER);  // the blocking path feeds the same states
   bool ok = hashed ? decide_hashed_core(s, 0, items, r, trace)
                    : decide_core(s, 0, items, r, trace);
   if (gate != nullptr && gate->exchange(true)) {
@@ -1670,6 +1833,7 @@ bool run_decide(Server* s, std::vector<Pending>& items,
 // a dispatcher still inside a Python decide at stop time will enqueue
 // its Reply afterward, and those waiters must still be answered.
 void responder_main(Server* s) {
+  CpuClocks::Member cpu(&s->cpu_clocks, TR_RESPONDER);
   while (true) {
     Server::Reply r;
     {
@@ -1711,6 +1875,7 @@ void dispatch_group(Server* s, uint32_t shard, std::vector<Pending>&& group,
     if (run_trace == 0 && p.trace_id) run_trace = p.trace_id;
   }
   uint64_t t_d0 = mono_ns();
+  book_at(TS_GATHER, t_d0);  // until GilHold's call of PyGILState_Ensure
   if (pipelined) {
     Server::Reply r;
     size_t total = 0;
@@ -1738,11 +1903,19 @@ void dispatch_group(Server* s, uint32_t shard, std::vector<Pending>&& group,
       // completer's batched drain, which are still unresolved device
       // dispatches; on stop, push anyway — the completer drains
       // everything before exiting.
-      pq.cv_space.wait(lk, [&] {
+      auto room = [&] {
         return pq.entries.size() + pq.resolving <
                    s->inflight_window ||
                s->stop.load();
-      });
+      };
+      if (!room()) {
+        // The dispatcher's `slot` state: the window is full, so the
+        // device or the resolve side paces the door. A free slot costs
+        // no clock read.
+        book_to(TS_SLOT);
+        pq.cv_space.wait(lk, room);
+        book_to(TS_OTHER);
+      }
       pq.entries.push_back({std::move(group), ticket, total, ep, hashed,
                             run_io, t_d0, mono_ns(), run_trace});
     }
@@ -1824,7 +1997,7 @@ void handle_reset(Server* s, uint32_t shard, const Pending& p) {
   uint16_t err_code = 0;
   std::string err_msg;
   {
-    PyGILState_STATE g = PyGILState_Ensure();
+    GilHold gil;
     PyObject* res = PyObject_CallFunction(
         s->cb_reset, "Iy#", (unsigned int)shard, p.keys[0].data(),
         (Py_ssize_t)p.keys[0].size());
@@ -1834,7 +2007,6 @@ void handle_reset(Server* s, uint32_t shard, const Pending& p) {
     } else {
       Py_DECREF(res);
     }
-    PyGILState_Release(g);
   }
   std::string out;
   if (err_code) {
@@ -1852,7 +2024,7 @@ void handle_dcn(Server* s, const Pending& p) {
   uint16_t err_code = 0;
   std::string err_msg;
   {
-    PyGILState_STATE g = PyGILState_Ensure();
+    GilHold gil;
     PyObject* res = PyObject_CallFunction(
         s->cb_dcn, "y#", p.keys[0].data(), (Py_ssize_t)p.keys[0].size());
     if (res == nullptr) {
@@ -1860,7 +2032,6 @@ void handle_dcn(Server* s, const Pending& p) {
     } else {
       Py_DECREF(res);
     }
-    PyGILState_Release(g);
   }
   std::string out;
   if (err_code) {
@@ -1874,7 +2045,7 @@ void handle_dcn(Server* s, const Pending& p) {
 void handle_metrics(Server* s, const Pending& p) {
   std::string text;
   {
-    PyGILState_STATE g = PyGILState_Ensure();
+    GilHold gil;
     PyObject* res = s->cb_metrics && s->cb_metrics != Py_None
                         ? PyObject_CallNoArgs(s->cb_metrics)
                         : nullptr;
@@ -1891,7 +2062,6 @@ void handle_metrics(Server* s, const Pending& p) {
     } else if (PyErr_Occurred()) {
       PyErr_Clear();
     }
-    PyGILState_Release(g);
   }
   std::string out;
   frame_header(out, T_METRICS_R, p.req_id, 4 + (uint32_t)text.size());
@@ -1902,6 +2072,8 @@ void handle_metrics(Server* s, const Pending& p) {
 
 void dispatcher_main(Server* s, uint32_t shard) {
   Server::ShardQ& q = *s->shardqs[shard];
+  CpuClocks::Member cpu(&s->cpu_clocks, TR_DISPATCHER);
+  BookScope book(s->dispatcher_books[shard].get());
   s->live_dispatchers.fetch_add(1);
   struct Depart {
     Server* s;
@@ -1923,15 +2095,24 @@ void dispatcher_main(Server* s, uint32_t shard) {
     size_t run_keys = 0;
     {
       std::unique_lock<std::mutex> lk(q.qmx);
+      // The dispatcher's `idle` state: blocked with nothing it may
+      // drain — the empty queue, and the coalescing wait for a run that
+      // is not full yet. A full run waiting costs no clock read.
       if (q.queue.empty()) {
+        book_to(TS_IDLE);
         q.qcv.wait(lk, [&] { return s->stop.load() || !q.queue.empty(); });
+        book_to(TS_OTHER);
       } else {
         // First item already waiting: coalesce for up to max_delay.
-        q.qcv.wait_for(lk, std::chrono::microseconds(s->max_delay_us),
-                       [&] {
-                         return s->stop.load() ||
-                                q.queued_keys >= s->max_batch;
-                       });
+        auto full = [&] {
+          return s->stop.load() || q.queued_keys >= s->max_batch;
+        };
+        if (!full()) {
+          book_to(TS_IDLE);
+          q.qcv.wait_for(lk, std::chrono::microseconds(s->max_delay_us),
+                         full);
+          book_to(TS_OTHER);
+        }
       }
       if (s->stop.load() && q.queue.empty()) return;
       while (!q.queue.empty() && run_keys < s->max_batch) {
@@ -2850,6 +3031,7 @@ void ring_drain_pending(Server* s, IoRing* r) {
 }
 
 void ring_main(Server* s, IoRing* r) {
+  CpuClocks::Member cpu(&s->cpu_clocks, TR_IO);
   std::vector<NetEvent> events(128);
   char buf[65536];
   while (!s->stop.load()) {
@@ -3092,6 +3274,13 @@ PyObject* server_start(PyObject* self, PyObject* args) {
   if (s->pipelined)
     for (uint32_t i = 0; i < s->num_shards; ++i)
       s->pipeqs.push_back(std::make_unique<Server::PipeQ>());
+  s->dispatcher_books.clear();
+  s->completer_books.clear();
+  for (uint32_t i = 0; i < s->num_shards; ++i) {
+    s->dispatcher_books.push_back(std::make_unique<ThreadBook>());
+    if (s->pipelined)
+      s->completer_books.push_back(std::make_unique<ThreadBook>());
+  }
   for (auto& ring : s->rings)
     ring->thread = std::thread(ring_main, s, ring.get());
   for (uint32_t i = 0; i < s->num_shards; ++i)
@@ -3256,6 +3445,51 @@ PyObject* server_stats(PyObject* self, PyObject* Py_UNUSED(ignored)) {
     Py_DECREF(per_quar);
     return nullptr;
   }
+  // Thread-state wall time, summed over dispatch units, with the state
+  // each thread is in right now counted up to this instant — so each
+  // thread's states sum to its wall since start — and the CPU time of
+  // the door's threads by role, read off their clocks here.
+  uint64_t disp_ns[TS_COUNT] = {0}, comp_ns[TS_COUNT] = {0}, one[TS_COUNT];
+  uint64_t t_now = mono_ns();
+  for (auto& b : ps->s->dispatcher_books) {
+    b->read(one, t_now);
+    for (int i = 0; i < TS_COUNT; ++i) disp_ns[i] += one[i];
+  }
+  for (auto& b : ps->s->completer_books) {
+    b->read(one, t_now);
+    for (int i = 0; i < TS_COUNT; ++i) comp_ns[i] += one[i];
+  }
+  // A completer is never in gather or slot: those are the dispatcher's.
+  PyObject* thread_ns = Py_BuildValue(
+      "{s:{s:K,s:K,s:K,s:K,s:K,s:K},s:{s:K,s:K,s:K,s:K}}",
+      "dispatcher",
+      "idle", (unsigned long long)disp_ns[TS_IDLE],
+      "gather", (unsigned long long)disp_ns[TS_GATHER],
+      "gil", (unsigned long long)disp_ns[TS_GIL],
+      "python", (unsigned long long)disp_ns[TS_PYTHON],
+      "slot", (unsigned long long)disp_ns[TS_SLOT],
+      "other", (unsigned long long)disp_ns[TS_OTHER],
+      "completer",
+      "idle", (unsigned long long)comp_ns[TS_IDLE],
+      "gil", (unsigned long long)comp_ns[TS_GIL],
+      "python", (unsigned long long)comp_ns[TS_PYTHON],
+      "other", (unsigned long long)comp_ns[TS_OTHER]);
+  uint64_t cpu_ns[TR_COUNT];
+  ps->s->cpu_clocks.total(cpu_ns);
+  PyObject* cpu_d = Py_BuildValue(
+      "{s:K,s:K,s:K,s:K}",
+      "io", (unsigned long long)cpu_ns[TR_IO],
+      "dispatcher", (unsigned long long)cpu_ns[TR_DISPATCHER],
+      "completer", (unsigned long long)cpu_ns[TR_COMPLETER],
+      "responder", (unsigned long long)cpu_ns[TR_RESPONDER]);
+  if (thread_ns == nullptr || cpu_d == nullptr) {
+    Py_DECREF(per_shard);
+    Py_DECREF(per_quar);
+    Py_DECREF(stage_ns);
+    Py_XDECREF(thread_ns);
+    Py_XDECREF(cpu_d);
+    return nullptr;
+  }
   // Per-transport accepts + shm lane counters (ADR-025): the same
   // shape the asyncio door's transport_stats() reports, so the metrics
   // collect hook and bench tooling read one schema from either door.
@@ -3309,13 +3543,15 @@ PyObject* server_stats(PyObject* self, PyObject* Py_UNUSED(ignored)) {
     Py_DECREF(per_shard);
     Py_DECREF(per_quar);
     Py_DECREF(stage_ns);
+    Py_DECREF(thread_ns);
+    Py_DECREF(cpu_d);
     Py_XDECREF(transport);
     Py_XDECREF(shm_stats);
     Py_XDECREF(net);
     return nullptr;
   }
   PyObject* out = Py_BuildValue(
-      "{s:K,s:K,s:K,s:d,s:K,s:I,s:O,s:I,s:O,s:O,s:O,s:O,s:O,s:O}",
+      "{s:K,s:K,s:K,s:d,s:K,s:I,s:O,s:I,s:O,s:O,s:O,s:O,s:O,s:O,s:O,s:O}",
       "decisions_total",
       (unsigned long long)ps->s->decisions.load(), "slo_breaches_total",
       (unsigned long long)ps->s->slo_breaches.load(),
@@ -3329,7 +3565,10 @@ PyObject* server_stats(PyObject* self, PyObject* Py_UNUSED(ignored)) {
       // device, so this is the per-device decision balance, ADR-012).
       "num_shards", ps->s->num_shards, "shard_decisions", per_shard,
       "shard_quarantined", per_quar, "stage_ns", stage_ns,
-      "transport", transport, "shm", shm_stats, "net", net);
+      "transport", transport, "shm", shm_stats, "net", net,
+      "thread_ns", thread_ns, "thread_cpu_ns", cpu_d);
+  Py_DECREF(thread_ns);
+  Py_DECREF(cpu_d);
   Py_DECREF(per_shard);  // Py_BuildValue "O" took its own reference
   Py_DECREF(per_quar);
   Py_DECREF(stage_ns);

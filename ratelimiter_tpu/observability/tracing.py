@@ -92,15 +92,20 @@ STAGES = (
                   # under the WINDOW-level trace id (ADR-021)
     # The native door's ``dispatch`` stage from inside, in order
     # (sketch-family launch path; ADR-014 addendum):
-    "enter",      # C++ drain stamp -> first line of the launch callback
-                  # (column gather + the wait for the GIL); ring only
+    "enter",      # C++ drain stamp -> first line of the launch callback;
+                  # ring only. Two waits under one name, split exactly by
+                  # rate_limiter_door_thread_seconds_total{thread=
+                  # "dispatcher"}: state="gather" (the column gather) and
+                  # state="gil" (inside PyGILState_Ensure)
     "hash",       # string lane: bulk-hash the drained keys
     "prep",       # staging slot, pad copies, lock waits, rollover check
     "place",      # host -> device placement of the step's operands
     "step",       # the jitted step call returning (enqueue, not execution)
     "finish",     # host limits, finish/pack programs enqueued, ticket filled
-    "leave",      # launch callback's last line -> C++ push stamp (GIL
-                  # release + the wait for an in-flight slot); ring only
+    "leave",      # launch callback's last line -> C++ push stamp; ring
+                  # only. The GIL's release (the tail of the dispatcher's
+                  # state="python") + the wait for an in-flight slot
+                  # (state="slot", counted apart)
     # The collective router's launch (parallel/collective.py) uses prep /
     # place / step / finish for the work it shares with the launch above,
     # route for the whole launch, barrier for its resolve, and two stages
@@ -112,13 +117,25 @@ STAGES = (
                   # state leaf (between step and finish)
     # The resolve half of a dispatch (HashedLane._resolve_ticket, the
     # collective router's resolve), on the resolving thread:
-    "fetch",      # device ready -> BatchResult's NumPy columns built: the
-                  # one packed result buffer fetched (a shard a device),
-                  # the 64-bit and float columns rebuilt on the host
+    "fetch",      # device ready -> np.asarray returned: the one packed
+                  # result buffer fetched (a shard a device) and the wait
+                  # to get the GIL back; "unpack" follows. What the
+                  # completer waits for the GIL on entry is
+                  # ...thread_seconds_total{thread="completer",state="gil"}
     # The dense backend's key directory (ADR-027), under the limiter's
     # lock, inside "prep" when a launch triggers it:
     "reclaim",    # the table-sized reclaim pass enqueued AND waited for
                   # (its count of freed entries is fetched)
+    # The launch callback outside the lane's launch; ring only, recorded
+    # like "enter" / "leave" from stamps the ticket carries (the
+    # callback's in t_door, the lane's first and last in t_lane):
+    "descend",    # first line of the callback (after "hash") -> where
+                  # "prep" opens: frombuffer, the shard lock, decorators
+    "ascend",     # where "finish" closed -> the callback's last line:
+                  # the decorators on the way up, depth lock, gauge, hist
+    # The second half of what "fetch" was (resolve, completer's thread):
+    "unpack",     # np.asarray returned -> BatchResult's NumPy columns
+                  # built (unpack_window / unpack_bucket on [:b])
 )
 _STAGE_CODE: Dict[str, int] = {s: i for i, s in enumerate(STAGES) if s}
 
@@ -458,6 +475,7 @@ class _NoSpan:
     no clock read, no allocation."""
 
     __slots__ = ()
+    t_open = t_close = 0
 
     def __enter__(self):
         return self
@@ -489,17 +507,20 @@ def annotation(name: str):
 
 class _Span:
     """One open stage with up to two sinks: a ring row at exit (recorder
-    on) and a TraceMe held open for the same interval (``--trace`` on)."""
+    on) and a TraceMe held open for the same interval (``--trace`` on).
+    ``t_open`` / ``t_close``: the stamps of its first stage's start and
+    its last stage's end (0 with the recorder off), for a caller whose
+    own rows begin and end there."""
 
     __slots__ = ("_rec", "_annotate", "_ann", "_stage", "_t0", "_trace_id",
-                 "_shard", "_batch")
+                 "_shard", "_batch", "t_open", "t_close")
 
     def __init__(self, rec, annotate_on, stage, trace_id, shard, batch):
         self._rec = rec
         self._annotate = annotate_on
         self._ann = None
         self._stage = stage
-        self._t0 = 0
+        self._t0 = self.t_open = self.t_close = 0
         self._trace_id = trace_id
         self._shard = shard
         self._batch = batch
@@ -509,12 +530,13 @@ class _Span:
             self._ann = _trace_me(self._stage)
             self._ann.__enter__()
         if self._rec is not None:
-            self._t0 = now()
+            self._t0 = self.t_open = now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._rec is not None:
-            self._row(now(), ERROR if exc_type is not None else OK)
+            self.t_close = now()
+            self._row(self.t_close, ERROR if exc_type is not None else OK)
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
             self._ann = None
@@ -575,17 +597,47 @@ def clock_anchor() -> int:
     return mono_ns
 
 
+class Anchors:
+    """A capture's two readings of ``clock_anchor``: ``start`` just after
+    the trace started, ``end`` just before it stopped (None until then).
+    Each is one instant on both clocks, so the two bound the drift
+    between CLOCK_MONOTONIC and the profiler's timeline over the
+    capture."""
+
+    __slots__ = ("start", "end")
+
+    def __init__(self, start: int):
+        self.start = start
+        self.end: Optional[int] = None
+
+
 @contextlib.contextmanager
-def profile(out_dir: str):
+def profile(out_dir: str, *, python_tracer: bool = False):
     """One ``jax.profiler`` capture into ``out_dir`` (xplane format):
     the start/stop behind ``TracingDecorator.capture`` and
-    ``/debug/profile``. Yields the capture's ``clock_anchor`` reading."""
+    ``/debug/profile``. Yields the capture's ``Anchors``.
+
+    The capture holds host TraceMes (``ratelimiter/*``, ``PjitFunction``,
+    the runtime's transfers) and the device planes. It does NOT hold
+    Python frames unless ``python_tracer`` asks for them: the profiler's
+    Python tracer hooks every call and return of every thread, which
+    cost the server it read ~10 % of its rate while the capture ran, at
+    a 1.7 ms dispatch cycle (PERF.md §6, PR 37; what else a traced run
+    loses is the recorder's rows, the TraceMes and the stop's decoding)
+    — for an operator hunting a Python hot spot, not for timing a
+    run."""
     import jax.profiler
 
-    jax.profiler.start_trace(out_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 1 if python_tracer else 0
+    jax.profiler.start_trace(out_dir, profiler_options=opts)
+    anchors = None
     try:
-        yield clock_anchor()
+        anchors = Anchors(clock_anchor())
+        yield anchors
     finally:
+        if anchors is not None:
+            anchors.end = clock_anchor()
         # Stopping collects and writes the trace, and that grows with the
         # device programs captured (every op of every execution on every
         # chip): minutes for a few seconds of a busy mesh. Logged, so

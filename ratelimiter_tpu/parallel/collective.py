@@ -321,7 +321,8 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
             t.premix = premix
             t.wire_lane = bool(wire and premix)
             t.wire = t.wire_lane
-            return t
+        t.t_lane = (sp.t_open, sp.t_close)
+        return t
 
     def _launch_routed_guarded(self, arrays: np.ndarray, ns: np.ndarray,
                                now: float, *, premix: bool,
@@ -357,10 +358,12 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                 # a shard a device.
                 shards = fetch_count(ticket.outs)
                 with tracing.span("fetch", batch=ticket.b,
-                                  trace_id=trace_id):
+                                  trace_id=trace_id) as sp:
+                    words = np.asarray(ticket.outs)
+                    sp.next("unpack")
                     (allowed, remaining, retry, reset_at), tails = \
                         self.slices[0]._unpack(
-                            np.asarray(ticket.outs), ticket, shards,
+                            words, ticket, shards,
                             route_kernels.ROUTED_TAIL)
         except Exception as exc:
             ticket.outs = None

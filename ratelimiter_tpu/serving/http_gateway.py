@@ -527,8 +527,11 @@ class HttpGateway:
                 trace, holds THIS handler thread for N seconds while
                 traffic keeps flowing, and reports the artifact
                 directory (xplane format — open with Perfetto or
-                tensorboard's profile plugin). One capture at a time;
-                same gate as /debug/trace."""
+                tensorboard's profile plugin). The capture holds host
+                TraceMes and the device planes; ``&python=1`` adds the
+                profiler's Python tracer (every frame of every thread,
+                at the price of a slower host — tracing.profile). One
+                capture at a time; same gate as /debug/trace."""
                 if not gateway.enable_debug:
                     self._send(403, {"error": "debug endpoints are "
                                      "disabled on this gateway"})
@@ -541,6 +544,7 @@ class HttpGateway:
                 if seconds <= 0:
                     self._send(400, {"error": "seconds must be > 0"})
                     return
+                python_tracer = q.get("python", ["0"])[0] == "1"
                 if not gateway._profile_lock.acquire(blocking=False):
                     self._send(409, {"error": "a profile capture is "
                                      "already running"})
@@ -552,6 +556,7 @@ class HttpGateway:
 
                 reply = {"error": "profiler unavailable"}
                 beat = None
+                locked = True
                 try:
                     with contextlib.ExitStack() as capture:
                         try:
@@ -560,13 +565,18 @@ class HttpGateway:
                             # several seconds of profiler-server init on
                             # top of N — budget the client timeout
                             # accordingly.
-                            anchor_ns = capture.enter_context(
-                                tracing.profile(out_dir))
+                            anchors = capture.enter_context(
+                                tracing.profile(
+                                    out_dir, python_tracer=python_tracer))
                         except Exception as exc:  # noqa: BLE001 — profiler
                             # is best-effort (unsupported platform,
                             # concurrent capture by another tool): report,
                             # never crash.
                             log.exception("debug profile capture failed")
+                            # Free before the answer: a client that asks
+                            # again on reading it must not meet a 409.
+                            gateway._profile_lock.release()
+                            locked = False
                             self._send(503, {"error": "profiler "
                                              f"unavailable: {exc}"})
                             return
@@ -592,17 +602,22 @@ class HttpGateway:
                         for root, _, fs in os.walk(out_dir) for f in fs)
                     reply = {"ok": True, "dir": out_dir,
                              "seconds": seconds, "files": files,
-                             # tracing.now() at the start of the trace's
-                             # ratelimiter/clock_anchor TraceMe: the two
-                             # clocks' offset.
-                             "clock_anchor_mono_ns": anchor_ns}
+                             "python_tracer": python_tracer,
+                             # tracing.now() at the start of each of the
+                             # trace's two ratelimiter/clock_anchor
+                             # TraceMes (just after the start, just
+                             # before the stop): the two clocks' offset,
+                             # and between the two a bound on its drift.
+                             "clock_anchor_mono_ns": anchors.start,
+                             "clock_anchor_end_mono_ns": anchors.end}
                 except Exception as exc:  # noqa: BLE001 — stop_trace
                     # failed after the 200 went out: say so in the body.
                     log.exception("debug profile capture failed")
                     reply = {"ok": False,
                              "error": f"profiler unavailable: {exc}"}
                 finally:
-                    gateway._profile_lock.release()
+                    if locked:
+                        gateway._profile_lock.release()
                     if beat is not None:
                         beat.stop()
                 # Written OUTSIDE the capture try: a client that gave up

@@ -216,10 +216,10 @@ class NativeRateLimitServer:
                 self._shard_limiters.append(
                     shard_decorate(clone, i) if shard_decorate else clone)
         self._locks = [threading.Lock() for _ in range(shards)]
-        #: Per shard: the launch callback's (enter, leave) stamps of the
-        #: ticket its completer resolved last (_resolve -> _spans, same
-        #: thread, back to back).
-        self._door_ns = [None] * shards
+        #: Per shard: the launch callback's stamps and the lane's (t_door,
+        #: t_lane) of the ticket its completer resolved last (_resolve ->
+        #: _spans, same thread, back to back).
+        self._door_ns = [(None, None)] * shards
 
         # Fleet tier (ADR-017): the bridge partitions every decision
         # frame by keyspace owner BEFORE the shard limiter sees it —
@@ -342,8 +342,8 @@ class NativeRateLimitServer:
                        batch=count)
         rec.record("dispatch", t_d0, t_d1, trace_id=trace_id, shard=shard,
                    batch=count)
-        door = self._door_ns[shard]
-        if door is not None and t_d0 <= door[0] <= door[1] <= t_d1:
+        door, lane = self._door_ns[shard]
+        if door is not None and t_d0 <= door[0] <= door[2] <= t_d1:
             # What C++ holds of the stage, on either side of the launch
             # callback (_resolve left the ticket's stamps here just
             # before this call): the gather of the group's columns + the
@@ -351,7 +351,17 @@ class NativeRateLimitServer:
             # slot of the in-flight window + the push.
             rec.record("enter", t_d0, door[0], trace_id=trace_id,
                        shard=shard, batch=count)
-            rec.record("leave", door[1], t_d1, trace_id=trace_id,
+            if lane is not None and door[1] <= lane[0] <= lane[1] <= door[2]:
+                # What the callback holds on either side of the lane's
+                # launch (prep ... finish, the lane's own rows):
+                # frombuffer, the shard lock and the decorators on the
+                # way down; the decorators on the way up, the depth
+                # lock, gauge and histogram.
+                rec.record("descend", door[1], lane[0], trace_id=trace_id,
+                           shard=shard, batch=count)
+                rec.record("ascend", lane[1], door[2], trace_id=trace_id,
+                           shard=shard, batch=count)
+            rec.record("leave", door[2], t_d1, trace_id=trace_id,
                        shard=shard, batch=count)
         rec.record("device", t_v0, t_v1, trace_id=trace_id, shard=shard,
                    batch=count)
@@ -582,11 +592,13 @@ class NativeRateLimitServer:
         return tracing.now()
 
     @staticmethod
-    def _trace_leave(ticket, t_enter: int):
+    def _trace_leave(ticket, t_enter: int, t_descend: int = 0):
         """Last line of both launch callbacks: the ticket carries the
-        callback's two stamps to _spans."""
+        callback's stamps to _spans — its first line, where "descend"
+        begins (the first line again, or where the string lane's "hash"
+        closed) and its last line."""
         if t_enter:
-            ticket.t_door = (t_enter, tracing.now())
+            ticket.t_door = (t_enter, t_descend or t_enter, tracing.now())
         return ticket
 
     def _launch_hashed_cb(self, shard: int, ids_b: bytes, ns_b: bytes,
@@ -635,9 +647,10 @@ class NativeRateLimitServer:
         t0 = time.perf_counter()
         lim = self._shard_limiters[shard]
         try:
-            with tracing.span("hash", batch=len(offsets_b) // 8):
+            with tracing.span("hash", batch=len(offsets_b) // 8) as sp:
                 h64, ns = self._hash_buffers(blob, offsets_b, lengths_b,
                                              ns_b)
+            t_descend = sp.t_close
             if self._fleet is not None:
                 ticket = self._fleet_launch(
                     shard, h64, ns, blob=blob,
@@ -651,7 +664,7 @@ class NativeRateLimitServer:
                         self._depth += 1
                         self._inflight_gauge.set(float(self._depth))
                     self._launch_hist.observe(time.perf_counter() - t0)
-                    return self._trace_leave(ticket, t_enter)
+                    return self._trace_leave(ticket, t_enter, t_descend)
             with self._locks[shard]:
                 ticket = lim.launch_hashed(h64, ns)
         except Exception as exc:
@@ -663,7 +676,7 @@ class NativeRateLimitServer:
             self._depth += 1
             self._inflight_gauge.set(float(self._depth))
         self._launch_hist.observe(time.perf_counter() - t0)
-        return self._trace_leave(ticket, t_enter)
+        return self._trace_leave(ticket, t_enter, t_descend)
 
     def _fleet_resolve(self, shard: int, ticket):
         """Resolve one ticket, merging fleet tickets (local sub-resolve
@@ -699,7 +712,7 @@ class NativeRateLimitServer:
         buffers back to the C++ responder."""
         t0 = time.perf_counter()
         lim = self._shard_limiters[shard]
-        self._door_ns[shard] = ticket.t_door
+        self._door_ns[shard] = (ticket.t_door, ticket.t_lane)
         try:
             out = self._fleet_resolve(shard, ticket)
         except Exception as exc:
@@ -1114,7 +1127,8 @@ class NativeRateLimitServer:
         # handed), recorder on or off — what a stage costs in an
         # untraced run, and the exact twin of the ring-derived
         # rate_limiter_stage_seconds.
-        stage = self.stats()["stage_ns"]
+        door = self.stats()
+        stage = door["stage_ns"]
         tg = self.registry.gauge(
             "rate_limiter_door_stage_seconds_total",
             "Wall time the native door's pipeline stages have consumed "
@@ -1125,6 +1139,32 @@ class NativeRateLimitServer:
             "dispatch")
         for name in ("io", "dispatch", "device", "complete"):
             tg.set(stage[name] / 1e9, stage=name)
+        # What the door's threads wait for (ADR-014 addendum): wall time
+        # per state, C++ atomics added to where the waiting happens,
+        # recorder on or off. The states of a thread tile its loop, so a
+        # thread's states sum to its wall since start (summed over
+        # dispatch units: four dispatchers sum to four walls).
+        sg = self.registry.gauge(
+            "rate_limiter_door_thread_seconds_total",
+            "Wall time the native door's dispatcher and completer "
+            "threads have spent in each state (cumulative, always on, "
+            "summed over dispatch units): idle = nothing to drain / "
+            "nothing in flight, gather = drain to the call for the GIL, "
+            "gil = inside PyGILState_Ensure, python = GIL taken to GIL "
+            "released (the launch / resolve callback), slot = waiting "
+            "for an in-flight slot, other = the rest of the loop; a "
+            "thread's states sum to its wall")
+        for thread, states in door["thread_ns"].items():
+            for state, ns in states.items():
+                sg.set(ns / 1e9, thread=thread, state=state)
+        cg = self.registry.gauge(
+            "rate_limiter_door_thread_cpu_seconds_total",
+            "CPU time of the native door's threads by role (cumulative; "
+            "each thread's CPU clock read at scrape): over the thread's "
+            "states' sum, the share of its time it computes and does "
+            "not wait")
+        for thread, ns in door["thread_cpu_ns"].items():
+            cg.set(ns / 1e9, thread=thread)
         self.registry.gauge(
             "rate_limiter_door_dispatches_total",
             "Batched dispatches the native door has completed "

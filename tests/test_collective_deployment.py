@@ -184,12 +184,15 @@ def test_the_launch_is_on_the_span_primitive(pair, recorder, lane):
     assert by["route"]["t_start_ns"] <= by["prep"]["t_start_ns"]
     assert by["finish"]["t_end_ns"] <= by["route"]["t_end_ns"]
     coll.resolve(ticket)
-    # Resolve: the barrier, and inside it (after the wait) the one fetch.
-    barrier, fetch = recorder.dump()[-2:]
-    assert (barrier["stage"], fetch["stage"]) == ("barrier", "fetch")
+    # Resolve: the barrier, and inside it (after the wait) the one fetch
+    # and, from the instant np.asarray returned, the NumPy rebuild.
+    barrier, fetch, unpack = recorder.dump()[-3:]
+    assert (barrier["stage"], fetch["stage"], unpack["stage"]) \
+        == ("barrier", "fetch", "unpack")
     assert barrier["t_start_ns"] <= fetch["t_start_ns"]
-    assert fetch["t_end_ns"] <= barrier["t_end_ns"]
-    assert fetch["batch"] == 203
+    assert fetch["t_end_ns"] == unpack["t_start_ns"]
+    assert unpack["t_end_ns"] <= barrier["t_end_ns"]
+    assert fetch["batch"] == unpack["batch"] == 203
 
 
 def test_spans_cost_nothing_with_tracing_off(pair):

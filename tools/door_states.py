@@ -1,0 +1,80 @@
+"""The host cycle of an UNTRACED server, read off the door's always-on
+thread-state counters (PR 37; PERF.md section 5's host-cycle table): start the cell's server with its configuration's flags alone,
+drive the cell's own traffic, scrape /metrics over the wire 3 s and 23 s
+into the load, and print one JSON line: per dispatch, each thread state,
+the CPU of each door thread, the door's exact stage sums, and the
+generator's completed decisions a second.
+
+    chiprun -- python3 tools/door_states.py <cell> <seed> [extra server flags]
+
+The parent never imports JAX (the server child holds the chip); with
+JAX_PLATFORMS=cpu it is a rehearsal at the cell's tiny geometry (counts
+only).
+"""
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from chipbench import promtext, runner            # noqa: E402
+from chipbench.wire import Wire                   # noqa: E402
+
+F = "rate_limiter_door_thread_seconds_total"
+C = "rate_limiter_door_thread_cpu_seconds_total"
+S = "rate_limiter_door_stage_seconds_total"
+N = "rate_limiter_door_dispatches_total"
+
+
+def main() -> int:
+    cell = runner.load_cell(sys.argv[1])
+    seed = int(sys.argv[2])
+    extra = sys.argv[3:]
+    cell["config"] = dict(cell["config"], server_flags=list(
+        cell["config"]["server_flags"]) + extra)
+    out_dir = os.path.join(runner.HERE, "out", f"states-{sys.argv[1]}-{seed}")
+    shutil.rmtree(out_dir, ignore_errors=True)
+    os.makedirs(out_dir)
+    binary, _ = runner.build_loadgen()
+    seconds = 20.0
+    with runner.serving(cell, out_dir, trace=False) as srv:
+        start_at = time.monotonic() + 0.3
+        gen = subprocess.Popen(
+            [binary] + runner.loadgen_args(cell, srv.port, seed, seconds,
+                                           start_at),
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        t0 = start_at + runner.WARMUP_S
+        time.sleep(max(0.0, t0 - time.monotonic()))
+        with Wire(srv.port) as wire:
+            a_t, a = time.monotonic(), promtext.parse(wire.metrics())
+        time.sleep(max(0.0, t0 + seconds - 0.05 - time.monotonic()))
+        with Wire(srv.port) as wire:
+            b_t, b = time.monotonic(), promtext.parse(wire.metrics())
+        gen_out, gen_err = gen.communicate(timeout=120)
+    g = json.loads(gen_out.strip().splitlines()[-1])
+    n = promtext.delta(a, b, N)
+    row = {"cell": sys.argv[1], "seed": seed, "extra": extra,
+           "device": srv.device, "scrape_s": b_t - a_t, "dispatches": n,
+           "decisions_per_s": g["completed"] / g["window_s"]}
+    for thread, states in (("dispatcher", ("idle", "gather", "gil", "python",
+                                           "slot", "other")),
+                           ("completer", ("idle", "gil", "python", "other"))):
+        wall = promtext.delta(a, b, F, thread=thread)
+        row[thread] = {st: promtext.delta(a, b, F, thread=thread, state=st)
+                       / n * 1e6 for st in states}
+        row[thread]["wall_s"] = wall
+        row[thread]["cpu_us"] = promtext.delta(a, b, C, thread=thread) / n * 1e6
+        row[thread]["cpu_pct"] = 100 * promtext.delta(a, b, C, thread=thread) \
+            / wall if wall else None
+    for thread in ("io", "responder"):
+        row[thread + "_cpu_us"] = promtext.delta(a, b, C, thread=thread) / n * 1e6
+    row["stage_us"] = {st: promtext.delta(a, b, S, stage=st) / n * 1e6
+                       for st in ("io", "dispatch", "device", "complete")}
+    print(json.dumps(row), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
