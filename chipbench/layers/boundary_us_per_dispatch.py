@@ -1,0 +1,38 @@
+"""Device time a dispatch spends carrying the state across the step
+program's boundary: ops of the step's program that touch whole state
+leaves and no batch row. From the device planes, never a host timer.
+
+How the op set is chosen: the trace's ``custom-call`` op group
+(``trace_reduce.op_group``: the HLO name with its digits stripped),
+divided by the executions of the step module. On a chip without 64-bit
+vectors XLA splits every 64-bit array that enters a program into 32-bit
+halves and recombines every one that leaves it, and the v5e's trace
+names those passes ``custom-call``; on the dense step the arrays that
+matter are the state's int64 leaves (``cols``, ``dir_keys``), so the
+group is a pass over the WHOLE table a dispatch — 246 us of a 1,043 us
+step at 2^21 entries (PR 33), ~31 ms at 2^26 (ledger, PR 41). The
+staged batch's own uint64 words ride in the same group (a few us).
+
+Once a layout change keeps the state in 32-bit leaves the group shrinks
+to the batch's few us and may fall out of the ten groups
+``trace_reduce.py`` keeps: a trace that ran the step and lists no
+``custom-call`` group therefore reads 0.0 (the truth is under the
+tenth-largest group's time, which ``breakdown.device_ops`` shows), not
+None. None only without a trace or without a step in it."""
+
+from chipbench.layers import _memory
+
+GROUP = "custom-call"
+
+META = {"name": "boundary_us_per_dispatch", "unit": "us", "better": "lower",
+        "layer": "device step", "moves": "decisions_per_s",
+        "source": "device_trace", "applies": _memory.applies}
+
+
+def read(sources: dict):
+    trace = sources.get("trace")
+    if not trace or not trace.get("step") or not trace["step"]["executions"]:
+        return None
+    seconds = sum(s for name, s in trace.get("device_ops") or []
+                  if name == GROUP)
+    return seconds / trace["step"]["executions"] * 1e6

@@ -70,6 +70,7 @@ class DenseLimiter(HashedLane, RateLimiter):
         self._install_steps(self.config)
         self._state = dense_kernels.init_directory_state(self.config,
                                                          self._capacity)
+        self._note_resident()
         self._lock = threading.Lock()
         self._init_staging()
         self._injected_failure: Optional[Exception] = None

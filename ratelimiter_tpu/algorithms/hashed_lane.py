@@ -15,7 +15,8 @@ The sketch family (algorithms/sketch.py) and the dense backend
 that mixes this in provides:
 
 ``_lock``, ``_state``, ``_device``, ``_window_us``, ``_injected_failure``,
-``_policy_table``              its own state;
+``_policy_table``              its own state (``_note_resident()`` once
+                               ``_state`` is built);
 ``_step`` / ``_get_ids_step()``  the compiled steps (finalized / premix);
 ``_gate_locked(b, now_us)``    what must happen under the lock before a
                                step is enqueued (a rollover, a reclaim);
@@ -62,6 +63,22 @@ def fetch_count(buf) -> int:
     """Device buffers a fetch of the array ``buf`` asks the device for:
     one per addressable shard."""
     return len(buf.sharding.addressable_devices)
+
+
+def resident_bytes(state) -> dict:
+    """``{device: bytes}`` of a state's array leaves, from their shapes,
+    dtypes and shardings alone (one shard's bytes on every device that
+    holds one: a replicated leaf counts once a device) — no buffer is
+    read, so it costs nothing and never waits for the device."""
+    import jax
+
+    out: dict = {}
+    for leaf in jax.tree_util.tree_leaves(state):
+        shard = int(np.prod(leaf.sharding.shard_shape(leaf.shape),
+                            dtype=np.int64)) * leaf.dtype.itemsize
+        for dev in leaf.sharding.device_set:
+            out[dev] = out.get(dev, 0) + shard
+    return out
 
 
 class HashedLane:
@@ -135,6 +152,26 @@ class HashedLane:
         # Dispatches launched while the override table held an entry
         # (override_lookup_dispatches).
         self._override_lookups = 0
+
+    def _note_resident(self) -> None:
+        """Reckon what ``state_resident_bytes`` reports. Called where the
+        state is BUILT (the constructors, a mesh placement, a window
+        migration that changes the ring) with the leaves at hand — never
+        at scrape, when the leaves may be buffers a launch has donated."""
+        self._resident = resident_bytes(self._state)
+
+    def state_resident_bytes(self) -> dict:
+        """``{jax.Device: bytes}`` of limiter state resident on each
+        device: the ``nbytes`` of the state leaves the decision step is
+        handed, as reckoned when the state was built. Exported at scrape
+        as ``rate_limiter_state_resident_bytes``."""
+        return dict(self._resident)
+
+    def memory_bytes(self) -> int:
+        """Device memory the limiter's state holds, every device summed
+        (a mesh's replicas each count) — constant in key cardinality on
+        the sketches, 32 B an entry on the dense token bucket."""
+        return sum(self._resident.values())
 
     def _acquire_staging(self, padded: int) -> np.ndarray:
         with self._staging_lock:

@@ -52,6 +52,7 @@ class SketchLimiter(HashedLane, RateLimiter):
 
         self._install_steps(self.config)
         self._state = self._pin_state(sketch_kernels.init_state(self.config))
+        self._note_resident()
         self._window_us = to_micros(self.config.window)
         self._sub_us = sketch_kernels.sketch_geometry(self.config)[1]
         self._seed = self.config.sketch.seed
@@ -441,6 +442,7 @@ class SketchLimiter(HashedLane, RateLimiter):
         with self._lock:
             old_sub = self._sub_us
             self._state = migrate(self._state, jnp.int64(now_us))
+            self._note_resident()    # the ring's length may have changed
             self._install_steps(new_cfg)
             self._window_us = to_micros(new_cfg.window)
             self._sub_us = new_sub
@@ -544,11 +546,6 @@ class SketchLimiter(HashedLane, RateLimiter):
 
     # ----------------------------------------------------- introspection
 
-    def memory_bytes(self) -> int:
-        """Device memory held by the sketch — constant in key cardinality."""
-        return sum(int(np.prod(v.shape)) * v.dtype.itemsize
-                   for v in self._state.values() if hasattr(v, "shape"))
-
     @property
     def has_hh(self) -> bool:
         """Whether the heavy-hitter side table is configured
@@ -620,6 +617,7 @@ class SketchTokenBucketLimiter(SketchLimiter):
 
         self._install_steps(self.config)
         self._state = self._pin_state(bucket_kernels.init_state(self.config))
+        self._note_resident()
         self._window_us = to_micros(self.config.window)
         self._seed = self.config.sketch.seed
         self._lock = threading.Lock()

@@ -495,6 +495,48 @@ class MetricsDecorator(LimiterDecorator):
                      "Entries the directory has (--dense-capacity)"))}
             reg.add_collect_hook(self._collect_directory)
 
+        # What the device holds: the limiter's resident state (the state
+        # leaves' bytes per device, reckoned from their shapes when the
+        # state was built) and, where the platform keeps memory statistics
+        # (the CPU keeps none: no sample, not a 0), the bytes in use and
+        # their high-water mark on each device that holds state. All set
+        # at scrape, with no limiter lock taken and no buffer read. The
+        # exact backend keeps its state on the host and exports none.
+        self._resident = (base if hasattr(base, "state_resident_bytes")
+                          else None)
+        if self._resident is not None:
+            self._resident_g = reg.gauge(
+                "rate_limiter_state_resident_bytes",
+                "Bytes of limiter state resident on the device: the "
+                "nbytes of the state leaves the decision step is handed "
+                "(sketch slabs, debt slab, the dense columns and the "
+                "directory's keys)")
+            self._device_stat_gauges = (
+                ("peak_bytes_in_use", reg.gauge(
+                    "rate_limiter_device_peak_bytes",
+                    "Peak bytes in use on a device that holds limiter "
+                    "state since the process started "
+                    "(device.memory_stats(); no sample where the platform "
+                    "reports none)")),
+                ("bytes_in_use", reg.gauge(
+                    "rate_limiter_device_bytes_in_use",
+                    "Bytes in use now on a device that holds limiter "
+                    "state (device.memory_stats(); no sample where the "
+                    "platform reports none)")))
+            reg.add_collect_hook(self._collect_device_memory)
+
+    def _collect_device_memory(self) -> None:
+        for dev, nbytes in self._resident.state_resident_bytes().items():
+            device = str(dev.id)
+            self._resident_g.set(float(nbytes), shard=self._shard,
+                                 device=device)
+            # The process's figures, not a shard's: no shard label, so
+            # several dispatch shards on one device set one sample.
+            stats = dev.memory_stats() or {}
+            for key, gauge in self._device_stat_gauges:
+                if key in stats:
+                    gauge.set(float(stats[key]), device=device)
+
     def _collect_directory(self) -> None:
         st = self._directory.directory_stats()
         for name, gauge in self._dir_gauges.items():
@@ -559,6 +601,10 @@ class MetricsDecorator(LimiterDecorator):
             self.registry.remove_collect_hook(self._collect_dispatch_counts)
         if self._accesses is not None:
             self.registry.remove_collect_hook(self._collect_table_accesses)
+        if self._directory is not None:
+            self.registry.remove_collect_hook(self._collect_directory)
+        if self._resident is not None:
+            self.registry.remove_collect_hook(self._collect_device_memory)
         super().close()
 
     def _observe_envelope(self) -> None:

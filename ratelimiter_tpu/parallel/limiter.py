@@ -93,6 +93,7 @@ class _MeshPlacement:
         self.n_chips = int(np.prod(self.mesh.devices.shape))
         super().__init__(config, clock)
         self._state = mesh_kernels.replicate_state(self._state, self.mesh)
+        self._note_resident()
 
     def _build_step(self, cfg: Config, premix: bool):
         return mesh_kernels.build_mesh_hashed_step(
@@ -123,11 +124,6 @@ class _MeshPlacement:
 
         return jax.device_put(arr, NamedSharding(self.mesh, P()))
 
-    def memory_bytes(self) -> int:
-        """Total HBM across the mesh: state is fully replicated, so each of
-        the n_chips devices holds a complete copy."""
-        return super().memory_bytes() * self.n_chips
-
     def _fence_dispatch(self, outs) -> None:
         # At most ONE in-flight collective execution on the host platform:
         # xla:cpu's all_gather/psum rendezvous parks device-pool threads
@@ -156,6 +152,7 @@ class MeshSketchLimiter(_MeshPlacement, SketchLimiter):
         super()._apply_window(new_cfg)
         with self._lock:
             self._state = mesh_kernels.replicate_state(self._state, self.mesh)
+            self._note_resident()
 
 
 class MeshTokenBucketLimiter(_MeshPlacement, SketchTokenBucketLimiter):
@@ -786,8 +783,17 @@ class SlicedMeshLimiter(RateLimiter):
         these)."""
         return list(self.slices)
 
+    def state_resident_bytes(self) -> dict:
+        """The slices' own resident state
+        (HashedLane.state_resident_bytes), summed per device."""
+        out: dict = {}
+        for s in self.slices:
+            for dev, nbytes in s.state_resident_bytes().items():
+                out[dev] = out.get(dev, 0) + nbytes
+        return out
+
     def memory_bytes(self) -> int:
-        return sum(s.memory_bytes() for s in self.slices)
+        return sum(self.state_resident_bytes().values())
 
     @property
     def result_fetches(self) -> int:

@@ -125,6 +125,105 @@ class TestMetricsDecorator:
         lim.close()
 
 
+#: (backend, rule) of each kind of limiter that keeps its state on a device.
+DEVICE_BACKENDS = {
+    "dense": ("dense", Algorithm.TOKEN_BUCKET),
+    "sketch": ("sketch", Algorithm.SLIDING_WINDOW),
+    "bucket": ("sketch", Algorithm.TOKEN_BUCKET),
+    "mesh": ("mesh", Algorithm.SLIDING_WINDOW),
+}
+
+
+class TestDeviceMemoryGauges:
+    """``rate_limiter_state_resident_bytes{shard,device}`` and the two
+    ``device.memory_stats()`` gauges (ISSUE 42), beside the
+    ``rate_limiter_directory_*`` gauges: set at scrape by a collect hook,
+    for every backend that keeps its state on a device."""
+
+    @staticmethod
+    def limiter(name):
+        from ratelimiter_tpu import DenseParams, SketchParams
+
+        backend, algo = DEVICE_BACKENDS[name]
+        return make(algo=algo, backend=backend,
+                    dense=DenseParams(capacity=4096),
+                    sketch=SketchParams(depth=2, width=1024))
+
+    @pytest.mark.parametrize("name", list(DEVICE_BACKENDS))
+    def test_resident_bytes_are_the_state_leaves_nbytes(self, name):
+        import jax
+
+        from ratelimiter_tpu.observability.decorators import undecorated
+
+        lim, reg, _ = self.limiter(name)
+        base = undecorated(lim)
+        units = base.sub_limiters() if name == "mesh" else [base]
+        want: dict = {}
+        for unit in units:
+            leaves = jax.tree_util.tree_leaves(undecorated(unit)._state)
+            (dev,) = leaves[0].devices()
+            want[str(dev.id)] = sum(leaf.nbytes for leaf in leaves)
+        assert len(want) == (len(jax.devices()) if name == "mesh" else 1)
+        lim.allow_batch([f"k{i}" for i in range(40)])   # donates the state
+        text = reg.render()
+        gauge = reg.get("rate_limiter_state_resident_bytes")
+        for device, nbytes in want.items():
+            assert gauge.value(shard="0", device=device) == nbytes > 0
+        assert len(gauge._values) == len(want)
+        # The CPU keeps no memory statistics: the families are declared
+        # and carry NO sample — absent, not 0.
+        for family in ("rate_limiter_device_peak_bytes",
+                       "rate_limiter_device_bytes_in_use"):
+            assert f"# TYPE {family} gauge" in text
+            assert f"\n{family}" not in text
+            assert reg.get(family)._values == {}
+        lim.close()
+        assert lim._collect_device_memory not in reg._collect_hooks
+        assert lim._collect_directory not in reg._collect_hooks
+
+    def test_where_the_platform_keeps_statistics_they_are_samples(
+            self, monkeypatch):
+        """A device whose ``memory_stats()`` answers (the TPU's): one
+        sample a device, no shard label."""
+        lim, reg, _ = self.limiter("dense")
+
+        class Chip:
+            id = 3
+
+            @staticmethod
+            def memory_stats():
+                return {"peak_bytes_in_use": 2_836_000_000,
+                        "bytes_in_use": 2_200_000_000, "bytes_limit": 1}
+
+        monkeypatch.setattr(lim._resident, "state_resident_bytes",
+                            lambda: {Chip: 2_147_483_672})
+        text = reg.render()
+        assert ('rate_limiter_state_resident_bytes{device="3",shard="0"} '
+                '2147483672') in text
+        assert 'rate_limiter_device_peak_bytes{device="3"} 2836000000' in text
+        assert 'rate_limiter_device_bytes_in_use{device="3"} 2200000000' \
+            in text
+        lim.close()
+
+    @pytest.mark.parametrize("name", ["dense", "sketch", "bucket"])
+    def test_the_scrape_hook_waits_for_no_dispatch(self, name):
+        """The hook takes no limiter lock and reads no buffer: it returns
+        while a launch holds the limiter's lock."""
+        import threading
+
+        lim, reg, _ = self.limiter(name)
+        base = lim._resident
+        done = threading.Event()
+        with base._lock:
+            worker = threading.Thread(
+                target=lambda: (lim._collect_device_memory(), done.set()),
+                daemon=True)
+            worker.start()
+            assert done.wait(timeout=10.0)
+        assert reg.get("rate_limiter_state_resident_bytes")._values
+        lim.close()
+
+
 class TestLoggingDecorator:
     def test_decisions_logged_at_debug(self, caplog):
         clock = ManualClock(0.0)

@@ -634,3 +634,123 @@ def test_the_native_door_serves_it_hashed_and_pipelined(algo):
     assert dense.result_fetches >= 12
     dense.close()
     exact.close()
+
+
+# ------------------------------------------ YCSB's skew: a cold tail all day
+
+ZIPF_S = 0.99          # YCSB's ZipfianGenerator.ZIPFIAN_CONSTANT
+BATCH = 256
+
+
+def zipf_stream(population: int, dispatches: int, seed: int) -> np.ndarray:
+    """``dispatches`` batches of BATCH ids in [2, population + 2): ranks
+    drawn Zipf(0.99), rank -> id by a seeded permutation (the
+    generator's scheme, chipbench/loadgen)."""
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, population + 1) ** ZIPF_S
+    ranks = rng.choice(population, size=(dispatches, BATCH), p=p / p.sum())
+    return (rng.permutation(population)[ranks] + 2).astype(np.uint64)
+
+
+def first_seen(stream: np.ndarray) -> list:
+    """Per dispatch, the rows whose key no earlier dispatch held."""
+    seen, out = set(), []
+    for rows in stream:
+        out.append(np.array([int(r) not in seen for r in rows]))
+        seen.update(int(r) for r in rows)
+    return out
+
+
+#: geometry -> (capacity, lanes, population, dispatches). The small table
+#: has 4-lane buckets, so first-seen keys share home buckets all the
+#: time; the large one is the server's geometry (128 lanes) at a capacity
+#: that is not tiny: 2^20 entries are 32 MB of state on the CPU.
+ZIPF_GEOMETRY = {"small": (16_384, 4, 32 * BATCH, 12),
+                 "2^20": (1 << 20, 128, 256 * BATCH, 6)}
+ZIPF_CASES = ([("small", algo, "hashed") for algo in ALGOS]
+              + [("small", "bucket", "ids"), ("small", "bucket", "door"),
+                 ("2^20", "bucket", "hashed")])
+
+
+@pytest.mark.parametrize("geometry, algo, lane", ZIPF_CASES,
+                         ids=["-".join(c) for c in ZIPF_CASES])
+def test_a_zipf_099_stream_whose_tail_keeps_arriving(geometry, algo, lane):
+    """exact-tb-20m's traffic in small (ISSUE 42): YCSB's zipfian constant
+    over a population >= 32x the batch, so that every dispatch brings
+    >= 10 % rows of keys never seen before — the directory's claim path
+    does a large share of the rows, not 1 % of them. Decisions and
+    ``remaining`` equal the plain rule's fed the same requests in the
+    same order, four tickets in flight; the directory inserted exactly
+    the reference's distinct keys; the state's resident bytes are
+    32 B an entry (the bucket's) + the padding slot's row."""
+    capacity, lanes, population, dispatches = ZIPF_GEOMETRY[geometry]
+    dense, exact, clock = pair(algo, capacity=capacity, lanes=lanes,
+                               probe_bound=8, limit=3)
+    stream = zipf_stream(population, dispatches, seed=99)
+    assert population >= 32 * BATCH
+    # The door finalizes an id with splitmix64 (as launch_ids does in the
+    # step); the hashed lane takes the hash as it comes.
+    hashes = stream if lane == "hashed" else splitmix64(stream)
+    geo = directory.geometry(capacity, lanes, 8)
+    new = first_seen(stream)
+    assert min(n.mean() for n in new) >= 0.10, [n.mean() for n in new]
+    # What hurts a claim: several first-seen keys of one home bucket in
+    # one dispatch, and one new key twice in one dispatch.
+    crowded = twice = 0
+    for rows, h, n in zip(stream, hashes, new):
+        twice += int(np.sum(np.unique(rows[n], return_counts=True)[1] > 1))
+        homes = bucket_of(np.unique(h[n]), geo["nb"], geo["w"])
+        crowded += int(np.sum(np.unique(homes, return_counts=True)[1] > 1))
+    assert twice > 0 and (crowded > 0 or lanes == 128), (twice, crowded)
+
+    def decided(rows):
+        return exact.allow_batch(names(rows))
+
+    if lane == "door":
+        from ratelimiter_tpu.serving import Client
+        from ratelimiter_tpu.serving.native_server import (
+            NativeRateLimitServer,
+            native_server_available,
+        )
+
+        if not native_server_available():
+            pytest.skip("needs g++ for the native server")
+        srv = NativeRateLimitServer(dense, "127.0.0.1", 0)
+        srv.start()
+        try:
+            with Client(port=srv.port) as c:
+                for step, rows in enumerate(stream):
+                    got, want = c.allow_hashed(rows), decided(rows)
+                    np.testing.assert_array_equal(got.allowed, want.allowed,
+                                                  err_msg=str(step))
+                    np.testing.assert_array_equal(
+                        got.remaining, want.remaining, err_msg=str(step))
+                    clock.advance(7.0)
+        finally:
+            srv.shutdown()
+    else:
+        launch = dense.launch_hashed if lane == "hashed" else dense.launch_ids
+        pending = []
+        for step, rows in enumerate(stream):
+            pending.append((launch(rows), decided(rows), step))
+            if len(pending) == 4:
+                for ticket, want, s in pending:
+                    same(dense.resolve(ticket), want, s)
+                pending.clear()
+            clock.advance(7.0)
+        for ticket, want, s in pending:
+            same(dense.resolve(ticket), want, s)
+    st = dense.directory_stats()
+    distinct = np.unique(stream).shape[0]
+    assert st["inserts"] == st["entries"] == exact.key_count() == distinct
+    assert st["unplaced"] == 0 and st["lookups"] == stream.size
+    assert st["inserts"] >= 0.10 * st["lookups"]
+    # A row of the rule's int64 columns + the directory's 64-bit key an
+    # entry (the bucket's three columns: 32 B), and the padding slot's row.
+    row = dense._fresh.nbytes
+    assert row == 24 or algo != "bucket"
+    (resident,) = dense.state_resident_bytes().values()
+    assert resident == (row + 8) * capacity + row == sum(
+        leaf.nbytes for leaf in dense._state.values())
+    dense.close()
+    exact.close()
