@@ -2,10 +2,11 @@
 
 The TPU answer to "Redis holds a key per user" (reference
 ``docs/ARCHITECTURE.md:458-469``): state lives in dense int64 columns in
-device memory, one row a key, and the keyspace directory that maps a key
-to its row lives there too (ops/directory.py, ADR-027) — lookup and
-insertion run inside the decision step, keyed by the 64-bit id the lane
-carries (string keys: the bulk hash of the prefixed key). Every decision
+device memory (held as their 32-bit words), one row a key, and the
+keyspace directory that maps a key to its row lives there too
+(ops/directory.py, ADR-027) — lookup and insertion run inside the
+decision step, keyed by the 64-bit id the lane carries (string keys:
+the bulk hash of the prefixed key). Every decision
 batch is one transfer, one fused jitted call (ops/dense_kernels.py,
 ``jit_dense_step``) and one fetch, through the hashed and pipelined
 surface the sketch backends use (algorithms/hashed_lane.py): the host
@@ -123,22 +124,34 @@ class DenseLimiter(HashedLane, RateLimiter):
 
         return dense_kernels.DENSE_ROWS, dense_kernels.unpack_dense
 
-    # The state is ``cols int64[K, C+1]`` (ops/dense_kernels.COLUMNS) and
-    # the directory's keys; the table-sized control updates below name
-    # their columns.
+    # The state is 32-bit words on the device — ``cols uint32[2K, C+1]``
+    # (ops/dense_kernels.COLUMNS) and the directory's ``dir_lo`` /
+    # ``dir_hi`` — and int64 only on the host: the two views below are
+    # what a snapshot holds and the one way tests and tools read it.
 
-    def _columns(self) -> dict:
-        from ratelimiter_tpu.ops.dense_kernels import COLUMNS
+    def _columns(self) -> np.ndarray:
+        """Host view: the state columns, ``int64[K, C+1]``, a row a name
+        of ``COLUMNS``."""
+        from ratelimiter_tpu.ops.sketch_kernels import join_words
 
-        return dict(zip(COLUMNS[self.config.algorithm], self._state["cols"]))
+        words = np.asarray(self._state["cols"])
+        k = words.shape[0] // 2
+        return join_words(words[:k], words[k:])
 
-    def _set_columns(self, **columns) -> None:
-        from ratelimiter_tpu.ops.dense_kernels import column
+    def _dir_keys(self) -> np.ndarray:
+        """Host view: the directory's keys, ``int64[NB, W]``."""
+        from ratelimiter_tpu.ops.sketch_kernels import join_words
 
-        cols = self._state["cols"]
-        for name, values in columns.items():
-            cols = cols.at[column(self.config.algorithm, name)].set(values)
-        self._state = dict(self._state, cols=cols)
+        return join_words(np.asarray(self._state["dir_lo"]),
+                          np.asarray(self._state["dir_hi"]))
+
+    def _rewrite(self, update, *scalars) -> None:
+        """One table-sized control update (ops/dense_kernels.build_rewrite)
+        of every slot. Lock must be held."""
+        from ratelimiter_tpu.ops.dense_kernels import build_rewrite
+
+        self._state = build_rewrite(self.config.algorithm, update)(
+            self._state, *scalars)
 
     def _policy_key(self, key: str) -> int:
         # The key's directory id, bit-cast: the step searches the table
@@ -179,18 +192,14 @@ class DenseLimiter(HashedLane, RateLimiter):
         [0, new_cap] (the consumption-stands contract, see
         exact.ExactLimiter._apply_config) and the pristine row used for
         fresh slots moves to the new full level."""
-        import jax.numpy as jnp
-
         from ratelimiter_tpu.ops import dense_kernels
 
         with self._lock:
             self._install_steps(new_cfg)
             if self.config.algorithm is Algorithm.TOKEN_BUCKET:
-                delta = (new_cfg.limit - self.config.limit) * MICROS
-                cap = new_cfg.limit * MICROS
-                self._set_columns(
-                    tokens=jnp.clip(self._columns()["tokens"] + delta, 0, cap),
-                    rem=0)
+                self._rewrite(dense_kernels.shift_tokens,
+                              (new_cfg.limit - self.config.limit) * MICROS,
+                              new_cfg.limit * MICROS)
 
     def _apply_window(self, new_cfg: Config) -> None:
         """Dynamic window: slot-state re-bucketing, same contract as the
@@ -200,8 +209,9 @@ class DenseLimiter(HashedLane, RateLimiter):
         from the kernel cache (window is part of its key).
 
         All grid quantities are host scalars, so the migration lowers to
-        a handful of elementwise selects over the slot arrays."""
-        import jax.numpy as jnp
+        a handful of elementwise selects over the slot arrays
+        (ops/dense_kernels.rebucket_*)."""
+        from ratelimiter_tpu.ops import dense_kernels
 
         W_new = to_micros(new_cfg.window)
         with self._lock:
@@ -217,38 +227,17 @@ class DenseLimiter(HashedLane, RateLimiter):
             self._install_steps(new_cfg)
             algo = self.config.algorithm
             if algo is Algorithm.FIXED_WINDOW:
-                # The live old window's span always reaches into the
-                # current new-grid window (now < cur_old + W_old), so a
-                # live count is always carried; stale slots zero.
-                was = self._columns()
-                live = was["win_start"] == cur_old
-                self._set_columns(
-                    count=jnp.where(live, was["count"], 0),
-                    win_start=jnp.where(live, jnp.int64(new_start), 0))
+                self._rewrite(dense_kernels.rebucket_fixed,
+                              cur_old, new_start)
             elif algo in (Algorithm.SLIDING_WINDOW, Algorithm.TPU_SKETCH):
-                was = self._columns()
-                ws = was["win_start"]
-                on_cur = ws == cur_old
-                on_prev = ws == cur_old - W_old
-                curr = jnp.where(on_cur, was["curr"], 0)
-                prev = jnp.where(on_cur, was["prev"],
-                                 jnp.where(on_prev, was["curr"], 0))
-                # The old curr bucket's span always overlaps the current
-                # new window (same argument as FW above) -> new curr.
-                # Old prev lands by its span end: current window, the
-                # one before (weighted boundary), or aged out.
+                # Where the old prev bucket's span ends on the new grid.
                 q_prev = (cur_old - 1) // W_new
-                new_curr = curr + (prev if q_prev >= p_now else 0)
-                new_prev = prev if q_prev == p_now - 1 else jnp.zeros_like(prev)
-                keep = (new_curr > 0) | (new_prev > 0)
-                self._set_columns(
-                    curr=jnp.where(keep, new_curr, 0),
-                    prev=jnp.where(keep, new_prev, 0),
-                    win_start=jnp.where(keep, jnp.int64(new_start), 0))
-            else:  # token bucket: rate changes (baked into the new step),
-                self._window_us = W_new  # levels/last stand, remainder
-                self._set_columns(rem=0)  # resets (< 1 micro-token,
-                return                    # toward denying).
+                self._rewrite(dense_kernels.rebucket_sliding, cur_old,
+                              W_old, new_start, q_prev >= p_now,
+                              q_prev == p_now - 1)
+            else:  # token bucket: the rate changes (baked into the new
+                # step), levels and last stand.
+                self._rewrite(dense_kernels.clear_rem)
             self._window_us = W_new
 
 
@@ -361,13 +350,16 @@ class DenseLimiter(HashedLane, RateLimiter):
 
     def capture_state(self):
         """Lock-held device→host transfer of the state columns and the
-        directory's keys (``state_dir_keys``); serialization/writing
-        happen in the caller, off-lock. Format/staleness contract:
+        directory's keys, joined ONCE here to the file's format
+        (``state_cols int64[K, C+1]``, ``state_dir_keys int64[NB, W]`` —
+        what the int64 layout before PR 43 wrote, so snapshots restore
+        across it both ways); serialization/writing happen in the
+        caller, off-lock. Format/staleness contract:
         ratelimiter_tpu/checkpoint.py."""
         self._check_open()
         with self._lock:
-            arrays = {f"state_{k}": np.asarray(v)
-                      for k, v in self._state.items()}
+            arrays = {"state_cols": self._columns(),
+                      "state_dir_keys": self._dir_keys()}
             arrays.update(self._policy_table.snapshot_arrays())
             extra = {"saved_at": self.clock.now(), "capacity": self._capacity}
         return "dense", arrays, extra
@@ -383,7 +375,7 @@ class DenseLimiter(HashedLane, RateLimiter):
 
         from ratelimiter_tpu.checkpoint import load_state
         from ratelimiter_tpu.core.errors import CheckpointError
-        from ratelimiter_tpu.ops import directory
+        from ratelimiter_tpu.ops import dense_kernels, directory
 
         self._check_open()
         arrays, meta = load_state(path, "dense", self.config)
@@ -393,21 +385,23 @@ class DenseLimiter(HashedLane, RateLimiter):
                 f"limiter capacity {self._capacity}")
         with self._lock:
             self._policy_table.restore_arrays(arrays)  # pops policy_* columns
-        expected = {f"state_{k}" for k in self._state}
+        expected = {"state_cols", "state_dir_keys"}
         if set(arrays) != expected:
             raise CheckpointError(
                 f"{path}: state arrays {sorted(arrays)} != expected "
                 f"{sorted(expected)}")
         keys = arrays["state_dir_keys"]
-        if keys.shape != self._state["dir_keys"].shape:
+        if keys.shape != self._state["dir_lo"].shape:
             raise CheckpointError(
                 f"{path}: directory of shape {keys.shape} != this "
-                f"limiter's {self._state['dir_keys'].shape}")
+                f"limiter's {self._state['dir_lo'].shape}")
+        # The file's int64 arrays as the device's words, split once here.
+        dir_lo, dir_hi = dense_kernels.split_host(keys[None])
+        words = {"cols": dense_kernels.split_host(arrays["state_cols"]),
+                 "dir_lo": dir_lo, "dir_hi": dir_hi}
         with self._lock:
             self._policy_dev = None
-            self._state = {
-                k: jax.device_put(arrays[f"state_{k}"], v.sharding)
-                for k, v in self._state.items()
-            }
+            self._state = {k: jax.device_put(words[k], v.sharding)
+                           for k, v in self._state.items()}
             self._entries = int(np.count_nonzero(
                 (keys != directory.EMPTY) & (keys != directory.TOMB)))

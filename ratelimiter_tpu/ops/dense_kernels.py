@@ -8,8 +8,10 @@ directory (ops/directory.py: lookup and insertion inside this same
 program), gather state for the batch's slots, sequence same-slot requests
 with ops.segment.admit, scatter the touched rows back IN PLACE into the
 donated columns. Nothing a dispatch does scales with the capacity: the
-work is the batch's. State lives in HBM across calls (donated buffers);
-time is an explicit int64-microsecond operand (SURVEY.md §2.4.14).
+work is the batch's — true on the chip since PR 43, which is why every
+capacity-sized leaf below is 32-bit. State lives in HBM across calls
+(donated buffers); time is an explicit int64-microsecond operand
+(SURVEY.md §2.4.14).
 
 The integer recurrences are bit-identical to algorithms/exact.py (see its
 module docstring for the micro-token / window-scaled representations), with
@@ -17,21 +19,32 @@ an int64-overflow gate checked at build time: configs too large for the
 exact-integer path (limits or windows beyond the gates below) raise at
 construction rather than silently losing precision.
 
-State layout: ``cols:int64[K, C+1]``, the rule's K columns stacked
-(``COLUMNS``), one slot a column index; the last slot is the padding slot
-that padding rows and rows the directory could not place are sent to —
-they carry n=0 and are discarded on the host. Stacked, a batch's rows
-are read by ONE gather and written by ONE scatter: on the TPU a gather
-or scatter costs by the index, not by the element (PERF.md §6, PR 33:
-three scatters 1.15 ms, one 0.1).
+State layout: ``cols:uint32[2K, C+1]``, the rule's K int64 columns
+(``COLUMNS``) as their 32-bit words — the low words stacked in rows
+``0..K-1``, the high words in rows ``K..2K-1`` — one slot a column
+index; the last slot is the padding slot that padding rows and rows the
+directory could not place are sent to — they carry n=0 and are discarded
+on the host. Stacked, a batch's rows are read by ONE gather and written
+by ONE scatter: on the TPU a gather or scatter costs by the index, not
+by the element (PERF.md §6, PR 33: three scatters 1.15 ms, one 0.1).
+The 64-bit values exist for the batch's rows only: gathered words are
+joined to ``int64[B]`` (``_read``), run through the rule unchanged, and
+split again where they are scattered (``_write_once``). The TPU has no
+64-bit vectors, so a 64-bit array is split where it enters a program
+and recombined where it leaves: held as int64 the state cost two passes
+over the whole table a dispatch (PERF.md §6, PR 42: 30.7 of a 32.2 ms
+step at 2^26 entries). No leaf sized by the capacity is 64-bit, at any
+program's boundary (tests/test_contract_dense.py pins it); the snapshot
+FILE keeps int64 arrays, converted on the host (algorithms/dense.py).
 
 * fixed window:  count, win_start (us)
 * sliding:       curr, prev, win_start (us)
 * token bucket:  tokens (micro-tokens), rem (refill remainder), last (us)
-* the directory: dir_keys:int64[NB, W], NB * W == C (ops/directory.py);
-                 entry (b, l) is slot b * W + l. An entry that holds no
-                 key always has a pristine state column, so inserting a
-                 key writes the key and nothing else.
+* the directory: dir_lo, dir_hi: uint32[NB, W], NB * W == C, a key's two
+                 words (ops/directory.py); entry (b, l) is slot b * W +
+                 l. An entry that holds no key always has a pristine
+                 state column, so inserting a key writes the key and
+                 nothing else.
 
 The serving step (``build_hashed_step``, module ``jit_dense_step``) takes
 ONE staged uint64 buffer ``[ids | n | now_us]`` (sketch_kernels.unstage)
@@ -157,17 +170,27 @@ def _divmod(x, d, static: int, overridden):
                         lambda: (x // static, x % static))
 
 
+def _read(state: State, sid):
+    """The batch's rows of every column, ``int64[K, B]``: ONE gather of
+    the words at the batch's slots, joined."""
+    rows = state["cols"][:, sid]
+    k = rows.shape[0] // 2
+    return directory.join(rows[:k], rows[k:])
+
+
 def _write_once(state: State, sid, last, *columns) -> State:
-    """The state with ``columns`` (one int64[B] a row of ``cols``, in
-    order) written at the batch's slots, each touched slot ONCE and all
-    columns by one scatter: a request that is the last of its slot in the
-    batch (``admit(..., tails=True)``) keeps its slot, every other one
-    gets an index of its own past the end, which ``mode="drop"``
-    discards. No two indices are equal, so the scatter is told so."""
+    """The state with ``columns`` (one int64[B] a column of ``COLUMNS``,
+    in order) written at the batch's slots as their words, each touched
+    slot ONCE and all words by one scatter: a request that is the last of
+    its slot in the batch (``admit(..., tails=True)``) keeps its slot,
+    every other one gets an index of its own past the end, which
+    ``mode="drop"`` discards. No two indices are equal, so the scatter is
+    told so."""
     at = jnp.where(last, sid, _PAST + jax.lax.iota(jnp.int32, sid.shape[0]))
     rows = jnp.stack([jnp.broadcast_to(c, sid.shape) for c in columns])
     return {**state, "cols": state["cols"].at[:, at].set(
-        rows, mode="drop", unique_indices=True)}
+        jnp.concatenate(directory.words(rows)), mode="drop",
+        unique_indices=True)}
 
 
 #: Where dropped scatter indices start: past any column (step_statics
@@ -230,7 +253,7 @@ def _fixed_window_step(state: State, sid, n, now_us, policy=None, keyq=None,
                               (limit, window_us))
     # per-request grid when windows are per-key
     cur_ws = _divmod(now_us, W, window_us, over)[0] * W
-    count, win_start = state["cols"][:, sid]      # one gather
+    count, win_start = _read(state, sid)
     stale = win_start != cur_ws
     count_eff = jnp.where(stale, 0, count)
 
@@ -261,7 +284,7 @@ def _sliding_window_step(state: State, sid, n, now_us, policy=None, keyq=None,
     (lim, W), over = _resolve(policy, keyq, ("limit", "window_us"),
                               (limit, window_us))
     cur_ws = _divmod(now_us, W, window_us, over)[0] * W
-    curr, prev, ws = state["cols"][:, sid]        # one gather
+    curr, prev, ws = _read(state, sid)
     current = ws == cur_ws
     rolled_one = ws == cur_ws - W
     curr_eff = jnp.where(current, curr, 0)
@@ -295,7 +318,7 @@ def _token_bucket_step(state: State, sid, n, now_us, policy=None, keyq=None,
         (limit, window_us, rate_num, rate_den))
     cap = lim * MICROS
     with jax.named_scope("refill"):
-        tokens, rem, last = state["cols"][:, sid]     # one gather
+        tokens, rem, last = _read(state, sid)
 
         elapsed = jnp.maximum(0, now_us - last)
         full = elapsed >= W  # time-to-full from any level <= window
@@ -345,11 +368,21 @@ def fresh_row(algorithm: Algorithm, limit: int) -> Tuple[int, ...]:
     return (0,) * len(COLUMNS[algorithm])
 
 
+def split_host(x):
+    """Host twin of ``directory.words``, stacked: ``int64[K, ...]`` ->
+    ``uint32[2K, ...]``, low words first (the layout of ``cols``)."""
+    import numpy as np
+
+    x = np.asarray(x, np.int64)
+    return np.concatenate([(x & 0xFFFFFFFF).astype(np.uint32),
+                           (x >> 32).astype(np.uint32)])
+
+
 def init_state(algorithm: Algorithm, capacity: int, limit: int) -> State:
     """Fresh ``cols`` of capacity+1 slots (last = padding slot), every
-    slot ``fresh_row``."""
+    slot ``fresh_row``'s words."""
     ensure_x64()
-    fresh = jnp.asarray(fresh_row(algorithm, limit), jnp.int64)
+    fresh = jnp.asarray(split_host(fresh_row(algorithm, limit)))
     return {"cols": jnp.tile(fresh[:, None], (1, capacity + 1))}
 
 
@@ -412,8 +445,9 @@ def column(algorithm: Algorithm, name: str) -> int:
 def init_directory_state(cfg: Config, capacity: int) -> State:
     """``init_state`` plus an empty directory of ``capacity`` entries."""
     geo = directory.geometry(capacity, cfg.dense.lanes, cfg.dense.probe_bound)
+    dir_lo, dir_hi = directory.init_keys(geo["nb"], geo["w"])
     return {**init_state(cfg.algorithm, capacity, cfg.limit),
-            "dir_keys": directory.init_keys(geo["nb"], geo["w"])}
+            "dir_lo": dir_lo, "dir_hi": dir_hi}
 
 
 def step_statics(cfg: Config, capacity: int) -> dict:
@@ -489,8 +523,8 @@ def _dense_step_staged(state: State, staged, policy, *, premix: bool,
             ids = splitmix64_dev(ids)
     keyq = ids.astype(jnp.int64)            # the override table's key
     valid = n > 0                           # padding rows carry n = 0
-    dir_keys, slot, placed, claimed, probes = directory.probe(
-        state["dir_keys"], directory.canon(ids), valid,
+    (dir_lo, dir_hi), slot, placed, claimed, probes = directory.probe(
+        (state["dir_lo"], state["dir_hi"]), directory.canon(ids), valid,
         nb=nb, w=w, pb=pb, insert=True)
     # A row without an entry goes to the padding slot with n = 0: it
     # reads and writes nothing of any key.
@@ -504,7 +538,7 @@ def _dense_step_staged(state: State, staged, policy, *, premix: bool,
                 directory.distinct(slot, claimed),
                 jnp.sum(unplaced, dtype=jnp.int32))
         zero = jnp.int64(0)
-        return {**state, "dir_keys": dir_keys}, pack_dense(
+        return {**state, "dir_lo": dir_lo, "dir_hi": dir_hi}, pack_dense(
             allowed | unplaced,
             jnp.where(unplaced, zero, jnp.maximum(remaining, zero)),
             jnp.where(unplaced, zero, retry_us), reset_us - now_us, tail)
@@ -526,43 +560,53 @@ def build_hashed_step(cfg: Config, capacity: int, *,
 def _dense_reclaim(state: State, now_us, fresh, *, stamp: int, nb: int,
                    w: int, pb: int, horizon_us: int):
     """The table-sized pass (``jit_dense_reclaim``): entries idle for the
-    horizon (by row ``stamp`` of ``cols``) are given up and their slots
+    horizon (by column ``stamp`` of ``COLUMNS``) are given up and their slots
     made pristine (``fresh int64[K]``), tombstones no live key walked
     past become EMPTY. Returns ``(state, entries freed)``."""
     cap = nb * w
     with jax.named_scope("reclaim"):
         cols = state["cols"]
-        dir_keys, freed = directory.reclaim(
-            state["dir_keys"], cols[stamp, :cap].reshape(nb, w), now_us,
-            nb=nb, w=w, pb=pb, horizon_us=horizon_us)
+        k = cols.shape[0] // 2
+        stamps = directory.join(cols[stamp, :cap], cols[k + stamp, :cap])
+        (dir_lo, dir_hi), freed = directory.reclaim(
+            (state["dir_lo"], state["dir_hi"]), stamps.reshape(nb, w),
+            now_us, nb=nb, w=w, pb=pb, horizon_us=horizon_us)
         flat = jnp.concatenate([freed.reshape(cap), jnp.zeros((1,), bool)])
-        return ({"cols": jnp.where(flat[None, :], fresh[:, None], cols),
-                 "dir_keys": dir_keys}, jnp.sum(freed, dtype=jnp.int32))
+        return ({"cols": jnp.where(flat[None, :], _fresh_words(fresh), cols),
+                 "dir_lo": dir_lo, "dir_hi": dir_hi},
+                jnp.sum(freed, dtype=jnp.int32))
+
+
+def _fresh_words(fresh):
+    """``fresh int64[K]`` (the pristine row, a program's operand) as the
+    ``uint32[2K, 1]`` column of words it is in ``cols``."""
+    return jnp.concatenate(directory.words(fresh))[:, None]
 
 
 def _dense_forget(state: State, ids, valid, fresh, *, clear, nb: int,
                   w: int, pb: int):
     """Find each key (no insertion). ``clear`` None (reset): its entry
     becomes a tombstone and its slot pristine (``fresh int64[K]``);
-    ``clear`` a row of ``cols``: that column of its slot is zeroed (the
-    token bucket's refill remainder, when an override changed the rate it
-    is denominated in). Returns ``(state, keys found)``."""
+    ``clear`` a column of ``COLUMNS``: both its words of the slot are
+    zeroed (the token bucket's refill remainder, when an override changed
+    the rate it is denominated in). Returns ``(state, keys found)``."""
     cap = nb * w
+    cols, dir_lo, dir_hi = state["cols"], state["dir_lo"], state["dir_hi"]
     _, slot, found, _, _ = directory.probe(
-        state["dir_keys"], directory.canon(ids), valid,
+        (dir_lo, dir_hi), directory.canon(ids), valid,
         nb=nb, w=w, pb=pb, insert=False)
     at = jnp.where(found, slot, cap + 1)           # out of range: dropped
-    cols, dir_keys = state["cols"], state["dir_keys"]
     if clear is not None:
-        cols = cols.at[clear, at].set(0, mode="drop")
+        both = jnp.asarray([clear, cols.shape[0] // 2 + clear])
+        cols = cols.at[both[:, None], at[None, :]].set(0, mode="drop")
     else:
-        dir_keys = dir_keys.at[jnp.where(found, slot // w, nb),
-                               slot % w].set(jnp.int64(directory.TOMB),
-                                             mode="drop")
+        entry = (jnp.where(found, slot // w, nb), slot % w)
+        dir_lo = dir_lo.at[entry].set(jnp.uint32(directory.TOMB), mode="drop")
+        dir_hi = dir_hi.at[entry].set(jnp.uint32(0), mode="drop")
         cols = cols.at[:, at].set(
-            jnp.broadcast_to(fresh[:, None], (fresh.shape[0], at.shape[0])),
-            mode="drop")
-    return ({"cols": cols, "dir_keys": dir_keys},
+            jnp.broadcast_to(_fresh_words(fresh),
+                             (cols.shape[0], at.shape[0])), mode="drop")
+    return ({"cols": cols, "dir_lo": dir_lo, "dir_hi": dir_hi},
             jnp.sum(found, dtype=jnp.int32))
 
 
@@ -589,6 +633,78 @@ def build_controls(cfg: Config, capacity: int) -> Tuple[Callable, ...]:
             named("dense_clear_rem", _dense_forget, clear=rem, **geo),
             donate_argnums=(0,))),
     )
+
+
+# ------------------------------------- table-sized control updates
+#
+# What a dynamic limit or window does to EVERY slot: elementwise selects
+# over the columns with host scalars as operands. Each ``update(was,
+# *scalars)`` takes the columns by name as int64[C+1] and returns the
+# ones it replaces; ``_dense_rewrite`` runs it between a join and a
+# split inside one program, so the 64-bit columns never cross a
+# boundary. Control plane: the decision step never runs them.
+
+def shift_tokens(was, delta, cap):
+    """Dynamic limit, token bucket: levels move by the limit's delta,
+    clamped to [0, new cap]; the remainder resets."""
+    return dict(tokens=jnp.clip(was["tokens"] + delta, 0, cap), rem=0)
+
+
+def clear_rem(was):
+    """Dynamic window, token bucket: the rate changed, the remainder is
+    in the old one's denomination (< 1 micro-token, toward denying)."""
+    return dict(rem=0)
+
+
+def rebucket_fixed(was, cur_old, new_start):
+    """Dynamic window, fixed window: the live old window's span always
+    reaches into the current new-grid window (now < cur_old + W_old), so
+    a live count is always carried; stale slots zero."""
+    live = was["win_start"] == cur_old
+    return dict(count=jnp.where(live, was["count"], 0),
+                win_start=jnp.where(live, new_start, 0))
+
+
+def rebucket_sliding(was, cur_old, w_old, new_start, prev_is_current,
+                     prev_is_previous):
+    """Dynamic window, sliding: the old curr bucket's span always
+    overlaps the current new window (as above) -> new curr. Old prev
+    lands by its span end (host scalars: ``prev_is_current``, the
+    current new window; ``prev_is_previous``, the one before — the
+    weighted boundary; neither: aged out)."""
+    ws = was["win_start"]
+    on_cur = ws == cur_old
+    curr = jnp.where(on_cur, was["curr"], 0)
+    prev = jnp.where(on_cur, was["prev"],
+                     jnp.where(ws == cur_old - w_old, was["curr"], 0))
+    new_curr = curr + jnp.where(prev_is_current, prev, 0)
+    new_prev = jnp.where(prev_is_previous, prev, 0)
+    keep = (new_curr > 0) | (new_prev > 0)
+    return dict(curr=jnp.where(keep, new_curr, 0),
+                prev=jnp.where(keep, new_prev, 0),
+                win_start=jnp.where(keep, new_start, 0))
+
+
+def _dense_rewrite(state: State, *scalars, algorithm, update):
+    names = COLUMNS[algorithm]
+    cols = state["cols"]
+    k = len(names)
+    was = dict(zip(names, directory.join(cols[:k], cols[k:])))
+    now = {**was, **update(was, *scalars)}
+    rows = jnp.stack([jnp.broadcast_to(jnp.asarray(now[name], jnp.int64),
+                                       cols.shape[1:]) for name in names])
+    return {**state, "cols": jnp.concatenate(directory.words(rows))}
+
+
+def build_rewrite(algorithm: Algorithm, update: Callable) -> Callable:
+    """Jitted ``rewrite(state, *scalars) -> state`` applying ``update``
+    (one of the functions above) to every slot, module
+    ``jit_dense_<update>``; state is donated."""
+    ensure_x64()
+    kw = dict(algorithm=algorithm, update=update)
+    return memoized(_BUILT, kw, ("rewrite",), lambda: jax.jit(
+        named(f"dense_{update.__name__}", _dense_rewrite, **kw),
+        donate_argnums=(0,)))
 
 
 def _dense_scan(state: State, sids, ns, now0_us, dt_us, *, fn):

@@ -5,12 +5,20 @@ carries. Lookup AND insertion run inside the one jitted decision step, so
 the host never maps a key to a slot: an entry's position IS the slot of
 the key's state row.
 
-Layout: ``dir_keys int64[NB, W]`` — ``NB`` buckets of ``W`` lanes,
-``NB * W == capacity``; entry ``(b, l)`` is slot ``b * W + l`` of the
-state columns. ``W = gcd(capacity, DenseParams.lanes)`` (128, a vector
-register's lanes, for any capacity that is a multiple of 128): a probe
-reads one whole bucket row, so at a load of one half almost every key is
-found by ONE row gather.
+Layout: a key is its two 32-bit words, everywhere in this module a pair
+``(low, high)`` of uint32 arrays of one shape — the table is the pair
+``(dir_lo, dir_hi)``, each ``uint32[NB, W]``: ``NB`` buckets of ``W``
+lanes, ``NB * W == capacity``; entry ``(b, l)`` is slot ``b * W + l`` of
+the state columns. Two leaves of 32-bit words and not one of int64: the
+TPU has no 64-bit vectors, so XLA splits a 64-bit array where it enters
+a program and recombines it where it leaves — a pass over the whole
+table at both ends of every dispatch (30.7 of a 32.2 ms step at 2^26
+entries, PERF.md §6, PR 42 / 43) — and not one stacked ``[2, NB, W]``
+array either, which every pass of the probe loop would relay out. ``W =
+gcd(capacity, DenseParams.lanes)`` (128, a vector register's lanes, for
+any capacity that is a multiple of 128): a probe reads one whole bucket
+row of each leaf, so at a load of one half almost every key is found by
+one pair of row gathers.
 
 Probing: a key's home bucket is a 32-bit mix of its two words; a probe
 walks home, home+1, ... (mod NB) for at most ``probe_bound`` buckets. A
@@ -23,14 +31,23 @@ Identity: two rows of a batch that carry the same new key compute the
 same claim (a function of key and table only) and both read their key
 back; two DIFFERENT keys that claim one lane are told apart by the
 re-read — the scatter keeps one, the loser tries again in the same
-bucket. A row is never given another key's slot: it resolves only to an
-entry that reads back its own key. A row that finds neither its key nor
-an EMPTY lane within the bound is UNPLACED: it touches no state and the
-host answers it by the fail-open / fail-closed policy.
+bucket. The claim has two phases, one a word: every claimant of a lane
+writes its LOW word and reads it back; the survivors (low word equal —
+they may still differ in the high word) write their HIGH word and read
+that back; a row has won only when BOTH words read back its own key.
+The lane always ends the pass holding one claimant's whole key: the low
+word's scatter keeps one claimant's, that claimant survives, and only
+survivors — all of that low word — write a high word. A row is never
+given another key's slot: it resolves only to an entry that reads back
+its own key. A row that finds neither its key nor an EMPTY lane within
+the bound is UNPLACED: it touches no state and the host answers it by
+the fail-open / fail-closed policy.
 
-Reserved values: EMPTY = 0 and TOMB = 1. An id equal to one of them is
-remapped (xor with a constant); beside a 64-bit collision that is the
-only way two ids can share a bucket.
+Reserved values: EMPTY = (0, 0) and TOMB = (1, 0), the 64-bit values 0
+and 1. An id equal to one of them is remapped (xor with a constant);
+beside a 64-bit collision that is the only way two ids can share a
+bucket — a key whose low word is 0 or 1 with a non-zero high word is an
+ordinary key, and an equal low or high word alone matches nothing.
 
 Reclaim (``reclaim``, a program of its own): an entry idle for the
 horizon equals a fresh one, so it becomes a tombstone and its state row
@@ -49,8 +66,8 @@ import jax.numpy as jnp
 
 EMPTY = 0
 TOMB = 1
-#: What a reserved id is xor-ed with (the 64-bit golden ratio, as int64).
-_REMAP = 0x9E3779B97F4A7C15 - (1 << 64)
+#: What a reserved id is xor-ed with (the 64-bit golden ratio), by word.
+_REMAP_LO, _REMAP_HI = 0x7F4A7C15, 0x9E3779B9
 
 #: Words the step appends to its packed result: rows looked up, buckets
 #: examined, entries inserted (distinct keys), rows left unplaced.
@@ -65,14 +82,45 @@ def geometry(capacity: int, lanes: int, probe_bound: int) -> dict:
     return dict(nb=nb, w=w, pb=min(int(probe_bound), nb))
 
 
+def words(x):
+    """A 64-bit integer array as its ``(low, high)`` uint32 words, by a
+    mask and a shift (a ``bitcast_convert_type`` on a 64-bit type is
+    what the TPU's X64 rewriter refuses, PERF.md §5)."""
+    u = x.astype(jnp.uint64)
+    return ((u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
+            (u >> jnp.uint64(32)).astype(jnp.uint32))
+
+
+def join(lo, hi):
+    """The int64 array of uint32 words ``(lo, hi)``: ``words``' inverse.
+    For a batch's rows and inside table-sized control programs only — a
+    capacity-sized 64-bit array never crosses a program's boundary."""
+    return ((hi.astype(jnp.uint64) << jnp.uint64(32))
+            | lo.astype(jnp.uint64)).astype(jnp.int64)
+
+
 def init_keys(nb: int, w: int):
-    return jnp.zeros((nb, w), jnp.int64)
+    """An empty table: ``(dir_lo, dir_hi)``, every lane EMPTY."""
+    return jnp.zeros((nb, w), jnp.uint32), jnp.zeros((nb, w), jnp.uint32)
 
 
 def canon(ids):
-    """uint64 ids -> int64 directory keys, reserved values remapped."""
-    k = ids.astype(jnp.int64)
-    return jnp.where((k == EMPTY) | (k == TOMB), k ^ jnp.int64(_REMAP), k)
+    """uint64 ids -> directory keys (word pairs), reserved values
+    remapped."""
+    lo, hi = k = words(ids)
+    reserved = _reserved(k)
+    return (jnp.where(reserved, lo ^ jnp.uint32(_REMAP_LO), lo),
+            jnp.where(reserved, jnp.uint32(_REMAP_HI), hi))
+
+
+def _is(keys, value: int):
+    """Where ``keys`` holds the reserved 64-bit ``value`` (EMPTY, TOMB)."""
+    return (keys[0] == value) & (keys[1] == 0)
+
+
+def _reserved(keys):
+    """Where ``keys`` holds EMPTY or TOMB: no key."""
+    return (keys[1] == 0) & (keys[0] <= TOMB)
 
 
 def _fmix32(h):
@@ -84,9 +132,8 @@ def _fmix32(h):
 
 
 def home(k, nb: int, w: int):
-    """(home bucket, preferred lane) int32 of int64 keys, any shape."""
-    lo = (k & 0xFFFFFFFF).astype(jnp.uint32)
-    hi = (k >> 32).astype(jnp.uint32)
+    """(home bucket, preferred lane) int32 of keys, any shape."""
+    lo, hi = k
     h = _fmix32(lo ^ (hi * jnp.uint32(0x9E3779B1) + jnp.uint32(0x7F4A7C15)))
     bucket = (h & jnp.uint32(nb - 1)) if nb & (nb - 1) == 0 \
         else h % jnp.uint32(nb)
@@ -95,7 +142,8 @@ def home(k, nb: int, w: int):
 
 
 def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
-    """Resolve ``k int64[B]`` (rows with ``valid``) against ``keys2d``.
+    """Resolve the keys ``k`` (a pair of ``uint32[B]``; rows with
+    ``valid``) against the table ``keys2d`` (a pair of ``uint32[NB, W]``).
 
     Returns ``(keys2d, slot int32[B], placed bool[B], claimed bool[B],
     probes int32[])``: ``slot`` is the entry of the row's key where
@@ -105,7 +153,8 @@ def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
     claims an EMPTY lane of the first bucket on its path that has one;
     without, an absent key is simply not ``placed``.
     """
-    B = k.shape[0]
+    klo, khi = k
+    B = klo.shape[0]
     pos0, pref = home(k, nb, w)
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (B, w), 1)
     # Every pass places at least one claimant of each contested lane, so
@@ -113,20 +162,21 @@ def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
     cap = pb + B
 
     def cond(c):
-        return jnp.any(c[3]) & (c[7] < cap)
+        return jnp.any(c[4]) & (c[8] < cap)
 
     def body(c):
-        keys2d, pos, hops, active, slot, claimed, probes, it = c
+        lo2d, hi2d, pos, hops, active, slot, claimed, probes, it = c
         with jax.named_scope("directory_probe"):
-            rows = keys2d.at[pos].get(mode="promise_in_bounds")   # [B, w]
-            hit = rows == k[:, None]
+            rows = (lo2d.at[pos].get(mode="promise_in_bounds"),    # [B, w]
+                    hi2d.at[pos].get(mode="promise_in_bounds"))
+            hit = (rows[0] == klo[:, None]) & (rows[1] == khi[:, None])
             found = active & jnp.any(hit, axis=1)
             slot = jnp.where(
                 found, pos * w + jnp.argmax(hit, axis=1).astype(jnp.int32),
                 slot)
             probes = probes + jnp.sum(active, dtype=jnp.int32)
             active = active & ~found
-            empty = rows == EMPTY
+            empty = _is(rows, EMPTY)
             has_empty = jnp.any(empty, axis=1)
         if insert:
             with jax.named_scope("directory_insert"):
@@ -136,10 +186,17 @@ def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
                 # lanes, so one pass places nearly all of them.
                 dist = jnp.where(empty, (lane_iota - pref[:, None]) % w, w)
                 lane = jnp.argmin(dist, axis=1).astype(jnp.int32)
-                keys2d = keys2d.at[jnp.where(claim, pos, nb), lane].set(
-                    k, mode="drop")
-                won = claim & (keys2d.at[pos, lane].get(
-                    mode="promise_in_bounds") == k)
+                # Phase one, the low word: the scatter keeps one
+                # claimant's; whoever reads its own back goes on.
+                lo2d = lo2d.at[jnp.where(claim, pos, nb), lane].set(
+                    klo, mode="drop")
+                low = claim & (lo2d.at[pos, lane].get(
+                    mode="promise_in_bounds") == klo)
+                # Phase two, the high word, among those of that low word.
+                hi2d = hi2d.at[jnp.where(low, pos, nb), lane].set(
+                    khi, mode="drop")
+                won = low & (hi2d.at[pos, lane].get(
+                    mode="promise_in_bounds") == khi)
                 slot = jnp.where(won, pos * w + lane, slot)
                 claimed = claimed | won
                 active = active & ~won
@@ -151,14 +208,14 @@ def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
         hops = hops + move.astype(jnp.int32)
         active = active & (hops < pb)
         pos = jnp.where(move, (pos + 1) % nb, pos)
-        return keys2d, pos, hops, active, slot, claimed, probes, it + 1
+        return lo2d, hi2d, pos, hops, active, slot, claimed, probes, it + 1
 
-    init = (keys2d, pos0, jnp.zeros((B,), jnp.int32), valid,
+    init = (*keys2d, pos0, jnp.zeros((B,), jnp.int32), valid,
             jnp.full((B,), -1, jnp.int32), jnp.zeros((B,), bool),
             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-    keys2d, _, _, _, slot, claimed, probes, _ = jax.lax.while_loop(
+    lo2d, hi2d, _, _, _, slot, claimed, probes, _ = jax.lax.while_loop(
         cond, body, init)
-    return keys2d, slot, valid & (slot >= 0), claimed, probes
+    return (lo2d, hi2d), slot, valid & (slot >= 0), claimed, probes
 
 
 def distinct(slot, mask):
@@ -174,17 +231,18 @@ def reclaim(keys2d, stamp, now_us, *, nb: int, w: int, pb: int,
     """One table-sized pass: ``(keys2d, freed bool[NB, W])``.
 
     ``stamp int64[NB, W]`` is each entry's last-touched instant (the
-    bucket's ``last`` / the window rules' ``win_start``); a live entry
-    with ``stamp <= now - horizon`` is given up. Then tombstones — these
-    and earlier ones — go back to EMPTY wherever the cover rule allows:
-    bucket ``b`` is covered while some live key sits ``j >= 1`` buckets
-    past it having been displaced at least ``j``, i.e. having walked
-    through ``b``; only a covered bucket's tombstones must stay.
+    bucket's ``last`` / the window rules' ``win_start``), joined from
+    its words inside the calling program; a live entry with ``stamp <=
+    now - horizon`` is given up. Then tombstones — these and earlier
+    ones — go back to EMPTY wherever the cover rule allows: bucket ``b``
+    is covered while some live key sits ``j >= 1`` buckets past it
+    having been displaced at least ``j``, i.e. having walked through
+    ``b``; only a covered bucket's tombstones must stay.
     """
-    live = (keys2d != EMPTY) & (keys2d != TOMB)
+    live = ~_reserved(keys2d)
     freed = live & (stamp <= now_us - horizon_us)
-    keys2d = jnp.where(freed, jnp.int64(TOMB), keys2d)
     live = live & ~freed
+    tomb = freed | _is(keys2d, TOMB)
     hb, _ = home(keys2d, nb, w)
     here = jax.lax.broadcasted_iota(jnp.int32, (nb, w), 0)
     disp = jnp.where(live, (here - hb) % nb, 0)
@@ -192,6 +250,8 @@ def reclaim(keys2d, stamp, now_us, *, nb: int, w: int, pb: int,
     covered = jnp.zeros((nb,), bool)
     for j in range(1, pb):
         covered = covered | (jnp.roll(reach, -j) >= j)
-    keys2d = jnp.where((keys2d == TOMB) & ~covered[:, None],
-                       jnp.int64(EMPTY), keys2d)
-    return keys2d, freed
+    # A tombstone is (TOMB, 0) where it must stay, (EMPTY, 0) elsewhere.
+    lo, hi = keys2d
+    lo = jnp.where(tomb, jnp.where(covered[:, None], jnp.uint32(TOMB),
+                                   jnp.uint32(EMPTY)), lo)
+    return (lo, jnp.where(tomb, jnp.uint32(0), hi)), freed

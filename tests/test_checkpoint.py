@@ -235,6 +235,139 @@ class TestValidation:
         lim2.close()
 
 
+class TestDenseSnapshotAcrossLayouts:
+    """ISSUE 43: the dense state is 32-bit words on the device, the
+    snapshot FILE stays what the int64 layout wrote — ``state_cols
+    int64[K, C+1]`` and ``state_dir_keys int64[NB, W]`` — converted once
+    on the host, so a snapshot crosses the change in both directions."""
+
+    CAPACITY, LANES, LIMIT, SPENT = 64, 4, 5, 3
+    #: Finalized 64-bit ids: small, above 2**32, negative as int64, and a
+    #: pair that shares each word with another key.
+    IDS = np.array([7, (1 << 32) + 7, (9 << 32) + 7, (9 << 32) + 8,
+                    (1 << 63) + 5, (1 << 64) - 2, 0xDEADBEEF12345678,
+                    (1 << 63) | (1 << 31)], np.uint64)
+
+    def config(self, algo):
+        from ratelimiter_tpu import DenseParams
+
+        return Config(algorithm=algo, limit=self.LIMIT, window=60.0,
+                      dense=DenseParams(capacity=self.CAPACITY,
+                                        lanes=self.LANES, probe_bound=16))
+
+    def by_hand(self, algo):
+        """The arrays the int64 layout held after every key of IDS spent
+        SPENT at T0, written down here with no help from the backend:
+        each key in the first free lane of its home bucket, its state
+        row beside it, every other row pristine."""
+        import jax.numpy as jnp
+
+        from ratelimiter_tpu.ops import directory
+
+        nb, w = self.CAPACITY // self.LANES, self.LANES
+        lo = jnp.asarray((self.IDS & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+        hi = jnp.asarray((self.IDS >> np.uint64(32)).astype(np.uint32))
+        homes = np.asarray(directory.home((lo, hi), nb, w)[0])
+        micros, t0_us = 1_000_000, int(T0 * 1_000_000)
+        win = t0_us // (60 * micros) * (60 * micros)
+        fresh, used = {
+            Algorithm.TOKEN_BUCKET: (
+                (self.LIMIT * micros, 0, 0),
+                ((self.LIMIT - self.SPENT) * micros, 0, t0_us)),
+            Algorithm.FIXED_WINDOW: ((0, 0), (self.SPENT, win)),
+            Algorithm.SLIDING_WINDOW: ((0, 0, 0), (self.SPENT, 0, win)),
+        }[algo]
+        cols = np.tile(np.array(fresh, np.int64)[:, None],
+                       (1, self.CAPACITY + 1))
+        keys = np.zeros((nb, w), np.int64)
+        for key, b in zip(self.IDS.view(np.int64), homes):
+            lane = int(np.argmin(keys[b] != 0))
+            assert keys[b, lane] == 0, "home bucket full: pick other ids"
+            keys[b, lane] = key
+            cols[:, b * w + lane] = used
+        assert (keys < 0).sum() >= 4 and (keys > 1 << 32).sum() >= 2
+        return {"state_cols": cols, "state_dir_keys": keys}
+
+    def write(self, path, lim, arrays):
+        from ratelimiter_tpu.checkpoint import save_state
+
+        save_state(path, "dense", lim.config, arrays,
+                   {"saved_at": T0, "capacity": self.CAPACITY})
+
+    @pytest.mark.parametrize("algo", [Algorithm.TOKEN_BUCKET,
+                                      Algorithm.FIXED_WINDOW,
+                                      Algorithm.SLIDING_WINDOW],
+                             ids=lambda a: a.value)
+    def test_an_int64_snapshot_restores_and_is_written_back_byte_equal(
+            self, algo, tmp_path):
+        path, again = str(tmp_path / "old.npz"), str(tmp_path / "new.npz")
+        arrays = self.by_hand(algo)
+        clock = ManualClock(T0)
+        dense = create_limiter(self.config(algo), backend="dense",
+                               clock=clock)
+        self.write(path, dense, arrays)
+        dense.restore(path)
+        assert dense.key_count() == self.IDS.shape[0]
+        # On the device: words. In the file: what was read, byte for byte.
+        assert {str(v.dtype) for v in dense._state.values()} == {"uint32"}
+        kind, written, extra = dense.capture_state()
+        assert kind == "dense" and extra["capacity"] == self.CAPACITY
+        for name, want in arrays.items():
+            assert written[name].dtype == np.int64
+            assert written[name].shape == want.shape
+            assert written[name].tobytes() == want.tobytes(), name
+        # ... and through a file of its own, into a second limiter.
+        dense.save(again)
+        twin = create_limiter(self.config(algo), backend="dense",
+                              clock=clock)
+        twin.restore(again)
+        for name, want in arrays.items():
+            assert twin.capture_state()[1][name].tobytes() == want.tobytes()
+        # The restored state decides as the plain rule that lived the
+        # same history: every key spent SPENT at T0.
+        exact = create_limiter(self.config(algo), backend="exact",
+                               clock=clock)
+        names = [f"k{int(i)}" for i in self.IDS]
+        assert exact.allow_batch(names, [self.SPENT] * len(names)
+                                 ).allowed.all()
+        rng = np.random.default_rng(43)
+        new = np.array([11, (3 << 32) + 7, (1 << 63) + 6], np.uint64)
+        for step in range(5):
+            clock.advance(float(rng.uniform(0.5, 20.0)))
+            ids = rng.choice(np.concatenate([self.IDS, new]), size=24)
+            want = exact.allow_batch([f"k{int(i)}" for i in ids])
+            for lim in (dense, twin):
+                got = lim.resolve(lim.launch_hashed(ids))
+                for col in ("allowed", "remaining", "retry_after",
+                            "reset_at"):
+                    np.testing.assert_array_equal(
+                        getattr(got, col), getattr(want, col),
+                        err_msg=f"{col} step {step}")
+        assert dense.key_count() == exact.key_count()
+        for lim in (dense, twin, exact):
+            lim.close()
+
+    def test_a_wrong_shape_is_still_refused(self, tmp_path):
+        algo = Algorithm.TOKEN_BUCKET
+        arrays = self.by_hand(algo)
+        dense = create_limiter(self.config(algo), backend="dense",
+                               clock=ManualClock(T0))
+        path = str(tmp_path / "wide.npz")
+        self.write(path, dense, dict(
+            arrays, state_dir_keys=arrays["state_dir_keys"].reshape(8, 8)))
+        with pytest.raises(CheckpointError,
+                           match=r"directory of shape \(8, 8\) != this "
+                                 r"limiter's \(16, 4\)"):
+            dense.restore(path)
+        path = str(tmp_path / "words.npz")
+        self.write(path, dense, {"state_cols": arrays["state_cols"],
+                                "state_dir_lo": arrays["state_dir_keys"]})
+        with pytest.raises(CheckpointError, match="state arrays"):
+            dense.restore(path)
+        assert dense.key_count() == 0
+        dense.close()
+
+
 class TestCrashAtomicSave:
     """ISSUE-2 satellite: save_state is crash-atomic on its own — tmp
     write + fsync(file) + os.replace + fsync(dir). A failure injected

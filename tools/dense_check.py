@@ -148,10 +148,10 @@ def main(argv=None) -> int:
     check_s = time.monotonic() - t_run
 
     stats = dense.directory_stats()
-    table = np.asarray(dense._state["dir_keys"]).ravel()
+    table = dense._dir_keys().ravel()
     live = table[(table != directory.EMPTY) & (table != directory.TOMB)]
-    want_keys = np.asarray(directory.canon(
-        splitmix64(np.fromiter(seen, np.uint64, len(seen)))))
+    want_keys = np.asarray(directory.join(*directory.canon(
+        splitmix64(np.fromiter(seen, np.uint64, len(seen))))))
     held_once = (live.shape[0] == np.unique(live).shape[0]
                  and np.array_equal(np.sort(live), np.sort(want_keys)))
     fetches = dense.result_fetches

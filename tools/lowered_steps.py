@@ -10,10 +10,14 @@ file's checkout), builds a limiter for each of chipbench/configs/*.json
 at its rehearsal width and lowers, without running anything: the serving
 step on both lanes (finalized hashes, raw ids to premix), the reset and
 rotate controls, the replicated mesh's step in both merge modes and, for
-a ``--router collective`` config, the routed step. One ``<name>.mlir`` a
-program plus ``index.json`` (name -> jit module name, sha256). It reads
-only the limiter's placement hooks and ``_step`` / ``_reset_step`` /
-``_rollover``, which every checkout since PR 26 has.
+a ``--router collective`` config, the routed step; for a ``--backend
+dense`` config (they live under configs/added/) the dense limiter's
+serving step on both lanes and its reclaim / forget / clear_rem controls
+(ISSUE 43). One ``<name>.mlir`` a program plus ``index.json`` (name ->
+jit module name, sha256). It reads only the limiter's placement hooks
+and ``_step`` / ``_reset_step`` / ``_rollover``, which every checkout
+since PR 26 has, and the dense limiter's ``_reclaim_step`` /
+``_forget_step`` / ``_clear_rem_step`` / ``_fresh`` (since PR 33).
 
 ``--batch N`` lowers the steps of an N-row dispatch instead of the
 rehearsal's 256, ``--published`` at the configurations' published widths,
@@ -64,6 +68,23 @@ def _configs(repo: Path):
                           router=router))
 
 
+def _dense_configs(repo: Path):
+    from ratelimiter_tpu import Algorithm, Config, DenseParams
+
+    for path in sorted((repo / "chipbench" / "configs").glob("**/*.json")):
+        c = json.loads(path.read_text())
+        if not PUBLISHED:
+            c.update(c.get("rehearsal", {}))
+        flags = c["server_flags"]
+        if flags[flags.index("--backend") + 1] != "dense":
+            continue
+        yield path.stem, Config(
+            algorithm=Algorithm(c["algorithm"]), limit=c["limit"],
+            window=float(c["window_s"]),
+            dense=DenseParams(capacity=c["capacity"], lanes=c["lanes"],
+                              probe_bound=c["probe_bound"]))
+
+
 def _programs(repo: Path):
     """(name, jitted callable, args) for every program of every config."""
     import jax
@@ -90,6 +111,17 @@ def _programs(repo: Path):
                     lim._policy_device())
             yield f"{name}.hashed", lim._step, args
             yield f"{name}.premix", lim._get_ids_step(), args
+
+    for name, cfg in _dense_configs(repo):
+        from ratelimiter_tpu.algorithms.dense import DenseLimiter
+
+        dense = DenseLimiter(cfg, clock)
+        yield from serving(name, dense)
+        key = (np.zeros(1, np.uint64), np.ones(1, bool), dense._fresh)
+        yield (f"{name}.reclaim", dense._reclaim_step,
+               (dense._state, np.int64(1_700_000_000_000_000), dense._fresh))
+        yield f"{name}.forget", dense._forget_step, (dense._state, *key)
+        yield f"{name}.clear_rem", dense._clear_rem_step, (dense._state, *key)
 
     for name, backend, cfg in _configs(repo):
         bucket = cfg.algorithm is Algorithm.TOKEN_BUCKET
