@@ -81,7 +81,8 @@ class DenseLimiter(HashedLane, RateLimiter):
         # step's tail words.
         self._entries = 0
         self._dir = {"lookups": 0, "probes": 0, "inserts": 0, "unplaced": 0,
-                     "reclaimed": 0, "reclaim_passes": 0}
+                     "reclaimed": 0, "reclaim_passes": 0,
+                     "reclaim_seconds": 0.0}
         self._next_reclaim_us = 0
         # Policy engine: overrides resolved in-kernel (binary search over
         # the device-resident table, ops/policy_kernels.py). Entries are
@@ -298,10 +299,15 @@ class DenseLimiter(HashedLane, RateLimiter):
         """One reclaim pass (ops/dense_kernels._dense_reclaim) on the
         dispatch stream, waited for: entries idle for two windows — the
         TTL analog (SURVEY.md §2.4.9) — are given up. Lock must be held."""
+        t0 = tracing.now()
         with tracing.span("reclaim"):
             self._state, freed = self._reclaim_step(
                 self._state, np.int64(now_us), self._fresh)
             freed = int(freed)
+        # What the launch waited (the span's interval, read here so that
+        # it counts with the recorder off): the steps in flight ahead of
+        # the pass, the pass, its count's fetch.
+        self._dir["reclaim_seconds"] += (tracing.now() - t0) / 1e9
         self._entries -= freed
         self._dir["reclaimed"] += freed
         self._dir["reclaim_passes"] += 1
@@ -322,7 +328,8 @@ class DenseLimiter(HashedLane, RateLimiter):
         now; cumulative ``lookups`` (rows), ``probes`` (buckets examined),
         ``inserts`` (keys), ``unplaced`` (rows answered by policy for want
         of an entry) as the steps' results reported them at resolve;
-        ``reclaimed`` entries over ``reclaim_passes``."""
+        ``reclaimed`` entries over ``reclaim_passes``, which held their
+        launches for ``reclaim_seconds`` in all."""
         with self._lock:
             return dict(self._dir, entries=self._entries,
                         capacity=self._capacity)

@@ -489,6 +489,14 @@ class MetricsDecorator(LimiterDecorator):
                     ("reclaimed_total",
                      "Entries the reclaim pass gave up, idle for two "
                      "windows (cumulative)"),
+                    ("reclaim_passes_total",
+                     "Reclaim passes run (cumulative): one when a launch "
+                     "finds the directory over 7/8 full, at most one in "
+                     "an eighth of a window, and after prune / reset"),
+                    ("reclaim_seconds_total",
+                     "Seconds launches were held by reclaim passes "
+                     "(cumulative): the pass enqueued behind the steps "
+                     "in flight and waited for, under the lane's lock"),
                     ("entries",
                      "Keys the directory holds now"),
                     ("capacity",

@@ -23,9 +23,20 @@ one pair of row gathers.
 Probing: a key's home bucket is a 32-bit mix of its two words; a probe
 walks home, home+1, ... (mod NB) for at most ``probe_bound`` buckets. A
 bucket answers a key when it holds it (hit), or when it holds an EMPTY
-lane (the key is in no later bucket, so it is absent — and, inserting,
-the lane is claimed). Invariant: every bucket between a live key's home
-and the bucket it sits in has no EMPTY lane.
+lane (the key is in no later bucket, so it is absent). Invariant: every
+bucket between a live key's home and the bucket it sits in has no EMPTY
+lane.
+
+Inserting: an absent key takes the first FREE lane on its path — EMPTY
+or a tombstone (below). A tombstone says a key may have walked past, so
+the row first walks on until a bucket with an EMPTY lane (or the bound)
+tells it the key is absent, remembering the first bucket that had a free
+lane; if that bucket lies behind, it goes back there and takes the first
+free lane from it on, judged by the rows the same pass read. Without
+this a table that expires entries under load dies: a bucket that was
+full once keeps its tombstones while a displaced key lives, new keys of
+that bucket walk past it too and keep it covered, and every bucket ends
+full of tombstones no insert may take (PERF.md §6, PR 44).
 
 Identity: two rows of a batch that carry the same new key compute the
 same claim (a function of key and table only) and both read their key
@@ -52,9 +63,10 @@ ordinary key, and an equal low or high word alone matches nothing.
 Reclaim (``reclaim``, a program of its own): an entry idle for the
 horizon equals a fresh one, so it becomes a tombstone and its state row
 is reset; a tombstone matches no key and is not EMPTY, so lookups of
-surviving keys that walked past it still do. A tombstone goes back to
-EMPTY when no live key sits in a later bucket having walked past its
-bucket (the cover rule below) — at any sane load nearly all do at once.
+surviving keys that walked past it still do, and the next insert whose
+path holds it takes it. A tombstone goes back to EMPTY when no live key
+sits in a later bucket having walked past its bucket (the cover rule
+below) — at any sane load nearly all do at once.
 """
 
 from __future__ import annotations
@@ -150,22 +162,25 @@ def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
     ``placed``; ``claimed`` marks the rows whose key this call inserted
     (every row of a new key, so count distinct slots); ``probes`` counts
     the buckets examined over all rows. With ``insert`` an absent key
-    claims an EMPTY lane of the first bucket on its path that has one;
-    without, an absent key is simply not ``placed``.
+    claims a free lane (EMPTY or tombstone) of the first bucket on its
+    path that has one; without, an absent key is simply not ``placed``.
     """
     klo, khi = k
     B = klo.shape[0]
     pos0, pref = home(k, nb, w)
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (B, w), 1)
-    # Every pass places at least one claimant of each contested lane, so
-    # pb + B passes always suffice; the cap only bounds a faulty device.
-    cap = pb + B
+    # Every pass places at least one claimant of each contested lane, and
+    # a row walks its path at most twice (to learn its key is absent,
+    # then back to the first bucket with a free lane): 2 pb + B passes
+    # always suffice; the cap only bounds a faulty device.
+    cap = 2 * pb + B
 
     def cond(c):
-        return jnp.any(c[4]) & (c[8] < cap)
+        return jnp.any(c[3]) & (c[-1] < cap)
 
     def body(c):
-        lo2d, hi2d, pos, hops, active, slot, claimed, probes, it = c
+        lo2d, hi2d, pos, active, slot, claimed, tpos, placing, probes, it = c
+        hops = (pos - pos0) % nb
         with jax.named_scope("directory_probe"):
             rows = (lo2d.at[pos].get(mode="promise_in_bounds"),    # [B, w]
                     hi2d.at[pos].get(mode="promise_in_bounds"))
@@ -180,11 +195,25 @@ def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
             has_empty = jnp.any(empty, axis=1)
         if insert:
             with jax.named_scope("directory_insert"):
-                claim = active & has_empty
-                # The first EMPTY lane at or (cyclically) after the key's
+                # A lane is free when it is EMPTY or a tombstone. A
+                # tombstone may be taken only by a key known to be
+                # absent — a later bucket may hold it — so a row first
+                # walks on to a bucket with an EMPTY lane (or to the
+                # bound), noting the first bucket that had a free lane
+                # (``tpos``), then goes back there ``placing``: it takes
+                # the first free lane from that bucket on, judged by the
+                # rows this pass read, never by what an earlier pass saw.
+                free = empty | _is(rows, TOMB)
+                has_free = jnp.any(free, axis=1)
+                searching = active & ~placing
+                tpos = jnp.where(searching & (tpos < 0) & has_free, pos, tpos)
+                absent = searching & (has_empty | (hops + 1 >= pb))
+                back = absent & (tpos >= 0) & (tpos != pos)
+                claim = active & has_free & (placing | (absent & ~back))
+                # The first free lane at or (cyclically) after the key's
                 # preferred one: keys of one bucket spread over its free
                 # lanes, so one pass places nearly all of them.
-                dist = jnp.where(empty, (lane_iota - pref[:, None]) % w, w)
+                dist = jnp.where(free, (lane_iota - pref[:, None]) % w, w)
                 lane = jnp.argmin(dist, axis=1).astype(jnp.int32)
                 # Phase one, the low word: the scatter keeps one
                 # claimant's; whoever reads its own back goes on.
@@ -200,20 +229,22 @@ def probe(keys2d, k, valid, *, nb: int, w: int, pb: int, insert: bool):
                 slot = jnp.where(won, pos * w + lane, slot)
                 claimed = claimed | won
                 active = active & ~won
-                stay = claim            # a loser looks at this bucket again
+                placing = placing | back
+                stay = claim | back     # a loser looks at this bucket again
         else:
             active = active & ~has_empty    # absent: an EMPTY lane ends it
-            stay = jnp.zeros_like(active)
+            stay = back = jnp.zeros_like(active)
         move = active & ~stay
-        hops = hops + move.astype(jnp.int32)
-        active = active & (hops < pb)
-        pos = jnp.where(move, (pos + 1) % nb, pos)
-        return lo2d, hi2d, pos, hops, active, slot, claimed, probes, it + 1
+        active = active & ~(move & (hops + 1 >= pb))
+        pos = jnp.where(back, tpos, jnp.where(move, (pos + 1) % nb, pos))
+        return (lo2d, hi2d, pos, active, slot, claimed, tpos, placing,
+                probes, it + 1)
 
-    init = (*keys2d, pos0, jnp.zeros((B,), jnp.int32), valid,
-            jnp.full((B,), -1, jnp.int32), jnp.zeros((B,), bool),
-            jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-    lo2d, hi2d, _, _, _, slot, claimed, probes, _ = jax.lax.while_loop(
+    init = (*keys2d, pos0, valid, jnp.full((B,), -1, jnp.int32),
+            jnp.zeros((B,), bool), jnp.full((B,), -1, jnp.int32),
+            jnp.zeros((B,), bool), jnp.zeros((), jnp.int32),
+            jnp.zeros((), jnp.int32))
+    lo2d, hi2d, _, _, slot, claimed, _, _, probes, _ = jax.lax.while_loop(
         cond, body, init)
     return (lo2d, hi2d), slot, valid & (slot >= 0), claimed, probes
 

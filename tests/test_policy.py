@@ -603,7 +603,7 @@ CONTROLS = ([f"{c}.reset" for c in _CONFIGS]
             + [f"{c}.rotate" for c in _CONFIGS if c != "bucket-c3"])
 #: The dense configurations' programs (ISSUE 43: the tool lowers them too,
 #: so that a change of the dense state's layout shows as these and no other).
-DENSE = [f"{c}.{p}" for c in ("exact-tb-1m", "exact-tb-20m")
+DENSE = [f"{c}.{p}" for c in ("exact-tb-1m", "exact-tb-20m", "exact-tb-ttl")
          for p in ("hashed", "premix", "reclaim", "forget", "clear_rem")]
 
 
@@ -658,7 +658,7 @@ class TestLoweredPrograms:
 
     def test_one_program_a_shape_as_before(self, lowered):
         assert sorted(lowered) == sorted(SERVING + CONTROLS + DENSE)
-        assert len(lowered) == 33 + 10
+        assert len(lowered) == 33 + 15
 
     @pytest.mark.parametrize("name", SERVING)
     def test_every_table_gather_is_inside_the_one_conditional(self, lowered,
