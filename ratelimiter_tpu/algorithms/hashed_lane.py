@@ -127,9 +127,12 @@ class HashedLane:
     def _stage_operands(self, buf: np.ndarray, padded: int) -> tuple:
         """The step's batch operands from a filled staging buffer: ONE
         explicit host->device transfer of the whole buffer (the step
-        slices it, sketch_kernels.unstage). The mesh placement overrides
-        this with its own — a buffer with a scalar tail cannot be
-        sharded by batch."""
+        slices it, sketch_kernels.unstage). The replicated mesh's
+        placement overrides this with the slot's three views, its
+        timestamp replicated (_MeshPlacement._stage_operands); the
+        collective router shards ONE buffer by rows instead, the scalars
+        repeated in each device's row (CollectiveMeshLimiter
+        ._acquire_slot)."""
         return (self._place_replicated(buf),)
 
     def _init_staging(self) -> None:

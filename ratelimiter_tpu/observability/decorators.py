@@ -414,6 +414,12 @@ class MetricsDecorator(LimiterDecorator):
                 "Frames the collective mesh router launched as one "
                 "shard_map program (cumulative; a frame that then "
                 "overflowed a bin is counted here and under fallbacks)")
+            self._coll_place_g = reg.gauge(
+                "rate_limiter_collective_placements_total",
+                "Operand shards the collective mesh router's launches "
+                "sent to the devices (cumulative): one per array operand "
+                "per addressable shard — one staged frame a launch, a "
+                "row a device")
             self._coll_fall_g = reg.gauge(
                 "rate_limiter_collective_fallbacks_total",
                 "Frames the collective mesh router handed to the host "
@@ -566,6 +572,7 @@ class MetricsDecorator(LimiterDecorator):
     def _collect_router(self) -> None:
         st = self._router.router_stats()
         self._coll_disp_g.set(float(st["dispatches"]), shard=self._shard)
+        self._coll_place_g.set(float(st["placements"]), shard=self._shard)
         for reason, count in st["fallback_reasons"].items():
             self._coll_fall_g.set(float(count), shard=self._shard,
                                   reason=reason)

@@ -6,7 +6,9 @@ and an index-map scatter of results. This module is the SPMD answer the
 ADR deferred: one shard_map'd step over the slice mesh in which each
 device
 
-1. receives an even 1/n shard of the frame's (h64, ns) columns,
+1. receives its row of the ONE staged operand — an even 1/n shard of the
+   frame's (h64, ns) columns, the decision timestamp and the frame's row
+   count (sketch_kernels.unstage_routed),
 2. computes ``owner = h64 % n`` on device (premix lanes splitmix64
    first — the same finalize-then-mod rule as
    ``SlicedMeshLimiter.owner_of_id``),
@@ -212,15 +214,17 @@ _ROUTED_CACHE: Dict[tuple, Callable] = {}
 
 def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
                       capacity: int) -> Callable:
-    """Jitted collective ``step(mut, ro, h64, ns, b, now_us, policy[,
-    hier])`` over the slice mesh.
+    """Jitted collective ``step(mut, ro, staged, policy[, hier])`` over
+    the slice mesh.
 
     ``mut``/``ro`` are the sharded per-slice state groups
-    (state_layout), ``h64``/``ns`` the (n*L,)-padded frame columns
-    sharded over AXIS, ``b`` the true row count and ``now_us`` the
-    decision timestamp (both replicated scalars; traced, so varying b
-    never recompiles — only a new L bucket does). Policy (and cascade)
-    tables ride replicated, exactly as on the single-slice step.
+    (state_layout); ``staged`` is the frame's ONE operand, ``uint64[n,
+    2L + 2]`` sharded by rows over AXIS: chip c's row holds frame rows
+    c*L .. c*L + L - 1 as ``[h64(L) | ns(L) | now_us | b]``, the decision
+    timestamp and the true row count repeated in every row
+    (sketch_kernels.unstage_routed; both traced, so varying b never
+    recompiles — only a new L bucket does). Policy (and cascade) tables
+    ride replicated, exactly as on the single-slice step.
 
     Returns ``(new_mut, words)``: ONE int32 buffer sharded over AXIS,
     each device's shard the rule's packed rows over its L frame rows
@@ -263,7 +267,8 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
                 out[k] = jnp.where(ovf, old_mut[k], v)
             return out
 
-        def body(mut, ro, h64, ns, b, now_us, policy, hier=None):
+        def body(mut, ro, staged, policy, hier=None):
+            h64, ns, now_us, b = sketch_kernels.unstage_routed(staged[0])
             h_own, ns_own, order, binpos, keep, ovf_l = _route(
                 h64, ns, b, n, L, C, premix)
             ovf = jax.lax.pmax(ovf_l.astype(jnp.int32), AXIS) > 0
@@ -295,8 +300,7 @@ def build_routed_step(cfg: Config, mesh, *, premix: bool, L: int,
         mut_spec = {k: P(AXIS) for k in mut_keys}
         ro_spec = {k: P(AXIS) for k in ro_keys}
         policy_spec = {"key": P(), "limit": P()}
-        in_specs = [mut_spec, ro_spec, P(AXIS), P(AXIS), P(), P(),
-                    policy_spec]
+        in_specs = [mut_spec, ro_spec, P(AXIS), policy_spec]
         if step_kw["tenants"]:
             in_specs.append(_HIER_SPEC)
         # check_vma=False for the same reason as mesh_kernels: ovf IS

@@ -975,6 +975,15 @@ def unstage(staged):
             staged[2 * P].astype(jnp.int64))
 
 
+def unstage_routed(staged):
+    """One chip's row ``uint64[2L + 2]`` of the collective launch's
+    staging slot -> ``(ids uint64[L], n int32[L], now_us int64[], b
+    int64[])``: ``unstage``'s layout followed by the frame's true row
+    count, both scalars repeated in every chip's row so that the slot
+    shards by rows (CollectiveMeshLimiter._stage_frame)."""
+    return (*unstage(staged[:-1]), staged[-1].astype(jnp.int64))
+
+
 def split_staged(h64, premix: bool, seed: int):
     """(h1, h2) of staged keys: finalized hashes, or raw ids to premix."""
     from ratelimiter_tpu.ops.hashing import split_hash_dev, splitmix64_dev

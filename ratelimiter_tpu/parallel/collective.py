@@ -9,9 +9,11 @@ ONE jitted shard_map step over the slice mesh
 shard of the frame columns, computes owners on device, all-to-all's
 rows to their owning slices, runs the unchanged fused decision kernel
 against its own slice state, and all-to-all's the verdicts back to
-frame order. The host stages two columns and fetches one packed buffer
-(a shard a device); it never argsorts, never builds index maps, never
-fans out sub-launches, and resolve blocks on ONE ticket.
+frame order. The host stages ONE buffer — a row a device: its shard of
+the two columns, the timestamp and the row count (_fill_column) — places
+it with one sharded device_put and fetches one packed buffer (a shard a
+device); it never argsorts, never builds index maps, never fans out
+sub-launches, and resolve blocks on ONE ticket.
 
 Because the per-slice states stay exactly where the host router keeps
 them (``self.slices[i]._state``, assembled zero-copy into a global
@@ -66,7 +68,9 @@ class CollectiveDispatchTicket(DispatchTicket):
     rows and a tail of that slice's admitted mass and the overflow flag
     (ops/route_kernels.build_routed_step). The original frame columns
     ride along so the overflow fallback can re-dispatch through the host
-    router with the ORIGINAL decision timestamp."""
+    router with the ORIGINAL decision timestamp — never from ``slot``,
+    the pooled staging buffer the ticket owns until resolve gives it
+    back (``padded`` is its L)."""
 
     __slots__ = ("arrays", "premix", "wire_lane")
 
@@ -75,6 +79,20 @@ class CollectiveDispatchTicket(DispatchTicket):
         self.arrays = None
         self.premix = False
         self.wire_lane = False
+
+
+def _fill_column(dst: np.ndarray, src: np.ndarray) -> None:
+    """Frame column ``src`` into ``dst``, the (n, L) view of one column of
+    the staging slot: row c takes frame rows c*L .. c*L + L - 1 (global
+    row order unchanged), what is left of the slot is zeroed — pad rows
+    (key 0, n = 0) are decision-inert."""
+    L = dst.shape[1]
+    full, rest = divmod(src.shape[0], L)
+    dst[:full] = src[:full * L].reshape(full, L)
+    if full < dst.shape[0]:
+        dst[full, :rest] = src[full * L:]
+        dst[full, rest:] = 0
+        dst[full + 1:] = 0
 
 
 class CollectiveMeshLimiter(SlicedMeshLimiter):
@@ -93,10 +111,18 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                 "router='collective' cannot wrap slices in quarantine "
                 "guards (whole-mesh blast radius; MeshSpec.validate "
                 "refuses this combination)")
-        from jax.sharding import Mesh
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         self.mesh = Mesh(np.asarray([s._device for s in self.slices]),
                          ("chips",))
+        #: How the staged frame is placed: a row of the slot a device.
+        self._frame_sharding = NamedSharding(self.mesh, P("chips"))
+        # Reusable staging slots per L, as on the one-chip lane
+        # (HashedLane._acquire_staging): a launch pops a free one, the
+        # ticket owns it, resolve gives it back once the step has
+        # consumed the transfer — on every exit.
+        self._slots: dict = {}
+        self._slots_lock = threading.Lock()
         from ratelimiter_tpu.ops import route_kernels
 
         _, self._mut_keys, self._ro_keys = route_kernels.state_layout(
@@ -117,6 +143,12 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
         #: launch counts under _mesh_lock; an overflow is found on the
         #: resolving thread, so the fallbacks have a lock of their own.
         self.dispatches = 0
+        #: Operand shards the routed launches sent to the devices, one
+        #: per array operand per addressable shard (a host scalar handed
+        #: to the jitted call would count as the n shards jit makes of
+        #: it): n_slices a dispatch, the one staged slot. Counted beside
+        #: ``dispatches``.
+        self.placements = 0
         self._fallbacks = {"overflow": 0, "strict": 0}
         #: Device buffers this router's own resolve has fetched (the
         #: slices count their own, result_fetches sums both).
@@ -224,15 +256,50 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
             self._fell_back("strict")
         return b == 0 or self._strict_gate
 
+    def _acquire_slot(self, L: int) -> np.ndarray:
+        with self._slots_lock:
+            free = self._slots.get(L)
+            if free:
+                return free.pop()
+        # ONE uint64 buffer, a row a device: [ids(L) | n(L) | now_us | b]
+        # — the one-chip slot's layout (HashedLane._acquire_staging) plus
+        # the frame's true row count, the two scalars repeated in every
+        # row so that the buffer shards by rows and no host scalar reaches
+        # the jitted call (sketch_kernels.unstage_routed).
+        return np.empty((self.n_slices, 2 * L + 2), dtype=np.uint64)
+
+    def _stage_frame(self, t: DispatchTicket, L: int, arrays: np.ndarray,
+                     ns: np.ndarray, now_us: int) -> np.ndarray:
+        """A slot of the pool, owned by ``t`` from here on, filled with
+        the frame."""
+        t.padded = L
+        t.slot = slot = self._acquire_slot(L)
+        _fill_column(slot[:, :L], arrays)
+        # n, now_us and b are signed: written through an int64 view of
+        # the same bytes, narrowed back on device.
+        tail = slot.view(np.int64)
+        _fill_column(tail[:, L:2 * L], ns)
+        tail[:, 2 * L:] = (now_us, arrays.shape[0])
+        return slot
+
+    def _release_slot(self, t: DispatchTicket) -> None:
+        """Give a ticket's slot back, once: the step consumed the
+        transfer when its result is ready (or it failed)."""
+        if t.slot is None:
+            return
+        with self._slots_lock:
+            self._slots.setdefault(t.padded, []).append(t.slot)
+        t.slot = None
+
     def _launch_routed(self, arrays: np.ndarray, ns: np.ndarray,
                        now: float, *, premix: bool,
                        wire: bool) -> CollectiveDispatchTicket:
         import jax
 
         from ratelimiter_tpu.ops import route_kernels
-        from ratelimiter_tpu.parallel import mesh_kernels
 
         b = int(arrays.shape[0])
+        t = CollectiveDispatchTicket()
         # The launch from inside, on the stage names the single-chip
         # launch uses where the work is the same (prep -> place -> step
         # -> finish, algorithms/sketch.py) plus the two stages only this
@@ -247,69 +314,78 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                 L, n, self.config.mesh.bin_headroom)
             step = route_kernels.build_routed_step(
                 self.config, self.mesh, premix=premix, L=L, capacity=C)
-            padded = L * n
-            h64p = np.zeros(padded, dtype=np.uint64)
-            h64p[:b] = arrays
-            nsp = np.zeros(padded, dtype=np.int32)
-            nsp[:b] = ns
-            # The frame's columns are placed BEFORE the locks: they read
-            # no limiter state, and they are the longest host stage of
-            # the launch (two placements sharded four ways). Held across
-            # them, the slice locks were taken for nearly the whole
-            # launch, and resolve — which needs each slice's lock for its
-            # mass bookkeeping — could finish only between two launches:
-            # on the chip the door then fell, within seconds and for
-            # good, into a state where dispatcher and completer take
-            # turns (PERF.md §6, PR 27).
-            sp.next("place")
-            frame = (mesh_kernels.shard_batch(h64p, self.mesh),
-                     mesh_kernels.shard_batch(nsp, self.mesh),
-                     np.int64(b), np.int64(now_us))
-            sp.next("assemble")
-            with self._mesh_lock:
-                for s in self.slices:
-                    s._lock.acquire()
-                try:
+            slot = self._stage_frame(t, L, arrays, ns, now_us)
+            try:
+                # The frame is placed BEFORE the locks: it reads no
+                # limiter state, and placement is the longest host stage
+                # of the launch (ONE sharded device_put, a row of the
+                # slot a device). Held across it, the slice locks were
+                # taken for nearly the whole launch, and resolve — which
+                # needs each slice's lock for its mass bookkeeping —
+                # could finish only between two launches: on the chip the
+                # door then fell, within seconds and for good, into a
+                # state where dispatcher and completer take turns
+                # (PERF.md §6, PR 27).
+                sp.next("place")
+                staged = jax.device_put(slot, self._frame_sharding)
+                sp.next("assemble")
+                with self._mesh_lock:
                     for s in self.slices:
-                        if s._injected_failure is not None:
-                            raise s._injected_failure
-                        s._sync_period(now_us)
-                    mut, ro = self._assemble_state()
-                    # The tables are cached device copies, rebuilt under
-                    # the slice locks when an override or tenant changes.
-                    args = (mut, ro, *frame, self._policy_mesh())
-                    hier = self._hier_mesh()
-                    if hier is not None:
-                        args = args + (hier,)
-                    sp.next("step")
-                    new_mut, words = step(*args)
-                    if self._cpu:
-                        # Same rationale as _MeshPlacement._fence_dispatch:
-                        # xla:cpu collective rendezvous starve the shared
-                        # device pool under concurrent executions — cap
-                        # the stream at one while the dispatch locks are
-                        # held.
-                        jax.block_until_ready(words)
-                    sp.next("writeback")
-                    self._writeback(new_mut)
-                    self.dispatches += 1
-                    window_us = self.slices[0]._window_us
-                    sp.next("finish")
-                    limits = None
-                    if len(self.slices[0]._policy_table):
-                        self._override_lookups += 1
-                        if premix:
-                            from ratelimiter_tpu.ops.hashing import splitmix64
+                        s._lock.acquire()
+                    try:
+                        for s in self.slices:
+                            if s._injected_failure is not None:
+                                raise s._injected_failure
+                            s._sync_period(now_us)
+                        mut, ro = self._assemble_state()
+                        # Every operand is a committed device array with
+                        # the sharding the program was built for: the
+                        # state views, the staged frame, and the tables —
+                        # cached device copies, rebuilt under the slice
+                        # locks when an override or tenant changes.
+                        args = (mut, ro, staged, self._policy_mesh())
+                        hier = self._hier_mesh()
+                        if hier is not None:
+                            args = args + (hier,)
+                        sp.next("step")
+                        new_mut, words = step(*args)
+                        if self._cpu:
+                            # Same rationale as
+                            # _MeshPlacement._fence_dispatch: xla:cpu
+                            # collective rendezvous starve the shared
+                            # device pool under concurrent executions —
+                            # cap the stream at one while the dispatch
+                            # locks are held.
+                            jax.block_until_ready(words)
+                        sp.next("writeback")
+                        self._writeback(new_mut)
+                        self.dispatches += 1
+                        self.placements += fetch_count(staged)
+                        window_us = self.slices[0]._window_us
+                        sp.next("finish")
+                        limits = None
+                        if len(self.slices[0]._policy_table):
+                            self._override_lookups += 1
+                            if premix:
+                                from ratelimiter_tpu.ops.hashing import (
+                                    splitmix64)
 
-                            limits = self.slices[0]._policy_limits(
-                                splitmix64(arrays))
-                        else:
-                            limits = self.slices[0]._policy_limits(arrays)
-                finally:
-                    for s in reversed(self.slices):
-                        s._lock.release()
-            t = CollectiveDispatchTicket()
-            t.outs = words
+                                limits = self.slices[0]._policy_limits(
+                                    splitmix64(arrays))
+                            else:
+                                limits = self.slices[0]._policy_limits(
+                                    arrays)
+                    finally:
+                        for s in reversed(self.slices):
+                            s._lock.release()
+                t.outs = words
+            finally:
+                # Any exit without a launched step (an injected failure,
+                # a failing placement, step or rollover) gives the slot
+                # back here — only a launched ticket keeps it for
+                # resolve.
+                if t.outs is None:
+                    self._release_slot(t)
             t.window_us = window_us
             t.b = b
             t.limit = self.config.limit
@@ -375,6 +451,10 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                 return res
             raise StorageUnavailableError(
                 f"collective resolve failed: {exc}") from exc
+        finally:
+            # The result is ready (or the step failed): the transfer of
+            # the staged frame is consumed either way, on every exit.
+            self._release_slot(ticket)
         with self._stats_lock:
             self._fetches += shards
         ticket.outs = None
@@ -511,5 +591,6 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
     def router_stats(self) -> dict:
         """Collective-path bookkeeping for /v1/health and the bench."""
         return {"mode": "collective", "dispatches": self.dispatches,
+                "placements": self.placements,
                 "fallbacks": self.fallbacks,
                 "fallback_reasons": dict(self._fallbacks)}

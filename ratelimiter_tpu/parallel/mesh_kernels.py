@@ -78,9 +78,10 @@ def _delta_step(step, state, h1, h2, n, now_us, policy, hier, *, step_kw):
 # statics, which state leaves and which packer are read off the config
 # in ops/route_kernels (step_rule, state_layout), the same table the
 # collective router's step is built from. The operands stay three
-# arrays: a buffer with a scalar tail cannot be sharded by batch, so the
-# mesh placement stages the single-chip slot's three views itself
-# (_MeshPlacement._stage_operands).
+# arrays here: the mesh placement stages the single-chip slot's three
+# views itself, the timestamp replicated (_MeshPlacement._stage_operands;
+# the collective router's launch repeats its scalars in each device's row
+# of ONE sharded buffer instead, sketch_kernels.unstage_routed).
 
 _BUILT: Dict[tuple, Callable] = {}
 
@@ -156,8 +157,3 @@ def replicate_state(state, mesh: Mesh):
     """Place a (host or single-device) state dict fully replicated on the mesh."""
     sh = NamedSharding(mesh, P())
     return {k: jax.device_put(v, sh) for k, v in state.items()}
-
-
-def shard_batch(arr, mesh: Mesh):
-    """Place a host batch array sharded over the mesh axis."""
-    return jax.device_put(arr, NamedSharding(mesh, P(AXIS)))

@@ -147,7 +147,8 @@ def test_an_overflowing_frame_is_decided_once_and_counted(pair, lane):
     assert tally.remaining_equal == tally.allowed == 8 + 512 + 512
     assert tally.columns_differ == 0
     assert coll.router_stats() == {
-        "mode": "collective", "dispatches": 3, "fallbacks": 2,
+        "mode": "collective", "dispatches": 3, "placements": 12,
+        "fallbacks": 2,
         "fallback_reasons": {"overflow": 2, "strict": 0}}
     scraped = {key: value
                for key, value in promtext.parse(registry.render()).items()
@@ -155,6 +156,7 @@ def test_an_overflowing_frame_is_decided_once_and_counted(pair, lane):
     shard = ("shard", "0")
     assert scraped == {
         ("rate_limiter_collective_dispatches_total", (shard,)): 3.0,
+        ("rate_limiter_collective_placements_total", (shard,)): 12.0,
         ("rate_limiter_collective_fallbacks_total",
          (("reason", "overflow"), shard)): 2.0,
         ("rate_limiter_collective_fallbacks_total",
@@ -225,7 +227,8 @@ def test_the_strict_gate_counts_its_fallbacks():
                                 now=check.T0)
         assert res.allowed.all()
         assert coll.router_stats() == {
-            "mode": "collective", "dispatches": 0, "fallbacks": 1,
+            "mode": "collective", "dispatches": 0, "placements": 0,
+            "fallbacks": 1,
             "fallback_reasons": {"overflow": 0, "strict": 1}}
     finally:
         coll.close()

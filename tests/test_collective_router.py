@@ -9,10 +9,15 @@ changing the ROUTING must never change the DECISIONS — pinned here
 bit-for-bit against the host-routed sliced oracle for mixed and affine
 frames, across sub-window rollovers, under policy overrides and the
 hierarchy cascade, on the token-bucket backend, and through the raw-id
-wire lane. The overflow fallback (capacity-1 bins via bin_headroom < 1)
-must re-dispatch through the host router with no admission mass lost or
-duplicated, and ``--quarantine`` must be refused loudly (a collective
-dispatch is one mesh-wide execution — per-slice failure domains cannot
+wire lane. The launch's ONE staged operand (PR 45: a pooled ``uint64[n,
+2L + 2]`` slot, a row a device, placed by one sharded device_put) is
+held to the same oracle — replies and every state leaf — over rule x
+lane x pad, and its pool to the one-chip lane's ownership rule: a slot
+belongs to its ticket until resolve, on every exit. The overflow
+fallback (capacity-1 bins via bin_headroom < 1) must re-dispatch
+through the host router with no admission mass lost or duplicated,
+and ``--quarantine`` must be refused loudly (a collective dispatch is
+one mesh-wide execution — per-slice failure domains cannot
 contain it). CI runs this file in the explicit 8-virtual-device mesh
 lane with zero skips allowed (ci.yml); ``make test-collective`` runs it
 locally.
@@ -103,7 +108,8 @@ class TestDecisionParity:
                               coll.allow_hashed(h, ns, now=now), i=i)
             assert coll.fallbacks == 0
             assert coll.router_stats() == {
-                "mode": "collective", "dispatches": 12, "fallbacks": 0,
+                "mode": "collective", "dispatches": 12, "placements": 96,
+                "fallbacks": 0,
                 "fallback_reasons": {"overflow": 0, "strict": 0}}
         finally:
             host.close()
@@ -237,6 +243,220 @@ class TestDecisionParity:
                                           np.asarray(words_c))
         finally:
             host.close()
+            coll.close()
+
+
+# ------------------------------------------------- the staged operand
+
+
+RULES = {"windowed": Algorithm.SLIDING_WINDOW,
+         "bucket": Algorithm.TOKEN_BUCKET}
+N = 4  # the deployment's slice count (mesh4-c3-coll)
+
+
+def _decide(lim, premix, h, ns, now):
+    return (lim.allow_ids if premix else lim.allow_hashed)(h, ns, now=now)
+
+
+def _assert_state_equal(host, coll, touched=range(N)):
+    """Every state leaf of the slices a frame ``touched``. (A slice that
+    owns no row of a frame is not dispatched to by the host router; the
+    routed step runs on every slice, over zero rows: its period and
+    refill bookkeeping advance there one frame earlier.)"""
+    for i in touched:
+        sh, sc = host.slices[i], coll.slices[i]
+        assert set(sh._state) == set(sc._state)
+        for k in sh._state:
+            np.testing.assert_array_equal(
+                np.asarray(sh._state[k]), np.asarray(sc._state[k]),
+                err_msg=f"state leaf {k} of slice {i} diverged")
+
+
+@pytest.mark.parametrize("L", [8, 16, 64])
+@pytest.mark.parametrize("premix", [False, True],
+                         ids=["hashed", "premix"])
+@pytest.mark.parametrize("rule", list(RULES))
+class TestStagedFrame:
+    def test_replies_and_every_state_leaf_equal_the_host_routers(
+            self, rule, premix, L):
+        """Frames of one pad bucket, the fullest first, so that every
+        later frame is staged into a recycled slot that still holds a
+        longer frame's rows: b = n*L (no pad row), a partial last row,
+        the bucket's smallest frame (whole rows of padding), b = n*L
+        again, and a frame with fewer rows than slices."""
+        host, coll = _pair(algo=RULES[rule], devices=N)
+        rng = np.random.default_rng(L)
+        owner_of = host.owner_of_id if premix else host.owner_of_hash
+        try:
+            for i, b in enumerate([N * L, N * L - 5, N * (L // 2) + 1,
+                                   N * L, 3]):
+                h = rng.integers(0, 1 << 62, size=b, dtype=np.uint64)
+                h[-min(6, b - 1):] = h[0]  # one key many times: sequencing
+                ns = rng.integers(1, 4, size=b).astype(np.int64)
+                now = T0 + i * 0.5
+                _assert_equal(_decide(host, premix, h, ns, now),
+                              _decide(coll, premix, h, ns, now), i=i)
+                touched = np.unique(owner_of(h)).tolist()
+                assert i or len(touched) == N
+                _assert_state_equal(host, coll, touched)
+            assert coll.fallbacks == 0 and coll.dispatches == 5
+            # Two buckets were used (L, and 8 for the three-row frame),
+            # and every launch of a bucket took the one slot back.
+            assert {k: len(v) for k, v in coll._slots.items()} \
+                == dict.fromkeys({L, 8}, 1)
+        finally:
+            host.close()
+            coll.close()
+
+    def test_a_launch_places_one_shard_a_device_and_no_host_scalar(
+            self, rule, premix, L, monkeypatch):
+        """``placements`` rises by n_slices a launch, and every operand
+        the jitted call sees is a committed device array on the mesh —
+        no NumPy or Python scalar for ``jit`` to replicate per call."""
+        from ratelimiter_tpu.ops import route_kernels
+
+        seen = []
+        real = route_kernels.build_routed_step
+
+        def spying(*args, **kw):
+            step = real(*args, **kw)
+
+            def call(*operands):
+                seen.append(jax.tree_util.tree_leaves(operands))
+                return step(*operands)
+            return call
+
+        monkeypatch.setattr(route_kernels, "build_routed_step", spying)
+        coll = create_limiter(_cfg("collective", algo=RULES[rule],
+                                   devices=N),
+                              backend="mesh", clock=ManualClock(T0))
+        try:
+            devices = set(coll.mesh.devices.flat)
+            for i, b in enumerate([N * L, N * L - 5]):
+                h = np.arange(1, b + 1, dtype=np.uint64)
+                before = coll.placements
+                _decide(coll, premix, h, None, T0 + i)
+                assert coll.placements - before == N
+                assert coll.router_stats()["placements"] == coll.placements
+            assert len(seen) == 2
+            for leaves in seen:
+                assert leaves and all(
+                    isinstance(x, jax.Array) and x.committed
+                    and set(x.sharding.device_set) == devices
+                    for x in leaves), [type(x) for x in leaves]
+                staged = [x for x in leaves if x.dtype == np.uint64]
+                assert [x.shape for x in staged] == [(N, 2 * L + 2)]
+                assert [sh.data.shape for sh in
+                        staged[0].addressable_shards] == [(1, 2 * L + 2)] * N
+        finally:
+            coll.close()
+
+
+class _NeverReady:
+    """A launched ticket's result whose wait fails at resolve."""
+
+    def block_until_ready(self):
+        raise RuntimeError("device lost")
+
+
+@pytest.mark.parametrize("premix", [False, True], ids=["hashed", "premix"])
+class TestSlotOwnership:
+    """A slot is the ticket's from launch to resolve and the pool's
+    otherwise (HashedLane's rule): never handed out while its ticket is
+    open, always handed out again after — whichever way the ticket
+    ended."""
+
+    def _launch(self, coll, premix, h, now=T0):
+        return (coll.launch_ids if premix else coll.launch_hashed)(
+            h, now=now)
+
+    def test_not_before_the_ticket_resolves_and_again_after(self, premix):
+        host, coll = _pair(devices=N)
+        try:
+            h = np.arange(1, 30, dtype=np.uint64)
+            t1 = self._launch(coll, premix, h)
+            t2 = self._launch(coll, premix, h)
+            first, second = t1.slot, t2.slot
+            assert first is not None and second is not None
+            assert first is not second and first.shape == (N, 2 * 8 + 2)
+            assert not coll._slots.get(8)
+            r1 = coll.resolve(t1)
+            assert t1.slot is None and coll._slots[8] == [first]
+            assert coll.resolve(t1) is r1  # and gives nothing back twice
+            assert coll._slots[8] == [first]
+            t3 = self._launch(coll, premix, h)
+            assert t3.slot is first
+            r2, r3 = coll.resolve(t2), coll.resolve(t3)
+            assert sorted(map(id, coll._slots[8])) == sorted(
+                map(id, (first, second)))
+            for rc in (r1, r2, r3):  # decided in launch order
+                _assert_equal(_decide(host, premix, h, None, T0), rc)
+        finally:
+            host.close()
+            coll.close()
+
+    def test_again_after_an_overflow_fallback(self, premix):
+        """The fallback re-dispatches the ORIGINAL frame (ticket.arrays),
+        never the slot: its replies are the host router's, and the slot
+        is back in the pool before the host router runs."""
+        host, coll = _pair(router_cfg_kw={"headroom": 0.001}, devices=N)
+        try:
+            h = np.full(24, 0xF00D, dtype=np.uint64)
+            t = self._launch(coll, premix, h)
+            slot = t.slot
+            slot_ids = slot[:, :8].copy()
+            res = coll.resolve(t)
+            assert coll.fallbacks == 1 and t.slot is None
+            _assert_equal(_decide(host, premix, h, None, T0), res)
+            _assert_state_equal(host, coll, np.unique(
+                (host.owner_of_id if premix else host.owner_of_hash)(h)))
+            np.testing.assert_array_equal(slot[:, :8], slot_ids)
+            assert self._launch(coll, premix, h, now=T0 + 1).slot is slot
+        finally:
+            host.close()
+            coll.close()
+
+    @pytest.mark.parametrize("fail_open", [True, False],
+                             ids=["fail-open", "fail-closed"])
+    def test_again_after_a_failed_launch(self, premix, fail_open):
+        """An injected failure is raised under the locks, after the
+        frame was staged and placed: fail-open answers the frame by
+        policy, fail-closed raises — the slot is back either way."""
+        from ratelimiter_tpu.core.errors import StorageUnavailableError
+
+        coll = create_limiter(_cfg("collective", devices=N,
+                                   fail_open=fail_open),
+                              backend="mesh", clock=ManualClock(T0))
+        try:
+            h = np.arange(1, 30, dtype=np.uint64)
+            slot = self._launch(coll, premix, h).slot  # left open on purpose
+            coll.slices[2].inject_failure()
+            if fail_open:
+                t = self._launch(coll, premix, h)
+                assert t.slot is None and t.result.allowed.all()
+            else:
+                with pytest.raises(StorageUnavailableError):
+                    self._launch(coll, premix, h)
+            (spare,) = coll._slots[8]
+            assert spare is not slot
+            assert coll.dispatches == 1 and coll.placements == N
+            coll.slices[2].heal()
+            assert self._launch(coll, premix, h).slot is spare
+        finally:
+            coll.close()
+
+    def test_again_after_a_result_that_never_came(self, premix):
+        coll = create_limiter(_cfg("collective", devices=N, fail_open=True),
+                              backend="mesh", clock=ManualClock(T0))
+        try:
+            h = np.arange(1, 30, dtype=np.uint64)
+            t = self._launch(coll, premix, h)
+            slot = t.slot
+            t.outs = _NeverReady()
+            assert coll.resolve(t).allowed.all()  # answered by policy
+            assert t.slot is None and coll._slots[8] == [slot]
+            assert self._launch(coll, premix, h).slot is slot
+        finally:
             coll.close()
 
 

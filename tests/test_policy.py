@@ -660,6 +660,45 @@ class TestLoweredPrograms:
         assert sorted(lowered) == sorted(SERVING + CONTROLS + DENSE)
         assert len(lowered) == 33 + 15
 
+    @pytest.mark.parametrize("name", SERVING + CONTROLS + DENSE)
+    def test_every_program_is_the_pinned_text(self, lowered, name):
+        """``tests/data/lowered_programs/index.json`` is the index
+        ``tools/lowered_steps.py --out`` writes: every one-chip and
+        host-router program's entry there was lowered from PR 44's
+        checkout (ISSUE 45 changed the routed launch's operands and
+        nothing else: those two entries are its own). A PR that means to
+        change a program lowers again, copies the index over this file
+        and says which entries moved; one that does not is held to the
+        parent's text here."""
+        import hashlib
+        import json
+        from pathlib import Path
+
+        pinned = json.loads((Path(__file__).parent / "data"
+                             / "lowered_programs" / "index.json").read_text())
+        assert sorted(pinned) == sorted(lowered)
+        assert hashlib.sha256(lowered[name].encode()).hexdigest() \
+            == pinned[name]["sha256"], name
+
+    @pytest.mark.parametrize("lane", _LANES)
+    def test_the_routed_step_takes_one_batch_operand(self, lowered, lane):
+        """The collective launch's frame is ONE operand, ``uint64[n, 2L +
+        2]`` sharded by rows (a row a device): no second column, and no
+        scalar for ``jit`` to replicate on every call."""
+        import re
+
+        text = lowered[f"mesh4-c3-coll.routed-{lane}"]
+        head = text[text.index("func.func public @main("):]
+        head = head[:head.index(") -> (")]
+        operands = re.findall(r"%arg\d+: tensor<([^>]*)>", head)
+        rows = 256 // 4   # the tool's frame over the config's four slices
+        assert [t for t in operands if t.endswith("ui64")] == [
+            f"4x{2 * rows + 2}xui64"]
+        assert not [t for t in operands if "x" not in t], \
+            "a scalar operand reaches the routed step"
+        assert not [t for t in operands if t.startswith("256x")], \
+            "a frame column rides beside the staged operand"
+
     @pytest.mark.parametrize("name", SERVING)
     def test_every_table_gather_is_inside_the_one_conditional(self, lowered,
                                                               name):

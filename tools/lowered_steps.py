@@ -10,7 +10,8 @@ file's checkout), builds a limiter for each of chipbench/configs/*.json
 at its rehearsal width and lowers, without running anything: the serving
 step on both lanes (finalized hashes, raw ids to premix), the reset and
 rotate controls, the replicated mesh's step in both merge modes and, for
-a ``--router collective`` config, the routed step; for a ``--backend
+a ``--router collective`` config, the routed step over its one staged
+operand (four operands in a checkout before PR 45); for a ``--backend
 dense`` config (they live under configs/added/) the dense limiter's
 serving step on both lanes and its reclaim / forget / clear_rem controls
 (ISSUE 43). One ``<name>.mlir`` a program plus ``index.json`` (name ->
@@ -143,11 +144,20 @@ def _programs(repo: Path):
             n = coll.n_slices
             L = B // n
             C = route_kernels.bin_capacity(L, n, cfg.mesh.bin_headroom)
-            frame = (mesh_kernels.shard_batch(np.zeros(B, np.uint64),
-                                              coll.mesh),
-                     mesh_kernels.shard_batch(np.zeros(B, np.int32),
-                                              coll.mesh),
-                     np.int64(B), now)
+            if hasattr(coll, "_acquire_slot"):
+                # Since PR 45 the frame is ONE staged operand, a row a
+                # device, placed as the launch places it.
+                slot = coll._acquire_slot(L)
+                slot[:] = 0
+                frame = (jax.device_put(slot, coll._frame_sharding),)
+            else:
+                # A checkout before PR 45: two sharded columns and two
+                # host scalars.
+                frame = (mesh_kernels.shard_batch(np.zeros(B, np.uint64),
+                                                  coll.mesh),
+                         mesh_kernels.shard_batch(np.zeros(B, np.int32),
+                                                  coll.mesh),
+                         np.int64(B), now)
             for s in coll.slices:
                 s._lock.acquire()
             try:
