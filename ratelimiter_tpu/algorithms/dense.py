@@ -45,12 +45,41 @@ from ratelimiter_tpu.core.errors import StorageUnavailableError
 from ratelimiter_tpu.core.types import Algorithm, BatchResult, DispatchTicket
 from ratelimiter_tpu.observability import tracing
 
-#: The occupancy (of capacity, counting every unit of cost in flight as a
-#: new key) above which a launch first runs the reclaim pass ...
-_RECLAIM_ABOVE = 0.875
-#: ... at most once in this share of a window: a directory that stays
-#: full of live keys is not swept on every dispatch.
-_RECLAIM_EVERY = 0.125
+#: The reclaim gate: two lines of one reckoning. One unplaced row fails
+#: its whole dispatch (the policy answers it), so the table is kept
+#: ``_MARGIN`` of load under ``directory.unplaced_from(w, pb)``, the
+#: lowest load at which a fill leaves a row unplaced: that is the LINE,
+#: 0.8188 of the capacity at the server's 128 lanes and bound of 8. A
+#: launch whose batch could cross it — every unit of cost in flight and
+#: every row of the batch counted as a new key — runs the pass first,
+#: whenever the last one was: a table that full is throttled to what
+#: expires, not failed (a cold start under a closed loop is served at the
+#: host's pace, 3.6 M decisions/s where 2^21 entries hold 3.1 M, and
+#: nothing is idle for its first two windows: PERF.md section 6, PR 48).
+_MARGIN = 0.05
+#: ``_CREST`` under the line is the GATE, 0.79875 there: from it the pass
+#: runs at most once in ``_RECLAIM_EVERY`` of a window, so a table whose
+#: live keys stand near the gate is not swept on every dispatch. The
+#: crest is what one ``_RECLAIM_EVERY`` of first-seen keys adds when a
+#: pass frees too little to close the gate again: 1.7 % of 2^21 entries
+#: at 2.7 M decisions/s of zipfian 0.99 over 20 M keys, 2.2 % at 3.6 M
+#: (replayed).
+_CREST = 0.02
+_RECLAIM_EVERY = 0.0625
+
+
+def reclaim_line(w: int, pb: int) -> float:
+    """The share of the capacity no launch may cross without a pass, for
+    a directory of ``w``-lane buckets probed ``pb`` deep (never under a
+    quarter: a table of one-lane buckets is for tests)."""
+    from ratelimiter_tpu.ops import directory
+
+    return max(0.25, directory.unplaced_from(w, pb) - _MARGIN)
+
+
+def reclaim_above(w: int, pb: int) -> float:
+    """The gate's share of the capacity: ``_CREST`` under the line."""
+    return max(0.25, reclaim_line(w, pb) - _CREST)
 
 
 class DenseLimiter(HashedLane, RateLimiter):
@@ -62,7 +91,7 @@ class DenseLimiter(HashedLane, RateLimiter):
                  capacity: Optional[int] = None):
         super().__init__(config, clock)
         # Import lazily so the exact backend works without JAX present.
-        from ratelimiter_tpu.ops import dense_kernels
+        from ratelimiter_tpu.ops import dense_kernels, directory
 
         self._capacity = int(capacity if capacity is not None
                              else self.config.dense.capacity)
@@ -84,6 +113,10 @@ class DenseLimiter(HashedLane, RateLimiter):
                      "reclaimed": 0, "reclaim_passes": 0,
                      "reclaim_seconds": 0.0}
         self._next_reclaim_us = 0
+        geo = directory.geometry(self._capacity, self.config.dense.lanes,
+                                 self.config.dense.probe_bound)
+        self._reclaim_above = reclaim_above(geo["w"], geo["pb"])
+        self._reclaim_line = reclaim_line(geo["w"], geo["pb"])
         # Policy engine: overrides resolved in-kernel (binary search over
         # the device-resident table, ops/policy_kernels.py). Entries are
         # re-gated through the same overflow checks as the base config.
@@ -251,10 +284,13 @@ class DenseLimiter(HashedLane, RateLimiter):
         """Run the reclaim pass first when this batch could fill the
         directory: what is in flight (the lane's offered mass, at least
         its rows) and every row of this batch counted as new keys,
-        against ``_RECLAIM_ABOVE`` of the capacity."""
-        if (self._entries + self._inflight_mass + b
-                > _RECLAIM_ABOVE * self._capacity
-                and now_us >= self._next_reclaim_us):
+        against the gate's share of the capacity (``reclaim_above``) at
+        most once in ``_RECLAIM_EVERY`` of a window, against the line's
+        (``reclaim_line``) at every launch."""
+        filled = self._entries + self._inflight_mass + b
+        if (filled > self._reclaim_above * self._capacity
+                and (now_us >= self._next_reclaim_us
+                     or filled > self._reclaim_line * self._capacity)):
             self._reclaim_locked(now_us)
         return None
 

@@ -17,7 +17,7 @@
 //                        HEALTH answered inline from atomics; writes
 //                        flushed from per-conn output queues.
 //   dispatcher thread(s) one per shard: waits up to max_delay_us for
-//                        work, drains up to max_batch keys, builds the
+//                        work, drains up to drain_cap keys, builds the
 //                        contiguous (blob, offsets, lengths, ns) buffers
 //                        WITH the key prefix prepended (so Python hashes
 //                        ready-made bytes). Pipelined mode (launch +
@@ -853,7 +853,7 @@ struct IoRing {
 // positions; the LAST one to finish encodes and sends the single
 // response frame. `remaining` counts SEGMENTS, not shards (ADR-013):
 // besides the io thread's per-shard split of a mixed frame, the
-// dispatcher may carve a hashed segment at the max_batch boundary so a
+// dispatcher may carve a hashed segment at the drain_cap boundary so a
 // coalesced run never overshoots the largest prewarmed pad shape — the
 // continuation registers itself with a fetch_add BEFORE its first half
 // can deposit, so the count can never hit zero early.
@@ -964,7 +964,16 @@ struct Server {
   std::atomic<uint64_t> shm_records_in{0}, shm_records_out{0};
   std::atomic<uint64_t> shm_ring_full_stalls{0};
   std::atomic<uint64_t> shm_req_highwater{0}, shm_rep_highwater{0};
+  // The coalescer's two numbers. `max_batch` is the WAIT threshold: a
+  // queue that holds this many keys dispatches at once, a thinner one
+  // waits up to max_delay_us. `drain_cap` (>= max_batch) is the most
+  // rows ONE drain takes of what is already queued, and the boundary
+  // the carve cuts at; the Python side prewarms every pad shape up to
+  // 2 * drain_cap. An operator's explicit --max-batch sets both to the
+  // same value; left out, the door waits for 4,096 and drains up to
+  // 16,384 (serving/native_server.py: batch_rule).
   uint32_t max_batch = 4096;
+  uint32_t drain_cap = 4096;
   uint32_t max_delay_us = 200;
   // Dispatch SLO (0 = disabled): when one batched decide exceeds this,
   // waiters are answered immediately per fail_open policy while the
@@ -1026,7 +1035,7 @@ struct Server {
   // What coalescing adds (same dispatches as stage_batches): Pendings
   // drained into them — a wire frame, or the part of one a dispatch
   // took (a carved head counts once, its continuation once in the
-  // next) — and frames the dispatcher cut at the max_batch boundary.
+  // next) — and frames the dispatcher cut at the drain_cap boundary.
   std::atomic<uint64_t> stage_frames{0};
   std::atomic<uint64_t> carved_frames{0};
   // Thread-state seconds and thread CPU clocks (see ThreadBook): one
@@ -2115,7 +2124,10 @@ void dispatcher_main(Server* s, uint32_t shard) {
         }
       }
       if (s->stop.load() && q.queue.empty()) return;
-      while (!q.queue.empty() && run_keys < s->max_batch) {
+      // Take what is THERE, up to drain_cap rows: the wait above never
+      // waits for more than max_batch keys, so a deeper queue is only
+      // ever what piled up while this thread was in a launch.
+      while (!q.queue.empty() && run_keys < s->drain_cap) {
         // RESET/METRICS ride the same queue (keys empty or kind marker).
         Pending& front = q.queue.front();
         // Forward-lane boundary (ADR-019): never mix forward windows
@@ -2129,32 +2141,32 @@ void dispatcher_main(Server* s, uint32_t shard) {
         // under a closed loop, whose queue never empties — EVERY run
         // after it carved a frame (PERF.md section 6, PR 35).
         size_t nk = is_control(front) ? 0 : pending_count(front);
-        size_t room = s->max_batch - run_keys;
-        // Cut BEFORE crossing max_batch (never overshoot the largest
+        size_t room = s->drain_cap - run_keys;
+        // Cut BEFORE crossing drain_cap (never overshoot the largest
         // prewarmed pad shape). Mid-run, string Pendings cut whole
         // (the next run takes them); an oversized Pending — hashed
         // anywhere in a run, string opening one — is carved at the
         // boundary below. Only SLO mode still dispatches an oversized
         // Pending whole: the SLO watcher answers per-Pending with no
         // join awareness, and prewarm covers one pad shape past
-        // max_batch, so only an SLO-mode frame past 2*max_batch pays
+        // drain_cap, so only an SLO-mode frame past 2*drain_cap pays
         // a hot-path compile.
         if (nk > room && run_keys > 0 &&
             (!front.hashed || s->slo_us > 0)) break;
         if (nk > room && s->slo_us == 0) {
-          // Never let a dispatch overshoot max_batch: the Python side
-          // prewarms every pad shape up to max_batch, so a run of
-          // max_batch+1 items pads to the NEXT power of two and pays a
+          // Never let a dispatch overshoot drain_cap: the Python side
+          // prewarms every pad shape up to drain_cap, so a run of
+          // drain_cap+1 items pads to the NEXT power of two and pays a
           // full jit compile on the hot path — the multi-second stalls
           // behind the r06 mixed-traffic collapse (ADR-013). Segments
           // are position-indexed (`pos`), so carve off exactly `room`
           // items and leave a continuation that reassembles through
           // the same (extended) BatchJoin — the string lane rides the
           // shard-split deposit path verbatim. (room >= 1 here: the
-          // loop condition guarantees run_keys < max_batch; a string
+          // loop condition guarantees run_keys < drain_cap; a string
           // Pending only reaches the carve opening a run — the
           // whole-Pending cut above breaks first — so room is the
-          // full max_batch there.)
+          // full drain_cap there.)
           JoinPtr j = front.join;
           if (j == nullptr) {
             // Whole frame about to be segmented: wrap it in a join so
@@ -3399,6 +3411,13 @@ PyObject* server_stats(PyObject* self, PyObject* Py_UNUSED(ignored)) {
     // are launched-but-unresolved.
     depth += pq->entries.size() + (size_t)pq->resolving;
   }
+  // Rows the io threads have queued and no drain has taken yet: what
+  // the next drain finds (up to drain_cap of it a dispatch).
+  size_t queued_keys = 0;
+  for (auto& q : ps->s->shardqs) {
+    std::lock_guard<std::mutex> g(q->qmx);
+    queued_keys += q->queued_keys;
+  }
   PyObject* per_shard = PyList_New(ps->s->num_shards);
   if (per_shard == nullptr) return nullptr;
   for (uint32_t i = 0; i < ps->s->num_shards; ++i) {
@@ -3551,7 +3570,7 @@ PyObject* server_stats(PyObject* self, PyObject* Py_UNUSED(ignored)) {
     return nullptr;
   }
   PyObject* out = Py_BuildValue(
-      "{s:K,s:K,s:K,s:d,s:K,s:I,s:O,s:I,s:O,s:O,s:O,s:O,s:O,s:O,s:O,s:O}",
+      "{s:K,s:K,s:K,s:d,s:K,s:K,s:I,s:O,s:I,s:O,s:O,s:O,s:O,s:O,s:O,s:O,s:O}",
       "decisions_total",
       (unsigned long long)ps->s->decisions.load(), "slo_breaches_total",
       (unsigned long long)ps->s->slo_breaches.load(),
@@ -3559,7 +3578,9 @@ PyObject* server_stats(PyObject* self, PyObject* Py_UNUSED(ignored)) {
       "deadline_shed_total",
       (unsigned long long)ps->s->deadline_shed.load(), "uptime_s",
       now_s() - ps->s->started_at, "inflight_depth",
-      (unsigned long long)depth, "inflight_window", ps->s->inflight_window,
+      (unsigned long long)depth, "queued_keys",
+      (unsigned long long)queued_keys, "inflight_window",
+      ps->s->inflight_window,
       "pipelined", ps->s->pipelined ? Py_True : Py_False,
       // Shard routing observability (mesh mode: one shard == one
       // device, so this is the per-device decision balance, ADR-012).
@@ -3682,7 +3703,7 @@ PyObject* create_server(PyObject* Py_UNUSED(mod), PyObject* args,
                                  "decide_hashed", "launch_hashed",
                                  "spans",
                                  "shm", "shm_dir", "shm_ring_bytes",
-                                 "net_engine", "io_rings",
+                                 "net_engine", "io_rings", "drain_cap",
                                  nullptr};
   PyObject *decide, *reset, *metrics = Py_None, *dcn = Py_None;
   PyObject *launch = Py_None, *resolve = Py_None;
@@ -3701,7 +3722,8 @@ PyObject* create_server(PyObject* Py_UNUSED(mod), PyObject* args,
   unsigned int shm_ring_bytes = 0;
   const char* net_engine = nullptr;
   unsigned int io_rings = 0;
-  if (!PyArg_ParseTupleAndKeywords(args, kwargs, "OO|OIIIpLdy#IOOOIpIOOOpsIsI",
+  unsigned int drain_cap = 0;  // 0 = max_batch: one number, as before
+  if (!PyArg_ParseTupleAndKeywords(args, kwargs, "OO|OIIIpLdy#IOOOIpIOOOpsIsII",
                                    (char**)kwlist,
                                    &decide, &reset, &metrics, &max_batch,
                                    &max_delay_us, &slo_us, &fail_open, &limit,
@@ -3710,7 +3732,8 @@ PyObject* create_server(PyObject* Py_UNUSED(mod), PyObject* args,
                                    &inflight, &dcn_auth_required,
                                    &max_dcn_conns, &decide_hashed,
                                    &launch_hashed, &spans, &shm, &shm_dir,
-                                   &shm_ring_bytes, &net_engine, &io_rings))
+                                   &shm_ring_bytes, &net_engine, &io_rings,
+                                   &drain_cap))
     return nullptr;
   uint32_t net_engine_req = 0;  // auto
   if (net_engine != nullptr && net_engine[0] != '\0') {
@@ -3736,6 +3759,7 @@ PyObject* create_server(PyObject* Py_UNUSED(mod), PyObject* args,
   if (ps == nullptr) return nullptr;
   ps->s = new Server();
   ps->s->max_batch = max_batch;
+  ps->s->drain_cap = std::max(drain_cap, max_batch);
   ps->s->max_delay_us = max_delay_us;
   ps->s->slo_us = slo_us;
   ps->s->fail_open = fail_open != 0;
@@ -3780,7 +3804,7 @@ PyMethodDef module_methods[] = {
     {"create_server", (PyCFunction)create_server,
      METH_VARARGS | METH_KEYWORDS,
      "create_server(decide, reset, metrics=None, max_batch=4096, "
-     "max_delay_us=200) -> Server"},
+     "max_delay_us=200, ..., drain_cap=0) -> Server"},
     {nullptr, nullptr, 0, nullptr},
 };
 
@@ -3795,7 +3819,7 @@ struct PyModuleDef server_module = {
 extern "C" {
 
 // C ABI probe so the loader can verify the build (native/__init__ pattern).
-int64_t rl_server_abi_version() { return 13; }
+int64_t rl_server_abi_version() { return 14; }
 
 // SHA-256 of server.cpp + shm_ring.h as built (see hasher.cpp).
 #ifndef RL_SRC_HASH

@@ -497,8 +497,11 @@ class MetricsDecorator(LimiterDecorator):
                      "windows (cumulative)"),
                     ("reclaim_passes_total",
                      "Reclaim passes run (cumulative): one when a launch "
-                     "finds the directory over 7/8 full, at most one in "
-                     "an eighth of a window, and after prune / reset"),
+                     "finds the directory over its gate (four fifths "
+                     "full at 128 lanes and a probe bound of 8), at most "
+                     "one in a sixteenth of a window — at every launch "
+                     "once two hundredths further, the line —, and "
+                     "after prune / reset"),
                     ("reclaim_seconds_total",
                      "Seconds launches were held by reclaim passes "
                      "(cumulative): the pass enqueued behind the steps "

@@ -94,6 +94,31 @@ def geometry(capacity: int, lanes: int, probe_bound: int) -> dict:
     return dict(nb=nb, w=w, pb=min(int(probe_bound), nb))
 
 
+def unplaced_from(w: int, pb: int) -> float:
+    """The load (live entries / capacity) under which no fill of a table
+    of ``w``-lane buckets probed ``pb`` deep has left a row unplaced: a
+    row finds no lane when the ``w * pb`` lanes of its path are all
+    live, the keys homed on a path are ~Poisson, so the first unplaced
+    row comes a number of standard deviations under a full path — and
+    WHICH number is a draw: ``1 - 4.2 / sqrt(w * pb)`` lies under every
+    one seen up to 2^24 entries. At w, pb = 128, 8 that is 0.8688.
+    Measured (ISSUE 48), the load at a fill's first unplaced row,
+    uniform keys: the bucket counts alone, 2^21 entries, 40 seeds:
+    lowest 0.8723, first decile 0.8885, median 0.8992 (2^17 entries, 200
+    seeds: 0.8779 / 0.9053 / 0.9214; 2^24, 6 seeds: lowest 0.8764; 2^26,
+    2 seeds: 0.8654 and 0.8720 — a larger table has more paths to
+    overflow, which the margin ``algorithms/dense.py`` keeps under this
+    covers); the limiter itself on a CPU, 2^21 entries: 0.8965 and
+    0.8984 fresh, 0.8966-0.9036 on the churned table of an expiring
+    Zipf(0.99) stream (4,096 and 16,384 rows a dispatch); on the chip
+    under that stream from a cold start: 0.882-0.899 by the host's count
+    at the first unplaced rows; other geometries, one seed each at 2^21:
+    128, 16: 0.9336; 128, 4: 0.8613; 64, 8: 0.8535; 32, 8: 0.8047 (the
+    formula: 0.9072, 0.8144, 0.8144, 0.7375).
+    ``tests/test_dense_directory.py`` holds the curve."""
+    return 1.0 - 4.2 / math.sqrt(w * pb)
+
+
 def words(x):
     """A 64-bit integer array as its ``(low, high)`` uint32 words, by a
     mask and a shift (a ``bitcast_convert_type`` on a 64-bit type is

@@ -238,8 +238,15 @@ def build_parser() -> argparse.ArgumentParser:
                          "dry-run / apply / abort) on the HTTP gateway, "
                          "gated by this bearer token. No token, no "
                          "endpoint — same posture as /v1/fleet/migrate")
-    ap.add_argument("--max-batch", type=int, default=4096,
-                    help="micro-batcher flush size")
+    ap.add_argument("--max-batch", type=int, default=None,
+                    help="micro-batcher flush size: the queue depth that "
+                         "dispatches at once AND the most rows one "
+                         "dispatch takes. Not given: 4096 for both on the "
+                         "asyncio door; the --native door still dispatches "
+                         "at once from 4096 queued keys but a dispatch "
+                         "takes every whole frame already waiting, up to "
+                         "16384 rows (nothing waits for the larger run; "
+                         "at most --inflight x 16384 rows are in flight)")
     ap.add_argument("--max-delay-us", type=float, default=200.0,
                     help="micro-batcher coalescing window, microseconds")
     ap.add_argument("--dispatch-timeout-ms", type=float, default=None,
@@ -962,11 +969,13 @@ def _lease_health(lease_mgr) -> dict:
 
 def _prewarm(limiter, max_batch: int) -> None:
     """Compile every batch pad shape the serving tier can produce BEFORE
-    accepting traffic, so no client request ever pays a jit compile: the
-    powers of two up to max_batch, PLUS one shape past it — the native
-    door's coalescer cuts runs at max_batch (and segments hashed frames
-    across the boundary, ADR-013), but a single wire frame larger than
-    max_batch still dispatches alone and pads to the next shape. (The
+    accepting traffic, so no client request ever pays a jit compile.
+    ``max_batch`` is the most rows a dispatch takes: the door's drain
+    cap (native_server.batch_rule). Warmed are the powers of two up to
+    it, PLUS one shape past it — the native door's coalescer cuts runs
+    at max_batch (and segments hashed frames across the boundary,
+    ADR-013), but a single wire frame larger than max_batch still
+    dispatches alone and pads to the next shape. (The
     r06 mixed-traffic collapse was exactly this: ragged coalesced runs
     overshooting max_batch by a slice landed multi-second XLA compiles
     on the hot path.) With the persistent compilation cache this is fast
@@ -1075,6 +1084,7 @@ async def amain(args) -> None:
     _configure_jax(args)
     from ratelimiter_tpu import HierarchySpec, MeshSpec, PersistenceSpec
     from ratelimiter_tpu.observability import tracing
+    from ratelimiter_tpu.serving.native_server import batch_rule
 
     if args.flight_recorder:
         # Before any serving thread starts; the registry hookup derives
@@ -1265,11 +1275,17 @@ async def amain(args) -> None:
             from ratelimiter_tpu.observability.decorators import undecorated
 
             qmgr = getattr(undecorated(limiter), "quarantine", None)
+    # The coalescer's two numbers: the queue depth that dispatches at
+    # once, and the most rows a dispatch takes (what prewarm must cover).
+    # Only the native door's default tells them apart.
+    wait_rows, drain_rows = batch_rule(
+        args.max_batch, native=args.native,
+        slo=bool(args.dispatch_timeout_ms))
     if args.backend != "exact" and not args.no_prewarm:
-        _prewarm(limiter, args.max_batch)
+        _prewarm(limiter, drain_rows)
         if slices is not None:
             for i, s in enumerate(slices[1:], start=1):
-                _prewarm(s, args.max_batch)
+                _prewarm(s, drain_rows)
     device_report = _device_report(
         args, slices if slices is not None else [limiter])
     # Live accuracy observatory (ADR-016): shadow-oracle auditor + SLO
@@ -1878,7 +1894,7 @@ async def amain(args) -> None:
         limiter, args.listen or args.host, args.port,
         shm=args.shm, shm_dir=args.shm_dir,
         shm_ring_bytes=args.shm_ring_bytes,
-        max_batch=args.max_batch,
+        max_batch=wait_rows,
         max_delay=args.max_delay_us * 1e-6,
         dispatch_timeout=(args.dispatch_timeout_ms * 1e-3
                           if args.dispatch_timeout_ms else None),

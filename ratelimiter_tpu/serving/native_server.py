@@ -41,7 +41,33 @@ from ratelimiter_tpu.observability import metrics as m
 from ratelimiter_tpu.serving import protocol as p
 
 
-_ABI = 13
+_ABI = 14
+
+#: The coalescer's rule when ``--max-batch`` is not given: a queue that
+#: holds ``AUTO_WAIT_ROWS`` keys dispatches at once (a thinner one waits
+#: up to ``--max-delay-us``), and a drain takes every whole item that is
+#: already queued, up to ``AUTO_DRAIN_ROWS`` rows. Nothing waits for the
+#: larger run: it is what piled up while the dispatcher was in a launch.
+AUTO_WAIT_ROWS = 4096
+AUTO_DRAIN_ROWS = 16384
+
+
+def batch_rule(max_batch: Optional[int], *, native: bool = True,
+               slo: bool = False) -> tuple:
+    """``(wait threshold, drain cap)`` of a door's coalescer.
+
+    An explicit ``max_batch`` is the operator's cap and the wait
+    threshold both. ``None`` is the default rule above on the native
+    door; it stays one frame's worth a dispatch, (4,096, 4,096), under a
+    dispatch SLO — a deadline per dispatch, set against runs of at most
+    the wait threshold — and on the asyncio door, whose batcher has one
+    number. What the door observes (a flag, an SLO) decides, never the
+    limiter behind it. Prewarm covers every pad shape up to twice the
+    drain cap (serving/__main__.py:_prewarm)."""
+    if max_batch is not None:
+        return max_batch, max_batch
+    return AUTO_WAIT_ROWS, (AUTO_DRAIN_ROWS if native and not slo
+                            else AUTO_WAIT_ROWS)
 
 
 @functools.lru_cache(maxsize=None)
@@ -94,6 +120,10 @@ class NativeRateLimitServer:
     fail-open stamps (the dispatch that would resolve them never
     completed; the decision fields are policy-driven either way).
 
+    ``max_batch`` given is the coalescer's one number, as in the asyncio
+    door; left ``None`` the door sizes a run from its own queue
+    (``batch_rule``; ``self.max_batch``, ``self.drain_cap``).
+
     ``inflight`` (default 8; >1 requires a sketch-family limiter and no
     dispatch_timeout) enables the pipelined launch/resolve hot path:
     that many device dispatches stay in flight per shard, with
@@ -106,7 +136,7 @@ class NativeRateLimitServer:
     """
 
     def __init__(self, limiter: RateLimiter, host: str = "127.0.0.1",
-                 port: int = 0, *, max_batch: int = 4096,
+                 port: int = 0, *, max_batch: Optional[int] = None,
                  max_delay: float = 200e-6,
                  dispatch_timeout: Optional[float] = None,
                  inflight: int = 8,
@@ -253,9 +283,12 @@ class NativeRateLimitServer:
         self.inflight = inflight
         self._pipelined = bool(self._fast and dispatch_timeout is None
                                and inflight > 1)
+        self.max_batch, self.drain_cap = batch_rule(
+            max_batch, slo=bool(dispatch_timeout))
         self._server = ext.create_server(
             decide=self._decide, reset=self._reset, metrics=self._metrics,
-            max_batch=max_batch, max_delay_us=int(max_delay * 1e6),
+            max_batch=self.max_batch, drain_cap=self.drain_cap,
+            max_delay_us=int(max_delay * 1e6),
             slo_us=int(dispatch_timeout * 1e6) if dispatch_timeout else 0,
             fail_open=bool(limiter.config.fail_open),
             limit=int(limiter.config.limit),
@@ -1175,12 +1208,13 @@ class NativeRateLimitServer:
             "rate_limiter_door_frames_total",
             "Queued requests the native door's coalescer has drained "
             "into completed dispatches (cumulative): a wire frame, or "
-            "the part of one a dispatch took — a frame cut at the "
-            "max_batch boundary counts once in each dispatch that took "
+            "the part of one a dispatch took — a frame cut at a "
+            "dispatch's row cap counts once in each dispatch that took "
             "a part of it").set(stage["frames"])
         self.registry.gauge(
             "rate_limiter_door_carved_frames_total",
-            "Frames the native door's coalescer has cut at the max_batch "
-            "boundary (cumulative): the head filled one dispatch, the "
+            "Frames the native door's coalescer has cut at a dispatch's "
+            "row cap (--max-batch; 16,384 rows where it is not given; "
+            "cumulative): the head filled one dispatch, the "
             "rest opened the next, the reply still goes out as one "
             "frame").set(stage["carved"])

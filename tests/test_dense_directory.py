@@ -405,26 +405,34 @@ def test_reuse_after_prune(algo):
 
 @every_rule
 def test_a_nearly_full_directory_is_swept_before_a_launch(algo):
-    """No prune() call: the launch that could fill the table runs the
-    pass itself (idle keys give way), and not again within an eighth of
-    a window."""
-    dense, exact, clock = pair(algo, capacity=8, probe_bound=2,
-                               window=16.0)
-    old = np.arange(1, 8, dtype=np.uint64) * 19
-    same(dense.resolve(dense.launch_hashed(old)),
-         exact.allow_batch(names(old)))
-    passes = dense.directory_stats()["reclaim_passes"]
+    """No prune() call: the launch that could fill the table past the
+    gate (0.79875 of 1,024 entries: 817) runs the pass itself (idle keys
+    give way) and not again within a sixteenth of a window — unless the
+    batch could cross the line (0.8188: 838), where it runs whenever
+    the last one was."""
+    dense, exact, clock = pair(algo, capacity=1024, lanes=128,
+                               probe_bound=8, window=16.0)
+    assert (int(dense._reclaim_above * 1024),
+            int(dense._reclaim_line * 1024)) == (817, 838)
+
+    def launch(lo, hi, mult):
+        ids = np.arange(lo, hi, dtype=np.uint64) * np.uint64(mult)
+        same(dense.resolve(dense.launch_hashed(ids)),
+             exact.allow_batch(names(ids)))
+        return dense.directory_stats()
+
+    passes = launch(1, 801, 19)["reclaim_passes"]     # 800: under the gate
     clock.advance(33.0)
-    new = np.arange(1, 7, dtype=np.uint64) * 23
-    same(dense.resolve(dense.launch_hashed(new)),
-         exact.allow_batch(names(new)))
-    st = dense.directory_stats()
-    assert st["reclaim_passes"] == passes + 1 and st["reclaimed"] == 7
-    assert st["entries"] == 6 and st["unplaced"] == 0
-    clock.advance(1.0)
-    same(dense.resolve(dense.launch_hashed(new)),
-         exact.allow_batch(names(new)))
-    assert dense.directory_stats()["reclaim_passes"] == passes + 1
+    st = launch(1, 21, 23)                            # 820: over it, idle
+    assert st["reclaim_passes"] == passes + 1 and st["reclaimed"] == 800
+    assert st["entries"] == 20 and st["unplaced"] == 0
+    clock.advance(0.9)
+    assert launch(21, 811, 23)["reclaim_passes"] == passes + 1    # 810
+    st = launch(811, 821, 23)       # 820: over the gate, inside the 1/16
+    assert st["reclaim_passes"] == passes + 1 and st["entries"] == 820
+    st = launch(821, 841, 23)       # 840: over the line, at once
+    assert st["reclaim_passes"] == passes + 2 and st["reclaimed"] == 800
+    assert st["entries"] == 840 and st["unplaced"] == 0
     dense.close()
     exact.close()
 
@@ -877,3 +885,67 @@ def test_a_zipf_099_stream_whose_tail_keeps_arriving(geometry, algo, lane):
         leaf.nbytes for leaf in dense._state.values())
     dense.close()
     exact.close()
+
+
+# ------------------------------------------------------- the fill curve
+
+#: (capacity, lanes, probe bound): the server's geometry at two sizes and
+#: two others of ``directory.unplaced_from``'s table.
+FILLS = [(1 << 17, 128, 8), (1 << 18, 128, 8), (1 << 17, 64, 8),
+         (1 << 17, 128, 4)]
+
+
+@pytest.mark.parametrize("capacity, lanes, pb", FILLS,
+                         ids=[f"2^{c.bit_length() - 1}-{w}x{pb}"
+                              for c, w, pb in FILLS])
+def test_no_row_is_unplaced_under_the_load_the_gate_is_reckoned_from(
+        capacity, lanes, pb):
+    """A fresh table filled with uniform keys, 2,048 a dispatch: not one
+    row is left unplaced up to ``directory.unplaced_from(w, pb)`` — the
+    reclaim gate's load plus its crest and its margin, so neither
+    constant can drift back over the cliff unnoticed — and rows ARE left
+    unplaced before the table is full (the curve measures something)."""
+    from ratelimiter_tpu.algorithms import dense as dense_mod
+
+    cliff = directory.unplaced_from(lanes, pb)
+    gate = dense_mod.reclaim_above(lanes, pb)
+    line = dense_mod.reclaim_line(lanes, pb)
+    assert line == pytest.approx(cliff - dense_mod._MARGIN)
+    assert gate == pytest.approx(line - dense_mod._CREST)
+    assert dense_mod._MARGIN >= 0.05 and gate <= cliff - 0.07
+    cfg = Config(algorithm=Algorithm.TOKEN_BUCKET, limit=5, window=60.0,
+                 fail_open=True,
+                 dense=DenseParams(capacity=capacity, lanes=lanes,
+                                   probe_bound=pb))
+    dense = create_limiter(cfg, backend="dense", clock=ManualClock(T0))
+    assert (dense._reclaim_above, dense._reclaim_line) == (gate, line)
+    rng = np.random.default_rng(capacity + lanes + pb)
+    clean_up_to = first = None
+    while dense.key_count() < 0.99 * capacity:
+        dense.allow_hashed(rng.integers(2, 1 << 63, 2048, dtype=np.uint64))
+        st = dense.directory_stats()
+        if st["unplaced"] and first is None:
+            first = st["entries"] / capacity
+        if not st["unplaced"]:
+            clean_up_to = st["entries"] / capacity
+    assert clean_up_to >= cliff, (clean_up_to, cliff)
+    assert first is not None and first > cliff
+    # Nothing idle, so the passes the gate ran on the way freed nothing.
+    assert dense.directory_stats()["reclaimed"] == 0
+    dense.close()
+
+
+def test_the_gate_at_the_servers_geometry_is_four_fifths():
+    from ratelimiter_tpu.algorithms import dense as dense_mod
+
+    assert directory.unplaced_from(128, 8) == pytest.approx(0.86875)
+    assert dense_mod.reclaim_line(128, 8) == pytest.approx(0.81875)
+    assert dense_mod.reclaim_above(128, 8) == pytest.approx(0.79875)
+    # Never over the cliff's measured side for any geometry, never under
+    # a quarter.
+    for w, pb in ((128, 16), (64, 8), (32, 8), (8, 64), (4, 8), (1, 1)):
+        assert 0.25 <= dense_mod.reclaim_above(w, pb) \
+            <= max(0.25, directory.unplaced_from(w, pb) - 0.07)
+        assert dense_mod.reclaim_above(w, pb) \
+            <= dense_mod.reclaim_line(w, pb) \
+            <= max(0.25, directory.unplaced_from(w, pb) - 0.05)

@@ -37,7 +37,7 @@ from tests.test_dense_directory import (
 
 #: Rows a dispatch: four tickets in flight and the batch at the gate count
 #: as new keys (5 x 64 = 8 % of 4,096 entries), so the launch gate runs
-#: its pass at ~0.8 of the table by the host's count.
+#: its pass at ~0.72 of the table by the host's count.
 BATCH = 64
 ZIPF_S = 0.99
 
@@ -65,19 +65,20 @@ def present(dense, hashes) -> np.ndarray:
 #: load at which a pass is run). The 8-lane buckets are full all the time
 #: at these loads (a bucket holds 6.4 +- 2.5 keys at 0.8), so keys walk
 #: far and their probe bound is 64 buckets; the server's geometry, 128
-#: lanes, keeps its own bound of 8. 0.875 is the launch gate's own
-#: threshold (``_RECLAIM_ABOVE``: nothing is called, the launches run
-#: the passes); 0.5 calls ``prune()`` — the same pass — whenever the
-#: host's count passes half the table, tickets in flight.
+#: lanes, keeps its own bound of 8. ``None`` is the launch gate's own
+#: threshold (``dense.reclaim_above`` of the geometry, 0.80 and 0.75
+#: here: nothing is called, the launches run the passes); 0.5 calls
+#: ``prune()`` — the same pass — whenever the host's count passes half
+#: the table, tickets in flight.
 TTL_CASES = {
-    "128-lanes-at-0.875": (4096, 128, 65_536, 960, 1 / 40, 0.875),
-    "8-lanes-at-0.875": (4096, 8, 65_536, 960, 1 / 40, 0.875),
+    "128-lanes-at-the-gate": (4096, 128, 65_536, 960, 1 / 40, None),
+    "8-lanes-at-the-gate": (4096, 8, 65_536, 960, 1 / 40, None),
     "128-lanes-at-0.5": (4096, 128, 65_536, 960, 1 / 20, 0.5),
     "8-lanes-at-0.5": (4096, 8, 65_536, 960, 1 / 20, 0.5),
 }
 TTL_RULES = ([(case, "bucket") for case in TTL_CASES]
-             + [("8-lanes-at-0.875", "fixed"),
-                ("8-lanes-at-0.875", "sliding")])
+             + [("8-lanes-at-the-gate", "fixed"),
+                ("8-lanes-at-the-gate", "sliding")])
 
 
 @pytest.mark.parametrize("case, algo", TTL_RULES,
@@ -104,7 +105,7 @@ def test_expiry_keeps_a_table_far_smaller_than_its_keys(case, algo):
         pending.clear()
 
     for step, rows in enumerate(stream):
-        if at < dense_mod._RECLAIM_ABOVE and dense.key_count() > at * capacity:
+        if at is not None and dense.key_count() > at * capacity:
             dense.prune()                       # tickets in flight
         pending.append((dense.launch_hashed(rows),
                         exact.allow_batch(names(rows)), step))
@@ -127,6 +128,100 @@ def test_expiry_keeps_a_table_far_smaller_than_its_keys(case, algo):
     # Keys were given up and came back (as fresh keys, equal to the
     # reference's all along): more inserts than distinct keys.
     assert st["inserts"] > distinct
+    dense.close()
+    exact.close()
+
+
+#: What a launch waits for a pass, read on the chip (PERF.md section 6,
+#: PR 48): 2-4 ms while the host paces the server (a cold start: the
+#: device runs nothing ahead of the pass), 13-27 ms with eight
+#: device-paced steps in flight ahead of it. The clock moves by this much
+#: between the launch's stamp and its step.
+PASS_STALL_EARLY_S, PASS_STALL_S = 0.003, 0.02
+#: The closed loop at a sixteenth of the cell: decisions a second while
+#: the table holds no tombstone yet (the host's pace, 3.65 M in the cell:
+#: its two-second key set is 0.90 of the table here as there) and after
+#: (the device's, ~2.9-3.0 M there: 0.74).
+FAST, STEADY = 162_000, 130_000
+
+
+@pytest.mark.parametrize("line, seconds", [(True, 8.0), (False, 3.0)],
+                         ids=["the-line-holds-it", "without-the-line"])
+def test_the_cells_stream_from_a_cold_start_eight_tickets_four_frames(
+        line, seconds):
+    """``exact-hashed-ttl``'s shape at a sixteenth of its table: 2^17
+    entries of 128 lanes, bound 8, Zipf(0.99) over twenty times as many
+    keys, a one-second window, dispatches of four 256-id frames, EIGHT
+    tickets in flight, from an EMPTY table under a CLOSED LOOP: nothing
+    is idle for the first two windows and until a pass has given
+    something up the server runs at the host's pace, a rate whose
+    two-second key set does not fit under the cliff — what failed two to
+    seventeen frames a run on the chip under the single gate (PR 48). The
+    clock moves through every pass by what the launch waits on the chip.
+    Every column equals the reference's, no row is unplaced, the
+    launches ran ten passes or more after the start, and the host's
+    count never came within half the margin of
+    ``directory.unplaced_from``: past the line a pass at every launch
+    throttles the loop to what expires. Without the line (the same gate
+    and cadence) the same stream takes the table over that load: the
+    control."""
+    capacity, rows = 1 << 17, 1024
+    dense, exact, clock = pair("bucket", capacity=capacity, lanes=128,
+                               probe_bound=8, limit=100, window=1.0,
+                               fail_open=True)
+    if not line:
+        dense._reclaim_line = 2.0
+    inner = dense._reclaim_locked
+
+    def stalled(now_us):
+        freed = inner(now_us)
+        clock.advance(PASS_STALL_S if dense._dir["reclaimed"]
+                      else PASS_STALL_EARLY_S)
+        return freed
+
+    dense._reclaim_locked = stalled
+    dispatches = int(seconds * FAST / rows)
+    rng = np.random.default_rng(48)
+    p = 1.0 / np.arange(1, 20 * capacity + 1) ** ZIPF_S
+    ranks = rng.choice(20 * capacity, size=(dispatches, rows), p=p / p.sum())
+    stream = (ranks.astype(np.uint64) + np.uint64(1)) \
+        * np.uint64(0x9E3779B97F4A7C15)
+    t0, pending, most, sent = clock.now(), [], 0, 0
+
+    def settle(ticket, want, at):
+        got = dense.resolve(ticket)
+        if line:
+            same(got, want, at)
+
+    for step, batch in enumerate(stream):
+        if clock.now() - t0 >= seconds:
+            break
+        # The reference first: the launch stamps its rows with the clock
+        # as it stands now, then may wait for a pass.
+        want = exact.allow_batch(names(batch)) if line else None
+        pending.append((dense.launch_hashed(batch), want, step))
+        sent += 1
+        if len(pending) == 8:
+            settle(*pending.pop(0))
+            most = max(most, dense.key_count())
+        clock.advance(rows / (STEADY if dense._dir["reclaimed"] else FAST))
+    for item in pending:
+        settle(*item)
+    st = dense.directory_stats()
+    assert st["lookups"] == sent * rows
+    cliff = directory.unplaced_from(128, 8) * capacity
+    if not line:
+        # Over the load from which a fill leaves rows unplaced (a table
+        # of 2^17 entries does at 0.88-0.95, the cell's 2^21 at
+        # 0.87-0.92): whether THIS seed's rows found lanes is the draw.
+        assert most > cliff, (most, cliff)
+        return
+    assert st["unplaced"] == 0
+    assert st["reclaim_passes"] >= 10 + 16, st    # the start's, then more
+    assert 0.75 * capacity < most < cliff - dense_mod._MARGIN / 2 * capacity, \
+        (most, cliff)
+    assert st["inserts"] - st["reclaimed"] == st["entries"]
+    assert st["inserts"] > np.unique(stream[:sent]).shape[0]   # came back
     dense.close()
     exact.close()
 
@@ -223,8 +318,8 @@ def test_a_pass_with_tickets_in_flight_frees_no_key_they_touch(algo):
                          ids=["fail-open", "fail-closed"])
 def test_live_keys_beyond_the_table_are_answered_by_policy(fail_open):
     """The one case that does fill the table: more keys live inside two
-    windows than it has entries. The passes run (the gate asks for one
-    every eighth of a window) and find nothing idle; the rows without an
+    windows than it has entries. The passes run (past the line the gate
+    asks for one at every launch) and find nothing idle; the rows without an
     entry are counted and answered by the policy — flagged, or raised —
     and the keys that have an entry still answer as the reference."""
     dense, exact, clock = pair("bucket", capacity=256, lanes=8,

@@ -11,7 +11,14 @@ reclaim pass gave up and that came back must still answer inside the
 interval of a bucket nobody forgot.
 
     chiprun -- python3 tools/ttl_replay.py [--cell exact-hashed-ttl]
-        [--seed N] [--frames 4000]
+        [--seed N] [--frames 4000] [--frame-keys 16384]
+
+``--frame-keys`` replaces the traffic's 4,096 ids a frame: 16,384 is the
+most rows one drain of the default native door takes (ISSUE 48), so each
+frame is one dispatch of the largest shape the cell's server runs. Keep
+it at or under the server's drain cap (256 in the CPU rehearsal): a
+frame the door carves is decided at several instants, and the reference
+here holds a frame's rows to one.
 
 Prints one JSON line of counts: frames, decisions, seconds, replies
 outside the reference's interval (``outside``, with the first few), the
@@ -113,6 +120,7 @@ def main() -> int:
     ap.add_argument("--cell", default="exact-hashed-ttl")
     ap.add_argument("--seed", type=int, default=2147487101)
     ap.add_argument("--frames", type=int, default=4000)
+    ap.add_argument("--frame-keys", type=int, default=0)
     args = ap.parse_args()
     cell = runner.load_cell(args.cell)
     cfg, traffic = cell["config"], cell["traffic"]
@@ -120,7 +128,8 @@ def main() -> int:
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
     stream = zipf_frames(cfg["key_population"], traffic["zipf_s"],
-                         args.frames, traffic["frame_keys"], args.seed)
+                         args.frames,
+                         args.frame_keys or traffic["frame_keys"], args.seed)
     replies, instants, policy_frames = [], [], 0
     with runner.serving(cell, out_dir, trace=False) as srv:
         with Wire(srv.port) as wire:
