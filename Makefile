@@ -9,10 +9,7 @@ export PYTHONPATH := $(REPO):$(PYTHONPATH)
         test-audit test-fleet test-fleet-forward test-fleet-obs \
         test-reshard test-hierarchy test-leases test-placement test-shm \
         test-neteng lint check \
-        native bench bench-quick bench-audit bench-chaos bench-fleet \
-        bench-fleet-obs bench-reshard bench-hierarchy bench-leases \
-        bench-rebalance bench-shm bench-neteng bench-matrix serve verify \
-        smoke clean
+        native serve verify smoke clean
 
 help:            ## list targets
 	@grep -E '^[a-z-]+:.*##' $(MAKEFILE_LIST) | sed 's/:.*##/\t/'
@@ -74,36 +71,6 @@ test-shm:        ## shared-memory wire lane (ADR-025): uds/shm both doors, bit-i
 test-neteng:     ## multi-ring network engine (ADR-026): epoll==uring byte parity, asserted probe downgrade, mid-frame death, slow-loris, fairness, shm-over-uring
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_net_engine.py -q
 
-bench-fleet:     ## fleet scale-out numbers (single vs 2/4-host affine/mixed sweep + failover JSON, ADR-019)
-	JAX_PLATFORMS=cpu $(PY) bench.py --fleet-hosts 4
-
-bench-fleet-obs: ## all-observability-on fleet retention (interleaved off/on pairs, OBS_r01 JSON, ADR-021)
-	JAX_PLATFORMS=cpu $(PY) bench.py --fleet-obs
-
-bench-reshard:   ## elastic lifecycle numbers (migration window / rolling-restart retention / rejoin JSON)
-	JAX_PLATFORMS=cpu $(PY) bench.py --reshard
-
-bench-audit:     ## live-vs-offline accuracy agreement + audit overhead A/B JSON
-	$(PY) bench.py --audit
-
-bench-chaos:     ## degraded-serving numbers (retention/entry/recovery JSON)
-	$(PY) bench.py --chaos slow-slice
-
-bench-hierarchy: ## cascade overhead ratio + abuse-scenario numbers (tighten/recover timeline JSON, ADR-020)
-	JAX_PLATFORMS=cpu $(PY) bench.py --hierarchy
-
-bench-leases:    ## client-embedded lease numbers (leased vs wire rate, storm bound, Wilson delta, LEASE_r01 JSON, ADR-022)
-	JAX_PLATFORMS=cpu $(PY) bench.py --leases
-
-bench-rebalance: ## load-aware placement numbers (skewed fleet convergence, moved-range oracle, off-pin, REBALANCE_r01 JSON, ADR-023)
-	JAX_PLATFORMS=cpu $(PY) bench.py --rebalance
-
-bench-shm:       ## transport ladder A/B (interleaved tcp/uds/shm paired rounds, wire-phase breakdown, SHM_r01 JSON, ADR-025)
-	$(PY) bench.py --shm
-
-bench-neteng:    ## network-engine conn sweep (baseline vs multi-ring paired rounds at 16..512 conns, syscalls/decision, NETENG_r01 JSON, ADR-026)
-	JAX_PLATFORMS=cpu $(PY) bench.py --conn-sweep
-
 lint:            ## in-repo linter (ruff config in pyproject.toml where available)
 	$(PY) tools/lint.py
 
@@ -116,15 +83,6 @@ native:          ## build the C++ bulk hasher extension in place (rebuilds when 
 	$(PY) -c "from ratelimiter_tpu.native import native_available; \
 	          assert native_available(), 'build failed (g++ required)'; \
 	          print('native hasher built')"
-
-bench:           ## bench.py, one JSON line (its served legs run on the CPU device; ROADMAP S1)
-	$(PY) bench.py
-
-bench-quick:     ## 3-second smoke bench
-	BENCH_SECONDS=3 $(PY) bench.py
-
-bench-matrix:    ## full matrix + BASELINE configs + e2e serving bench
-	$(PY) -m benchmarks
 
 serve:           ## run the server binary locally (exact backend, instant start)
 	$(PY) -m ratelimiter_tpu.serving --backend exact --algorithm fixed_window \

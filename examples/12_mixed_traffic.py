@@ -2,7 +2,7 @@
 
 MIXED frames — frames whose keys span several device slices, what any
 un-sharded load balancer sends — used to fork-join across every device
-queue (16x collapse in MULTICHIP_r06). The scheduler splits each frame
+queue and collapsed under load. The scheduler splits each frame
 once, coalesces every frame that arrives within one batching window
 into ONE dispatch per touched device, and answers each frame from its
 row range of the window result. Run with a virtual mesh on any host:

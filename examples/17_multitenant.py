@@ -118,7 +118,7 @@ def adaptive_control():
     for k in vic:
         lim.assign_tenant(k, "victim")
     # In-process tick driving (a server runs this on a background
-    # thread via --controller); gains as in the bench.
+    # thread via --controller).
     ctl = AIMDController(
         lim, interval=999.0,
         gains=AIMDGains(decrease_factor=0.7, increase_fraction=0.2,

@@ -7,8 +7,8 @@ the frame, computes owners on device (`h64 % n`), routes rows to their
 owning slice with `jax.lax.all_to_all`, runs the fused kernels on owned
 rows, and routes results back to source order. Decisions are
 bit-identical to the host router; the host's only per-frame route cost
-is padding the frame to the shard shape (33x less host work measured —
-MULTICHIP_r08.json `route_phase_us`). Run with a virtual mesh anywhere:
+is padding the frame to the shard shape. Run with a virtual mesh
+anywhere:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
         python examples/20_collective_router.py

@@ -3,11 +3,10 @@
 The robustness contract of the sliced mesh tier — per-slice quarantine,
 degraded-mode serving, deadline shedding — is only a contract if it is
 *exercised*: this package is the injection seam the chaos suite
-(tests/test_chaos.py), ``loadgen --chaos`` and ``bench.py --chaos``
-drive. Design rules:
+(tests/test_chaos.py) and ``loadgen --chaos`` drive. Design rules:
 
 * **Off by default, zero overhead.** The module global ``INJECTOR`` is
-  ``None`` unless a test/bench installs one; every hook site checks that
+  ``None`` unless a test or the loadgen installs one; every hook site checks that
   one global before doing anything (the same pattern as
   ``tracing.RECORDER``). With no injector installed the hot path is
   byte-identical to a build without this package.

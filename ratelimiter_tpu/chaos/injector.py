@@ -256,8 +256,8 @@ def uninstall() -> None:
 
 def scenario(name: str, injector: ChaosInjector, *, slice_idx: int = 0,
              seconds: float = 0.05) -> None:
-    """Arm one named scenario — the vocabulary ``loadgen --chaos`` and
-    ``bench.py --chaos`` share with the chaos suite:
+    """Arm one named scenario — the vocabulary ``loadgen --chaos``
+    shares with the chaos suite:
 
     * ``kill-slice``     — slice faults every dispatch (dead device);
     * ``slow-slice``     — slice resolves sleep ``seconds``;

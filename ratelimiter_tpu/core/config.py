@@ -98,8 +98,9 @@ class SketchParams:
     # divided by width. The classic Markov bound (err <= e*M/w w.p.
     # 1-e^-d) is orders of magnitude loose for skewed traffic under
     # conservative update, so sizing here uses the calibrated operating
-    # curve measured against the on-device exact oracle (bench.py /
-    # benchmarks config 3, Zipf(1.1), conservative_update, depth >= 3):
+    # curve measured against the on-device exact oracle
+    # (evaluation/oracle_device.py; BASELINE.json config 3, Zipf(1.1),
+    # conservative_update, depth >= 3):
     #
     #   mean cell load M/w = 2.0 * limit   ->  ~0.8%  false denies
     #   mean cell load M/w = 0.27 * limit  ->  ~0.006% false denies
@@ -119,8 +120,8 @@ class SketchParams:
                  seed: int = 0x5bd1e995) -> "SketchParams":
         """Size a sketch geometry for an expected operating point.
 
-        Two error regimes bound the width (both measured on-chip against
-        the exact oracle, benchmarks config 3 round 4):
+        Two error regimes bound the width (both measured against the
+        exact oracle at BASELINE.json's config 3):
 
         * mass: collision error grows with admitted in-window mass per
           cell (the curve in the class comment above);

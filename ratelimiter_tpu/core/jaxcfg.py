@@ -1,8 +1,7 @@
 """Process-level JAX settings every entry point shares, in one place.
 
-Entry points (``python -m ratelimiter_tpu.serving``, ``bench.py``,
-``python -m benchmarks``) call :func:`configure` once, before any
-backend initializes. Kernel code asks :func:`on_tpu` for the one
+Entry points (``python -m ratelimiter_tpu.serving``, the tools) call
+:func:`configure` once, before any backend initializes. Kernel code asks :func:`on_tpu` for the one
 platform test the repo has. Importing this module does not import JAX.
 """
 

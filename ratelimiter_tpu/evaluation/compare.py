@@ -193,8 +193,7 @@ class ShadowComparator:
         # but pure dict arithmetic: no device dispatch, no XLA compile,
         # no slot capacity, and only microseconds of GIL per audited
         # batch — which is what lets the ONLINE auditor shadow a serving
-        # process without stealing its throughput (ADR-016 §3; the
-        # measured A/B in the bench's live_accuracy block guards this).
+        # process without stealing its throughput (ADR-016 §3).
         # Windowed algorithms take a further inlined u64-keyed fast path
         # (_oracle_fast — the ExactLimiter recurrence without string
         # keys, per-call locks, or Result objects; fuzz-pinned identical

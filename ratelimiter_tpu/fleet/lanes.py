@@ -3,9 +3,9 @@
 PR 10's forwarder proxied mis-routed rows over ONE blocking connection
 per peer, one wire round-trip per inbound frame fragment, drained by a
 single FIFO worker. Under mixed fleet traffic that serializes every
-frame's forward leg behind every other frame's RTT — FLEET_r01 measured
-the result: 2-host mixed throughput at 0.34x affine with frame p99 13x
-affine. This module is the cross-host twin of the ADR-013 scatter-gather
+frame's forward leg behind every other frame's RTT: mixed throughput
+falls to a fraction of affine and the frame tail grows by an order of
+magnitude. This module is the cross-host twin of the ADR-013 scatter-gather
 scheduler: carve, coalesce per destination, pipeline, and reassemble by
 row-range views.
 
@@ -294,8 +294,8 @@ class _PeerConn:
                         rec.link(f.trace, wid)
             # FORWARD_FLAG (ADR-019): the receiver dispatches this
             # window standalone — its reply must never wait on the
-            # receiver's own forward legs (the cross-host dependency
-            # chain behind FLEET_r01's p99). Outermost, after the trace
+            # receiver's own forward legs (an unbounded cross-host
+            # dependency chain otherwise). Outermost, after the trace
             # extension.
             frame = p.with_forward(frame)
             rfut = self._loop.create_future()

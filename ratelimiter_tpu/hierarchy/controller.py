@@ -168,7 +168,7 @@ class AIMDController:
 
     def tick(self, now: Optional[float] = None) -> Dict[str, int]:
         """One control step; returns {scope: new effective limit} for the
-        scopes it moved (exposed for tests and the bench harness)."""
+        scopes it moved (exposed for tests)."""
         import time as _time
 
         g = self.gains

@@ -155,8 +155,8 @@ constexpr uint8_t DEADLINE_FLAG = 0x20;
 // request frames with bit 4 set are fleet forward windows — every row
 // is owned by THIS host, and the frame must never share a dispatch
 // with client frames whose resolve waits on our own forward legs
-// (coupling the two builds the unbounded cross-host dependency chain
-// behind the FLEET_r01 mixed p99). Pure hint, no body prefix; the
+// (coupling the two builds an unbounded cross-host dependency chain
+// under symmetric mixed fleet traffic). Pure hint, no body prefix; the
 // dispatcher cuts its drain at forward/non-forward boundaries.
 constexpr uint8_t FORWARD_FLAG = 0x10;
 
@@ -936,11 +936,6 @@ struct Server {
   uint32_t io_rings = 0;
   uint32_t net_engine_req = 0;
   bool uring_active = false;
-  // Bench-honesty knob (env RL_NET_COALESCE=0, never a flag): restores
-  // the pre-ISSUE-20 write-syscall profile — one sendmsg per frame and
-  // one eventfd ding per conn_send — so the conn-sweep A/B measures
-  // the coalescing win with the same binary on both sides.
-  bool net_coalesce = true;
   std::string uring_probe_err;
   std::vector<std::unique_ptr<IoRing>> rings;
   std::atomic<uint64_t> accept_ctr{0};  // round-robin pin (ring 0 only)
@@ -1242,11 +1237,8 @@ void conn_send(Server* s, const ConnPtr& c, std::string frame) {
   // exchange(false) elects ONE producer per park — the burst of
   // replies a decide batch fans out pays a single eventfd write, not
   // one per connection (the ring clears the flag itself on wake, so a
-  // false winner can't strand a later park). The no-coalesce bench
-  // baseline dings unconditionally — that is the pre-ISSUE-20
-  // one-eventfd-write-per-reply profile under test.
-  if (!s->net_coalesce ||
-      (!was_dirty && r->sleeping.exchange(false))) {
+  // false winner can't strand a later park).
+  if (!was_dirty && r->sleeping.exchange(false)) {
     r->wake_calls.fetch_add(1, std::memory_order_relaxed);
     uint64_t one = 1;
     ssize_t w = write(r->event_fd, &one, 8);
@@ -2364,12 +2356,11 @@ void flush_writes(Server* s, const ConnPtr& c) {
   // factor the rate_limiter_net_writev_frames metric proves.
   constexpr int kMaxIov = 64;
   static_assert(kMaxIov <= IOV_MAX, "iov cap must respect IOV_MAX");
-  const int max_iov = s->net_coalesce ? kMaxIov : 1;
   while (!c->wq.empty()) {
     struct iovec iov[kMaxIov];
     int cnt = 0;
     size_t total = 0;
-    for (auto it = c->wq.begin(); it != c->wq.end() && cnt < max_iov; ++it) {
+    for (auto it = c->wq.begin(); it != c->wq.end() && cnt < kMaxIov; ++it) {
       size_t off = (cnt == 0) ? c->woff : 0;
       iov[cnt].iov_base = (void*)(it->data() + off);
       iov[cnt].iov_len = it->size() - off;
@@ -3153,9 +3144,8 @@ void ring_main(Server* s, IoRing* r) {
               // buffered; skip the EAGAIN probe that would otherwise
               // end every drain cycle (halves recv syscalls at high
               // conn counts — bytes landing after this instant re-arm
-              // the level-triggered wait). The no-coalesce bench
-              // baseline keeps the probe: pre-ISSUE-20 profile.
-              if (s->net_coalesce && (size_t)rd < sizeof(buf)) break;
+              // the level-triggered wait).
+              if ((size_t)rd < sizeof(buf)) break;
             } else if (rd == 0) {
               dead = true;
               break;
@@ -3242,10 +3232,6 @@ PyObject* server_start(PyObject* self, PyObject* args) {
     s->io_rings = hc == 0 ? 1 : (hc < 4 ? hc : 4);
   }
   if (s->io_rings > 64) s->io_rings = 64;
-  {
-    const char* nc = getenv("RL_NET_COALESCE");
-    s->net_coalesce = !(nc != nullptr && nc[0] == '0');
-  }
   s->uring_active = false;
   s->uring_probe_err.clear();
   if (s->net_engine_req != 1) {

@@ -2,8 +2,8 @@
 
 The sketch backend is approximate BY DESIGN, and until now its quality bar
 (<= 1% false-positive denies vs the exact sliding-window oracle —
-BASELINE.json, ``evaluation/accuracy.py``) was measured only OFFLINE, in
-bench phase B. This module closes the loop in production (ADR-016): both
+BASELINE.json, ``evaluation/accuracy.py``) was measured only OFFLINE.
+This module closes the loop in production (ADR-016): both
 front doors mirror a deterministic hash-sampled fraction of live decisions
 into an exact shadow oracle (plus a collision-free CMS twin) running off
 the hot path, so an operator can read the LIVE false-deny / false-allow
@@ -37,10 +37,10 @@ Design rules (ADR-016):
   folding it in would let an outage launder the accuracy number.
 * **One comparison engine.** The three-way core (sketch vs
   collision-free twin vs exact oracle) is ``evaluation/compare.py`` —
-  the same code the offline bench runs, so the live estimate and the
-  phase-B ground truth are the same measurement at two vantage points
-  (``bench.py --audit`` checks they agree within the live estimate's
-  confidence interval).
+  the same code ``evaluation.evaluate_accuracy`` runs offline, so the
+  live estimate and the offline ground truth are the same measurement
+  at two vantage points (tests/test_audit.py holds the offline rate
+  inside the live estimate's confidence interval).
 """
 
 from __future__ import annotations

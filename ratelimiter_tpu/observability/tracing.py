@@ -6,8 +6,8 @@ existing ``TracingDecorator`` realizes the device half of that with
 ``jax.profiler`` annotations, but nothing could attribute ONE frame's
 latency to the pipeline stages it crossed (io → route → coalesce →
 launch → device → resolve → encode, spanning C++ threads, asyncio
-executors, and mesh slices — the MULTICHIP_r07 p99 investigation was
-done by ad-hoc printf). This module is the missing half: a
+executors, and mesh slices — an early mesh p99 investigation was done
+by ad-hoc printf). This module is the missing half: a
 flight-recorder of binary span records cheap enough to leave stamped on
 the serving hot path.
 
@@ -359,8 +359,8 @@ class FlightRecorder:
         }
 
     def stage_summary(self) -> Dict[str, dict]:
-        """{stage: {count, total_us, mean_us, p99_us}} over the rings —
-        the bench's ``--trace`` breakdown block derives from this."""
+        """{stage: {count, total_us, mean_us, p99_us}} over the rings
+        (what ``rate_limiter_stage_seconds`` exports at scrape)."""
         per: Dict[str, list] = {}
         for _, ent, _ in self._snapshot():
             keep = ent[ent["t_end"] != 0]

@@ -56,7 +56,6 @@ from ratelimiter_tpu.ops.dense_kernels import _check_gates
 from ratelimiter_tpu.ops.segment import admit
 from ratelimiter_tpu.ops.sketch_kernels import (
     _columns,
-    _pack_bits,
     join_words,
     pack_rows,
     split_staged,
@@ -289,22 +288,6 @@ def _bucket_reset(state: State, h1, h2, now_us, *,
     return out
 
 
-def _bucket_scan(state: State, h1s, h2s, ns, now0_us, dt_us, *, step_kw):
-    """T sequential bucket steps on device (lax.scan), one dispatch —
-    sketch_kernels._sketch_scan's shape for the serving/bench loops. No
-    sub-window rollover precondition: decay is part of the step itself."""
-    def body(st, xs):
-        h1, h2, n, i = xs
-        st, (allowed, _rem, _retry) = _bucket_step(
-            st, h1, h2, n, now0_us + i * dt_us, **step_kw)
-        return st, (_pack_bits(allowed), jnp.sum(~allowed).astype(jnp.int32))
-
-    T = h1s.shape[0]
-    idx = jnp.arange(T, dtype=jnp.int64)
-    state, (packed, denies) = jax.lax.scan(body, state, (h1s, h2s, ns, idx))
-    return state, packed, denies
-
-
 #: Rows of the debt sketch's packed result: allowed, remaining, and
 #: ``retry_us`` as its low and high 32-bit words.
 BUCKET_ROWS = 4
@@ -385,12 +368,4 @@ def build_hashed_step(cfg: Config, *, premix: bool = False) -> Callable:
     return memoized(_BUILT, kw, ("step", seed, premix), lambda: jax.jit(
         named("bucket_step", _bucket_step_staged, seed=seed, premix=premix,
               **kw),
-        donate_argnums=(0,)))
-
-
-def build_scan(cfg: Config) -> Callable:
-    """Jitted multi-step runner, one dispatch for T batches (bench shape)."""
-    kw = step_statics(cfg)
-    return memoized(_BUILT, kw, ("scan",), lambda: jax.jit(
-        named("bucket_scan", _bucket_scan, step_kw=kw),
         donate_argnums=(0,)))

@@ -705,42 +705,3 @@ def build_rewrite(algorithm: Algorithm, update: Callable) -> Callable:
     return memoized(_BUILT, kw, ("rewrite",), lambda: jax.jit(
         named(f"dense_{update.__name__}", _dense_rewrite, **kw),
         donate_argnums=(0,)))
-
-
-def _dense_scan(state: State, sids, ns, now0_us, dt_us, *, fn):
-    """T sequential dense steps on device (lax.scan), one dispatch —
-    sketch_kernels._sketch_scan's shape for slot-addressed state. The
-    leading axis of sids/ns is time; timestamps advance dt_us per step.
-    Slot assignment (the host half of the dense backend) happens before
-    this: sids are already resolved slot ids."""
-    from ratelimiter_tpu.ops.sketch_kernels import _pack_bits
-
-    def body(st, xs):
-        sid, n, i = xs
-        st, (allowed, *_rest) = fn(st, sid, n, now0_us + i * dt_us)
-        return st, (_pack_bits(allowed), jnp.sum(~allowed).astype(jnp.int32))
-
-    T = sids.shape[0]
-    idx = jnp.arange(T, dtype=jnp.int64)
-    state, (packed, denies) = jax.lax.scan(body, state, (sids, ns, idx))
-    return state, packed, denies
-
-
-_SCAN_CACHE: Dict[tuple, Callable] = {}
-
-
-def build_scan(cfg: Config) -> Callable:
-    """Jitted multi-step runner: ``scan(state, sids, ns, now0_us, dt_us)
-    -> (state, packed_masks, deny_counts)``. One device dispatch for T
-    batches — the amortized shape benchmarks use to see device time
-    instead of per-dispatch host round-trips. Default policy only (the
-    bench path; policy-bearing traffic goes through build_step)."""
-    ensure_x64()
-    W, _, _ = _check_gates(cfg)
-    key = (cfg.algorithm, cfg.limit, W, cfg.max_batch_admission_iters)
-    cached = _SCAN_CACHE.get(key)
-    if cached is not None:
-        return cached
-    scan = jax.jit(partial(_dense_scan, fn=_step_fn(cfg)), donate_argnums=(0,))
-    _SCAN_CACHE[key] = scan
-    return scan

@@ -10,10 +10,9 @@ Two paths:
 * strings  -> ratelimiter_tpu.native bulk hasher (word-at-a-time
   multiply-rotate, C++ kernel with a bit-identical vectorized NumPy twin):
   stable across processes/restarts, so checkpointed sketches stay
-  addressable. Benched >= 10M keys/s including packing (tests/test_hashing
-  has the cross-checks; benchmarks/ the numbers).
+  addressable (tests/test_hashing has the cross-checks).
 * uint64 ids -> splitmix64 finalizer, fully vectorized in NumPy — the fast
-  path used by benchmarks and id-keyed tenants.
+  path of the hashed wire lane and id-keyed tenants.
 """
 
 from __future__ import annotations

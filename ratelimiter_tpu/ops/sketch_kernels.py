@@ -206,22 +206,13 @@ def _columns(h1, h2, d: int, w: int):
 
 
 def _boundary_weight(state: State, p, now_us, *, sub_us: int, SW: int,
-                     S: int, weighted: bool, pre=None):
+                     S: int, weighted: bool):
     """(frac, boundary) for the sliding-window boundary sub-window: the
     rollover-boundary check (is the slab at slot p % S the period p-SW
-    slab?) and its remaining-overlap weight. ``pre`` short-circuits with
-    scan-hoisted values (see _sketch_scan); fixed-window mode returns
+    slab?) and its remaining-overlap weight; fixed-window mode returns
     (0.0, None)."""
     if not weighted:
         return jnp.float32(0.0), None
-    if pre is not None:
-        # Scan path: (frac, boundary) precomputed OUTSIDE the loop
-        # body. Scalars derived from the loop carry defeat XLA's
-        # invariant hoisting, making the dynamic ring slice + dense
-        # combine re-run per iteration (measured 2 us -> 500+ us per
-        # step); the chunk precondition (one sub-window per chunk)
-        # makes the hoist exact. See _sketch_scan.
-        return pre
     # Ring size S == SW, so the boundary period p-SW lives at
     # slot p % S (the very slot the next rollover overwrites).
     b_idx = (p % S).astype(jnp.int32)
@@ -237,7 +228,7 @@ def _boundary_weight(state: State, p, now_us, *, sub_us: int, SW: int,
 
 
 def _estimate(state: State, cols, p, now_us, *, sub_us: int, SW: int, S: int,
-              weighted: bool = True, pre=None, runs=None):
+              weighted: bool = True, runs=None):
     """Min-over-rows window estimate at the given (B, d) columns, via
     sort-merge reads (ops/sortmerge.py — no gathers on the hot path).
     ``weighted`` adds the boundary sub-window scaled by its remaining
@@ -254,8 +245,7 @@ def _estimate(state: State, cols, p, now_us, *, sub_us: int, SW: int, S: int,
     d, w = state["totals"].shape
     if runs is not None:
         frac, boundary = _boundary_weight(state, p, now_us, sub_us=sub_us,
-                                          SW=SW, S=S, weighted=weighted,
-                                          pre=pre)
+                                          SW=SW, S=S, weighted=weighted)
         rows = tuple(state["totals"][r] for r in range(d))
         if weighted:
             # The dense pre-combination of the per-row branch below, a
@@ -267,7 +257,7 @@ def _estimate(state: State, cols, p, now_us, *, sub_us: int, SW: int, S: int,
     B = cols.shape[0]
     if weighted:
         frac, boundary = _boundary_weight(state, p, now_us, sub_us=sub_us,
-                                          SW=SW, S=S, weighted=True, pre=pre)
+                                          SW=SW, S=S, weighted=True)
         if not _use_sortmerge(B, w):
             # Direct-indexing regime: pre-combine the two tables DENSELY
             # (frac is a scalar) and gather once per row. Numerically
@@ -417,8 +407,7 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
                  limit: int, sub_us: int, SW: int, S: int, d: int, w: int,
                  iters: int, weighted: bool, conservative: bool,
                  hh: int = 0, hh_thresh: float = 0.0, tenants: int = 0,
-                 axis_name: str | None = None, pre=None, pre_hh=None,
-                 counted: bool = False):
+                 axis_name: str | None = None, counted: bool = False):
     """One batch against the windowed sketch: ``(state, (allowed,
     remaining, est))`` in batch order. ``counted`` adds a third element,
     the table-access runs of this batch (int32 scalar) on the programs
@@ -447,8 +436,7 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
         cols = None if runs is not None else _columns(h1, h2, d, w)  # (B, d)
         est, frac, boundary = _estimate(state, cols, p, now_us,
                                         sub_us=sub_us, SW=SW, S=S,
-                                        weighted=weighted, pre=pre,
-                                        runs=runs)
+                                        weighted=weighted, runs=runs)
         if in_batch_order:
             _, est, rank = jax.lax.sort(
                 (runs.orig, est, jax.lax.iota(jnp.int32, est.shape[0])),
@@ -475,8 +463,7 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
         mine = owner == h1
         est_hh = state["hh_totals"][sid_hh].astype(jnp.float32)
         if weighted:
-            hh_b = pre_hh if pre_hh is not None else _hh_boundary_slab(
-                state, p, SW=SW, S=S)
+            hh_b = _hh_boundary_slab(state, p, SW=SW, S=S)
             est_hh = est_hh + frac * hh_b[sid_hh].astype(jnp.float32)
         est = est + jnp.where(mine, jnp.maximum(est_hh, 0.0), 0.0)
     else:
@@ -613,8 +600,8 @@ def _sketch_step(state: State, h1, h2, n, now_us, policy=None, hier=None, *,
                               "tn_totals": state["tn_totals"] + th})
         else:
             # Hierarchy-shaped state on a path that did not receive the
-            # table operand (reset-adjacent internal calls, the scan
-            # bench path): counters carry through untouched.
+            # table operand (reset-adjacent internal calls): counters
+            # carry through untouched.
             new_state.update({k: state[k] for k in
                               ("tn_cur", "tn_slabs", "tn_totals")})
 
@@ -830,81 +817,13 @@ def unpack_window(rows: np.ndarray, b: int, now_us: int, window_us: int):
             np.full(b, reset_us / 1e6))
 
 
-def _pack_bits(mask):
-    """(B,) bool -> (B/8,) uint8 little-endian bit packing, on device. Keeps
-    per-decision results 1 bit wide so bulk readback is bandwidth-cheap."""
-    b = mask.reshape(-1, 8).astype(jnp.uint8)
-    weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))[None, :]
-    return (b * weights).sum(axis=1).astype(jnp.uint8)
-
-
-def _sketch_scan(state: State, h1s, h2s, ns, now0_us, dt_us, *, step_kw):
-    """Run T sequential sketch steps entirely on device (lax.scan), one
-    dispatch total. Timestamps advance dt_us per step. Returns packed allow
-    bitmasks (T, B/8) and the per-step deny counts — the shape the
-    micro-batching server and the throughput bench both consume
-    (SURVEY.md §7.4 hard part #4: amortize host/device boundary costs).
-
-    Precondition (host-enforced, same as the single step): the whole chunk
-    [now0, now0 + T*dt] lies within the current sub-window period — chunks
-    span tens of ms, sub-windows are ~1 s; callers split chunks at period
-    boundaries and dispatch the rollover kernel between them.
-
-    That precondition also makes the boundary slab and its validity
-    loop-invariant, so they are computed HERE, outside the scan body,
-    with only the per-step boundary weight riding the xs. This matters
-    enormously: scalars derived from the loop carry defeat XLA's
-    invariant hoisting and force the 64 MB dynamic ring slice + dense
-    combine to re-run every iteration (measured ~500 us/step at the
-    config-3 serving shape; hoisted: single-digit us)."""
-    T = h1s.shape[0]
-    weighted = step_kw.get("weighted", True)
-    sub_us = step_kw["sub_us"]
-    S, SW = step_kw["S"], step_kw["SW"]
-    hh = step_kw.get("hh", 0)
-
-    if weighted:
-        p = state["last_period"]
-        b_idx = (p % S).astype(jnp.int32)
-        boundary_valid = state["slab_period"][b_idx] == p - SW
-        boundary = jax.lax.dynamic_index_in_dim(state["slabs"], b_idx,
-                                                keepdims=False)
-        ts = now0_us + jnp.arange(T, dtype=jnp.int64) * dt_us
-        ts = jnp.maximum(ts, p * sub_us)  # same skew clamp as the step
-        elapsed = (ts - p * sub_us).astype(jnp.float32)
-        fracs = jnp.where(boundary_valid,
-                          jnp.clip(1.0 - elapsed / jnp.float32(sub_us),
-                                   0.0, 1.0),
-                          0.0)
-        # Same hoist for the side table's boundary column (loop-invariant
-        # under the one-sub-window-per-chunk precondition).
-        hh_b = (_hh_boundary_slab(state, p, SW=SW, S=S) if hh else None)
-    else:
-        boundary = None
-        fracs = jnp.zeros((T,), jnp.float32)
-        hh_b = None
-
-    def body(st, xs):
-        h1, h2, n, i, frac_t = xs
-        pre = (frac_t, boundary) if weighted else None
-        st, (allowed, _rem, _est) = _sketch_step(
-            st, h1, h2, n, now0_us + i * dt_us, pre=pre, pre_hh=hh_b,
-            **step_kw)
-        return st, (_pack_bits(allowed), jnp.sum(~allowed).astype(jnp.int32))
-
-    idx = jnp.arange(T, dtype=jnp.int64)
-    state, (packed, denies) = jax.lax.scan(
-        body, state, (h1s, h2s, ns, idx, fracs))
-    return state, packed, denies
-
-
 # ------------------------------------------------- the step's contract
 #
 # "Which compiled program decides a batch under this config" is answered
 # here and nowhere else. ``step_statics`` is the ONE derivation from a
 # Config to the static keyword arguments of ``_sketch_step`` (its twin:
 # bucket_kernels.step_statics); every builder of a program around that
-# body — build_hashed_step, build_controls and build_scan below, the
+# body — build_hashed_step and build_controls below, the
 # replicated mesh's (parallel/mesh_kernels.py) and the collective
 # router's (ops/route_kernels.py) — takes its statics from it and keys
 # its memo on that mapping's items (ops.memoized) plus what the builder
@@ -1109,13 +1028,3 @@ def build_migrate(old_cfg: Config, new_cfg: Config) -> Callable:
     return jax.jit(
         named("sketch_migrate", _migrate_window, sub_o=sub_o, SWo=SWo,
               So=So, sub_n=sub_n, SWn=SWn, Sn=Sn, hh=hh))
-
-
-def build_scan(cfg: Config) -> Callable:
-    """Jitted multi-step runner: ``scan(state, h1s, h2s, ns, now0_us, dt_us)
-    -> (state, packed_masks, deny_counts)`` where the leading axis of
-    h1s/h2s/ns is time. One device dispatch for T batches."""
-    kw = step_statics(cfg)
-    return memoized(_BUILT, kw, ("scan",), lambda: jax.jit(
-        named("sketch_scan", _sketch_scan, step_kw=kw),
-        donate_argnums=(0,)))

@@ -589,7 +589,7 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
     # -------------------------------------------------------------- stats
 
     def router_stats(self) -> dict:
-        """Collective-path bookkeeping for /v1/health and the bench."""
+        """Collective-path bookkeeping for /v1/health and /metrics."""
         return {"mode": "collective", "dispatches": self.dispatches,
                 "placements": self.placements,
                 "fallbacks": self.fallbacks,

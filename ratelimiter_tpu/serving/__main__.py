@@ -116,7 +116,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "kill-during-handoff, rejoin-storm). Requires "
                          "--quarantine for the slice scenarios; the "
                          "handoff/rejoin scenarios need --fleet-config. "
-                         "Test/bench lever — never set in production")
+                         "Test lever — never set in production")
     ap.add_argument("--chaos-slice", type=int, default=0,
                     help="victim slice index for slice scenarios")
     ap.add_argument("--chaos-after", type=float, default=0.0,
@@ -366,8 +366,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "jitted shadow dispatch per audited frame "
                          "(measured ~15-20%% of a CPU box's serving "
                          "throughput — ADR-016 §3), so it is off by "
-                         "default; the offline bench always runs the "
-                         "split (accuracy_three_way)")
+                         "default; evaluation.evaluate_accuracy always "
+                         "runs the split offline")
     ap.add_argument("--log-redact-keys", action="store_true",
                     help="with --log-decisions: log splitmix64 hashes "
                          "instead of raw keys (the PII trust boundary, "

@@ -109,9 +109,8 @@ class MicroBatcher:
         #: hold only locally-owned rows, so merging them with each
         #: other is safe batching — but merging them into a window
         #: that also holds client rows needing onward forwarding would
-        #: couple the forward reply to OUR peers' progress (the
-        #: unbounded cross-host dependency chain behind FLEET_r01's
-        #: mixed p99).
+        #: couple the forward reply to OUR peers' progress (an
+        #: unbounded cross-host dependency chain under mixed traffic).
         self._pending_fwd: List[tuple] = []
         self._pending_fwd_ids = 0
         #: Flight-recorder window context (ADR-014): first-enqueue stamp
@@ -440,8 +439,8 @@ class MicroBatcher:
             # legs. Coalescing the two would couple this reply to a
             # peer's progress — under symmetric mixed fleet traffic
             # that dependency chain extends without bound (each reply
-            # waiting on legs of a window formed later: the FLEET_r01
-            # 1.35 s p99 and the 4-host forward-deadline expiry).
+            # waiting on legs of a window formed later: second-long
+            # tails and the 4-host forward-deadline expiry).
             # Forward windows therefore coalesce in their OWN buffer —
             # with each other (windows from 3 peers merge into one
             # dispatch at n >= 4, where per-peer windows shrink to

@@ -1032,10 +1032,9 @@ class AsyncClient:
 # ====================================================================
 #
 # Client-side consistent-hash routing: the shard-affine loadgen mode
-# (benchmarks/e2e.py spread knob, ADR-013) promoted to first-class
-# client behavior. Every allow_batch / allow_hashed frame partitions by
-# keyspace owner (the SAME splitmix64 / h64 % buckets rule the servers
-# and mesh slices apply), fans out over per-host pooled connections
+# (ADR-013) promoted to first-class client behavior. Every allow_batch /
+# allow_hashed frame partitions by keyspace owner (the SAME splitmix64 /
+# h64 % buckets rule the servers and mesh slices apply), fans out over per-host pooled connections
 # with the PR 8 retry/deadline machinery, and reassembles per-frame
 # answers in request order. Affine routing means a frame's rows arrive
 # at servers that own them — the zero-forwarding fast path; a stale map
@@ -1388,7 +1387,7 @@ class FleetClient:
 class AsyncFleetClient:
     """Pipelined fleet client: one :class:`AsyncClient` per member,
     frames partitioned by owner and fanned out with ``asyncio.gather``
-    — the loadgen-grade surface (benchmarks/fleet.py drives it)."""
+    — the loadgen-grade surface."""
 
     def __init__(self):
         self.map = None

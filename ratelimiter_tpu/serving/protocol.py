@@ -225,8 +225,8 @@ _REQ_FLAGS = TRACE_FLAG | DEADLINE_FLAG
 # rows needing onward forwarding. Coalescing the two couples this
 # reply to the receiver's own forward legs, and under symmetric mixed
 # fleet traffic that dependency chain extends without bound (each
-# reply waits on legs of a window formed later — the FLEET_r01 1.35 s
-# p99, and outright forward-deadline expiry at 4 hosts). Misuse by an
+# reply waits on legs of a window formed later — second-long tails,
+# and outright forward-deadline expiry at 4 hosts). Misuse by an
 # ordinary client is harmless: the hint only steers batching. Applied
 # OUTERMOST (after with_deadline / before nothing): with_forward sets
 # only the bit.

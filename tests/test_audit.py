@@ -23,7 +23,8 @@ Covers, per ISSUE 9:
 * GET /debug/audit trust boundary and the combined /healthz envelope
   with mesh + quarantine + audit all enabled (the composition no test
   exercised before);
-* the bench's live_accuracy smoke (agreement machinery runs tiny).
+* the live estimate against ``evaluation.evaluate_accuracy`` on one
+  stream (the offline rate inside the live Wilson interval).
 """
 
 from __future__ import annotations
@@ -1042,23 +1043,64 @@ class TestHealthzComposition:
                 proc.kill()
 
 
-# ----------------------------------------------------------- bench smoke
+# ------------------------------------ live audit == offline evaluation
 
 
-class TestBenchSmoke:
-    def test_live_accuracy_block(self):
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        from bench import measure_live_accuracy
+class TestLiveAgreesWithOffline:
+    def test_offline_rate_inside_the_live_wilson_interval(self):
+        """The stream ``evaluation.evaluate_accuracy`` decides offline,
+        driven through a real door with the auditor sampling one key in
+        four: the population false-deny rate the offline run measures
+        lies inside the live estimate's 95 % Wilson interval, the live
+        tally is a strict sample of the stream, and once the seam is
+        off the door taps nothing more."""
+        from ratelimiter_tpu.evaluation import (
+            evaluate_accuracy,
+            zipf_key_ids,
+        )
 
-        out = measure_live_accuracy(
-            n_keys=800, n_requests=3000, batch=512, sample=4,
-            width=1 << 9, sub_windows=12, measure_overhead=False,
-            twin_width=1 << 14)
-        assert out["door_decisions_match_offline"] is True
-        assert out["agreement_within_wilson95"] is True
-        assert out["live"]["samples"] > 0
-        lo, hi = out["live"]["false_deny_wilson95"]
-        assert 0.0 <= lo <= hi <= 1.0
-        # The module seam is clean afterwards (bench disables it).
+        n_keys, n_requests, batch, rate = 800, 3000, 512, 50_000.0
+        sketch = SketchParams(depth=1, width=1 << 7, sub_windows=12)
+        off = evaluate_accuracy(
+            n_keys=n_keys, n_requests=n_requests, batch=batch, limit=20,
+            request_rate=rate, sketch=sketch, seed=0, include_twin=False)
+        # One narrow row collides: there is a rate to agree about.
+        assert off.requests == n_requests
+        assert 0 < off.false_denies_vs_oracle < off.oracle_allows
+
+        # What evaluate_accuracy builds, field for field.
+        cfg = Config(algorithm=Algorithm.TPU_SKETCH, sketch=sketch,
+                     limit=20, window=60.0, key_prefix="")
+        ids = zipf_key_ids(n_keys, n_requests, 1.1, 0)
+
+        async def run():
+            clock = ManualClock(T0)
+            lim = create_limiter(cfg, backend="sketch", clock=clock)
+            srv = RateLimitServer(lim, max_batch=batch, max_delay=100e-6)
+            await srv.start()
+            auditor = audit.enable(cfg, sample=4, n_slices=1,
+                                   include_twin=False)
+            c = await AsyncClient.connect(srv.host, srv.port)
+            for start in range(0, n_requests, batch):
+                clock.set(T0 + start / rate)
+                await c.allow_hashed(ids[start:start + batch])
+            assert auditor.flush(timeout=30)
+            st = auditor.status()
+            audit.disable()
+            # Off: the same door decides on, and no tap reaches the
+            # (closed) auditor — its books stand where they stood.
+            await c.allow_hashed(ids[:batch])
+            late = auditor.status()
+            await c.close()
+            await srv.shutdown()
+            lim.close()
+            return st, late
+
+        st, late = asyncio.run(run())
         assert audit.AUDITOR is None
+        assert 0 < st["samples"] < n_requests
+        assert st["dropped_frames"] == 0 and st["oracle_errors"] == 0
+        lo, hi = st["false_deny_wilson95"]
+        assert 0.0 <= lo <= off.false_deny_rate <= hi <= 1.0, (st, off)
+        assert (late["samples"], late["audited_frames"]) == (
+            st["samples"], st["audited_frames"])

@@ -179,15 +179,20 @@ def test_no_compile_after_prewarm_for_any_run_length(max_batch):
     lone oversized frame's (<= 2 x max_batch) adds a program."""
     from ratelimiter_tpu.serving.__main__ import _prewarm
 
-    # A width of its own: the compiled steps are shared by every limiter
-    # of one geometry in the process, and these counts are absolute.
+    # The compiled steps are shared by every limiter of one geometry in
+    # the process (ops.memoized), so a count is taken as a difference:
+    # what _prewarm ADDS is at most the pad shapes it owes (all of them on
+    # a geometry nothing built before, as with this width when the file
+    # runs alone), and what is THERE afterwards is at least those.
     lim = _limiter(width=2 * max_batch)
     try:
-        _prewarm(lim, max_batch)
         steps = (lim._step, lim._get_ids_step())
+        before = [s._cache_size() for s in steps]
+        _prewarm(lim, max_batch)
         compiled = [s._cache_size() for s in steps]
         shapes = max_batch.bit_length() + 1 - 3       # 8 ... 2 x max_batch
-        assert compiled == [shapes, shapes]
+        for had, has in zip(before, compiled):
+            assert shapes <= has <= had + shapes, (before, compiled)
         edges = {1, 2 * max_batch}
         size = 8
         while size <= 2 * max_batch:

@@ -577,25 +577,78 @@ class TestMeshDoors:
         assert "--mesh-devices needs --backend mesh" in proc.stderr
 
 
-# -------------------------------------------------------- scaling smoke
+# ------------------------------------------ a frame reaches its slices
 
 
-class TestScalingSmoke:
-    def test_throughput_scales_with_devices(self):
-        """Loose-ratio scaling smoke (the full curve is bench.py
-        --mesh-devices; this guards the mechanism, not the magnitude):
-        4 device slices driven concurrently must beat 1 on a big enough
-        box, and must NEVER collapse below it anywhere."""
-        sys.path.insert(0, REPO)
-        from bench import measure_mesh_step_rate
+class TestFrameReachesEverySliceOnce:
+    """What scaling across devices rests on, in counts: a mixed frame is
+    cut by ``splitmix64(id) % n`` into one part a slice that owns a row,
+    each part is ONE launch on that slice (and none on a slice that owns
+    nothing), and every row is decided exactly once."""
 
-        kw = dict(seconds=0.8, batch=4096, window=2,
-                  depth=2, width=1 << 12, sub_windows=6)
-        r1 = measure_mesh_step_rate(1, **kw)
-        r4 = measure_mesh_step_rate(4, **kw)
-        if (os.cpu_count() or 1) >= 8:
-            assert r4 >= 1.3 * r1, (r1, r4)
-        else:
-            # Tiny CI boxes cannot parallelize 4 devices; only guard
-            # against collapse.
-            assert r4 >= 0.7 * r1, (r1, r4)
+    N = 4
+
+    def _frames(self):
+        from ratelimiter_tpu.ops.hashing import splitmix64
+
+        def owners(a):
+            return (splitmix64(a) % np.uint64(self.N)).astype(np.int64)
+
+        ids = np.arange(1, 1025, dtype=np.uint64)
+        # A mixed frame, then one whose rows two of the slices own.
+        for frame in (ids, ids[np.isin(owners(ids), (1, 3))][:300]):
+            yield frame, np.bincount(owners(frame), minlength=self.N)
+
+    def test_the_python_router_launches_once_a_slice_that_owns_a_row(self):
+        mesh = SlicedMeshLimiter(_cfg(limit=5), ManualClock(T0),
+                                 n_devices=self.N)
+        try:
+            for frame, split in self._frames():
+                before = [s.result_fetches for s in mesh.slices]
+                ticket = mesh.launch_ids(frame)
+                assert [s for s, _, _ in ticket.subs] \
+                    == [s for s in range(self.N) if split[s]]
+                owners = mesh.owner_of_id(frame)
+                for s, pos, sub in ticket.subs:
+                    np.testing.assert_array_equal(
+                        pos, np.flatnonzero(owners == s))
+                    assert sub.outs.devices() \
+                        == {mesh.slices[s]._device}
+                out = mesh.resolve(ticket)
+                assert out.allowed.shape == frame.shape
+                launched = [s.result_fetches - b
+                            for s, b in zip(mesh.slices, before)]
+                assert launched == [int(n > 0) for n in split]
+        finally:
+            mesh.close()
+
+    def test_the_native_doors_router_does_the_same_split(self):
+        from ratelimiter_tpu.serving.client import Client
+        from ratelimiter_tpu.serving.native_server import (
+            NativeRateLimitServer,
+            native_server_available,
+        )
+        if not native_server_available():
+            pytest.skip("no compiler for the native front door")
+
+        slices = build_slices(_cfg(limit=5), n_devices=self.N)
+        srv = NativeRateLimitServer(slices[0], shards=self.N,
+                                    shard_limiters=slices, max_delay=1e-4)
+        srv.start()
+        try:
+            with Client(port=srv.port, timeout=60.0) as c:
+                for frame, split in self._frames():
+                    before = srv.stats()["shard_decisions"]
+                    fetched = [s.result_fetches for s in slices]
+                    out = c.allow_hashed(frame)
+                    assert len(out) == len(frame)
+                    after = srv.stats()["shard_decisions"]
+                    assert [a - b for a, b in zip(after, before)] \
+                        == split.tolist()
+                    assert [s.result_fetches - f
+                            for s, f in zip(slices, fetched)] \
+                        == [int(n > 0) for n in split]
+        finally:
+            srv.shutdown(close_limiters=False)
+            for s in slices:
+                s.close()

@@ -276,10 +276,15 @@ def test_no_compile_after_the_default_doors_prewarm_at_any_pad():
     lim = _sketch(width=1 << 16)         # a geometry of this test's own
     try:
         _, drain_rows = batch_rule(None)
-        _prewarm(lim, drain_rows)
         steps = (lim._step, lim._get_ids_step())
+        before = [s._cache_size() for s in steps]
+        _prewarm(lim, drain_rows)
         compiled = [s._cache_size() for s in steps]
-        assert compiled == [13, 13]                       # 2^3 ... 2^15
+        # 2^3 ... 2^15: thirteen shapes there, at most thirteen added
+        # (the process memoises a geometry's steps: a difference, never
+        # a total).
+        for had, has in zip(before, compiled):
+            assert 13 <= has <= had + 13, (before, compiled)
         for b in (1, 4096, 4097, 6550, 8192, 8193, 12288, 16384, 16385,
                   32768):
             ids = np.arange(b, dtype=np.uint64) + np.uint64(1 << 40)

@@ -1,8 +1,8 @@
 """Cross-slice scatter-gather scheduler tests (ISSUE-6 acceptance, ADR-013).
 
 Mixed frames — frames whose keys span several device slices — used to
-fork-join across every device queue and collapsed 16x under load
-(MULTICHIP_r06). The scheduler fixes that with (1) ragged per-device
+fork-join across every device queue and collapsed under load. The
+scheduler fixes that with (1) ragged per-device
 sub-framing with ONE completion barrier per frame, (2) cross-slice
 launch coalescing (many clients' frames merge into one padded dispatch
 per device per batching window, never overshooting the largest

@@ -81,18 +81,13 @@ def _routed(rule, cfg):
                                            capacity=8)
 
 
-def _scan(rule, cfg):
-    return KERNELS[rule].build_scan(cfg)
-
-
-#: name -> (build(rule, cfg), binds the hash seed)
+#: name -> build(rule, cfg); every one binds the hash seed.
 BUILDERS = {
-    "step-hashed": (partial(_serving, False), True),
-    "step-premix": (partial(_serving, True), True),
-    "mesh-gather": (partial(_meshed, "gather"), True),
-    "mesh-delta": (partial(_meshed, "delta"), True),
-    "routed": (_routed, True),
-    "scan": (_scan, False),
+    "step-hashed": partial(_serving, False),
+    "step-premix": partial(_serving, True),
+    "mesh-gather": partial(_meshed, "gather"),
+    "mesh-delta": partial(_meshed, "delta"),
+    "routed": _routed,
 }
 
 #: field -> the one-field change. Every one is read by the window rule's
@@ -113,9 +108,7 @@ CHANGES = {
 WINDOW_ONLY = ("sub_windows", "conservative_update", "hh_slots")
 
 
-def _reads(rule: str, builder: str, field: str) -> bool:
-    if field == "seed":
-        return BUILDERS[builder][1]
+def _reads(rule: str, field: str) -> bool:
     return rule == "window" or field not in WINDOW_ONLY
 
 
@@ -129,7 +122,7 @@ CASES = [pytest.param(rule, name,
 
 @pytest.mark.parametrize("rule, builder", CASES)
 def test_what_no_step_reads_recompiles_nothing(rule, builder):
-    build = partial(BUILDERS[builder][0], rule)
+    build = partial(BUILDERS[builder], rule)
     first = build(_cfg(rule))
     assert build(_cfg(rule)) is first
     for other in (
@@ -140,16 +133,16 @@ def test_what_no_step_reads_recompiles_nothing(rule, builder):
             dict(key_prefix="another")):
         assert build(_cfg(rule, **other)) is first, other
     for field, change in CHANGES.items():
-        if not _reads(rule, builder, field):
+        if not _reads(rule, field):
             assert build(_cfg(rule, **change)) is first, field
 
 
 @pytest.mark.parametrize("rule, builder, field", [
     pytest.param(*case.values, field, marks=case.marks)
     for case in CASES for field in CHANGES
-    if _reads(*case.values, field)])
+    if _reads(case.values[0], field)])
 def test_a_field_the_step_reads_gives_another_program(rule, builder, field):
-    build = partial(BUILDERS[builder][0], rule)
+    build = partial(BUILDERS[builder], rule)
     first = build(_cfg(rule))
     changed = build(_cfg(rule, **CHANGES[field]))
     assert changed is not first

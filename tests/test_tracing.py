@@ -1081,10 +1081,10 @@ class TestZeroOverhead:
         within 3% of OFF on the standard bench). The claim guarded here
         is structural — spans are stamped per *dispatch*, never per
         decision, at clock-read cost — so the CI margin is loose (1.5x)
-        to absorb shared-runner scheduler noise; the tight 3% A/B is a
-        bench measurement (``bench.py`` with/without ``--trace``,
-        recorded in ADR-014). Off and on rounds alternate on one warm
-        limiter and their MEDIANS are compared: a stall of the box under
+        to absorb shared-runner scheduler noise; the tight A/B is a
+        chip measurement (PERF.md §6, PR 25 and PR 37). Off and on
+        rounds alternate on one warm limiter and their MEDIANS are
+        compared: a stall of the box under
         six test workers lands in one round of either side, not in a
         whole side as it did when off ran to its end before on began."""
         import time as _time
@@ -1478,56 +1478,133 @@ class TestDebugEndpoints:
             lim.close()
 
 
-# ----------------------------------------------------- bench integration
+# ------------------------------------- what a served dispatch leaves behind
+
+#: The stage set of one dispatch of the native door, as PERF.md §3 names
+#: it: the completer's ring holds the door's own rows (io, dispatch,
+#: device, complete), the callback's tiling (enter, descend, ascend,
+#: leave) and the resolve's (fetch, unpack); the dispatcher's ring the
+#: lane's launch (prep, place, step, finish) and, on the string lane,
+#: hash.
+_SERVED_STAGES = {
+    "hashed": {"io", "dispatch", "device", "complete", "enter", "descend",
+               "ascend", "leave", "fetch", "unpack", "prep", "place",
+               "step", "finish"},
+}
+_SERVED_STAGES["string"] = _SERVED_STAGES["hashed"] | {"hash"}
 
 
-class TestBenchTrace:
-    def test_loadgen_trace_sampling(self):
-        """The e2e loadgen's trace_sample knob (`python -m benchmarks
-        --only e2e --trace-sample N`): sampled frames carry wire trace
-        ids and land client spans in the local recorder. The server is
-        IN-PROCESS here, so its spans share the loadgen's rings — size
-        the ring past the scalar-latency pass's span volume or the
-        early client spans wrap away (in the real subprocess loadgen
-        the client process records only its own spans)."""
-        from benchmarks.e2e import _drive
+@pytest.mark.skipif(not native_server_available(),
+                    reason="needs g++ for the native server")
+class TestServedDispatchStageSet:
+    @pytest.mark.parametrize("lane", ["hashed", "string"])
+    def test_the_rings_hold_every_named_stage_and_nothing_else(
+            self, recorder, lane):
+        """Frames through the native door with the recorder on: every
+        stage a per-layer reader looks for is in the rings, once a
+        dispatch, and no row carries a name no reader knows (a renamed
+        or added span would read as zero in the benchmark's breakdown
+        without failing anything there)."""
+        n = 12
+        with _native_door("windowed") as (srv, c):
+            for i in range(n):
+                _frame(c, lane, i, trace_id=i + 1)
+            assert srv.stats()["stage_ns"]["batches"] == n
+        rows = {}
+        for s in recorder.dump():
+            rows.setdefault(s["stage"], []).append(s["trace_id"])
+        assert set(rows) == _SERVED_STAGES[lane]
+        for stage, tids in rows.items():
+            assert sorted(tids) == list(range(1, n + 1)), stage
 
-        tracing.disable()
-        rec = tracing.enable(1 << 14)
-        lim = create_limiter(_sketch_cfg(), backend="sketch")
+
+# ---------------------------------------- the client's trace sampling
+
+
+class _Door:
+    """Either front door over one sketch limiter, with a synchronous
+    ``allow_hashed(ids, trace_id)`` whatever the door's client is."""
+
+    #: What a traced hashed frame leaves on each door (ADR-014).
+    STAGES = {"asyncio": ("client", "io", "coalesce", "launch", "device",
+                          "resolve", "encode"),
+              "native": ("client", "io", "dispatch", "device", "complete")}
+
+    def __init__(self, door: str):
+        self.door = door
+        self.lim = create_limiter(_sketch_cfg(), backend="sketch")
+        if door == "native":
+            self.srv = NativeRateLimitServer(self.lim, "127.0.0.1", 0,
+                                             max_batch=4096,
+                                             max_delay=200e-6)
+            self.srv.start()
+            self.client = Client(port=self.srv.port)
+        else:
+            self.loop = asyncio.new_event_loop()
+            self.srv = RateLimitServer(self.lim, max_batch=4096,
+                                       max_delay=200e-6)
+            self._run(self.srv.start())
+            self.client = self._run(
+                AsyncClient.connect(self.srv.host, self.srv.port))
+
+    def _run(self, coro):
+        return self.loop.run_until_complete(coro)
+
+    def allow_hashed(self, ids, trace_id):
+        if self.door == "native":
+            return self.client.allow_hashed(ids, trace_id=trace_id)
+        return self._run(self.client.allow_hashed(ids, trace_id=trace_id))
+
+    def close(self):
+        if self.door == "native":
+            self.client.close()
+            self.srv.shutdown()
+        else:
+            self._run(self.client.close())
+            self._run(self.srv.shutdown())
+            self.loop.close()
+        self.lim.close()
+
+
+class TestClientTraceSampling:
+    @pytest.mark.parametrize("door", [
+        "asyncio",
+        pytest.param("native", marks=pytest.mark.skipif(
+            not native_server_available(),
+            reason="needs g++ for the native server"))])
+    def test_one_frame_in_n_is_stamped_and_no_other(self, recorder, door):
+        """A client that gives every Nth frame a fresh wire trace id
+        (the loadgen's sampling, ADR-014): the server's rings hold a
+        whole span tree under each sampled id, that tree is the sampled
+        FRAME's (its row count), and every other frame's rows carry no
+        id at all."""
+        every, frames = 4, 16
+        served = _Door(door)
+        sampled = {}                         # trace id -> rows of its frame
         try:
-            async def run():
-                srv = RateLimitServer(lim, max_batch=256,
-                                      max_delay=200e-6)
-                await srv.start()
-                try:
-                    return await _drive(srv.port, seconds=0.3, conns=1,
-                                        window=64, n_keys=100,
-                                        warmup=0.0, trace_sample=1)
-                finally:
-                    await srv.shutdown()
-
-            out = asyncio.run(run())
-            assert out["completed"] > 0
-            clients = [s for s in rec.dump() if s["stage"] == "client"]
-            assert clients, "no sampled client spans recorded"
-            assert all(s["trace_id"] for s in clients)
+            for i in range(frames):
+                ids = np.arange(1, 9 + i, dtype=np.uint64)   # 8 + i rows
+                tid = tracing.new_trace_id() if i % every == 0 else 0
+                t0 = tracing.now()
+                out = served.allow_hashed(ids, tid)
+                assert len(out) == 8 + i
+                if tid:
+                    tracing.record("client", t0, tracing.now(),
+                                   trace_id=tid, batch=len(out))
+                    sampled[tid] = 8 + i
         finally:
-            tracing.disable()
-            lim.close()
-
-    def test_stage_breakdown_smoke(self):
-        """bench.py --trace block: tiny run, every expected stage key
-        present and the hot stages populated."""
-        import bench
-
-        tracing.disable()
-        out = bench.measure_stage_breakdown(seconds=0.3, batch=256,
-                                            width=1 << 11)
-        assert tracing.RECORDER is None      # restored the off default
-        for stage in ("io", "route", "queue", "coalesce", "launch",
-                      "device", "resolve", "encode"):
-            assert stage in out["stage_us"]
-        assert out["decisions"] > 0
-        assert out["stage_us"]["device"] > 0
-        assert out["stage_spans"]["io"] > 0
+            served.close()
+        assert len(sampled) == frames // every
+        spans = recorder.dump()
+        assert {s["trace_id"] for s in spans if s["trace_id"]} \
+            == set(sampled)
+        for tid, rows in sampled.items():
+            _assert_span_tree(spans, tid, want_stages=_Door.STAGES[door])
+            mine = [s for s in spans if s["trace_id"] == tid]
+            # One of each stage, each of this frame's size.
+            assert len(mine) == len({s["stage"] for s in mine})
+            assert {s["batch"] for s in mine} == {rows}
+        # The frames in between were served and recorded, unstamped.
+        device = [s for s in spans if s["stage"] == "device"]
+        assert sorted(s["batch"] for s in device if not s["trace_id"]) \
+            == [8 + i for i in range(frames) if i % every]

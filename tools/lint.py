@@ -22,10 +22,10 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LINT_DIRS = ("ratelimiter_tpu", "tests", "benchmarks", "tools")
-#: print() is the UI in these (CLI entry points, benches, test harness).
-PRINT_OK = {"ratelimiter_tpu/serving/__main__.py", "benchmarks",
-            "tools", "tests", "bench.py", "__graft_entry__.py"}
+LINT_DIRS = ("ratelimiter_tpu", "tests", "tools")
+#: print() is the UI in these (CLI entry points, test harness).
+PRINT_OK = {"ratelimiter_tpu/serving/__main__.py",
+            "tools", "tests", "__graft_entry__.py"}
 
 
 def _print_allowed(rel: str) -> bool:
@@ -123,8 +123,7 @@ def main() -> int:
                 continue
             targets.extend(os.path.join(dirpath, f)
                            for f in filenames if f.endswith(".py"))
-    targets.extend(os.path.join(REPO, f)
-                   for f in ("bench.py", "__graft_entry__.py"))
+    targets.append(os.path.join(REPO, "__graft_entry__.py"))
     for path in sorted(targets):
         rel = os.path.relpath(path, REPO)
         for kind, lineno, msg in lint_file(path, rel):
