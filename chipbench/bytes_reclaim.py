@@ -5,12 +5,11 @@ long — ``exact-tb-ttl``). Two counts, both the algorithm's own need from
 shapes, never what today's program moves (chipbench/bytes.py states the
 rule):
 
-``step_bytes`` / ``step_ops``  a dispatch: ``bytes_table``'s arithmetic,
-    row for row — one directory probe and one read-modify-write of each
-    int64 column of the key's row a decision, the wire columns — without
-    that model's premise that the table holds the whole population
-    (``capacity >= key_population``): here it holds the keys of the last
-    two windows, and what keeps it so is the pass below. The pass is not
+``step_bytes`` / ``step_ops``  a dispatch: ``bytes_table``'s own
+    functions (one arithmetic) — one directory probe and one
+    read-modify-write of each int64 column of the key's row a decision,
+    the wire columns. Here the table holds the keys of the last two
+    windows, and what keeps it so is the pass below. The pass is not
     shared over the dispatches: a table that expired lazily would need
     none, so it is no part of what a DECISION must move.
 
@@ -25,21 +24,12 @@ rule):
 """
 
 from chipbench import bytes_table
+from chipbench.bytes_table import step_bytes, step_ops  # noqa: F401
 
 STAMP_READ = 8
 KEY_READ = 8
 KEY_WRITE = 8
 COLUMN_WRITE = 8
-
-
-def step_bytes(cfg: dict, batch: float, dispatches_per_s: float) -> float:
-    row = bytes_table.COLUMNS[cfg["algorithm"]] * bytes_table.COLUMN_RMW
-    return batch * (bytes_table.DIRECTORY_PROBE + row
-                    + bytes_table.WIRE_IN + bytes_table.WIRE_OUT)
-
-
-def step_ops(cfg: dict, batch: float) -> float:
-    return bytes_table.step_ops(cfg, batch)
 
 
 def pass_bytes(cfg: dict, freed: float) -> float:

@@ -9,7 +9,10 @@ window's ``curr``, ``prev``, ``win_start``; the fixed window's ``count``,
 ``win_start``), the wire columns of bytes.py. Nothing sized by the table:
 the bucket refills by arithmetic on the row it touches, a probe that
 reads a whole bucket row of 128 keys reads more than it must, and the
-reclaim pass runs when the directory fills, not a dispatch."""
+reclaim pass runs when the directory fills, not a dispatch — so the count
+is the same whether the table holds the whole population or the keys of
+the last two windows (``bytes_reclaim`` adds that pass's own bytes):
+96.125 B a token-bucket decision."""
 
 from chipbench.bytes import WIRE_IN, WIRE_OUT
 
@@ -19,7 +22,6 @@ COLUMNS = {"token_bucket": 3, "sliding_window": 3, "fixed_window": 2}
 
 
 def step_bytes(cfg: dict, batch: float, dispatches_per_s: float) -> float:
-    assert cfg["capacity"] >= cfg["key_population"]
     row = COLUMNS[cfg["algorithm"]] * COLUMN_RMW
     return batch * (DIRECTORY_PROBE + row + WIRE_IN + WIRE_OUT)
 

@@ -1,7 +1,8 @@
 """What the reclaim readers share (PR 44): the dense backend's reclaim
 pass — ``jit_dense_reclaim``, entries idle for two windows given up, run
-by the launch that finds the directory over 7/8 full and waited for on
-the dispatcher's thread — as the program counts it on ``/metrics``,
+by the launch that finds the directory over the gate's share of its
+capacity (0.79875; at every launch over the line, 0.81875: since PR 48,
+one line at 7/8 before) and waited for on the dispatcher's thread — as the program counts it on ``/metrics``,
 recorder on or off: ``rate_limiter_directory_reclaim_passes_total``,
 ``…reclaimed_total`` and ``…reclaim_seconds_total`` (the ``reclaim``
 span's own stamps: what the launch waited, the steps in flight ahead of

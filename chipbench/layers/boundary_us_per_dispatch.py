@@ -7,14 +7,15 @@ How the op set is chosen: the trace's ``custom-call`` op group
 divided by the executions of the step module. On a chip without 64-bit
 vectors XLA splits every 64-bit array that enters a program into 32-bit
 halves and recombines every one that leaves it, and the v5e's trace
-names those passes ``custom-call``; on the dense step the arrays that
-matter are the state's int64 leaves (``cols``, ``dir_keys``), so the
-group is a pass over the WHOLE table a dispatch — 246 us of a 1,043 us
-step at 2^21 entries (PR 33), ~31 ms at 2^26 (ledger, PR 41). The
-staged batch's own uint64 words ride in the same group (a few us).
+names those passes ``custom-call``. Until PR 43 the dense state's
+leaves were int64 (``cols``, ``dir_keys``), so the group was a pass over
+the WHOLE table a dispatch — 246 us of a 1,043 us step at 2^21 entries
+(PR 33), ~31 ms at 2^26 (ledger, PR 41). Since PR 43 the state lives on
+the chip as the 32-bit words the chip computes on (``cols uint32[2K,
+C+1]``, ``dir_lo`` / ``dir_hi``): what is left in the group is the
+staged batch's and the override table's own uint64 words, ~4 us.
 
-Once a layout change keeps the state in 32-bit leaves the group shrinks
-to the batch's few us and may fall out of the ten groups
+At a few us the group may fall out of the ten groups
 ``trace_reduce.py`` keeps: a trace that ran the step and lists no
 ``custom-call`` group therefore reads 0.0 (the truth is under the
 tenth-largest group's time, which ``breakdown.device_ops`` shows), not

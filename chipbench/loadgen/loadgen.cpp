@@ -24,9 +24,24 @@
 //   --cost-n [1]                    the n of every decision
 //   --slices [1]                    tally decisions per owning slice (splitmix64(id) % slices)
 //   --start-at T                    CLOCK_MONOTONIC seconds at which warm-up starts [now + 0.2]
+//   --await-start 1                 the schedule and the port are GIVEN once the tables
+//                                   are built (below); excludes --port and --start-at
 //   --warmup [3] --seconds [10] --drain [2]
 //   --dump-ids N                    print N sampled "rank id" lines and exit (no network)
-// Output: one JSON object on stdout.
+// Output: one JSON object on stdout, the last line.
+//
+// The tables (alias table, permutation) take 1.6 s at 20 M keys and 7-9 s
+// at 80-100 M, so the clock starts after them. With --await-start 1 the
+// generator prints {"line": "ready", "keys", "build_s", "t_ready",
+// "peak_rss_bytes"} and reads ONE line from stdin, "<T> <port>": the
+// instant warm-up starts (CLOCK_MONOTONIC seconds) and the server's port
+// (the generator is started before the server has one). It answers
+// {"line": "schedule", "t_start", "t_window_start", "t_window_end"}:
+// whoever drives it reads the window from those words. An instant already
+// past is refused; with --start-at (a hand run) so are tables that are
+// ready only after start-at + warmup. Both exit 4, naming the keys and
+// the build's seconds: no path measures a window the generator was not
+// sending in.
 
 #include <arpa/inet.h>
 #include <fcntl.h>
@@ -34,6 +49,7 @@
 #include <netinet/tcp.h>
 #include <sys/epoll.h>
 #include <sys/prctl.h>
+#include <sys/resource.h>
 #include <sys/socket.h>
 #include <unistd.h>
 
@@ -43,6 +59,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <iostream>
 #include <map>
 #include <string>
 #include <thread>
@@ -125,6 +142,7 @@ struct Options {
   int port = 0, frame_keys = 4096, conns = 4, inflight = 4, slices = 1;
   int threads = 0;     // min(MAX_THREADS, conns)
   int top_ranks = 0;   // min(TOP_RANKS, keys)
+  int await_start = 0;
   uint32_t keys = 0, cost_n = 1;
   uint64_t seed = 1, id_base = 0, dump_ids = 0;
   double rate = 0, zipf_s = 1.1, start_at = 0, warmup = 3, seconds = 10, drain = 2;
@@ -518,6 +536,7 @@ bool parse(int argc, char** argv, Options* o) {
   take("port", &o->port, i32); take("frame-keys", &o->frame_keys, i32);
   take("conns", &o->conns, i32);
   take("inflight", &o->inflight, i32); take("slices", &o->slices, i32);
+  take("await-start", &o->await_start, i32);
   take("keys", &o->keys, u32); take("cost-n", &o->cost_n, u32);
   take("seed", &o->seed, u64); take("id-base", &o->id_base, u64);
   take("dump-ids", &o->dump_ids, u64);
@@ -532,9 +551,24 @@ bool parse(int argc, char** argv, Options* o) {
             (o->loop == "closed" || o->loop == "open") &&
             (o->arrival == "poisson" || o->arrival == "uniform") &&
             (o->loop == "closed" ? o->inflight > 0 : o->rate > 0) &&
-            (o->dump_ids > 0 || o->port > 0);
+            (o->dump_ids > 0 || (o->await_start ? o->port == 0 && o->start_at == 0
+                                                : o->port > 0));
   o->top_ranks = (int)std::min<uint32_t>(TOP_RANKS, o->keys);
   return ok;
+}
+
+// Peak resident set of this process so far (Linux: ru_maxrss is in KiB).
+uint64_t peak_rss_bytes() {
+  rusage ru{};
+  getrusage(RUSAGE_SELF, &ru);
+  return (uint64_t)ru.ru_maxrss * 1024;
+}
+
+int late(const Options& o, double build_s, const char* what) {
+  std::fprintf(stderr, "loadgen: %s: the tables for %u keys took %.3f s to build; "
+               "no window is measured that the generator was not sending in\n",
+               what, o.keys, build_s);
+  return 4;
 }
 
 }  // namespace
@@ -545,7 +579,8 @@ int main(int argc, char** argv) {
     std::fprintf(stderr, "usage: see the head of chipbench/loadgen/loadgen.cpp\n");
     return 2;
   }
-  const Options& o = sh.o;
+  Options& o = sh.o;
+  const double t_build0 = now_s();
   sh.zipf.build(o.keys, o.zipf_s);
   sh.perm.resize(o.keys);
   for (uint32_t i = 0; i < o.keys; ++i) sh.perm[i] = i;
@@ -567,11 +602,34 @@ int main(int argc, char** argv) {
     return 0;
   }
 
-  sh.t_start = o.start_at > 0 ? o.start_at : now_s() + 0.2;
+  const double t_ready = now_s(), build_s = t_ready - t_build0;
+  if (o.await_start) {
+    std::printf("{\"line\": \"ready\", \"keys\": %u, \"build_s\": %.6f, \"t_ready\": %.6f, "
+                "\"peak_rss_bytes\": %llu}\n", o.keys, build_s, t_ready,
+                (unsigned long long)peak_rss_bytes());
+    std::fflush(stdout);
+    std::string given;
+    std::getline(std::cin, given);
+    if (std::sscanf(given.c_str(), "%lf %d", &sh.t_start, &o.port) != 2 ||
+        sh.t_start <= 0 || o.port <= 0) {
+      std::fprintf(stderr, "loadgen: --await-start: no \"<T> <port>\" on stdin\n");
+      return 2;
+    }
+    if (now_s() > sh.t_start) return late(o, build_s, "the start instant given on stdin is past");
+  } else {
+    sh.t_start = o.start_at > 0 ? o.start_at : t_ready + 0.2;
+    if (t_ready > sh.t_start + o.warmup)
+      return late(o, build_s, "ready after --start-at + --warmup");
+  }
   sh.t_win0 = sh.t_start + o.warmup;
   sh.t_win1 = sh.t_win0 + o.seconds;
   sh.t_end = sh.t_win1 + o.drain;
   sh.n_slices_s = std::max(1, (int)std::ceil(o.seconds - 1e-9));
+  if (o.await_start) {
+    std::printf("{\"line\": \"schedule\", \"t_start\": %.6f, \"t_window_start\": %.6f, "
+                "\"t_window_end\": %.6f}\n", sh.t_start, sh.t_win0, sh.t_win1);
+    std::fflush(stdout);
+  }
 
   std::vector<Worker*> workers;
   for (int i = 0; i < o.threads; ++i) {
@@ -631,8 +689,10 @@ int main(int argc, char** argv) {
               o.loop.c_str(), o.lane.c_str(), (unsigned long long)o.seed, o.threads,
               o.conns, o.inflight, o.frame_keys, o.rate, o.keys, o.zipf_s,
               sum.io_failed ? "true" : "false");
-  std::printf("\"t_start\": %.6f, \"t_window_start\": %.6f, \"t_window_end\": %.6f, "
+  std::printf("\"build_s\": %.6f, \"t_ready\": %.6f, \"peak_rss_bytes\": %llu, "
+              "\"t_start\": %.6f, \"t_window_start\": %.6f, \"t_window_end\": %.6f, "
               "\"window_s\": %.17g, \"run_s\": %.17g, ",
+              build_s, t_ready, (unsigned long long)peak_rss_bytes(),
               sh.t_start, sh.t_win0, sh.t_win1, o.seconds, o.warmup + o.seconds);
   std::printf("\"sent\": %llu, \"sent_frames\": %llu, \"completed\": %llu, "
               "\"completed_frames\": %llu, \"allowed\": %llu, \"policy\": %llu, "

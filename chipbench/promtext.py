@@ -56,8 +56,7 @@ def mean_of(start: dict, end: dict, family: str, **labels):
 _POLICY = (("rate_limiter_requests_total", {"result": "fail_open"}),
            ("rate_limiter_server_slo_breach_decisions_total", {}),
            ("rate_limiter_breaker_short_circuits_total", {}))
-_ERRORS = (("rate_limiter_requests_total", {"result": re.compile("error:.*")}),
-           ("rate_limiter_storage_errors_total", {}))
+_ANY_ERROR = re.compile("error:.*")
 
 
 def policy_answered(samples: dict) -> float:
@@ -66,4 +65,19 @@ def policy_answered(samples: dict) -> float:
 
 
 def dispatch_errors(samples: dict) -> float:
-    return sum(total(samples, fam, **lab) for fam, lab in _ERRORS)
+    """Failed dispatches, each counted ONCE: the two families' sum less
+    the one failure both tell. ``MetricsDecorator._observe_error`` adds
+    one to ``requests_total{result="error:<kind>"}`` for every call that
+    raised and, where the kind is ``storage_unavailable``, one more to
+    ``storage_errors_total`` — so the plain sum read 2 for one error
+    frame (ledger, PR 47: ``dispatch_errors_unseen`` 1.0 for a frame the
+    generator had seen). Everything else either family holds is a failure
+    of its own and adds: an error of another kind (``requests_total``
+    alone), a dispatch answered by the fail-open policy
+    (``storage_errors_total`` alone, no error frame). Not the larger of
+    the two: one error frame of another kind and one fail-open dispatch
+    in the same run are two failures, and the larger read 1."""
+    requests = "rate_limiter_requests_total"
+    return (total(samples, requests, result=_ANY_ERROR)
+            + total(samples, "rate_limiter_storage_errors_total")
+            - total(samples, requests, result="error:storage_unavailable"))

@@ -7,7 +7,10 @@ program's ``python -m ratelimiter_tpu.serving --port 0 <server_flags>``
 unchanged, with the chip as its device), reads the device from the server's banner and
 refuses anything but a TPU, probes correctness, warms up with the cell's
 own traffic, measures, checks again, stops the child and prints the
-result object as the last line of stdout.
+result object as the last line of stdout. The generator is spawned
+BEFORE the server and builds its tables beside the server's start; once
+it has said ``ready`` and the probe is done it is given the port and the
+instant its warm-up starts.
 
 ``JAX_PLATFORMS=cpu`` set by the caller makes the run a rehearsal: the
 configuration's and the mix's ``rehearsal`` overrides (a tiny geometry),
@@ -50,6 +53,13 @@ PROFILE_S = 1.0 if REHEARSAL else 5.0   # the traced stretch of the window
 TRACED_MIN_S = 16.0        # a traced window holds profiler start-up + 5 s
 WARMUP_S = 3.0             # the cell's own traffic, counted as set-up
 DRAIN_S = 2.0              # open loop: a frame unanswered by then has failed
+START_LEAD_S = 0.3         # from the instant given to the start of warm-up
+#: The generator's tables (alias table, permutation) take 1.6 s at 20 M
+#: keys and ~9 s at 10^8 on a core of the chip's host: one that has not
+#: said ``ready`` this long after its spawn never will. A line said in
+#: time is taken whenever ``start`` comes to read it (the server's cold
+#: start between spawn and ``start`` is longer than this).
+READY_CEILING_S = 120.0
 #: /debug/profile answers 200 when the capture starts and then a space
 #: every 5 s until its body is ready (``jax.profiler.stop_trace()`` costs
 #: ~25 ms a captured program execution). A capture is waited for while
@@ -176,21 +186,107 @@ def build_loadgen(root: str = ROOT) -> tuple:
     return binary, time.monotonic() - t0
 
 
-def loadgen_args(cell: dict, port: int, seed: int, seconds: float,
-                 start_at: float) -> list:
+def loadgen_args(cell: dict, port: int | None, seed: int, seconds: float,
+                 start_at: float | None = None) -> list:
+    """The generator's options for ``cell``. Without ``start_at`` it is
+    told to build, say ``ready`` and wait for its schedule and the port on
+    its stdin (`Generator`; ``port`` is None); with one (a hand run) it
+    takes ``port`` and that instant, and exits 4 where its tables were not
+    ready by the end of the warm-up."""
+    assert (port is None) == (start_at is None)
     t, cfg = cell["traffic"], cell["config"]
     args = {
-        "port": port, "seed": seed, "lane": t["lane"],
+        "seed": seed, "lane": t["lane"],
         "frame-keys": t["frame_keys"], "conns": t["connections"],
         "loop": t["loop"], "inflight": t["inflight"],
         "arrival": t["arrival"], "zipf-s": t["zipf_s"],
         "keys": cfg["key_population"], "cost-n": t["cost_n"],
-        "slices": cell["chips"], "start-at": f"{start_at:.6f}",
+        "slices": cell["chips"],
         "warmup": WARMUP_S, "seconds": seconds, "drain": DRAIN_S,
     }
+    if start_at is None:
+        args["await-start"] = 1
+    else:
+        args["port"], args["start-at"] = port, f"{start_at:.6f}"
     if t["loop"] == "open":
         args["rate"] = t["rate"]
     return [x for k, v in args.items() for x in (f"--{k}", str(v))]
+
+
+class Generator:
+    """One generator process on a schedule it is GIVEN once its tables
+    are built. Spawning returns at once and needs no port: the build
+    runs beside whatever the caller does next (the server's start).
+    ``start`` waits for its ``ready`` line, gives it the server's port
+    and the instant warm-up starts (CLOCK_MONOTONIC, ``START_LEAD_S``
+    from now) and returns the schedule in the generator's own words;
+    ``result`` waits for its exit and returns its last line."""
+
+    def __init__(self, binary: str, cell: dict, seed: int, seconds: float):
+        self.keys = cell["config"]["key_population"]
+        self.seconds = seconds
+        self.t_spawn = time.monotonic()
+        self.proc = subprocess.Popen(
+            [binary] + loadgen_args(cell, None, seed, seconds),
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE)
+        self._buf = b""
+
+    def _line(self, kind: str, deadline: float) -> dict:
+        """The next stdout line, which must be a ``kind`` line."""
+        fd = self.proc.stdout.fileno()
+        while b"\n" not in self._buf:
+            # What is already in the pipe is read whatever the clock says:
+            # the ceiling is on the BUILD, and `start` is called a server's
+            # start (a cold compile: 140-630 s) after the spawn, long after
+            # a line that was written in time.
+            left = max(deadline - time.monotonic(), 0.0)
+            if not select.select([fd], [], [], left)[0]:
+                self.stop()
+                raise RunFailure(
+                    f"the load generator has not said {kind!r} "
+                    f"{time.monotonic() - self.t_spawn:.0f} s after its "
+                    f"spawn, building its tables for {self.keys} keys "
+                    f"(ceiling {READY_CEILING_S:g} s)")
+            chunk = os.read(fd, 65536)
+            if not chunk:                 # it has exited: say why
+                _, err = self.proc.communicate()
+                raise RunFailure(
+                    f"the load generator exited {self.proc.returncode} "
+                    f"before its {kind!r} line ({self.keys} keys): "
+                    f"{err.decode(errors='replace')[-2000:]}")
+            self._buf += chunk
+        line, self._buf = self._buf.split(b"\n", 1)
+        said = json.loads(line)
+        if said.get("line") != kind:
+            self.stop()
+            raise RunFailure(f"the load generator said {said!r} where its "
+                             f"{kind!r} line was due")
+        return said
+
+    def start(self, port: int) -> dict:
+        self._line("ready", self.t_spawn + READY_CEILING_S)
+        self.proc.stdin.write(
+            f"{time.monotonic() + START_LEAD_S:.6f} {port}\n".encode())
+        self.proc.stdin.flush()
+        return self._line("schedule", time.monotonic() + 10.0)
+
+    def result(self) -> dict:
+        try:
+            out, err = self.proc.communicate(
+                timeout=WARMUP_S + self.seconds + DRAIN_S + 60)
+        finally:
+            self.stop()
+        if self.proc.returncode != 0:
+            raise RunFailure(
+                f"the load generator exited {self.proc.returncode}: "
+                f"{err.decode(errors='replace')[-2000:]}")
+        return json.loads((self._buf + out).strip().splitlines()[-1])
+
+    def stop(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+        self.proc.wait()
 
 
 # -------------------------------------------------------------- the server
@@ -380,6 +476,39 @@ def holes(per_second: list) -> dict:
     return {"median_per_s": median, "seconds": slow, "held_s": len(slow)}
 
 
+def held_numbers(cfg: dict, probed: dict, gen: dict, cold: dict,
+                 policy: float, errors: float, served: int) -> list:
+    """Each number `correct` rests on: (name, value, its limit, what it
+    means when it is over). The probe stops at its first reply that
+    differs from the reference, so it counts 0 or 1; ``policy`` and
+    ``errors`` are the server's own counts (promtext), held against what
+    the generator saw."""
+    run_s = gen["run_s"] + DRAIN_S
+    cap = admitted_cap(cfg, run_s)
+    worst = max(gen["top_allowed"], default=0)
+    client_done = probed["sent"] + gen["all"]["completed"] + cold["sent"]
+    return [
+        ("probe_replies_differing", 0 if probed["sent"] else 1, 0, None),
+        ("hot_key_allowed_max", worst, cap,
+         f"a hot key was allowed {worst} times in {run_s:g} s; the rule "
+         f"admits at most {cap}"),
+        ("cold_false_deny_pct", cold["cold_false_deny_pct"], 1.0,
+         f"{cold['denied']} of {cold['sent']} never-seen keys denied"),
+        ("cold_policy_answers", cold["policy"], 0,
+         f"{cold['policy']} never-seen keys answered by policy"),
+        ("policy_answers_unseen", max(0, policy - gen["all"]["policy"]), 0,
+         f"server metrics: {policy:g} decisions answered by policy, the "
+         f"generator saw {gen['all']['policy']}"),
+        ("dispatch_errors_unseen",
+         max(0, errors - gen["all"]["error_frames"]), 0,
+         f"server metrics: {errors:g} dispatch errors, the generator saw "
+         f"{gen['all']['error_frames']}"),
+        ("decisions_server_short", max(0, client_done - served), 0,
+         f"the server counted {served} decisions, the client completed "
+         f"{client_done}"),
+    ]
+
+
 def result_line(correct: bool, gen: dict, metrics: dict, device: dict,
                 breakdown=None, compared=None) -> dict:
     """The one object the driver reads. ``compared`` (name -> (number,
@@ -430,53 +559,41 @@ def per_layer(cell: dict, sources: dict) -> dict:
     return out
 
 
-def drive(cell: dict, srv: Server, binary: str, seed: int, seconds: float,
-          trace: bool) -> tuple:
-    """Warm-up and window: one generator process on a schedule both sides
-    know (CLOCK_MONOTONIC). Traced, the window also holds the /metrics
-    scrapes at its two ends and the profile. Returns the generator's
-    JSON, the scrapes and what /debug/profile answered (None where the
-    server has no gateway: never in a traced run of ``run``)."""
-    start_at = time.monotonic() + 0.3
-    t_win0 = start_at + WARMUP_S
-    t_win1 = t_win0 + seconds
-    gen_proc = subprocess.Popen(
-        [binary] + loadgen_args(cell, srv.port, seed, seconds, start_at),
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+def drive(gen: Generator, srv: Server, trace: bool) -> tuple:
+    """Warm-up and window: the generator (spawned before the server, its
+    tables built beside the server's start) is given the port and its
+    start instant, and the window is where ITS schedule line puts it
+    (CLOCK_MONOTONIC, both sides'). Traced, the window also holds the
+    /metrics scrapes at its two ends and the profile. Returns the
+    generator's JSON, the scrapes and what /debug/profile answered (None
+    where the server has no gateway: never in a traced run of ``run``).
+    ``run`` stops the generator on every way out."""
+    schedule = gen.start(srv.port)
+    t_win0, t_win1 = schedule["t_window_start"], schedule["t_window_end"]
     box: dict = {}
     scrapes: dict = {}
     prof_thread = None
-    try:
-        if trace:
-            time.sleep(max(0.0, t_win0 - time.monotonic()))
-            with Wire(srv.port) as wire:
-                scrapes["start"] = (time.monotonic(), wire.metrics())
-            if srv.http_port:
-                prof_thread = threading.Thread(
-                    target=fetch_profile, args=(srv.http_port, box),
-                    daemon=True)
-                time.sleep(max(0.0, t_win0 + 1.0 - time.monotonic()))
-                prof_thread.start()
-            time.sleep(max(0.0, t_win1 - 0.05 - time.monotonic()))
-            with Wire(srv.port) as wire:
-                scrapes["end"] = (time.monotonic(), wire.metrics())
-        gen_out, gen_err = gen_proc.communicate(
-            timeout=WARMUP_S + seconds + DRAIN_S + 60)
-    finally:
-        if gen_proc.poll() is None:
-            gen_proc.kill()
-            gen_proc.wait()
-    if gen_proc.returncode != 0:
-        raise RunFailure(f"the load generator exited "
-                         f"{gen_proc.returncode}: {gen_err[-2000:]}")
+    if trace:
+        time.sleep(max(0.0, t_win0 - time.monotonic()))
+        with Wire(srv.port) as wire:
+            scrapes["start"] = (time.monotonic(), wire.metrics())
+        if srv.http_port:
+            prof_thread = threading.Thread(
+                target=fetch_profile, args=(srv.http_port, box),
+                daemon=True)
+            time.sleep(max(0.0, t_win0 + 1.0 - time.monotonic()))
+            prof_thread.start()
+        time.sleep(max(0.0, t_win1 - 0.05 - time.monotonic()))
+        with Wire(srv.port) as wire:
+            scrapes["end"] = (time.monotonic(), wire.metrics())
+    out = gen.result()
     profile = capture_of(box, prof_thread) if prof_thread is not None \
         else None
-    return json.loads(gen_out.strip().splitlines()[-1]), scrapes, profile
+    return out, scrapes, profile
 
 
 def run(args) -> int:
     cell = load_cell(args.workload)
-    cfg, traffic = cell["config"], cell["traffic"]
     trace = bool(args.trace)
     out_dir = os.path.join(HERE, "out",
                            f"{args.workload}-{args.seed}-{args.trace}")
@@ -488,6 +605,20 @@ def run(args) -> int:
         raise NoChip("no ratelimiter_tpu/ beside chipbench/: not a checkout "
                      "of the program")
     binary, build_s = build_loadgen()
+    # The generator builds its tables beside the server's start: 1.6 s at
+    # 20 M keys, ~9 s at 10^8, under the 25 s and more a server takes
+    # (the probe's 0.14 s would hide none of it).
+    generator = Generator(binary, cell, args.seed, seconds)
+    try:
+        return measure(args, cell, generator, out_dir, seconds, build_s)
+    finally:
+        generator.stop()
+
+
+def measure(args, cell: dict, generator: Generator, out_dir: str,
+            seconds: float, build_s: float) -> int:
+    """The run from the server's start to the result line."""
+    cfg, traffic, trace = cell["config"], cell["traffic"], bool(args.trace)
     failures: list = []
     t_server = time.monotonic()
     with serving(cell, out_dir, trace) as srv:
@@ -526,11 +657,11 @@ def run(args) -> int:
             except probe.CheckFailed as exc:
                 failures.append(str(exc))
                 probed = {"sent": 0}
-        probe_s = time.monotonic() - t_probe
+        t_probed = time.monotonic()
+        probe_s = t_probed - t_probe
 
-        # (5)+(6) warm-up and the window: one generator process.
-        gen, scrapes, profile = drive(cell, srv, binary, args.seed, seconds,
-                                      trace)
+        # (5)+(6) warm-up and the window, on the generator's schedule.
+        gen, scrapes, profile = drive(generator, srv, trace)
         with open(os.path.join(out_dir, "loadgen.json"), "w") as fh:
             json.dump(gen, fh)
         if trace:
@@ -560,38 +691,14 @@ def run(args) -> int:
     peak = max((d["peak_bytes_in_use"] or 0 for d in report["devices"]),
                default=0)
 
-    run_s = gen["run_s"] + DRAIN_S
-    cap = admitted_cap(cfg, run_s)
-    worst = max(gen["top_allowed"], default=0)
     policy, errors = promtext.policy_answered(samples), \
         promtext.dispatch_errors(samples)
     client_done = probed["sent"] + gen["all"]["completed"] + cold["sent"]
-    # Each number `correct` rests on, its limit, and what it means when it
-    # is over (the probe stops at its first reply that differs from the
-    # reference, so it counts 0 or 1).
-    held = [
-        ("probe_replies_differing", 0 if probed["sent"] else 1, 0, None),
-        ("hot_key_allowed_max", worst, cap,
-         f"a hot key was allowed {worst} times in {run_s:g} s; the rule "
-         f"admits at most {cap}"),
-        ("cold_false_deny_pct", cold["cold_false_deny_pct"], 1.0,
-         f"{cold['denied']} of {cold['sent']} never-seen keys denied"),
-        ("cold_policy_answers", cold["policy"], 0,
-         f"{cold['policy']} never-seen keys answered by policy"),
-        ("policy_answers_unseen", max(0, policy - gen["all"]["policy"]), 0,
-         f"server metrics: {policy:g} decisions answered by policy, the "
-         f"generator saw {gen['all']['policy']}"),
-        ("dispatch_errors_unseen",
-         max(0, errors - gen["all"]["error_frames"]), 0,
-         f"server metrics: {errors:g} dispatch errors, the generator saw "
-         f"{gen['all']['error_frames']}"),
-        ("decisions_server_short", max(0, client_done - served), 0,
-         f"the server counted {served} decisions, the client completed "
-         f"{client_done}"),
-    ]
+    held = held_numbers(cfg, probed, gen, cold, policy, errors, served)
     for _, value, limit, what in held[1:]:       # the probe said its own
         check(value <= limit, what, failures)
     compared = {name: (value, limit) for name, value, limit, _ in held}
+    worst, cap = compared["hot_key_allowed_max"]
     check(gen["completed"] > 0, "no decision completed in the window",
           failures)
     check(not gen["io_failed"], "a generator connection broke", failures)
@@ -613,10 +720,15 @@ def run(args) -> int:
         client_decisions=client_done, policy_answered=policy,
         dispatch_errors=errors, failures=failures)
     prewarm = prewarm_s.read({"server_log": server_log}) or 0.0
+    # gen_build_s rides under the server's start; what of it was still to
+    # come when the probe was done is gen_wait_s, which setup_s holds.
+    gen_wait_s = max(0.0, gen["t_ready"] - t_probed)
     say("setup", setup_s=setup_s, build_s=build_s,
         start_to_prewarm_s=start_s - prewarm, prewarm_s=prewarm,
-        probe_s=probe_s, warmup_s=WARMUP_S,
-        other_s=setup_s - build_s - start_s - probe_s - WARMUP_S)
+        probe_s=probe_s, gen_build_s=gen["build_s"], gen_wait_s=gen_wait_s,
+        gen_peak_rss_bytes=gen["peak_rss_bytes"], warmup_s=WARMUP_S,
+        other_s=setup_s - build_s - start_s - probe_s - gen_wait_s
+        - WARMUP_S)
 
     device = {**dev, "memory_peak_bytes": peak}
     correct = not failures

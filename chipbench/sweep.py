@@ -3,8 +3,9 @@
     python -m chipbench.sweep --workload wide-string-rpc --field rate \\
         --values 100000,200000,400000 --seconds 8
 
-For each value: the cell's generator with that field overridden, the
-run's 3 s of warm-up, ``--seconds`` of window. Prints one JSON line per value —
+For each value: the cell's generator with that field overridden, on the
+run's own schedule (`runner.Generator`: tables built, then 3 s of
+warm-up, ``--seconds`` of window). Prints one JSON line per value —
 completed decisions per second, latency, the generator's lateness, and
 the frames pending at the end of each second (a backlog that grows says
 the rate is above the knee). It is how the knee of an open-loop mix and
@@ -18,9 +19,7 @@ import argparse
 import json
 import os
 import shutil
-import subprocess
 import sys
-import time
 
 from chipbench import runner
 
@@ -43,16 +42,15 @@ def main() -> int:
         print(json.dumps({"banner": srv.banner}), flush=True)
         for i, raw in enumerate(args.values.split(",")):
             cell["traffic"][args.field] = kind(float(raw))
-            done = subprocess.run(
-                [binary] + runner.loadgen_args(
-                    cell, srv.port, args.seed + i, args.seconds,
-                    time.monotonic() + 0.3),
-                capture_output=True, text=True)
-            if done.returncode != 0:
-                print(json.dumps({args.field: raw, "failed": done.stderr[-500:]}),
+            generator = runner.Generator(binary, cell, args.seed + i,
+                                         args.seconds)
+            try:
+                generator.start(srv.port)
+                gen = generator.result()
+            except runner.RunFailure as exc:
+                print(json.dumps({args.field: raw, "failed": str(exc)[-500:]}),
                       flush=True)
                 continue
-            gen = json.loads(done.stdout.strip().splitlines()[-1])
             print(json.dumps({
                 args.field: cell["traffic"][args.field],
                 "offered_per_s": gen["sent"] / gen["window_s"],

@@ -12,7 +12,6 @@ SLOT_RMW = 2 * (8 + 8)
 
 
 def step_bytes(cfg: dict, batch: float, dispatches_per_s: float) -> float:
-    assert cfg["capacity"] >= cfg["key_population"]
     return batch * (DIRECTORY_PROBE + SLOT_RMW + WIRE_IN + WIRE_OUT)
 
 

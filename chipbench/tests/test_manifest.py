@@ -181,6 +181,24 @@ def check_readers_match(bench: dict, root: str) -> None:
         assert m.get("workloads", list(cells)) == on, m["name"]
 
 
+def listed_entry(reader, root: str = ROOT) -> tuple:
+    """(the reader's ``per_layer`` entry, the cells it applies to): the
+    entry found BY NAME, held to the reader's META, and its ``workloads``
+    to the reader's ``applies`` predicate over the manifest's cells as
+    they are today — never to the cells of some PR's day or to a place
+    in the list, which the next PR's mandatory append moves."""
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        manifest = json.load(fh)
+    entry = {m["name"]: m for m in manifest["per_layer"]}[reader.META["name"]]
+    for key in ("name", "unit", "better", "source", "layer", "moves"):
+        assert entry[key] == reader.META[key], (entry["name"], key)
+    cells = [w["name"] for w in manifest["workloads"]]
+    on = [n for n in cells
+          if reader.META["applies"](runner.load_cell(n, root))]
+    assert entry.get("workloads", cells) == on, entry["name"]
+    return entry, on
+
+
 def test_manifest_matches_the_reader_files(bench):
     check_readers_match(bench, ROOT)
 

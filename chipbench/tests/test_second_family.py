@@ -1,10 +1,11 @@
-"""A configuration of a second family — not a count-min sketch — fits the
-harness as FILES: a made-up ``--backend dense`` deployment with
-``byte_model: "table"`` and ``geometry_flags`` (data/second_family/),
-its byte model beside it on this test's own path. ``load_cell``, the
-manifest's checks and ``step_roofline`` on a recorded fixture take it;
-without a byte model it is refused in words. Nothing of it is listed in
-BENCHMARK.json."""
+"""A configuration of another family fits the harness as FILES: a made-up
+``--backend dense`` deployment with a byte model of its own
+(``byte_model: "ring"``) and ``geometry_flags`` (data/second_family/),
+its byte model beside it on this test's own path, laid BESIDE the real
+families — the sketches and, since PR 33, the real exact tables.
+``load_cell``, the manifest's checks and ``step_roofline`` on a recorded
+fixture take it; without a byte model it is refused in words. Nothing of
+it is listed in BENCHMARK.json, and it takes no name a real file has."""
 
 import json
 import os
@@ -20,7 +21,7 @@ from chipbench.layers import step_roofline
 from chipbench.tests import test_manifest, test_readers_old_program
 
 DATA = os.path.join(os.path.dirname(__file__), "data", "second_family")
-CELL = "exact-hashed-sat"
+CELL = "ring-hashed-sat"
 
 
 @pytest.fixture()
@@ -33,17 +34,17 @@ def family(tmp_path, monkeypatch):
         shutil.copytree(os.path.join(runner.HERE, sub),
                         tmp_path / "chipbench" / sub)
     before = {p: p.read_bytes() for p in tmp_path.rglob("*") if p.is_file()}
-    shutil.copy(os.path.join(DATA, "exact-tb.json"),
-                tmp_path / "chipbench" / "configs" / "exact-tb.json")
-    with open(os.path.join(DATA, "exact-tb.json")) as fh:
+    shutil.copy(os.path.join(DATA, "ring-tb.json"),
+                tmp_path / "chipbench" / "configs" / "ring-tb.json")
+    with open(os.path.join(DATA, "ring-tb.json")) as fh:
         cfg = json.load(fh)
     with open(os.path.join(runner.ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     bench["configs"].append({
-        "name": "exact-tb", "source": cfg["source"],
-        "file": "chipbench/configs/exact-tb.json",
+        "name": "ring-tb", "source": cfg["source"],
+        "file": "chipbench/configs/ring-tb.json",
         "reduced": cfg["reduced"], "why": "test data"})
-    bench["workloads"].append({"name": CELL, "config": "exact-tb",
+    bench["workloads"].append({"name": CELL, "config": "ring-tb",
                                "traffic": "hashed-sat", "chips": 1,
                                "why": "test data"})
     facts = {"name": CELL, "chips": 1, "config": cfg,
@@ -55,29 +56,32 @@ def family(tmp_path, monkeypatch):
                                      and applies[m["name"]](facts))):
             m["workloads"].append(CELL)
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    # chipbench/bytes_table.py as a later PR would add it: here it lives
+    # chipbench/bytes_ring.py as a later PR would add it: here it lives
     # under the test's data, put on the package's path.
     monkeypatch.setattr(chipbench, "__path__",
                         list(chipbench.__path__) + [DATA])
-    monkeypatch.delitem(sys.modules, "chipbench.bytes_table", raising=False)
+    monkeypatch.delitem(sys.modules, "chipbench.bytes_ring", raising=False)
     yield str(tmp_path), bench
     assert {p: p.read_bytes() for p in before} == before
-    sys.modules.pop("chipbench.bytes_table", None)
+    sys.modules.pop("chipbench.bytes_ring", None)
 
 
 def test_nothing_of_it_is_in_the_benchmark():
     with open(os.path.join(runner.ROOT, "BENCHMARK.json")) as fh:
         text = fh.read()
-    assert "exact-tb" not in text and "second_family" not in text
-    assert not os.path.exists(os.path.join(runner.HERE, "bytes_table.py"))
+    assert "ring-tb" not in text and "second_family" not in text
+    assert CELL not in text
+    assert not os.path.exists(os.path.join(runner.HERE, "bytes_ring.py"))
+    assert not os.path.exists(os.path.join(runner.HERE, "configs",
+                                           "ring-tb.json"))
 
 
 def test_load_cell_takes_it(family):
     root, _ = family
     cell = runner.load_cell(CELL, root)
-    assert cell["config"]["byte_model"] == "table"
+    assert cell["config"]["byte_model"] == "ring"
     assert "depth" not in cell["config"] and "width" not in cell["config"]
-    assert need.model_of(cell["config"]).__name__ == "chipbench.bytes_table"
+    assert need.model_of(cell["config"]).__name__ == "chipbench.bytes_ring"
 
 
 def test_the_manifests_checks_take_it(family):
@@ -110,7 +114,7 @@ def test_step_roofline_reads_through_its_own_model(family):
 ], ids=["no-model-no-depth", "model-without-module"])
 def test_without_its_model_it_is_refused_in_words(family, spoil, said):
     root, bench = family
-    path = os.path.join(root, "chipbench", "configs", "exact-tb.json")
+    path = os.path.join(root, "chipbench", "configs", "ring-tb.json")
     with open(path) as fh:
         cfg = json.load(fh)
     spoil(cfg)
@@ -141,7 +145,7 @@ def test_without_its_model_it_is_refused_in_words(family, spoil, said):
         "false-deny-unexplained", "snapshot-dir"])
 def test_what_holds_for_every_family_still_holds(family, spoil, said):
     root, bench = family
-    path = os.path.join(root, "chipbench", "configs", "exact-tb.json")
+    path = os.path.join(root, "chipbench", "configs", "ring-tb.json")
     with open(path) as fh:
         cfg = json.load(fh)
     spoil(cfg)

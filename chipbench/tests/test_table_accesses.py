@@ -7,6 +7,7 @@ import os
 
 from chipbench import promtext, runner
 from chipbench.layers import table_accesses_per_row as reader
+from chipbench.tests.test_manifest import listed_entry
 
 CELL = "wide-hashed-big"
 
@@ -50,18 +51,14 @@ def test_a_program_without_the_counters_gives_nothing_and_does_not_raise():
 def test_its_manifest_entry_and_where_it_applies():
     with open(os.path.join(runner.ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    entry = bench["per_layer"][-1]
+    # A predicate over the cell, never a list of names: closed loop, the
+    # windowed sketch, several frames a dispatch.
+    entry, on = listed_entry(reader)
     assert entry == {"name": "table_accesses_per_row", "unit": "runs/row",
                      "better": "lower", "source": "program_counter",
                      "layer": "device step", "moves": "decisions_per_s",
                      "workloads": [CELL]}
-    for key in ("name", "unit", "better", "source", "layer", "moves"):
-        assert reader.META[key] == entry[key]
-    # A predicate over the cell, never a list of names: closed loop, the
-    # windowed sketch, several frames a dispatch.
-    on = {w["name"]: reader.META["applies"](runner.load_cell(w["name"]))
-          for w in bench["workloads"]}
-    assert on == {name: name == CELL for name in on}
+    assert on == [CELL]
     big = runner.load_cell(CELL)
     flags = [a if a != "sketch" else "dense"
              for a in big["config"]["server_flags"]]

@@ -28,9 +28,10 @@ from chipbench.layers import (
     stage_us,
     unpack_us_per_dispatch,
 )
+from chipbench.tests.test_manifest import listed_entry
 from chipbench.tests.test_recorded_runs import sources_of
 
-#: The manifest's twelve entries, in its order.
+#: The twelve readers, in the order PR 37 listed them.
 READERS = (dispatcher_idle_us_per_dispatch,
            dispatcher_idle_us_per_dispatch_open,
            dispatcher_gather_us_per_dispatch,
@@ -205,19 +206,15 @@ def test_the_counters_split_the_rings_stages(run, cell, units):
 def test_their_manifest_entries_and_where_they_apply():
     with open(os.path.join(runner.ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    entries = bench["per_layer"][-len(READERS):]
-    assert [e["name"] for e in entries] == [r.META["name"] for r in READERS]
-    cells = {w["name"]: runner.load_cell(w["name"])
-             for w in bench["workloads"]}
-    reports = {m["name"]: m.get("workloads", list(cells))
+    cells = [w["name"] for w in bench["workloads"]]
+    reports = {m["name"]: m.get("workloads", cells)
                for m in bench["end_to_end"]}
-    for entry, reader in zip(entries, READERS):
-        for key in ("name", "unit", "better", "source", "layer", "moves"):
-            assert reader.META[key] == entry[key], (entry["name"], key)
+    entries = []
+    for reader in READERS:
         # A predicate over the cell, never a list of names: the cells it
         # applies to are the cells that report the metric it moves.
-        applies = [name for name, cell in cells.items()
-                   if reader.META["applies"](cell)]
-        assert applies == entry["workloads"] == reports[entry["moves"]]
+        entry, on = listed_entry(reader)
+        assert on == reports[entry["moves"]]
+        entries.append(entry)
     assert {e["source"] for e in entries[:-2]} == {"program_counter"}
     assert {e["source"] for e in entries[-2:]} == {"program_span"}
