@@ -84,22 +84,28 @@ def reclaim_above(w: int, pb: int) -> float:
 
 class DenseLimiter(HashedLane, RateLimiter):
     _lane_name = "dense"
+    _CKPT_KIND = "dense"
     #: The capacity may be a constructor argument the config lacks.
     state_from_config = False
 
     def __init__(self, config: Config, clock: Optional[Clock] = None,
-                 capacity: Optional[int] = None):
+                 capacity: Optional[int] = None, *, device=None):
+        """``device`` pins this limiter's columns, directory, staged
+        batches and override table to one ``jax.Device`` — the slice
+        seam of the slice-parallel tier (parallel/limiter.build_slices),
+        as ``SketchLimiter``'s: every program follows the committed
+        state, so N pinned limiters decide on N devices with no traffic
+        between them. None keeps the default device, byte for byte."""
         super().__init__(config, clock)
         # Import lazily so the exact backend works without JAX present.
-        from ratelimiter_tpu.ops import dense_kernels, directory
+        from ratelimiter_tpu.ops import directory
 
         self._capacity = int(capacity if capacity is not None
                              else self.config.dense.capacity)
-        self._device = None
+        self._device = device
         self._window_us = to_micros(self.config.window)
         self._install_steps(self.config)
-        self._state = dense_kernels.init_directory_state(self.config,
-                                                         self._capacity)
+        self._state = self._init_state()
         self._note_resident()
         self._lock = threading.Lock()
         self._init_staging()
@@ -129,6 +135,21 @@ class DenseLimiter(HashedLane, RateLimiter):
             window_scaling=True)
         self._policy_dev = None
         self._policy_dev_version = -1
+
+    def _init_state(self):
+        """A fresh table, built ON the pinned device (a slice's 2 GB never
+        pass through the default one) and committed there."""
+        import jax
+
+        from ratelimiter_tpu.ops import dense_kernels
+
+        if self._device is None:
+            return dense_kernels.init_directory_state(self.config,
+                                                      self._capacity)
+        with jax.default_device(self._device):
+            state = dense_kernels.init_directory_state(self.config,
+                                                       self._capacity)
+        return jax.device_put(state, self._device)
 
     # ------------------------------------------------ compiled programs
 
@@ -405,7 +426,7 @@ class DenseLimiter(HashedLane, RateLimiter):
                       "state_dir_keys": self._dir_keys()}
             arrays.update(self._policy_table.snapshot_arrays())
             extra = {"saved_at": self.clock.now(), "capacity": self._capacity}
-        return "dense", arrays, extra
+        return self._CKPT_KIND, arrays, extra
 
     def restore(self, path: str) -> None:
         """Replace device state and directory with the snapshot.
@@ -421,7 +442,7 @@ class DenseLimiter(HashedLane, RateLimiter):
         from ratelimiter_tpu.ops import dense_kernels, directory
 
         self._check_open()
-        arrays, meta = load_state(path, "dense", self.config)
+        arrays, meta = load_state(path, self._CKPT_KIND, self.config)
         if meta.get("capacity") != self._capacity:
             raise CheckpointError(
                 f"{path}: snapshot capacity {meta.get('capacity')} != "

@@ -7,7 +7,10 @@ the algorithm (Config.algorithm) and the state backend:
 
 * ``exact``  — host dict, exact semantics, the oracle (algorithms/exact.py).
 * ``dense``  — JAX device arrays, a state row a key behind a device-resident
-  key directory, batched kernels on the hashed, pipelined lane.
+  key directory, batched kernels on the hashed, pipelined lane. With
+  ``Config.mesh.devices`` (or the ``n_devices`` kwarg) set: one such table
+  per chip, a key's row on the chip that owns its hash (the slice-parallel
+  tier with exact slices; host router only).
 * ``sketch`` — count-min sketch + sub-window decay on device; approximate,
   unbounded keys (the BASELINE.json north star).
 * ``mesh``   — slice-parallel serving over every visible device (ADR-012):
@@ -43,6 +46,18 @@ def create_limiter(
 
         return ExactLimiter(config, clock)
     if backend == "dense":
+        if config.mesh.devices is not None or "n_devices" in kwargs:
+            if config.mesh.router == "collective":
+                # The routed step neither donates its state nor may
+                # select between an old and a new 2 GB leaf
+                # (ops/route_kernels.py): sketch slices only.
+                raise InvalidConfigError(
+                    "the collective router cannot carry dense slices; "
+                    "use router='host'")
+            from ratelimiter_tpu.parallel.limiter import SlicedMeshLimiter
+
+            return SlicedMeshLimiter(config, clock, backend="dense",
+                                     **kwargs)
         from ratelimiter_tpu.algorithms.dense import DenseLimiter
 
         return DenseLimiter(config, clock, **kwargs)

@@ -136,6 +136,12 @@ STAGES = (
     # The second half of what "fetch" was (resolve, completer's thread):
     "unpack",     # np.asarray returned -> BatchResult's NumPy columns
                   # built (unpack_window / unpack_bucket on [:b])
+    # Before the server serves (serving/__main__._prewarm), a thread a
+    # dispatch target, ``shard`` the target's place among them:
+    "prewarm",    # every pad shape of both lanes compiled (or read from
+                  # the compile cache) and run once on that target's
+                  # device; the slices' spans overlap when they warm
+                  # side by side
 )
 _STAGE_CODE: Dict[str, int] = {s: i for i, s in enumerate(STAGES) if s}
 

@@ -166,33 +166,48 @@ class MeshTokenBucketLimiter(_MeshPlacement, SketchTokenBucketLimiter):
 #                      slice-parallel serving tier
 # ===================================================================
 
+#: What a slice may be: the backends whose single-chip limiter takes a
+#: ``device=`` (``create_limiter``'s names for them).
+SLICE_BACKENDS = ("sketch", "dense")
+
+
 def build_slices(config: Config, clock: Optional[Clock] = None, *,
+                 backend: str = "sketch",
                  n_devices: Optional[int] = None,
-                 devices: Optional[Sequence] = None) -> List[SketchLimiter]:
+                 devices: Optional[Sequence] = None) -> List[HashedLane]:
     """One device-pinned single-chip limiter per device (the slices of
     ``SlicedMeshLimiter``; the native front door mounts them directly as
-    its dispatch shards, ADR-012). Token-bucket configs get the sketched
-    token bucket, everything else the windowed sketch — the same
-    algorithm selection as ``create_limiter(backend="sketch")``."""
+    its dispatch shards, ADR-012) — the ONE place a slice's class is
+    chosen, from the backend and the algorithm as ``create_limiter``
+    chooses a single limiter's: ``sketch`` gives token-bucket configs
+    the sketched token bucket and everything else the windowed sketch,
+    ``dense`` the exact table (a slice holds ``config.dense.capacity``
+    entries, as a sketch slice is ``config.sketch.width`` wide)."""
     import jax
 
+    from ratelimiter_tpu.core.errors import InvalidConfigError
+
+    if backend not in SLICE_BACKENDS:
+        raise InvalidConfigError(
+            f"no device slices of backend {backend!r}; expected one of "
+            f"{SLICE_BACKENDS}")
     if devices is None:
         devices = jax.devices()
     n = n_devices if n_devices is not None else config.mesh.devices
     if n is not None:
         if n < 1:
-            from ratelimiter_tpu.core.errors import InvalidConfigError
-
             raise InvalidConfigError(
                 f"mesh needs at least 1 device, got {n}")
         if n > len(devices):
-            from ratelimiter_tpu.core.errors import InvalidConfigError
-
             raise InvalidConfigError(
                 f"mesh wants {n} devices but only {len(devices)} are "
                 f"visible (XLA_FLAGS=--xla_force_host_platform_device_"
                 f"count=N on CPU)")
         devices = list(devices)[:n]
+    if backend == "dense":
+        from ratelimiter_tpu.algorithms.dense import DenseLimiter
+
+        return [DenseLimiter(config, clock, device=d) for d in devices]
     cls = (SketchTokenBucketLimiter
            if config.algorithm is Algorithm.TOKEN_BUCKET else SketchLimiter)
     # Hierarchy scopes on a hash-partitioned mesh: each slice enforces an
@@ -222,9 +237,11 @@ class MeshDispatchTicket(DispatchTicket):
 class SlicedMeshLimiter(RateLimiter):
     """Slice-parallel serving limiter (``--backend mesh``, ADR-012).
 
-    One independent single-chip limiter (windowed sketch or sketched
-    token bucket, per ``config.algorithm``) is pinned to each of the
-    mesh's devices; every key is routed to its OWNING slice by hash:
+    One independent single-chip limiter (``build_slices``: windowed
+    sketch or sketched token bucket per ``config.algorithm``, or with
+    ``backend="dense"`` the exact table — ``--backend dense
+    --mesh-devices N``) is pinned to each of the mesh's devices; every
+    key is routed to its OWNING slice by hash:
 
     * pre-hashed keys (``allow_hashed``/``launch_hashed``): owner =
       ``h64 % n_slices``;
@@ -250,14 +267,23 @@ class SlicedMeshLimiter(RateLimiter):
     pipelined = True
 
     def __init__(self, config: Config, clock: Optional[Clock] = None, *,
+                 backend: str = "sketch",
                  n_devices: Optional[int] = None,
                  devices: Optional[Sequence] = None):
         super().__init__(config, clock)
-        self.slices = build_slices(self.config, self.clock,
+        self.slices = build_slices(self.config, self.clock, backend=backend,
                                    n_devices=n_devices, devices=devices)
         self.n_slices = len(self.slices)
         self._CKPT_KIND = f"mesh:{self.slices[0]._CKPT_KIND}"
-        self._seed = self.config.sketch.seed
+        self._backend = backend
+        if backend == "dense":
+            # Exact slices: the composite answers for their directories
+            # as one (what MetricsDecorator and the door's control lane
+            # look for; a sketch mesh has none of the three, as a sketch
+            # limiter has none).
+            self.prune = self._prune
+            self.key_count = self._key_count
+            self.directory_stats = self._directory_stats
         #: Failure-domain isolation (ADR-015, opt-in via
         #: ``MeshSpec.quarantine``): every slice is wrapped in a
         #: SliceGuard enforcing a per-slice dispatch deadline and
@@ -591,6 +617,23 @@ class SlicedMeshLimiter(RateLimiter):
 
         self.config = replace(self.config, window=float(new_window))
 
+    # The exact slices' directory controls (attached by ``__init__`` for
+    # ``backend="dense"``; a sketch slice holds no key and has none):
+    # every slice, each pass under its own lane's lock.
+
+    def _prune(self, now: Optional[float] = None) -> int:
+        self._check_open()
+        return sum(s.prune(now) for s in self.slices)
+
+    def _key_count(self) -> int:
+        return sum(s.key_count() for s in self.slices)
+
+    def _directory_stats(self) -> dict:
+        """The slices' ``DenseLimiter.directory_stats``, summed: the
+        host's entries and capacity, every count cumulative."""
+        stats = [s.directory_stats() for s in self.slices]
+        return {k: sum(st[k] for st in stats) for k in stats[0]}
+
     # Policy overrides apply on EVERY slice (idempotent for non-owners —
     # their copy is simply never queried for the key), the same rule as
     # the native door's shard router; reads route to the owner.
@@ -678,6 +721,17 @@ class SlicedMeshLimiter(RateLimiter):
 
     # ------------------------------------------------- checkpoint seam
 
+    def _check_combined(self) -> None:
+        """The combined snapshot is the sketch slices' (re-bucketing onto
+        another slice count is sketch arithmetic, parallel/reshard.py):
+        a mesh of exact slices claims no durability and says so."""
+        if self._backend == "dense":
+            raise CheckpointError(
+                f"no combined snapshot of {self._CKPT_KIND} slices: an "
+                f"exact slice's entries sit where its own directory put "
+                f"them and cannot be re-bucketed; save and restore each "
+                f"slice by itself (sub_limiters())")
+
     def capture_state(self):
         """One combined snapshot over every slice: each slice captures
         under its own lock (device→host only — the persistence tier
@@ -688,6 +742,7 @@ class SlicedMeshLimiter(RateLimiter):
         REFUSES a different count — slice counters are only meaningful
         under the routing that produced them."""
         self._check_open()
+        self._check_combined()
         arrays = {}
         extras = []
         for i, s in enumerate(self.slices):
@@ -712,6 +767,7 @@ class SlicedMeshLimiter(RateLimiter):
         from ratelimiter_tpu.checkpoint import load_state
 
         self._check_open()
+        self._check_combined()
         arrays, meta = load_state(path, self._CKPT_KIND, self.config)
         saved = int(meta.get("n_slices", -1))
         if saved != self.n_slices:
@@ -743,6 +799,7 @@ class SlicedMeshLimiter(RateLimiter):
         from ratelimiter_tpu.checkpoint import load_state
 
         self._check_open()
+        self._check_combined()
         if not 0 <= index < self.n_slices:
             raise CheckpointError(
                 f"restore_slice: slice {index} out of range "

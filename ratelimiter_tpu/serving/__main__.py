@@ -71,7 +71,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--mesh-devices", type=int, default=None,
                     help="--backend mesh: devices to span (default: all "
                          "visible; on CPU set XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=N)")
+                         "--xla_force_host_platform_device_count=N). "
+                         "--backend dense: mount one exact table per "
+                         "device, N of them, a key's row on the chip "
+                         "that owns its hash (host router only; unset = "
+                         "one table on the default device)")
     ap.add_argument("--router", default="host",
                     choices=["host", "collective"],
                     help="--backend mesh: how a mixed frame reaches its "
@@ -143,7 +147,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "bucket's three columns); a multiple of 128 "
                          "keeps a probe to one vector row. Size it to "
                          "about twice the active keys: rows whose key "
-                         "finds no entry are answered by --fail-open")
+                         "finds no entry are answered by --fail-open. "
+                         "With --mesh-devices N it is ONE chip's entries "
+                         "(as --sketch-width is a slice's width): about "
+                         "twice the active keys / N, since keys spread "
+                         "evenly by hash")
     ap.add_argument("--hh-slots", type=int, default=0,
                     help="heavy-hitter side table slots (0 = off; power "
                          "of two >= 16): promoted hot keys get exact "
@@ -967,9 +975,10 @@ def _lease_health(lease_mgr) -> dict:
     return {"leases": lease_mgr.status()} if lease_mgr is not None else {}
 
 
-def _prewarm(limiter, max_batch: int) -> None:
+def _prewarm(limiters, max_batch: int) -> None:
     """Compile every batch pad shape the serving tier can produce BEFORE
     accepting traffic, so no client request ever pays a jit compile.
+    ``limiters`` is one limiter or the list of a door's dispatch units;
     ``max_batch`` is the most rows a dispatch takes: the door's drain
     cap (native_server.batch_rule). Warmed are the powers of two up to
     it, PLUS one shape past it — the native door's coalescer cuts runs
@@ -979,20 +988,58 @@ def _prewarm(limiter, max_batch: int) -> None:
     r06 mixed-traffic collapse was exactly this: ragged coalesced runs
     overshooting max_batch by a slice landed multi-second XLA compiles
     on the hot path.) With the persistent compilation cache this is fast
-    on every start after the first. A sliced mesh limiter warms EVERY
-    device slice across the full shape range (a skewed frame can hand
-    any slice up to the whole batch, so partial per-slice warming would
-    leave compiles on the hot path)."""
-    import numpy as np
+    on every start after the first. Every device slice is warmed across
+    the full shape range (a skewed frame can hand any slice up to the
+    whole batch, so partial per-slice warming would leave compiles on
+    the hot path), the slices SIDE BY SIDE, a thread a slice: a device's
+    programs are compiled for that device, XLA compiles with the GIL
+    released, and four 2 GB tables warmed one after another would not
+    start inside the time a first run is allowed."""
+    from concurrent.futures import ThreadPoolExecutor
 
     from ratelimiter_tpu.observability.decorators import undecorated
 
     t0 = time.time()
+    if not isinstance(limiters, (list, tuple)):
+        limiters = [limiters]
     top = 2 * max_batch
-    targets = undecorated(limiter).sub_limiters()
-    for tgt in targets:
-        # The dense backend's directory holds a key per id it has seen.
-        keyed = hasattr(undecorated(tgt), "directory_stats")
+    targets = [tgt for lim in limiters
+               for tgt in undecorated(lim).sub_limiters()]
+    if len(targets) == 1:
+        _prewarm_slice(targets[0], 0, top)
+    else:
+        with ThreadPoolExecutor(len(targets),
+                                thread_name_prefix="prewarm") as pool:
+            # list(): a slice's failure is the start's.
+            list(pool.map(lambda it: _prewarm_slice(it[1], it[0], top),
+                          enumerate(targets)))
+    for lim in limiters:
+        und = undecorated(lim)
+        if hasattr(und, "prewarm_routed"):
+            # Collective router (ADR-024): the shard_map'd all_to_all
+            # step is its own compilation per pad shape, distinct from
+            # the per-slice kernels warmed above (those stay warm for
+            # the overflow/strict fallback path).
+            und.prewarm_routed(max_batch)
+    logging.getLogger("ratelimiter_tpu.serving").info(
+        "prewarmed pad shapes up to %d (%d dispatch target%s) in %.1fs",
+        top, len(targets), "s" if len(targets) != 1 else "",
+        time.time() - t0)
+
+
+def _prewarm_slice(tgt, index: int, top: int) -> None:
+    """One dispatch target's share of ``_prewarm``, under a ``prewarm``
+    span that names the slice: a capture of a start (or the ring) shows
+    whether the slices did compile side by side. A pad shape is warmed
+    by deciding it once on both lanes."""
+    import numpy as np
+
+    from ratelimiter_tpu.observability import tracing
+    from ratelimiter_tpu.observability.decorators import undecorated
+
+    # The dense backend's directory holds a key per id it has seen.
+    keyed = hasattr(undecorated(tgt), "directory_stats")
+    with tracing.span("prewarm", shard=index, batch=top):
         size = 8
         while True:
             size = min(size, top)
@@ -1015,17 +1062,6 @@ def _prewarm(limiter, max_batch: int) -> None:
             # Give up the made-up ids above (sent at now=0, so idle for
             # good) before the server serves.
             tgt.prune()
-    und = undecorated(limiter)
-    if hasattr(und, "prewarm_routed"):
-        # Collective router (ADR-024): the shard_map'd all_to_all step is
-        # its own compilation per pad shape, distinct from the per-slice
-        # kernels warmed above (those stay warm for the overflow/strict
-        # fallback path).
-        und.prewarm_routed(max_batch)
-    logging.getLogger("ratelimiter_tpu.serving").info(
-        "prewarmed pad shapes up to %d (%d dispatch target%s) in %.1fs",
-        top, len(targets), "s" if len(targets) != 1 else "",
-        time.time() - t0)
 
 
 def _configure_jax(args) -> None:
@@ -1140,8 +1176,27 @@ async def amain(args) -> None:
         raise SystemExit("--controller needs --tenants > 0")
     if (args.tenant or args.assign) and not cfg.hierarchy.enabled:
         raise SystemExit("--tenant/--assign need --tenants > 0")
-    if args.mesh_devices is not None and args.backend != "mesh":
-        raise SystemExit("--mesh-devices needs --backend mesh")
+    if args.mesh_devices is not None and args.backend not in ("mesh",
+                                                              "dense"):
+        raise SystemExit("--mesh-devices needs --backend mesh (sketch "
+                         "slices) or --backend dense (exact slices)")
+    # --backend dense over --mesh-devices chips: exact slices mounted as
+    # --backend mesh mounts sketch ones, behind the host router.
+    dense_mesh = args.backend == "dense" and args.mesh_devices is not None
+    sliced = args.backend == "mesh" or dense_mesh
+    if dense_mesh and args.router == "collective":
+        raise SystemExit(
+            "--router collective cannot carry --backend dense: the routed "
+            "step neither donates its state nor may select between an old "
+            "and a new table-sized leaf (ops/route_kernels.py), so every "
+            "frame would copy each chip's table. Leave --router at host")
+    if dense_mesh and args.snapshot_dir:
+        raise SystemExit(
+            "--snapshot-dir is not supported with --backend dense "
+            "--mesh-devices: an exact slice's snapshot cannot be "
+            "re-bucketed onto another slice count and the deployment "
+            "claims no durability; run one table (no --mesh-devices) "
+            "to snapshot it")
     if args.rebalance and not args.fleet_config:
         raise SystemExit("--rebalance needs --fleet-config (the "
                          "placement brain moves fleet ranges)")
@@ -1209,9 +1264,10 @@ async def amain(args) -> None:
                 t.start()
             else:
                 _arm_chaos()
-    if args.backend == "mesh" and args.shards > 1:
-        raise SystemExit("--backend mesh routes one dispatch shard per "
-                         "device; use --mesh-devices, not --shards")
+    if sliced and args.shards > 1:
+        raise SystemExit(f"--backend {args.backend} over device slices "
+                         "routes one dispatch shard per device; use "
+                         "--mesh-devices, not --shards")
     persist = None
     if cfg.persistence.enabled:
         from ratelimiter_tpu.persistence import PersistenceManager
@@ -1234,14 +1290,15 @@ async def amain(args) -> None:
     # shape under BOTH doors: the whole mesh is one dispatch shard and
     # each frame is one shard_map'd SPMD step, so mounting per-device
     # shards would defeat the point.
-    mesh_native = bool(args.backend == "mesh" and args.native
+    mesh_native = bool(sliced and args.native
                        and args.router != "collective")
     slices = None
     qmgr = None
     if mesh_native:
         from ratelimiter_tpu.parallel.limiter import build_slices
 
-        slices = build_slices(cfg)
+        slices = build_slices(
+            cfg, backend="dense" if dense_mesh else "sketch")
         if cfg.mesh.quarantine:
             # Native door failure domains (ADR-015): one guard per
             # mounted shard — the C++ shard router IS the slice router,
@@ -1282,10 +1339,8 @@ async def amain(args) -> None:
         args.max_batch, native=args.native,
         slo=bool(args.dispatch_timeout_ms))
     if args.backend != "exact" and not args.no_prewarm:
-        _prewarm(limiter, drain_rows)
-        if slices is not None:
-            for i, s in enumerate(slices[1:], start=1):
-                _prewarm(s, drain_rows)
+        _prewarm([limiter] + (slices[1:] if slices is not None else []),
+                 drain_rows)
     device_report = _device_report(
         args, slices if slices is not None else [limiter])
     # Live accuracy observatory (ADR-016): shadow-oracle auditor + SLO

@@ -216,16 +216,17 @@ class NativeRateLimitServer:
         from ratelimiter_tpu.observability.decorators import undecorated
 
         base = undecorated(limiter)
-        if shards > 1 and not getattr(base, "state_from_config", False):
-            # Clones are rebuilt from (config, clock) alone; backends with
-            # extra constructor state (e.g. the dense backend's capacity
-            # override) would silently diverge between shards.
-            raise ValueError(
-                "shards > 1 requires a sketch-family limiter (its state "
-                "is fully determined by the config)")
         if shard_limiters is not None:
             self._shard_limiters = list(shard_limiters)
         else:
+            if shards > 1 and not getattr(base, "state_from_config", False):
+                # Clones are rebuilt from (config, clock) alone; backends
+                # with extra constructor state (e.g. the dense backend's
+                # capacity override) would silently diverge between
+                # shards. Pre-built shard limiters are nobody's clones.
+                raise ValueError(
+                    "shards > 1 requires a sketch-family limiter (its "
+                    "state is fully determined by the config)")
             self._shard_limiters = [limiter]
             for i in range(1, shards):
                 # Clones rebuilt from (config, clock); ``shard_decorate(

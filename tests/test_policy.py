@@ -603,7 +603,10 @@ CONTROLS = ([f"{c}.reset" for c in _CONFIGS]
             + [f"{c}.rotate" for c in _CONFIGS if c != "bucket-c3"])
 #: The dense configurations' programs (ISSUE 43: the tool lowers them too,
 #: so that a change of the dense state's layout shows as these and no other).
-DENSE = [f"{c}.{p}" for c in ("exact-tb-1m", "exact-tb-20m", "exact-tb-ttl")
+#: ``exact-tb-mesh4``'s five are ONE chip's (``capacity_a_chip``, ISSUE 51:
+#: a slice's programs are a single table's).
+DENSE = [f"{c}.{p}" for c in ("exact-tb-1m", "exact-tb-20m", "exact-tb-ttl",
+                              "exact-tb-mesh4")
          for p in ("hashed", "premix", "reclaim", "forget", "clear_rem")]
 
 
@@ -658,7 +661,7 @@ class TestLoweredPrograms:
 
     def test_one_program_a_shape_as_before(self, lowered):
         assert sorted(lowered) == sorted(SERVING + CONTROLS + DENSE)
-        assert len(lowered) == 33 + 15
+        assert len(lowered) == 33 + 20
 
     @pytest.mark.parametrize("name", SERVING + CONTROLS + DENSE)
     def test_every_program_is_the_pinned_text(self, lowered, name):

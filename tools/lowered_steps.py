@@ -14,8 +14,10 @@ a ``--router collective`` config, the routed step over its one staged
 operand (four operands in a checkout before PR 45); for a ``--backend
 dense`` config (they live under configs/added/) the dense limiter's
 serving step on both lanes and its reclaim / forget / clear_rem controls
-(ISSUE 43). One ``<name>.mlir`` a program plus ``index.json`` (name ->
-jit module name, sha256). It reads only the limiter's placement hooks
+(ISSUE 43), at ONE chip's capacity where the file spans several
+(``capacity_a_chip``, ISSUE 51: a slice's programs are a single table's).
+One ``<name>.mlir`` a program plus ``index.json`` (name -> jit module
+name, sha256). It reads only the limiter's placement hooks
 and ``_step`` / ``_reset_step`` / ``_rollover``, which every checkout
 since PR 26 has, and the dense limiter's ``_reclaim_step`` /
 ``_forget_step`` / ``_clear_rem_step`` / ``_fresh`` (since PR 33).
@@ -82,7 +84,11 @@ def _dense_configs(repo: Path):
         yield path.stem, Config(
             algorithm=Algorithm(c["algorithm"]), limit=c["limit"],
             window=float(c["window_s"]),
-            dense=DenseParams(capacity=c["capacity"], lanes=c["lanes"],
+            # A file of several chips states the host's total beside
+            # what ONE chip's table holds: the programs are a chip's.
+            dense=DenseParams(capacity=c.get("capacity_a_chip",
+                                             c["capacity"]),
+                              lanes=c["lanes"],
                               probe_bound=c["probe_bound"]))
 
 
