@@ -7,8 +7,10 @@ What the serving doors and ``_prewarm`` call on a device-backed limiter —
 one mechanism under them: a reusable staging slot ``[ids | n | now_us]``
 placed by ONE ``device_put``, ONE jitted step that returns its state and
 one packed int32 result buffer, a ``DispatchTicket``, ONE fetch at
-resolve, and the always-on counts of both (``result_fetches``,
-``override_lookup_dispatches``).
+resolve, ONE native pass that rebuilds the reply's columns from it
+(``_unpack``; the format's NumPy twin where nothing can be built), and
+the always-on counts of all three (``result_fetches``,
+``result_native_unpacks``, ``override_lookup_dispatches``).
 
 The sketch family (algorithms/sketch.py) and the dense backend
 (algorithms/dense.py) are both built on it; neither copies it. A limiter
@@ -36,6 +38,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from ratelimiter_tpu import native
 from ratelimiter_tpu.core.clock import to_micros
 from ratelimiter_tpu.core.errors import StorageUnavailableError
 from ratelimiter_tpu.core.types import (
@@ -155,6 +158,13 @@ class HashedLane:
         # Dispatches launched while the override table held an entry
         # (override_lookup_dispatches).
         self._override_lookups = 0
+        # The native rebuild of this lane's result format — loaded (built,
+        # on a checkout's first start) HERE, where the lane is built, so
+        # that prewarm runs it and no served dispatch waits for g++; None
+        # on a host that cannot build it (the NumPy twin serves) — and
+        # the resolves it served (result_native_unpacks).
+        self._native_unpack = native.column_unpacker(self._result_format()[1])
+        self._native_unpacks = 0
 
     def _note_resident(self) -> None:
         """Reckon what ``state_resident_bytes`` reports. Called where the
@@ -250,7 +260,8 @@ class HashedLane:
                             limits = self._policy_limits(splitmix64(h64))
                         else:
                             limits = self._policy_limits(h64)
-                    self._inflight_mass += int(ns.sum())
+                    offered = int(ns.sum())
+                    self._inflight_mass += offered
                 launched = True
             finally:
                 # Any non-launch exit (injected failure, an answer at the
@@ -272,6 +283,7 @@ class HashedLane:
             t.limit = self.config.limit
             t.limits = limits
             t.ns = np.asarray(ns)
+            t.offered = offered
             t.now_us = now_us
             t.t_sec = t_sec
             t.slot = slot
@@ -304,7 +316,8 @@ class HashedLane:
         shard (lock held)."""
 
     def _retire_ticket(self, t: DispatchTicket, admitted: int,
-                       fetched: int = 0, tails=None) -> None:
+                       fetched: int = 0, tails=None,
+                       native: bool = False) -> None:
         """Once per launched ticket (t.slot is the sentinel): recycle the
         staging buffers — the step consumed the transfer once its result
         is ready (or failed) — and, in ONE lock acquisition, swap the
@@ -312,16 +325,18 @@ class HashedLane:
         pessimism for its actual admitted mass. A two-step swap would
         open a window where the batch counts as neither, letting a
         concurrent launch slip past the budget. ``fetched`` device
-        buffers join the always-on count under the same lock, and the
-        result's tail words are handed over there too."""
+        buffers and a ``native`` rebuild of the columns join the
+        always-on counts under the same lock, and the result's tail
+        words are handed over there too."""
         if t.slot is None:
             return
         self._release_staging(t.padded, t.slot)
         t.slot = None
         with self._lock:
-            self._inflight_mass -= int(t.ns.sum())
+            self._inflight_mass -= t.offered
             self._note_mass_locked(admitted, t.now_us)
             self._fetches += fetched
+            self._native_unpacks += native
             if tails is not None:
                 self._note_tail_locked(t, tails)
 
@@ -333,6 +348,14 @@ class HashedLane:
         step packs its result; a four-column result was four (seven
         underneath on a TPU, a 64-bit array being two buffers)."""
         return self._fetches
+
+    @property
+    def result_native_unpacks(self) -> int:
+        """Resolves whose columns the native pass built (cumulative,
+        always on): ``rate_limiter_result_native_unpacks_total``. One a
+        dispatch where the extension is loaded; 0 on a host that serves
+        from the NumPy twin."""
+        return self._native_unpacks
 
     @property
     def override_lookup_dispatches(self) -> int:
@@ -347,14 +370,25 @@ class HashedLane:
 
     def _unpack(self, words: np.ndarray, t: DispatchTicket,
                 shards: int = 1, tail: int = 0) -> tuple:
-        """``(BatchResult's four columns, each shard's tail words)`` from
-        the fetched result buffer of ticket ``t``."""
+        """``(BatchResult's four columns, each shard's tail words, the
+        admitted mass)`` from the fetched result buffer of ticket ``t``
+        — the ONE place a fetch becomes columns. One native call that
+        keeps the interpreter (native/hasher.cpp unpack_columns) where
+        the extension is loaded; else the format's NumPy twin over
+        ``result_rows``, and None for the mass: the caller that wants it
+        sums ``ns`` over the allowed rows itself."""
+        if self._native_unpack is not None:
+            per = words.shape[0] // shards
+            cols, admitted = self._native_unpack(
+                words, shards, tail, t.b, t.now_us, t.window_us, t.ns)
+            return (cols, words.reshape(shards, per)[:, per - tail:],
+                    admitted)
         from ratelimiter_tpu.ops import sketch_kernels
 
-        n_rows, unpack = self._result_format()
+        n_rows, twin = self._result_format()
         rows, tails = sketch_kernels.result_rows(words, n_rows,
                                                  shards=shards, tail=tail)
-        return unpack(rows, t.b, t.now_us, t.window_us), tails
+        return twin(rows, t.b, t.now_us, t.window_us), tails, None
 
     def _resolve_ticket(self, t: DispatchTicket) -> BatchResult:
         if t.result is not None:
@@ -371,13 +405,13 @@ class HashedLane:
             # "fetch": device ready -> np.asarray returned. ONE buffer
             # (a shard per device) comes over in one call that blocks
             # with the GIL released and then waits to get it back;
-            # "unpack": the rest, NumPy on [:b].
+            # "unpack": the rest, one pass over [:b].
             with tracing.span("fetch", batch=t.b,
                               trace_id=t.trace_id) as sp:
                 words = np.asarray(t.outs)
                 sp.next("unpack")
-                (allowed, remaining, retry, reset_at), tails = self._unpack(
-                    words, t, shards, tail)
+                (allowed, remaining, retry, reset_at), tails, admitted = \
+                    self._unpack(words, t, shards, tail)
         except BaseException:
             self._retire_ticket(t, 0)
             raise
@@ -394,8 +428,11 @@ class HashedLane:
             limits=t.limits,
             wire_packed=wire_packed,
         )
-        self._retire_ticket(t, int(t.ns[allowed].sum()), fetched=shards,
-                            tails=tails if tail else None)
+        native = admitted is not None
+        if not native:
+            admitted = int(t.ns[allowed].sum())
+        self._retire_ticket(t, admitted, fetched=shards,
+                            tails=tails if tail else None, native=native)
         t.result = res
         t.outs = None
         return res

@@ -206,7 +206,7 @@ class DispatchTicket:
     __slots__ = ("outs", "b", "limit", "limits", "ns", "now_us",
                  "window_us", "t_sec", "slot", "padded", "result", "meta",
                  "wire", "trace_id", "audit", "t_door", "t_lane",
-                 "unplaced")
+                 "unplaced", "offered")
 
     def __init__(self, result: "BatchResult | None" = None):
         self.outs = None        # the step's own output, on device: ONE
@@ -218,6 +218,8 @@ class DispatchTicket:
         self.limit = result.limit if result is not None else 0
         self.limits = None      # host per-request override limits (or None)
         self.ns = None          # host ns[:b] (admitted-mass accounting)
+        self.offered = 0        # sum(ns), summed once at launch: what the
+        #                         strict gate holds in flight until resolve
         self.now_us = 0         # with window_us (the step's own, as
         self.window_us = 0      # launched): what resolve rebuilds
         #                         retry_after / reset_at from

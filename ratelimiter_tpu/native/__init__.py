@@ -1,11 +1,12 @@
-"""Native host runtime: C++ bulk string hashing behind a ctypes seam.
+"""Native host runtime: C++ bulk string hashing behind a ctypes seam, and
+the resolve's rebuild of a dispatch's reply columns in one call.
 
 The decision hot path is JAX/XLA on device; the *host* hot path is turning
 string keys into u64 hashes at ingest (SURVEY.md §7.4 hard part #4). The
 reference pays a Redis round-trip per key so its host cost never shows; at
 10M+ decisions/s ours does, so hashing is native:
 
-* ``hasher.cpp``   — the C++ kernel, built into ``_hasher.so`` on first
+* ``hasher.cpp``   — the C++ kernels, built into ``_hasher.so`` on first
                      use and again whenever its bytes change
                      (``build.py``: the binary carries a hash of its
                      source);
@@ -13,6 +14,11 @@ reference pays a Redis round-trip per key so its host cost never shows; at
                      compiler;
 * this module      — packing (Python strings -> one contiguous byte buffer
                      + offsets/lengths) and dispatch.
+
+The second per-row pass of the host is at the other end of a dispatch:
+``column_unpacker`` binds ``hasher.cpp``'s ``unpack_columns`` to one of the
+packed result formats (the NumPy twins, which serve where nothing can be
+built, are the formats' own ``unpack_*`` functions in ``ops/``).
 
 pybind11 is deliberately not used (not in the image); the ABI is a C array
 call through ctypes — zero copies beyond the unavoidable UTF-8 encode.
@@ -24,7 +30,7 @@ import ctypes
 import functools
 import os
 import threading
-from typing import Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -132,3 +138,49 @@ def bulk_hash_u64(keys: Sequence[str], seed: int = DEFAULT_SEED) -> np.ndarray:
                                out.ctypes.data)
         return out
     return hash_packed(*pack_keys(keys), seed=seed)
+
+
+#: The packed result formats hasher.cpp's unpack_columns rebuilds, by the
+#: name of the NumPy twin a lane's ``_result_format()`` returns
+#: (sketch_kernels.unpack_window, bucket_kernels.unpack_bucket,
+#: dense_kernels.unpack_dense) -> the C++ ``Format``.
+_WINDOW_FORMAT = 0
+_UNPACK_FORMATS = {"unpack_window": _WINDOW_FORMAT, "unpack_bucket": 1,
+                   "unpack_dense": 2}
+
+
+def column_unpacker(twin: Callable) -> Optional[Callable]:
+    """``unpack(words, shards, tail, b, now_us, window_us, ns) ->
+    ((allowed, remaining, retry_after, reset_at), admitted)`` for the
+    packed format whose NumPy twin is ``twin``: BatchResult's four
+    columns, bit for bit the twin's over ``sketch_kernels.result_rows``
+    of the same fetch, and ``int(ns[allowed].sum())`` (0 for ``ns`` None),
+    built by ONE call that never lets go of the interpreter. None where
+    the extension cannot be built or the format is not one it knows —
+    the twin serves there. Loads (and on a checkout's first use builds)
+    the extension: call it where the lane is built, not on a dispatch."""
+    loaded = _load()
+    fmt = _UNPACK_FORMATS.get(getattr(twin, "__name__", None))
+    if loaded is None or fmt is None:
+        return None
+    unpack_columns = loaded[1].unpack_columns
+    windowed = fmt == _WINDOW_FORMAT
+
+    def unpack(words: np.ndarray, shards: int, tail: int, b: int,
+               now_us: int, window_us: int, ns: Optional[np.ndarray]):
+        # The scalars the windowed twins compute in Python, as they do.
+        if windowed:
+            reset_us = now_us // window_us * window_us + window_us
+            retry_denied = (reset_us - now_us) / 1e6
+        else:
+            reset_us, retry_denied = now_us + window_us, 0.0
+        cols = (np.empty(b, np.bool_), np.empty(b, np.int64),
+                np.empty(b, np.float64), np.empty(b, np.float64))
+        if ns is not None:
+            ns = np.ascontiguousarray(ns, dtype=np.int64)
+        admitted = unpack_columns(
+            fmt, np.ascontiguousarray(words, dtype=np.int32), shards, tail,
+            b, now_us, retry_denied, reset_us / 1e6, ns, *cols)
+        return cols, admitted
+
+    return unpack

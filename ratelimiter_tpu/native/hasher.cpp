@@ -1,4 +1,6 @@
-// Bulk 64-bit string hashing — the host-ingest hot path.
+// The host's two per-row passes: bulk 64-bit string hashing at ingest, and
+// the rebuild of a dispatch's reply columns at resolve (unpack_columns,
+// below the hasher).
 //
 // The reference ships raw string keys to Redis and lets the store hash them
 // (SURVEY.md §2.4.8); here keys are reduced to u64 on the host at ingest
@@ -125,9 +127,140 @@ static PyObject* hash_keylist(PyObject*, PyObject* args) {
   Py_RETURN_NONE;
 }
 
+// ------------------------------------------------- the resolve's columns
+//
+// A device step leaves ONE packed int32 buffer a shard (ops/sketch_kernels
+// .pack_rows: `rows` runs of `pl` words, then the shard's tail words);
+// resolve rebuilds BatchResult's four columns from it. unpack_columns is
+// that rebuild as one pass that holds the GIL from its first line to its
+// last — the NumPy twins (sketch_kernels.unpack_window, bucket_kernels
+// .unpack_bucket, dense_kernels.unpack_dense) are 4 to 14 array calls, each
+// of which lets go of the interpreter above 500 elements and waits to get
+// it back from the door's other threads. Bit for bit the twins' columns:
+// int32 -> int64 sign-extended, (double)int64 / 1e6 in IEEE float64, no
+// -ffast-math.
+
+namespace {
+
+enum Format { kWindow = 0, kBucket = 1, kDense = 2 };
+
+// sketch_kernels.join_words: the high word sign-extended, the low not.
+inline int64_t join_words(int32_t low, int32_t high) {
+  return static_cast<int64_t>(
+      (static_cast<uint64_t>(static_cast<int64_t>(high)) << 32) |
+      static_cast<uint32_t>(low));
+}
+
+// One shard's first n rows: w[r * pl + i] is word r of row i. Returns the
+// sum of ns over the allowed rows (wrapping, as NumPy's int64 sum does).
+template <Format F>
+uint64_t unpack_shard(const int32_t* w, int64_t pl, int64_t n, int64_t now_us,
+                      double retry_denied, double reset_all,
+                      const int64_t* ns, uint8_t* allowed, int64_t* remaining,
+                      double* retry, double* reset) {
+  uint64_t mass = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const bool ok = w[i] != 0;
+    allowed[i] = ok;
+    remaining[i] = w[pl + i];
+    if (F == kWindow) {
+      retry[i] = ok ? 0.0 : retry_denied;
+    } else {
+      retry[i] = static_cast<double>(join_words(w[2 * pl + i],
+                                                w[3 * pl + i])) / 1e6;
+    }
+    if (F == kDense) {
+      const uint64_t at = static_cast<uint64_t>(now_us) +
+          static_cast<uint64_t>(join_words(w[4 * pl + i], w[5 * pl + i]));
+      reset[i] = static_cast<double>(static_cast<int64_t>(at)) / 1e6;
+    } else {
+      reset[i] = reset_all;
+    }
+    if (ns != nullptr && ok) mass += static_cast<uint64_t>(ns[i]);
+  }
+  return mass;
+}
+
+constexpr int kFormatRows[] = {2, 4, 6};  // WINDOW_ROWS, BUCKET_ROWS, DENSE_ROWS
+
+// A buffer view released on every exit.
+struct View {
+  Py_buffer view{};
+  bool taken = false;
+  bool take(PyObject* obj, int flags) {
+    taken = PyObject_GetBuffer(obj, &view, flags) == 0;
+    return taken;
+  }
+  int64_t len() const { return static_cast<int64_t>(view.len); }
+  template <typename T> T* as() const { return static_cast<T*>(view.buf); }
+  ~View() { if (taken) PyBuffer_Release(&view); }
+};
+
+}  // namespace
+
+// unpack_columns(format, words, shards, tail, b, now_us, retry_denied,
+//                reset_all, ns | None, allowed, remaining, retry, reset)
+//   -> int, the sum of ns over the allowed rows (0 without ns)
+// words: the fetched int32[shards * (rows * pl + tail)]; the four outputs
+// are the caller's np.empty(b) arrays (bool, int64, float64, float64); ns
+// int64[b]. Row i of the batch is word i % pl of shard i / pl
+// (sketch_kernels.result_rows' order). retry_denied / reset_all are the
+// scalars the windowed formats' twins compute in Python; now_us is the
+// dense format's. Buffers, not addresses: every length is checked here.
+static PyObject* unpack_columns(PyObject*, PyObject* args) {
+  int format;
+  long long shards, tail, b, now_us;
+  double retry_denied, reset_all;
+  PyObject *words_obj, *ns_obj, *out_obj[4];
+  if (!PyArg_ParseTuple(args, "iOLLLLddOOOOO", &format, &words_obj, &shards,
+                        &tail, &b, &now_us, &retry_denied, &reset_all, &ns_obj,
+                        &out_obj[0], &out_obj[1], &out_obj[2], &out_obj[3])) {
+    return nullptr;
+  }
+  View words, ns, allowed, remaining, retry, reset;
+  if (!words.take(words_obj, PyBUF_SIMPLE) ||
+      (ns_obj != Py_None && !ns.take(ns_obj, PyBUF_SIMPLE)) ||
+      !allowed.take(out_obj[0], PyBUF_WRITABLE) ||
+      !remaining.take(out_obj[1], PyBUF_WRITABLE) ||
+      !retry.take(out_obj[2], PyBUF_WRITABLE) ||
+      !reset.take(out_obj[3], PyBUF_WRITABLE)) {
+    return nullptr;
+  }
+  if (format < kWindow || format > kDense || shards < 1 || tail < 0 || b < 0) {
+    PyErr_SetString(PyExc_ValueError, "unpack_columns: bad format or shape");
+    return nullptr;
+  }
+  const int64_t rows = kFormatRows[format];
+  const int64_t per = words.len() / 4 / shards;
+  const int64_t pl = (per - tail) / rows;
+  if (words.len() != shards * per * 4 || per != rows * pl + tail ||
+      b > shards * pl || (ns.taken && ns.len() != b * 8) ||
+      allowed.len() != b || remaining.len() != b * 8 ||
+      retry.len() != b * 8 || reset.len() != b * 8) {
+    PyErr_SetString(PyExc_ValueError,
+                    "unpack_columns: buffer lengths do not fit the format");
+    return nullptr;
+  }
+  uint64_t mass = 0;
+  for (int64_t s = 0, at = 0; at < b; ++s, at += pl) {
+    const int32_t* w = words.as<const int32_t>() + s * per;
+    const int64_t n = b - at < pl ? b - at : pl;
+    const int64_t* n_at = ns.taken ? ns.as<const int64_t>() + at : nullptr;
+    const auto pass = format == kWindow ? unpack_shard<kWindow>
+                      : format == kBucket ? unpack_shard<kBucket>
+                                          : unpack_shard<kDense>;
+    mass += pass(w, pl, n, now_us, retry_denied, reset_all, n_at,
+                 allowed.as<uint8_t>() + at, remaining.as<int64_t>() + at,
+                 retry.as<double>() + at, reset.as<double>() + at);
+  }
+  return PyLong_FromLongLong(static_cast<int64_t>(mass));
+}
+
 static PyMethodDef kMethods[] = {
     {"hash_keylist", hash_keylist, METH_VARARGS,
      "Hash a list of str into the uint64 buffer at out_addr."},
+    {"unpack_columns", unpack_columns, METH_VARARGS,
+     "BatchResult's four columns from a step's packed int32 buffer."},
     {nullptr, nullptr, 0, nullptr},
 };
 

@@ -433,7 +433,9 @@ class MetricsDecorator(LimiterDecorator):
         # scrape over rate_limiter_door_dispatches_total. Device->host
         # fetches (the resolve half of a dispatch): device buffers resolve
         # has asked the device for — 1 a dispatch since the step packs
-        # its result. Override lookups (the device step's one
+        # its result. Native unpacks: resolves whose reply columns the
+        # one native pass built (1 a dispatch; 0 on a host that serves
+        # from the NumPy twin). Override lookups (the device step's one
         # data-dependent branch): dispatches launched while the override
         # table held an entry, whose step ran the per-row binary search;
         # 0 on a deployment with no override.
@@ -444,6 +446,11 @@ class MetricsDecorator(LimiterDecorator):
                 "Device buffers resolve has fetched from the device "
                 "(cumulative): one per array leaf per addressable shard "
                 "of a dispatch's result")
+            self._native_unpacks_g = reg.gauge(
+                "rate_limiter_result_native_unpacks_total",
+                "Resolves whose reply columns the native pass built in "
+                "one call (cumulative); the rest were rebuilt by the "
+                "NumPy twin, on a host that cannot build the extension")
             self._override_g = reg.gauge(
                 "rate_limiter_override_lookup_dispatches_total",
                 "Dispatches launched while the per-key override table "
@@ -568,6 +575,8 @@ class MetricsDecorator(LimiterDecorator):
     def _collect_dispatch_counts(self) -> None:
         self._fetches_g.set(float(self._fetcher.result_fetches),
                             shard=self._shard)
+        self._native_unpacks_g.set(
+            float(self._fetcher.result_native_unpacks), shard=self._shard)
         self._override_g.set(
             float(self._fetcher.override_lookup_dispatches),
             shard=self._shard)

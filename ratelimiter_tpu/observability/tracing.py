@@ -134,8 +134,8 @@ STAGES = (
     "ascend",     # where "finish" closed -> the callback's last line:
                   # the decorators on the way up, depth lock, gauge, hist
     # The second half of what "fetch" was (resolve, completer's thread):
-    "unpack",     # np.asarray returned -> BatchResult's NumPy columns
-                  # built (unpack_window / unpack_bucket on [:b])
+    "unpack",     # np.asarray returned -> BatchResult's columns built
+                  # (HashedLane._unpack: one native pass over [:b])
     # Before the server serves (serving/__main__._prewarm), a thread a
     # dispatch target, ``shard`` the target's place among them:
     "prewarm",    # every pad shape of both lanes compiled (or read from

@@ -150,9 +150,11 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
         #: ``dispatches``.
         self.placements = 0
         self._fallbacks = {"overflow": 0, "strict": 0}
-        #: Device buffers this router's own resolve has fetched (the
-        #: slices count their own, result_fetches sums both).
+        #: Device buffers this router's own resolve has fetched, and the
+        #: resolves whose columns the native pass built (the slices count
+        #: their own; result_fetches / result_native_unpacks sum both).
         self._fetches = 0
+        self._native_unpacks = 0
         #: Frames this router launched while the override table held an
         #: entry (the slices count their own dispatches).
         self._override_lookups = 0
@@ -437,7 +439,7 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
                                   trace_id=trace_id) as sp:
                     words = np.asarray(ticket.outs)
                     sp.next("unpack")
-                    (allowed, remaining, retry, reset_at), tails = \
+                    (allowed, remaining, retry, reset_at), tails, mass = \
                         self.slices[0]._unpack(
                             words, ticket, shards,
                             route_kernels.ROUTED_TAIL)
@@ -457,6 +459,9 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
             self._release_slot(ticket)
         with self._stats_lock:
             self._fetches += shards
+            # The slices' masses ride in the tails; what _unpack summed
+            # only says which pass built the columns.
+            self._native_unpacks += mass is not None
         ticket.outs = None
         if tails[:, 2].any():
             # Bin overflow: the step left every state leaf untouched,
@@ -494,6 +499,10 @@ class CollectiveMeshLimiter(SlicedMeshLimiter):
     @property
     def result_fetches(self) -> int:
         return self._fetches + super().result_fetches
+
+    @property
+    def result_native_unpacks(self) -> int:
+        return self._native_unpacks + super().result_native_unpacks
 
     @property
     def override_lookup_dispatches(self) -> int:

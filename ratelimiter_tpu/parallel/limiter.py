@@ -859,6 +859,12 @@ class SlicedMeshLimiter(RateLimiter):
         return sum(s.result_fetches for s in self.slices)
 
     @property
+    def result_native_unpacks(self) -> int:
+        """Resolves whose columns the native pass built
+        (HashedLane.result_native_unpacks), summed over the slices."""
+        return sum(s.result_native_unpacks for s in self.slices)
+
+    @property
     def table_access_stats(self) -> dict:
         """The slices' own table-access counts
         (SketchLimiter.table_access_stats), summed."""

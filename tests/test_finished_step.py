@@ -21,6 +21,9 @@ columns on the host. Held here:
 """
 
 import contextlib
+import sys
+import threading
+import time
 from functools import partial
 
 import numpy as np
@@ -35,6 +38,7 @@ from ratelimiter_tpu import (
     DenseParams,
     ManualClock,
     SketchParams,
+    native,
 )
 from ratelimiter_tpu.algorithms.dense import DenseLimiter
 from ratelimiter_tpu.algorithms.sketch import (
@@ -335,6 +339,236 @@ def test_pack_and_unpack_are_inverse_on_the_extremes():
     assert rows.tolist() == [[0, 1, 2, 3, 100, 101, 102, 103],
                              [4, 5, 6, 7, 104, 105, 106, 107]]
     assert tails.tolist() == [[8, 9, 10], [108, 109, 110]]
+
+
+# --------------------------------------- the native rebuild and its twin
+#
+# Resolve rebuilds BatchResult's columns from the fetched words in ONE
+# native call (native/hasher.cpp unpack_columns, through
+# native.column_unpacker); the formats' NumPy functions are its twin —
+# what a host without a compiler serves from, and what the pass is held
+# to here, byte for byte.
+
+FORMATS = {"window": (sketch_kernels.WINDOW_ROWS,
+                      sketch_kernels.unpack_window),
+           "bucket": (bucket_kernels.BUCKET_ROWS,
+                      bucket_kernels.unpack_bucket),
+           "dense": (dense_kernels.DENSE_ROWS, dense_kernels.unpack_dense)}
+UNPACK_PAD = 8192
+NOW_US, WINDOW_US = 1_700_000_000_123_457, 60_000_000
+
+needs_native = pytest.mark.skipif(
+    not native.native_available(),
+    reason="no native extension on this host (no g++, or "
+           "RATELIMITER_TPU_NO_BUILD=1): the NumPy twin serves")
+
+
+def _join(rows, r, v):
+    """Write int64 ``v`` as the word pair (r, r + 1) of ``rows``."""
+    rows[r] = (v & 0xFFFFFFFF).astype(np.uint32).view(np.int32)
+    rows[r + 1] = (v >> 32).astype(np.int32)
+
+
+def _packed_words(fmt: str, values: str, pl: int, shards: int, tail: int,
+                  seed: int) -> np.ndarray:
+    """A fetch as the device leaves it — ``int32[shards * (rows * pl +
+    tail)]`` — filled with the named extreme."""
+    n_rows, _ = FORMATS[fmt]
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(shards):
+        rows = np.zeros((n_rows, pl), dtype=np.int32)
+        if values != "zeros":
+            rows[0] = rng.integers(0, 2, pl)
+        if values == "largest-limit":
+            rows[1] = (1 << 24) - 1 if fmt == "window" else 4_398_046
+            rows[1, ::3] = rng.integers(0, 1 << 22, pl)[::3]
+        elif values == "beyond-32-bits":
+            rows[1] = rng.integers(0, 1 << 22, pl)
+            for r in range(2, n_rows, 2):
+                big = rng.integers(1 << 32, 1 << 62, pl, dtype=np.int64)
+                big[:4] = [(1 << 32) - 1, 1 << 32, (1 << 32) + 1,
+                           (1 << 53) + 1]
+                _join(rows, r, big)
+        elif values == "negative-words":
+            # No step ships these; the format's arithmetic still has one
+            # answer for them (sign extension, a wrapping int64 sum).
+            rows[1:] = rng.integers(-(1 << 31), 1 << 31, (n_rows - 1, pl))
+            rows[1:, :2] = [[-1, -(1 << 31)]] * (n_rows - 1)
+        out.append(np.concatenate(
+            [rows.ravel(), rng.integers(0, 99, tail).astype(np.int32)]))
+    return np.concatenate(out)
+
+
+def _assert_native_equals_twin(fmt, words, shards, tail, b, ns):
+    n_rows, twin = FORMATS[fmt]
+    rows, tails = sketch_kernels.result_rows(words, n_rows, shards=shards,
+                                             tail=tail)
+    want = twin(rows, b, NOW_US, WINDOW_US)
+    got, admitted = native.column_unpacker(twin)(
+        words, shards, tail, b, NOW_US, WINDOW_US, ns)
+    for name, w, g in zip(("allowed", "remaining", "retry_after",
+                           "reset_at"), want, got):
+        assert g.dtype == w.dtype and g.shape == w.shape == (b,), name
+        assert g.tobytes() == w.tobytes(), name
+    assert [g.dtype for g in got] == [np.bool_, np.int64, np.float64,
+                                      np.float64]
+    assert type(admitted) is int
+    assert admitted == int(ns[want[0]].sum())
+
+
+@needs_native
+@pytest.mark.parametrize("values", ["zeros", "largest-limit",
+                                    "beyond-32-bits", "negative-words"])
+@pytest.mark.parametrize("b", [1, 8, 499, 501, 4096, UNPACK_PAD - 1,
+                               UNPACK_PAD])
+@pytest.mark.parametrize("fmt", list(FORMATS))
+def test_the_native_pass_builds_the_twins_columns_byte_for_byte(fmt, b,
+                                                                values):
+    """One shard, as every served dispatch of a slice fetches it: the
+    four columns' bytes and dtypes and the admitted mass, on both sides
+    of NumPy's 500-element threshold and of the padded size, with the
+    tail words each format's steps ship behind the rows."""
+    tail = {"window": 2, "bucket": 0, "dense": 4}[fmt]
+    words = _packed_words(fmt, values, UNPACK_PAD, 1, tail, seed=b)
+    ns = np.random.default_rng(b).integers(1, 1 << 40, b, dtype=np.int64)
+    _assert_native_equals_twin(fmt, words, 1, tail, b, ns)
+
+
+@needs_native
+@pytest.mark.parametrize("shards,b", [(2, 9), (4, 32), (4, 25), (8, 4000)])
+@pytest.mark.parametrize("fmt", list(FORMATS))
+def test_the_native_pass_reads_the_shards_in_batch_order(fmt, shards, b):
+    """A fetch of a shard a device (the replicated placement, the
+    collective router): rows in batch order over the shards, as
+    result_rows' transpose lays them; the tails stay views."""
+    pl = -(-b // shards) if b > 100 else 8
+    words = _packed_words(fmt, "beyond-32-bits", pl, shards, 3, seed=b)
+    ns = np.arange(1, b + 1, dtype=np.int64)
+    _assert_native_equals_twin(fmt, words, shards, 3, b, ns)
+    # Without ns (no caller wants the mass) the columns are the same.
+    _, twin = FORMATS[fmt]
+    unpack = native.column_unpacker(twin)
+    with_ns, _ = unpack(words, shards, 3, b, NOW_US, WINDOW_US, ns)
+    without, mass = unpack(words, shards, 3, b, NOW_US, WINDOW_US, None)
+    assert mass == 0
+    assert [c.tobytes() for c in with_ns] == [c.tobytes() for c in without]
+
+
+@needs_native
+def test_the_native_pass_refuses_buffers_that_do_not_fit():
+    """Every length is checked where the words are read: a short output,
+    a fetch that is no whole number of rows, more rows than the words
+    hold and an unknown format raise and write nothing out of bounds."""
+    unpack_columns = native._load()[1].unpack_columns
+    words = np.zeros(2 * 8 + 2, dtype=np.int32)
+    cols = lambda b: (np.empty(b, np.bool_), np.empty(b, np.int64),
+                      np.empty(b, np.float64), np.empty(b, np.float64))
+    ns = np.ones(8, dtype=np.int64)
+    assert unpack_columns(0, words, 1, 2, 8, 5, 0.5, 1.5, ns, *cols(8)) == 0
+    for args in ((0, words, 1, 2, 8, 5, 0.5, 1.5, ns, *cols(7)),
+                 (0, words, 1, 3, 8, 5, 0.5, 1.5, ns, *cols(8)),
+                 (0, words, 1, 2, 9, 5, 0.5, 1.5, None, *cols(9)),
+                 (0, words, 1, 2, 8, 5, 0.5, 1.5, ns[:7], *cols(8)),
+                 (3, words, 1, 2, 8, 5, 0.5, 1.5, ns, *cols(8)),
+                 (0, words, 0, 2, 8, 5, 0.5, 1.5, ns, *cols(8))):
+        with pytest.raises(ValueError):
+            unpack_columns(*args)
+    frozen = cols(8)
+    frozen[2].flags.writeable = False
+    with pytest.raises(ValueError, match="read-only"):
+        unpack_columns(0, words, 1, 2, 8, 5, 0.5, 1.5, ns, *frozen)
+    assert native.column_unpacker(len) is None      # not a format's twin
+
+
+def _decide(algo: str):
+    """A limiter of lane ``algo`` and the BatchResults of three batches
+    through it, the last past a window's boundary."""
+    clock = ManualClock(T0)
+    lim = _cls(algo)(_cfg(algo), clock)
+    ids = np.arange(1, 700, dtype=np.uint64) % 97
+    out = []
+    for dt in (0.0, 0.3, 61.0):
+        clock.set(T0 + dt)
+        out.append(lim.resolve(lim.launch_ids(
+            ids, np.full(ids.shape[0], 2, dtype=np.int64))))
+    return lim, out
+
+
+@pytest.mark.parametrize("algo", ["windowed", "bucket", "dense-bucket"])
+def test_a_host_without_the_extension_serves_from_the_twin(algo,
+                                                           monkeypatch):
+    """The lane's loader returning None is what native/build.py gives
+    without g++ or under RATELIMITER_TPU_NO_BUILD=1: a limiter built
+    there decides through the NumPy twin, the same BatchResult, and its
+    counter of native rebuilds stays 0."""
+    with monkeypatch.context() as m:
+        m.setattr(native, "_load", lambda: None)
+        plain, want = _decide(algo)
+    assert plain._native_unpack is None
+    assert plain.result_native_unpacks == 0 and plain.result_fetches == 3
+    plain.close()
+    if not native.native_available():
+        return
+    lim, got = _decide(algo)
+    assert lim.result_native_unpacks == lim.result_fetches == 3
+    for g, w in zip(got, want):
+        for col in ("allowed", "remaining", "retry_after", "reset_at"):
+            a, b = getattr(g, col), getattr(w, col)
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), col
+        assert g.limit == w.limit
+    lim.close()
+
+
+@needs_native
+def test_the_native_pass_never_lets_go_of_the_interpreter():
+    """No clock: with the switch interval raised past the test's length
+    nothing forces the GIL from a thread, so a second thread that only
+    counts under it advances only when this one lets go. It does not
+    across the native pass on 65,536 rows, and does across the NumPy
+    twin's calls on the same words (NumPy releases the GIL around a loop
+    of more than 500 elements)."""
+    b = 65_536
+    n_rows, twin = FORMATS["dense"]
+    words = _packed_words("dense", "beyond-32-bits", b, 1, 4, seed=1)
+    ns = np.ones(b, dtype=np.int64)
+    unpack = native.column_unpacker(twin)
+    rows, _ = sketch_kernels.result_rows(words, n_rows, tail=4)
+    count, stop = [0], threading.Event()
+
+    def counter():
+        while not stop.is_set():
+            count[0] += 1
+            time.sleep(0)           # hand the interpreter back at once
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(60.0)
+    thread = threading.Thread(target=counter, daemon=True)
+    try:
+        thread.start()
+        while count[0] == 0:        # the counter is running and waiting
+            time.sleep(0.001)
+        held = []
+        for _ in range(20):
+            before = count[0]
+            unpack(words, 1, 4, b, NOW_US, WINDOW_US, ns)
+            held.append(count[0] - before)
+        # The control: the same thread does advance when this one lets
+        # go. A release need not be long enough for the waiter to wake,
+        # so the twin is given a bounded number of calls to show one.
+        released = 0
+        for _ in range(500):
+            before = count[0]
+            twin(rows, b, NOW_US, WINDOW_US)
+            released += count[0] - before
+            if released:
+                break
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+        thread.join(timeout=10)
+    assert held == [0] * 20
+    assert released > 0
 
 
 # ------------------------------------------------- one launch, one fetch

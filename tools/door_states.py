@@ -14,7 +14,6 @@ only).
 import json
 import os
 import shutil
-import subprocess
 import sys
 import time
 
@@ -26,6 +25,7 @@ F = "rate_limiter_door_thread_seconds_total"
 C = "rate_limiter_door_thread_cpu_seconds_total"
 S = "rate_limiter_door_stage_seconds_total"
 N = "rate_limiter_door_dispatches_total"
+U = "rate_limiter_result_native_unpacks_total"
 
 
 def main() -> int:
@@ -39,25 +39,31 @@ def main() -> int:
     os.makedirs(out_dir)
     binary, _ = runner.build_loadgen()
     seconds = 20.0
-    with runner.serving(cell, out_dir, trace=False) as srv:
-        start_at = time.monotonic() + 0.3
-        gen = subprocess.Popen(
-            [binary] + runner.loadgen_args(cell, srv.port, seed, seconds,
-                                           start_at),
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-        t0 = start_at + runner.WARMUP_S
-        time.sleep(max(0.0, t0 - time.monotonic()))
-        with Wire(srv.port) as wire:
-            a_t, a = time.monotonic(), promtext.parse(wire.metrics())
-        time.sleep(max(0.0, t0 + seconds - 0.05 - time.monotonic()))
-        with Wire(srv.port) as wire:
-            b_t, b = time.monotonic(), promtext.parse(wire.metrics())
-        gen_out, gen_err = gen.communicate(timeout=120)
-    g = json.loads(gen_out.strip().splitlines()[-1])
+    # Spawned before the server: its tables (80 M keys: 7-20 s) are built
+    # beside the server's start, and the window is the one its own
+    # ``schedule`` line states (chipbench/runner.Generator, PR 50).
+    gen = runner.Generator(binary, cell, seed, seconds)
+    try:
+        with runner.serving(cell, out_dir, trace=False) as srv:
+            t0 = gen.start(srv.port)["t_window_start"]
+            time.sleep(max(0.0, t0 - time.monotonic()))
+            with Wire(srv.port) as wire:
+                a_t, a = time.monotonic(), promtext.parse(wire.metrics())
+            time.sleep(max(0.0, t0 + seconds - 0.05 - time.monotonic()))
+            with Wire(srv.port) as wire:
+                b_t, b = time.monotonic(), promtext.parse(wire.metrics())
+            g = gen.result()
+    finally:
+        gen.stop()
     n = promtext.delta(a, b, N)
     row = {"cell": sys.argv[1], "seed": seed, "extra": extra,
            "device": srv.device, "scrape_s": b_t - a_t, "dispatches": n,
            "decisions_per_s": g["completed"] / g["window_s"]}
+    row["rows_per_dispatch"] = row["decisions_per_s"] * row["scrape_s"] / n
+    # Which pass rebuilt the reply columns (PR 52): 1.0 native, 0.0 the
+    # NumPy twin; None on a program without the counter.
+    row["native_unpacks_per_dispatch"] = (
+        promtext.delta(a, b, U) / n if any(k == U for k, _ in b) else None)
     for thread, states in (("dispatcher", ("idle", "gather", "gil", "python",
                                            "slot", "other")),
                            ("completer", ("idle", "gil", "python", "other"))):
